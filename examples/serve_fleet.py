@@ -11,6 +11,12 @@ the replica whose cache already holds the longest prefix — so a
 session's turn-2 history re-enters the warm §20 prefill path instead of
 re-prefilling cold on whichever box round-robin picked.
 
+The worker processes run ON THE CPU whatever device this process sees
+(``spawn_fleet_workers`` sets ``JAX_PLATFORMS=cpu`` for them: a chip
+belongs to one process, so children of a parent that may hold it cannot
+have it). The result line's counts are exact; any time it reports is a
+CPU time. The fleet on four chips is ROADMAP R4.
+
 This task drives a deterministic multi-turn stream (S sessions x T
 turns, each turn extending the last) through a freshly spawned fleet
 and reports routing + warm-path outcomes as one JSON line::

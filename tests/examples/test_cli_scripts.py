@@ -77,25 +77,35 @@ def test_bench_config_resolution():
         resolve_bench_config(env={"ZK_BENCH_MODEL": "Model"})
 
 
-def test_bench_reachability_probe_cpu_noop():
-    """Under an explicitly-requested cpu backend (the test env), the
-    reachability probe is an instant no-op — it must neither run a
-    device op nor trip the silent-fallback detector."""
+def test_bench_platform_check(monkeypatch):
+    """The platform is tpu unless the CPU was asked for: under an
+    explicitly-requested cpu backend (the test env) the check passes;
+    a cpu backend nobody requested is refused."""
+    import jax
+
     check = _bench_attr("check_device_reachable")
-    check(timeout_s=30)  # Raises/exits on failure; returning is the pass.
+    check()  # Raises on failure; returning is the pass.
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    asked = jax.config.jax_platforms
+    jax.config.update("jax_platforms", None)
+    try:
+        with pytest.raises(RuntimeError, match="not 'tpu'"):
+            check()
+    finally:
+        jax.config.update("jax_platforms", asked)
 
 
 def test_bench_peak_resolution():
-    """The MFU anchor: env override wins; off-TPU the recorded v5e
-    fallback applies (measurement needs the real MXU)."""
+    """The MFU anchor: env override wins; a backend that is not a TPU
+    has no anchor (it is never rated against the v5e's peak)."""
     resolve_peak_flops = _bench_attr("resolve_peak_flops")
 
     peak, source = resolve_peak_flops(env={"ZK_BENCH_PEAK_FLOPS": "9e13"})
     assert (peak, source) == (9e13, "env")
 
     peak, source = resolve_peak_flops(env={})
-    # Tests force JAX_PLATFORMS=cpu, so the TPU measurement is skipped.
-    assert (peak, source) == (184e12, "fallback_v5e")
+    # Tests force JAX_PLATFORMS=cpu: no TPU, no anchor.
+    assert (peak, source) == (None, "unknown")
 
 
 def test_bench_compiler_options_resolution():
@@ -211,18 +221,19 @@ def test_bench_peak_datasheet_clamp():
 
 
 def test_bench_int8_peak_resolution():
-    """The second MFU anchor (int8 MXU): env override wins; off-TPU the
-    recorded v5e measurement applies."""
+    """The second MFU anchor (int8 MXU): env override wins; a backend
+    that is not a TPU has no anchor."""
     resolve = _bench_attr("resolve_int8_peak")
 
     peak, source = resolve(env={"ZK_BENCH_INT8_PEAK_FLOPS": "3.9e14"})
     assert (peak, source) == (3.9e14, "env")
 
     peak, source = resolve(env={})
-    # Tests force JAX_PLATFORMS=cpu, so the TPU measurement is skipped.
-    assert (peak, source) == (369e12, "fallback_v5e")
-    # The recorded fallback sits below the physical 2x-bf16 ceiling.
-    assert peak < 2.0 * 197e12
+    # Tests force JAX_PLATFORMS=cpu: no TPU, no anchor.
+    assert (peak, source) == (None, "unknown")
+    # The recorded v5e measurement sits below the physical 2x-bf16
+    # ceiling.
+    assert _bench_attr("INT8_PEAK_FALLBACK") < 2.0 * 197e12
 
 
 def test_lm_bench_records_flash_blocks_and_sp_degree():

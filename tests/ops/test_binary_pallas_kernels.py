@@ -71,8 +71,12 @@ def test_explicit_pallas_on_mxu_path_warns_and_degrades():
 # -- fused sign+pack producer ------------------------------------------------
 
 
-@pytest.mark.parametrize("m", [1, 3, 37, 96])
-@pytest.mark.parametrize("k", [32, 96, 416])
+@pytest.mark.parametrize(
+    "m,k",
+    [(m, k) for m in (1, 3, 37, 96) for k in (32, 96, 416)]
+    # Past one _PACK_CHUNK: the ragged second chunk of the static walk.
+    + [(5, 4096 + 64)],
+)
 def test_pack_rows_matches_pack_bits(m, k):
     rng = np.random.default_rng(m * 1000 + k)
     x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)

@@ -149,3 +149,66 @@ def test_pack_rows_block_aligned_and_bounded():
     # Bigger K -> fewer rows (budget-bound), floored at 32.
     assert blocks._default_pack_rows_block(10**6) == 32
     assert blocks._default_pack_rows_block(32) == 256
+
+
+# -- the scoped-VMEM rule ----------------------------------------------------
+
+
+def test_vmem_limit_rule_bounds():
+    """``vmem_limit_bytes``: never below Mosaic's own default (a small
+    kernel compiles as it did with no limit passed), never above the
+    cap, and half again the estimate in between."""
+    assert blocks.vmem_limit_bytes(0) == blocks._VMEM_DEFAULT_LIMIT
+    assert blocks.vmem_limit_bytes(8 << 20) == blocks._VMEM_DEFAULT_LIMIT
+    assert blocks.vmem_limit_bytes(26 << 20) == 39 << 20
+    assert blocks.vmem_limit_bytes(1 << 40) == blocks._VMEM_LIMIT_CAP
+    assert blocks._FLASH_VMEM_BUDGET * 3 // 2 == blocks._VMEM_LIMIT_CAP
+
+
+def test_no_policy_returns_a_block_past_the_limit_its_call_passes():
+    """Every auto block choice's estimate is under the ``vmem_limit_bytes``
+    the pallas_call passes for it — the policy and the compiler limit
+    are one rule. (Each policy has a smallest legal block it takes
+    without a check; the sweep stays where that floor itself fits.)"""
+    limit = blocks.vmem_limit_bytes
+
+    for s in (128, 999, 2048, 8192, 65536):
+        for d in (8, 64, 128, 256, 1024, 2048):
+            for itemsize in (2, 4):
+                bq, bk = blocks._default_flash_blocks(
+                    s, None, None, head_dim=d, itemsize=itemsize
+                )
+                est = blocks._flash_bwd_vmem_estimate(bq, bk, d, itemsize)
+                assert est <= limit(est), (s, d, itemsize)
+
+    for capacity in (64, 2048, 32768):
+        for heads in (1, 8, 64):
+            for d in (8, 64, 256):
+                for itemsize in (1, 2, 4):
+                    bkv, bh = blocks._default_decode_blocks(
+                        capacity, heads, d, page_size=16, itemsize=itemsize
+                    )
+                    est = blocks._decode_vmem_estimate(bkv, bh, d, itemsize)
+                    assert est <= limit(est), (capacity, heads, d)
+
+    for m, n, kw in [(1, 1, 1), (8192, 512, 144), (100000, 4096, 16)]:
+        est = blocks._binary_gemm_vmem_estimate(
+            *blocks._default_binary_gemm_blocks(m, n, kw)
+        )
+        assert est <= limit(est), (m, n, kw)
+
+    for wo, ciw, co, kw in [(7, 16, 512, 3), (56, 2, 64, 3), (224, 144, 512, 7)]:
+        bn = blocks._default_binary_conv_block_n(wo, ciw, co)
+        est = blocks._binary_conv_vmem_estimate(wo, wo + kw - 1, ciw, kw, bn)
+        assert est <= limit(est), (wo, ciw, co)
+
+    for k, itemsize in [(32, 4), (2304, 2), (9216, 2), (65536, 4)]:
+        rows = blocks._default_pack_rows_block(k, itemsize)
+        est = blocks._pack_rows_vmem_estimate(rows, k, itemsize)
+        assert est <= limit(est), (k, itemsize)
+
+    for h, w, c, itemsize in [(7, 9, 64, 1), (32, 32, 512, 4), (56, 56, 64, 2)]:
+        est = blocks._resid_vmem_estimate(
+            *blocks._resid_blocks(h, w, c, itemsize), c, itemsize
+        )
+        assert est <= limit(est), (h, w, c)

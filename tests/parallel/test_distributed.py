@@ -1,14 +1,14 @@
-"""`initialize_distributed` hardening: public-API initialization probe
-(private `jax._src` state only as fallback), and loud config errors for
-explicit topology without a coordinator."""
+"""`initialize_distributed` hardening (loud config errors for explicit
+topology without a coordinator, no second initialize) and the
+compile-cache placement rule."""
 
 import pytest
 
 from zookeeper_tpu.core import configure
 from zookeeper_tpu.parallel import (
     DistributedRuntime,
+    enable_compile_cache,
     initialize_distributed,
-    is_distributed_initialized,
 )
 
 
@@ -26,48 +26,6 @@ def test_runtime_component_surfaces_the_same_error():
         runtime.initialize()
 
 
-def test_is_initialized_prefers_public_api(monkeypatch):
-    """When jax exposes ``jax.distributed.is_initialized`` it is the
-    source of truth — the version-fragile private probe is never
-    consulted."""
-    import jax
-
-    monkeypatch.setattr(
-        jax.distributed, "is_initialized", lambda: True, raising=False
-    )
-    assert is_distributed_initialized()
-    monkeypatch.setattr(
-        jax.distributed, "is_initialized", lambda: False, raising=False
-    )
-    assert not is_distributed_initialized()
-
-
-def test_is_initialized_falls_back_to_private_probe(monkeypatch):
-    """On jax versions without the public API the private global-state
-    probe still answers."""
-    import jax
-
-    monkeypatch.delattr(
-        jax.distributed, "is_initialized", raising=False
-    )
-
-    class FakeState:
-        client = object()
-
-    monkeypatch.setattr(
-        jax._src.distributed, "global_state", FakeState(), raising=False
-    )
-    assert is_distributed_initialized()
-
-    class EmptyState:
-        client = None
-
-    monkeypatch.setattr(
-        jax._src.distributed, "global_state", EmptyState(), raising=False
-    )
-    assert not is_distributed_initialized()
-
-
 def test_already_initialized_short_circuits(monkeypatch):
     """An initialized runtime makes initialize_distributed a no-op —
     it must not call jax.distributed.initialize again."""
@@ -82,3 +40,57 @@ def test_already_initialized_short_circuits(monkeypatch):
 
     monkeypatch.setattr(jax.distributed, "initialize", boom)
     initialize_distributed()
+
+
+def test_compile_cache_placed_from_outside_is_untouched(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no path of
+    its own: jax reads the variable itself."""
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/outside")
+
+    def boom(*args, **kwargs):  # pragma: no cover - must not run
+        raise AssertionError("config touched despite the env placement")
+
+    monkeypatch.setattr(jax.config, "update", boom)
+    assert enable_compile_cache() == "/somewhere/outside"
+
+
+def test_compile_cache_default_is_one_fixed_checkout_path(monkeypatch):
+    import os
+
+    import jax
+
+    from zookeeper_tpu.parallel.distributed import COMPILE_CACHE_DIR
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    seen = {}
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: seen.__setitem__(k, v)
+    )
+    repo = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    assert COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert enable_compile_cache() == COMPILE_CACHE_DIR
+    assert enable_compile_cache() == COMPILE_CACHE_DIR  # same every call
+    assert seen == {"jax_compilation_cache_dir": COMPILE_CACHE_DIR}
+
+
+def test_single_host_tpu_env_never_calls_autodetect(monkeypatch):
+    """A TPU environment that names one host (the sealed v5e machine:
+    TPU_WORKER_HOSTNAMES=localhost) must not reach jax's auto-detection,
+    which would query a metadata server that is not there."""
+    import jax
+
+    def boom(**kwargs):  # pragma: no cover - must not run
+        raise AssertionError("auto-detection ran on a single-host env")
+
+    monkeypatch.setattr(jax.distributed, "initialize", boom)
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    monkeypatch.delenv("MEGASCALE_COORDINATOR_ADDRESS", raising=False)
+    initialize_distributed()
+    # Several hosts, or a multislice coordinator: detection still runs.
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "host-0,host-1")
+    with pytest.raises(AssertionError, match="auto-detection ran"):
+        initialize_distributed()

@@ -91,16 +91,26 @@ def test_kernel_engine_token_exact_vs_reference_with_refill(lm, prompts):
     assert pal_engine.compile_count == pal_warm
 
 
-def test_unsupported_geometry_degrades_to_reference(caplog):
-    """head_dim 60/3 = 20 is off the kernel's lane quantum: the engine
-    must WARN, resolve the reference flavor, and still serve
-    token-identically to an explicit reference engine."""
+def test_unsupported_geometry_auto_degrades_explicit_pallas_raises(
+    caplog, monkeypatch
+):
+    """head_dim 60/3 = 20 is off the kernel's lane quantum. ``auto``
+    (resolving to the kernel, as on a TPU backend) must WARN, resolve
+    the reference flavor, and still serve token-identically to an
+    explicit reference engine; an explicit ``pallas`` that cannot be
+    honoured must raise at bind — never serve the reference under the
+    kernel's name."""
+    import jax
+
     module, params, state, _ = build_lm(d_model=60, num_heads=3)
+    with pytest.raises(ValueError, match="cannot be honoured"):
+        kernel_engine(module, params, state, flavor="pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with caplog.at_level(logging.WARNING):
-        engine = kernel_engine(module, params, state, flavor="pallas")
+        engine = kernel_engine(module, params, state, flavor="auto")
     assert engine.decode_attention_flavor == "reference"
     assert any(
-        "decode_attention='pallas'" in r.message for r in caplog.records
+        "decode_attention='auto'" in r.message for r in caplog.records
     )
     engine.warmup()
     ref = kernel_engine(module, params, state, flavor="reference")

@@ -108,7 +108,9 @@ def test_rebind_resets_the_warmup_watermark():
     assert engine.recompiles_detected == 0
 
 
-def test_observe_dispatch_feeds_watchdog_and_mfu_gauge():
+def test_observe_dispatch_feeds_watchdog_and_mfu_gauge(monkeypatch):
+    # The CPU backend has no peak anchor of its own; give one.
+    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "184e12")
     engine, _, _ = make_engine(buckets=(1, 4))
     engine.warmup()
     engine.infer(np.zeros((4, 6), np.float32))

@@ -194,19 +194,6 @@ def test_load_rejects_non_bench_documents(tmp_path):
         bench_diff.load_bench_json(str(notdict))
 
 
-def test_committed_artifacts_load():
-    """The CI gate compares against the committed latest BENCH_r*.json:
-    every committed artifact must stay loadable. (MULTICHIP_r*.json are
-    pass/fail dryrun records with no metric line — out of scope.)"""
-    import glob
-
-    paths = sorted(glob.glob("BENCH_r*.json"))
-    assert paths
-    for p in paths:
-        doc = bench_diff.load_bench_json(p)
-        assert "metric" in doc or "value" in doc
-
-
 # -- CLI contract --------------------------------------------------------
 
 

@@ -278,15 +278,18 @@ def test_profiling_window_still_closed_on_clean_run(tmp_path, monkeypatch):
 # -- device-side ledger / step-time watchdog / live MFU (docs §14) -------
 
 
-def test_live_run_publishes_step_time_and_mfu_gauges(tmp_path):
+def test_live_run_publishes_step_time_and_mfu_gauges(tmp_path, monkeypatch):
     """The acceptance artifact: a real (eager, log_every-synced)
     training run publishes zk_train_step_time_ms and zk_train_mfu from
     ledger FLOPs / measured step time / the shared reference peak —
     and the gauge agrees with the hand computation from its own
-    inputs."""
+    inputs. The CPU backend has no peak anchor of its own (see
+    test_cpu_run_publishes_unknown_mfu), so the anchor is given by its
+    override here."""
     from zookeeper_tpu.observability.ledger import default_ledger, mfu
     from zookeeper_tpu.observability.peaks import reference_peak_flops
 
+    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "184e12")
     exp = make_experiment(tmp_path, {"log_every": 2})
     exp.run()
     reg = exp.obs_registry
@@ -303,8 +306,18 @@ def test_live_run_publishes_step_time_and_mfu_gauges(tmp_path):
         assert mfu_value == -1  # unknown renders as the sentinel
 
 
+def test_cpu_run_publishes_unknown_mfu(tmp_path, monkeypatch):
+    """A backend in no peak table row (the CPU) is never rated against
+    the v5e's peak: the MFU gauge keeps its -1 "unknown"."""
+    monkeypatch.delenv("ZK_BENCH_PEAK_FLOPS", raising=False)
+    exp = make_experiment(tmp_path, {"log_every": 2})
+    exp.run()
+    assert exp.obs_registry.gauge("zk_train_step_time_ms").value > 0
+    assert exp.obs_registry.gauge("zk_train_mfu").value == -1
+
+
 def test_fused_run_ledgers_multi_step_and_divides_flops_by_unroll(
-    tmp_path,
+    tmp_path, monkeypatch,
 ):
     """The fused (unroll>1) loop's MFU divides the slab executable's
     FLOPs by the unroll factor — per-STEP utilization, same definition
@@ -312,6 +325,7 @@ def test_fused_run_ledgers_multi_step_and_divides_flops_by_unroll(
     from zookeeper_tpu.observability.ledger import default_ledger, mfu
     from zookeeper_tpu.observability.peaks import reference_peak_flops
 
+    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "184e12")
     exp = make_experiment(tmp_path, {"unroll": 2, "log_every": 2})
     exp.run()
     rec = default_ledger().latest("multi_step")
@@ -330,7 +344,7 @@ def test_fused_run_ledgers_multi_step_and_divides_flops_by_unroll(
 
 
 def test_mfu_divides_by_recorded_slab_size_not_configured_unroll(
-    tmp_path,
+    tmp_path, monkeypatch,
 ):
     """A partial first slab (mid-epoch resume, spe < unroll) compiles
     the recorded multi_step program for k < unroll steps; the MFU
@@ -338,6 +352,7 @@ def test_mfu_divides_by_recorded_slab_size_not_configured_unroll(
     from zookeeper_tpu.observability.ledger import ProgramRecord, mfu
     from zookeeper_tpu.observability.peaks import reference_peak_flops
 
+    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "184e12")
     exp = make_experiment(tmp_path, {"unroll": 8})
 
     class FakeProgram:

@@ -183,7 +183,7 @@ _LOWER = re.compile(
 _INFORMATIONAL = re.compile(
     r"(^model$|^metric$|^unit$|_source$|^binary_compute$|^n_chips$"
     r"|^batch_size$|^unroll$|^serve_bucket$|^seq|_seq_len$|_degree$"
-    r"|_flavor$|^pack_residuals$|^git_|^jax_version$|^device_kind$"
+    r"|_flavor$|^pack_residuals$|^git_|^jax_version$|^device_kind$|^platform$"
     r"|^bench_schema_version$|^compiler_options$|^lm_model$"
     r"|^lm_attention$|^lm_batch_size$|^lm_flash_block_|^lm_sp_degree$"
     r"|^host_cores$|^host_aug_native_available$|^shed_requests$"

@@ -59,6 +59,19 @@ class _TaskGroup(click.Group):
         return _make_task_command(task_cls)
 
 
+def _enable_compile_cache() -> None:
+    """Place jax's persistent compile cache before the task compiles
+    anything. Only when the task's own imports already loaded jax:
+    ``core/`` stays free of it, and a pure-config task never pays the
+    import."""
+    import sys
+
+    if "jax" in sys.modules:
+        from zookeeper_tpu.parallel.distributed import enable_compile_cache
+
+        enable_compile_cache()
+
+
 def _make_task_command(task_cls: type) -> click.Command:
     @click.command(
         name=task_cls.__name__,
@@ -80,6 +93,7 @@ def _make_task_command(task_cls: type) -> click.Command:
         except (utils.ConfigurationError, TypeError) as e:
             raise click.ClickException(str(e)) from e
         click.echo(pretty_print(instance, color=True))
+        _enable_compile_cache()
         instance.run()
 
     return run_task

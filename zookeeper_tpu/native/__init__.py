@@ -24,6 +24,11 @@ _SRC = os.path.join(_HERE, "src", "zk_native.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+#: How the library was obtained: "built" (compiled by this process),
+#: "found" (a binary for this source hash already lay on disk) or
+#: "unavailable" (no toolchain / build failed — the numpy and Python
+#: fallbacks serve).
+_status = "unavailable"
 
 
 def _build_dirs():
@@ -104,9 +109,11 @@ def _load() -> Optional[ctypes.CDLL]:
         lib = None
         for d in _build_dirs():
             lib_path = os.path.join(d, f"libzk_native-{digest}.so")
+            how = "found"
             if not os.path.exists(lib_path):
                 if not _build(lib_path):
                     continue
+                how = "built"
             try:
                 lib = ctypes.CDLL(lib_path)
                 break
@@ -117,6 +124,7 @@ def _load() -> Optional[ctypes.CDLL]:
                 except OSError:
                     continue
                 if _build(lib_path):
+                    how = "built"
                     try:
                         lib = ctypes.CDLL(lib_path)
                         break
@@ -124,6 +132,8 @@ def _load() -> Optional[ctypes.CDLL]:
                         continue
         if lib is None:
             return None
+        global _status
+        _status = how
         lib.zk_pack_bits_f32.argtypes = [
             ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_int32),
             ctypes.c_int64, ctypes.c_int64,
@@ -158,6 +168,13 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """How the library was obtained: "built", "found" or "unavailable"
+    (see ``_status``). Loads it on first use, like :func:`available`."""
+    _load()
+    return _status
 
 
 def _ptr(arr: np.ndarray, ctype):

@@ -48,6 +48,7 @@ from zookeeper_tpu.observability import trace
 from zookeeper_tpu.observability.device import (
     DeviceProbe,
     device_memory_stats,
+    device_summary,
 )
 from zookeeper_tpu.observability.export import (
     ObservabilityServer,
@@ -97,6 +98,7 @@ __all__ = [
     "default_ledger",
     "default_registry",
     "device_memory_stats",
+    "device_summary",
     "event",
     "export_chrome_trace",
     "mfu",

@@ -36,7 +36,7 @@ from zookeeper_tpu.observability.registry import (
     default_registry,
 )
 
-__all__ = ["DeviceProbe", "device_memory_stats"]
+__all__ = ["DeviceProbe", "device_memory_stats", "device_summary"]
 
 logger = logging.getLogger(__name__)
 
@@ -47,6 +47,21 @@ _STAT_GAUGES = (
     ("peak_bytes_in_use", "zk_hbm_peak_bytes_in_use"),
     ("bytes_limit", "zk_hbm_bytes_limit"),
 )
+
+
+def device_summary() -> Dict[str, Any]:
+    """The devices this process computes on, as jax reports them
+    (``platform``, ``device_kind``, device count). Every result line
+    carries it, so a number taken on the CPU backend can never be read
+    as a chip number."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
 
 
 def device_memory_stats() -> List[Dict[str, Any]]:

@@ -43,12 +43,22 @@ from zookeeper_tpu.ops.blocks import (  # noqa: F401  (re-exports)
     _default_decode_blocks,
     _default_flash_blocks,
     _flash_bwd_vmem_estimate,
+    vmem_limit_bytes,
 )
 
 # Large-negative mask value: finite (so a fully-masked row's exp()
 # underflows to 0 instead of producing -inf - -inf = nan in the online
 # rescale), far below any real fp32 score.
 _MASK_VALUE = -0.5 * float(jnp.finfo(jnp.float32).max)
+
+
+def _mosaic_params(estimate: int):
+    """The one ``compiler_params`` every pallas_call in the package
+    passes: the scoped-VMEM limit ``ops.blocks.vmem_limit_bytes``
+    derives from the call's own per-grid-step estimate."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes(estimate))
 
 
 def attention_reference(
@@ -370,6 +380,9 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        compiler_params=_mosaic_params(
+            _decode_vmem_estimate(block_kv, block_h, d, q.dtype.itemsize)
+        ),
         interpret=interpret,
     )(lens, qs, k_cache, v_cache)
     return out.reshape(b, 1, h, d)
@@ -669,6 +682,9 @@ def pool_paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
+        compiler_params=_mosaic_params(
+            _decode_vmem_estimate(ps, block_h, d, q.dtype.itemsize)
+        ),
         interpret=interpret,
     )(lens, table, *operands)
     return out.reshape(b, 1, h, d)
@@ -745,21 +761,11 @@ def sharded_pool_paged_decode_attention(
 
 
 def _shard_map_no_vma_check(local, *, mesh, in_specs, out_specs):
-    """shard_map with the varying-manual-axes checker disabled, across
-    the kwarg rename history (check_vma >= 0.4.35 > check_rep > none)."""
-    try:  # jax >= 0.4.35 moved shard_map out of experimental.
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - version shim
-        from jax.experimental.shard_map import shard_map
-
-    sm_kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return shard_map(local, **sm_kwargs, check_vma=False)
-    except TypeError:  # pragma: no cover - older jax
-        try:
-            return shard_map(local, **sm_kwargs, check_rep=False)
-        except TypeError:
-            return shard_map(local, **sm_kwargs)
+    """shard_map with the varying-manual-axes checker disabled."""
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def _check_self_attention_shapes(q, k, v):
@@ -911,7 +917,7 @@ def all_to_all_attention_local(
     flash kernel instead — O(block) VMEM at any length, which is what
     makes the Ulysses flavor long-context-capable (at s=16k the dense
     local scores alone are 8 GB and OOM; flash trains that length —
-    sweep_r07/flash_bwd_timing.py).
+    ``git show 34de816:sweep_r07/flash_bwd_timing.py``).
     """
     if local_attention not in ("dense", "flash"):
         raise ValueError(
@@ -1009,11 +1015,6 @@ def _sharded_attention_call(
 ):
     from jax.sharding import PartitionSpec as P
 
-    try:  # jax >= 0.4.35 moved shard_map out of experimental.
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - version shim
-        from jax.experimental.shard_map import shard_map
-
     # Checked on GLOBAL shapes too, so the error fires at the call
     # boundary rather than inside the shard_map trace (the local
     # kernels re-check their per-shard views for direct callers).
@@ -1036,7 +1037,7 @@ def _sharded_attention_call(
         scale=scale,
     )
     if check_vma:
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
         )
     else:
@@ -1062,9 +1063,10 @@ def flash_attention(
     one (block_q, d) query tile and one (block_k, d) key/value tile
     live on-chip per grid step, so sequence length is HBM-bound, not
     VMEM-bound, and the [s, s] score matrix never exists. Measured
-    verdict (sweep_r07/flash_bwd_timing.py, v5e, b1 h8 d64 bf16
-    causal, honest perturbed-chain marginals): with the auto-scaled
-    block sizes the TRAINING step (fwd+bwd) runs **2.5-5x faster than
+    verdict (``git show 34de816:sweep_r07/flash_bwd_timing.py``, v5e,
+    b1 h8 d64 bf16 causal, perturbed-chain marginals): with the
+    auto-scaled block sizes the TRAINING step (fwd+bwd) runs **2.5-5x
+    faster than
     XLA's fused dense path** (0.61 vs 1.54 ms at s=2048, 1.09 vs 5.40
     at s=4096, 5.26 vs 21.6 at s=8192) and trains s=16384 in 11.6
     ms/step where the dense path OOMs outright. The round-6
@@ -1260,18 +1262,8 @@ def _flash_forward(
     # simple map stays.
     # Inside a shard_map trace (the ring_flash composition) the output
     # avals must declare how they vary over the manual mesh axes;
-    # outside one, typeof(...).vma is empty and the kwarg is a no-op.
-    # Older jax has neither typeof().vma nor the kwarg — omit it there
-    # (such versions predate the vma checker entirely).
-    try:
-        vma = jax.typeof(qb).vma
-    except AttributeError:  # pragma: no cover - older jax
-        vma = None
-    # Attach the kwarg only when the set is non-empty: every jax new
-    # enough to run a pallas_call under manual axes supports it, while
-    # plain single-device calls (vma empty/absent) stay compatible with
-    # versions whose ShapeDtypeStruct lacks the parameter.
-    aval_kw = {"vma": vma} if vma else {}
+    # outside one the set is empty.
+    aval_kw = {"vma": jax.typeof(qb).vma}
     out_shape = [
         jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype, **aval_kw)
     ]
@@ -1298,6 +1290,9 @@ def _flash_forward(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=_mosaic_params(
+            _flash_bwd_vmem_estimate(block_q, block_k, d, q.dtype.itemsize)
+        ),
         interpret=interpret,
     )(qb, kb, vb)
     if want_lse:
@@ -1471,6 +1466,9 @@ def _flash_backward(
     q_spec = pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0))
     k_spec_inner = pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0))
     lse_spec = pl.BlockSpec((1, block_q, 1), lambda i, j, kk: (i, j, 0))
+    bwd_params = _mosaic_params(
+        _flash_bwd_vmem_estimate(block_q, block_k, d, q.dtype.itemsize)
+    )
     dq = pl.pallas_call(
         dq_kernel,
         out_shape=jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
@@ -1479,6 +1477,7 @@ def _flash_backward(
                   lse_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=bwd_params,
         interpret=interpret,
     )(qb, kb, vb, dob, lse, Db)
 
@@ -1503,6 +1502,7 @@ def _flash_backward(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=bwd_params,
         interpret=interpret,
     )(kb, vb, qb, dob, lse, Db)
 

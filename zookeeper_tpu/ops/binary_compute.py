@@ -44,13 +44,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from zookeeper_tpu.ops.attention import _mosaic_params
 from zookeeper_tpu.ops.blocks import (  # noqa: F401  (re-exports)
+    _PACK_CHUNK,
+    _PACKED_WEIGHT_SCRATCH_BUDGET,
     _RESID_BLOCK_BYTES,
+    _binary_conv_vmem_estimate,
+    _binary_gemm_vmem_estimate,
     _default_binary_conv_block_n,
     _default_binary_gemm_blocks,
     _default_pack_rows_block,
     _divisor_at_most,
+    _pack_rows_vmem_estimate,
+    _packed_weight_vmem_estimate,
     _resid_blocks,
+    _resid_vmem_estimate,
     _round_up,
 )
 
@@ -228,6 +236,9 @@ def pack_resid(
             lambda i, j, k: (i, j, k, 0),
             memory_space=pltpu.VMEM,
         ),
+        compiler_params=_mosaic_params(
+            _resid_vmem_estimate(bh, bw, c, jnp.dtype(x.dtype).itemsize)
+        ),
         interpret=_resid_interpret(interpret),
     )(x4)
     return out
@@ -255,6 +266,9 @@ def unpack_resid_pm1(words: Array, shape, dtype,
             (32, bh, bw, c),
             lambda i, j, k: (i, j, k, 0),
             memory_space=pltpu.VMEM,
+        ),
+        compiler_params=_mosaic_params(
+            _resid_vmem_estimate(bh, bw, c, jnp.dtype(dtype).itemsize)
         ),
         interpret=_resid_interpret(interpret),
     )(words)
@@ -289,6 +303,9 @@ def mask_mul_resid(g: Array, words: Array, interpret: bool = None) -> Array:
             (32, bh, bw, c),
             lambda i, j, k: (i, j, k, 0),
             memory_space=pltpu.VMEM,
+        ),
+        compiler_params=_mosaic_params(
+            _resid_vmem_estimate(bh, bw, c, jnp.dtype(g.dtype).itemsize)
         ),
         interpret=_resid_interpret(interpret),
     )(g4, words)
@@ -408,6 +425,9 @@ def xnor_matmul_packed(
         out_specs=pl.BlockSpec(
             (block_m, block_n), lambda i, j, k: (i, j), memory_space=pltpu.VMEM
         ),
+        compiler_params=_mosaic_params(
+            _binary_gemm_vmem_estimate(block_m, block_n, block_kw)
+        ),
         interpret=interpret,
     )(a_pad, b_pad)
     return out[:m, :n]
@@ -502,26 +522,57 @@ def _warn_pallas_fallback(what: str) -> None:
     )
 
 
-def _pack_rows_kernel(x_ref, out_ref):
+def _pack_rows_kernel(x_ref, plo_ref, phi_ref, out_ref):
     """Fused sign+pack of one [bm, kw*32] float block into [bm, kw]
     int32 wire-format words (little-endian bit b of word t is
     ``x[:, 32t+b] >= 0`` — exactly :func:`pack_bits`).
 
-    Bit b of every word is gathered by a stride-32 lane slice, so the
-    kernel is 32 unrolled compare/shift/or VPU steps over [bm, kw]
-    tiles — the ``_pack_resid_kernel`` idiom rotated onto the trailing
-    axis, with no in-kernel reshape (splitting the lane dim into
-    [kw, 32] would force a Mosaic relayout). Traffic: one read of the
-    float source, one 1/32-size write — this is what removes the 32x
-    [..., 32]-shaped HBM intermediates of the XLA pack_bits lowering
-    (the round-6 lesson at the top of this file, now applied to the
-    GEMM operand path)."""
-    acc = jnp.zeros(out_ref.shape, jnp.int32)
-    for b in range(32):
-        # fp32 compare: Mosaic has no bf16 vector cmpf on this target.
-        chunk = x_ref[:, b::32].astype(jnp.float32)
-        acc = acc | ((chunk >= 0).astype(jnp.int32) << b)
-    out_ref[:] = acc
+    Gathering 32 adjacent lanes into one is a lane compaction, which
+    Mosaic has no vector form for (a stride-32 lane load is refused for
+    every dtype), so the compaction runs on the MXU: the 0/1 sign bits
+    contract against two constant selector matrices whose row ``32t+b``
+    holds ``2^(b % 16)`` in column ``t`` — ``plo`` for bits 0..15,
+    ``phi`` for bits 16..31. Each output is a sum of distinct powers of
+    two below 2^16, exact in the fp32 accumulator, so
+    ``lo | (hi << 16)`` is the word bit for bit. K wider than
+    ``_PACK_CHUNK`` lanes is walked in static chunks against the same
+    selectors (they are block-diagonal). Traffic: one read of the float
+    source, one 1/32-size write — no [..., 32]-shaped HBM intermediates
+    (the round-6 lesson at the top of this file)."""
+    k = x_ref.shape[1]
+    kw = out_ref.shape[1]
+    # fp32 compare: Mosaic has no bf16 vector cmpf on this target.
+    bits = jnp.where(
+        x_ref[:].astype(jnp.float32) >= 0, 1.0, 0.0
+    ).astype(jnp.bfloat16)
+    for c0 in range(0, k, _PACK_CHUNK):
+        width = min(_PACK_CHUNK, k - c0)
+        chunk = bits[:, c0 : c0 + width]
+        lo = jnp.dot(
+            chunk, plo_ref[:width], preferred_element_type=jnp.float32
+        ).astype(jnp.int32)
+        hi = jnp.dot(
+            chunk, phi_ref[:width], preferred_element_type=jnp.float32
+        ).astype(jnp.int32)
+        w0 = c0 // 32
+        words = min(kw - w0, _PACK_CHUNK // 32)
+        out_ref[:, w0 : w0 + words] = (lo | (hi << 16))[:, :words]
+
+
+def _pack_selectors(k: int):
+    """The two [kc, 128-padded kc/32] bf16 selector matrices of
+    :func:`_pack_rows_kernel` (``kc = min(k, _PACK_CHUNK)``)."""
+    import numpy as np
+
+    kc = min(k, _PACK_CHUNK)
+    lanes = np.arange(kc)
+    bit = lanes % 32
+    out = []
+    for half in (bit < 16, bit >= 16):
+        sel = np.zeros((kc, _round_up(kc // 32, 128)), np.float32)
+        sel[lanes[half], lanes[half] // 32] = 2.0 ** (bit[half] % 16)
+        out.append(jnp.asarray(sel, jnp.bfloat16))
+    return out
 
 
 def pack_rows_packed(x: Array, *, interpret=None, block_m: int = None) -> Array:
@@ -546,6 +597,9 @@ def pack_rows_packed(x: Array, *, interpret=None, block_m: int = None) -> Array:
     mp = _round_up(m, block_m)
     if mp != m:
         x = jnp.pad(x, ((0, mp - m), (0, 0)))
+    plo, phi = _pack_selectors(k)
+    sel_spec = pl.BlockSpec(plo.shape, lambda i: (0, 0),
+                            memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         _pack_rows_kernel,
         out_shape=jax.ShapeDtypeStruct((mp, kw), jnp.int32),
@@ -553,11 +607,16 @@ def pack_rows_packed(x: Array, *, interpret=None, block_m: int = None) -> Array:
         in_specs=[
             pl.BlockSpec((block_m, k), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
+            sel_spec,
+            sel_spec,
         ],
         out_specs=pl.BlockSpec((block_m, kw), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        compiler_params=_mosaic_params(
+            _pack_rows_vmem_estimate(block_m, k, itemsize)
+        ),
         interpret=_resid_interpret(interpret),
-    )(x)
+    )(x, plo, phi)
     return out[:m]
 
 
@@ -671,6 +730,9 @@ def xnor_matmul_packed_scaled(
             (block_m, block_n), lambda i, j, k: (i, j), memory_space=pltpu.VMEM
         ),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+        compiler_params=_mosaic_params(
+            _binary_gemm_vmem_estimate(block_m, block_n, block_kw)
+        ),
         interpret=interpret,
     )(a_pad, b_pad, s_pad)
     return out[:m, :n]
@@ -788,12 +850,13 @@ def packed_weight_matmul(
     # K_pad x block_n int8. Lower block_n first; if even 128 lanes
     # exceed the budget (K in the tens of thousands), keep a single-slot
     # scratch and decode every grid step (always_decode fallback).
-    scratch_budget = 4 * 1024 * 1024
-    while block_n > 128 and _round_up(kw, block_kw) * 32 * block_n > scratch_budget:
+    slab_rows = _round_up(kw, block_kw) * 32
+    while (
+        block_n > 128
+        and slab_rows * block_n > _PACKED_WEIGHT_SCRATCH_BUDGET
+    ):
         block_n //= 2
-    always_decode = (
-        _round_up(kw, block_kw) * 32 * block_n > scratch_budget
-    )
+    always_decode = slab_rows * block_n > _PACKED_WEIGHT_SCRATCH_BUDGET
     mp = _round_up(m, block_m)
     np_ = _round_up(n, block_n)
     kwp = _round_up(kw, block_kw)
@@ -837,6 +900,12 @@ def packed_weight_matmul(
                 jnp.int8,
             )
         ],
+        compiler_params=_mosaic_params(
+            _packed_weight_vmem_estimate(
+                block_m, block_n, block_kw,
+                1 if always_decode else kwp // block_kw,
+            )
+        ),
         interpret=interpret,
     )(a_pad, b_pad)
     return out[:m, :n]
@@ -1010,6 +1079,9 @@ def _conv_gemm_popcount(
             memory_space=pltpu.VMEM,
         ),
         scratch_shapes=[pltpu.VMEM((wo, block_n), jnp.int32)],
+        compiler_params=_mosaic_params(
+            _binary_conv_vmem_estimate(wo, wp, ciw, kw, block_n)
+        ),
         interpret=_resid_interpret(interpret),
     )(xq, wq, s_pad)
     return out[..., :co]

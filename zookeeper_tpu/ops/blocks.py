@@ -19,11 +19,14 @@ are usable from tests and tools without pulling in a backend.
 """
 
 __all__ = [
+    "vmem_limit_bytes",
     "_FLASH_VMEM_BUDGET",
     "_RESID_BLOCK_BYTES",
     "_BINARY_GEMM_VMEM_BUDGET",
     "_BINARY_CONV_VMEM_BUDGET",
     "_BINARY_PACK_BLOCK_BYTES",
+    "_PACK_CHUNK",
+    "_PACKED_WEIGHT_SCRATCH_BUDGET",
     "_round_up",
     "_divisor_at_most",
     "_flash_bwd_vmem_estimate",
@@ -31,6 +34,10 @@ __all__ = [
     "_decode_vmem_estimate",
     "_default_decode_blocks",
     "_resid_blocks",
+    "_resid_vmem_estimate",
+    "_binary_conv_vmem_estimate",
+    "_pack_rows_vmem_estimate",
+    "_packed_weight_vmem_estimate",
     "_binary_gemm_vmem_estimate",
     "_default_binary_gemm_blocks",
     "_default_binary_conv_block_n",
@@ -49,17 +56,44 @@ def _divisor_at_most(n: int, cap: int) -> int:
     return 1
 
 
+# -- the scoped-VMEM rule ---------------------------------------------------
+
+_MIB = 1024 * 1024
+
+#: Mosaic's default scoped-VMEM limit on a v5e core. A pallas_call that
+#: passes no limit is held to it: the v5e compiler refused the flash
+#: backward at head_dim 256 fp32 with "Scoped allocation with size
+#: 21.96M and limit 16.00M exceeded scoped vmem limit".
+_VMEM_DEFAULT_LIMIT = 16 * _MIB
+
+#: The largest limit any call in the package requests: three quarters
+#: of the v5e core's 128 MiB, the rest left to XLA's own fusions.
+_VMEM_LIMIT_CAP = 96 * _MIB
+
+
+def vmem_limit_bytes(estimate: int) -> int:
+    """The ``vmem_limit_bytes`` every pallas_call in the package hands
+    to Mosaic, sized from the SAME per-grid-step estimate its block
+    policy budgets with: half again the estimate (the estimates count
+    tiles and named intermediates, not compiler temporaries), never
+    below Mosaic's own default and never above ``_VMEM_LIMIT_CAP``. The
+    policies budget against ``_FLASH_VMEM_BUDGET`` = cap / 1.5, so a
+    block a policy admits always gets a limit above its estimate;
+    explicit caller blocks past the cap meet Mosaic's error."""
+    want = _round_up(int(estimate) * 3 // 2, _MIB)
+    return min(_VMEM_LIMIT_CAP, max(_VMEM_DEFAULT_LIMIT, want))
+
+
 # -- flash attention (forward/backward + pool kernels) ----------------------
 
 #: VMEM the auto flash-block policy budgets for one backward grid step
 #: (bytes). The backward kernels are the binding residency: three
 #: (block_q, block_k) fp32 intermediates (scores, P, dS) plus the
 #: double-buffered (block, head_dim) input tiles and fp32 accumulators.
-#: 64 MiB keeps the measured sweep winner (block 1024 at head_dim 64,
-#: ~16 MiB) comfortably in and demotes only extreme head dims on
-#: v5e-class parts (128 MiB physical VMEM/core; older generations are
-#: ~16 MiB — pass explicit blocks or a smaller budget there).
-_FLASH_VMEM_BUDGET = 64 * 1024 * 1024
+#: 64 MiB is the largest estimate ``vmem_limit_bytes`` can still give
+#: its half-again headroom under ``_VMEM_LIMIT_CAP``; it keeps block
+#: 1024 at head_dim 64 (~14 MiB) in and demotes only extreme head dims.
+_FLASH_VMEM_BUDGET = _VMEM_LIMIT_CAP * 2 // 3
 
 
 def _flash_bwd_vmem_estimate(block_q, block_k, head_dim, itemsize):
@@ -79,10 +113,11 @@ def _default_flash_blocks(s, block_q, block_k, head_dim=None, itemsize=4):
     waste stays under 1/8 of the sequence AND whose backward working
     set fits the VMEM budget. Large blocks amortize the sequential
     grid iteration (the sweep winner at every measured power-of-two
-    length — sweep_r07/flash_bwd_timing.py: 22.7 -> 5.26 ms/step at
-    s=8192 going 128 -> 1024), but a big block on an awkward length
-    would round the padded sequence up to the block multiple (s=1100
-    at block 1024 pads to 2048 — 86% wasted rows), so awkward lengths
+    length — ``git show 34de816:sweep_r07/flash_bwd_timing.py``: 22.7
+    -> 5.26 ms/step at s=8192 going 128 -> 1024), but a big block on an
+    awkward length would round the padded sequence up to the block
+    multiple (s=1100 at block 1024 pads to 2048 — 86% wasted rows), so
+    awkward lengths
     fall back toward 128; and at head dims well above 64 the backward's
     (block, d) tiles grow until a 1024 block exceeds VMEM — a loud
     Mosaic compile failure if selected, so ``head_dim``-aware candidates
@@ -193,6 +228,14 @@ def _default_decode_blocks(
 _RESID_BLOCK_BYTES = 2 * 1024 * 1024
 
 
+def _resid_vmem_estimate(bh, bw, c, itemsize):
+    """Rough bytes one residual-kernel grid step keeps resident: the
+    32-deep float block on the input AND the output side (the fused
+    mask-multiply has both) plus the word block, double-buffered."""
+    deep = 32 * bh * bw * c * itemsize
+    return 2 * (2 * deep + bh * bw * c * 4)
+
+
 def _resid_blocks(h: int, w: int, c: int, itemsize: int):
     """(bh, bw): spatial block dims dividing (h, w) with the 32-deep
     input block inside the VMEM budget."""
@@ -271,6 +314,49 @@ def _default_binary_conv_block_n(wo, ciw, co):
     while bn > 128 and wo * ciw * bn * 4 > _BINARY_CONV_VMEM_BUDGET:
         bn //= 2
     return bn
+
+
+def _binary_conv_vmem_estimate(wo, wp, ciw, kw, block_n):
+    """Rough bytes one conv-as-gemm grid step keeps resident: the per-tap
+    xor broadcast and its popcount copy, the double-buffered packed row
+    (its few words pad to a 128-lane tile), weight and output blocks,
+    and the int32 accumulator."""
+    intermediate = 2 * wo * ciw * block_n * 4
+    tiles = 2 * (wp * _round_up(ciw, 128) + kw * ciw * block_n) * 4
+    accumulators = 3 * wo * block_n * 4
+    return intermediate + tiles + accumulators
+
+
+#: Input lanes one sign+pack MXU contraction covers (128 output words).
+_PACK_CHUNK = 4096
+
+
+def _pack_rows_vmem_estimate(block_m, k, itemsize):
+    """Rough bytes one sign+pack grid step keeps resident: the
+    double-buffered float block, its fp32 widening and bf16 sign bits,
+    the two resident selector matrices and the word block."""
+    kc = min(k, _PACK_CHUNK)
+    block = block_m * k
+    selectors = 2 * 2 * kc * _round_up(kc // 32, 128) * 2
+    words = 2 * block_m * _round_up(k // 32, 128) * 4
+    return 2 * block * itemsize + block * (4 + 2) + selectors + 2 * words
+
+
+#: VMEM the packed-weight MXU GEMM gives one n column's unpacked int8
+#: weight slabs; past it the kernel decodes every step instead.
+_PACKED_WEIGHT_SCRATCH_BUDGET = 4 * 1024 * 1024
+
+
+def _packed_weight_vmem_estimate(block_m, block_n, block_kw, slots):
+    """Rough bytes one packed-weight GEMM grid step keeps resident: the
+    unpacked int8 slab scratch, the [block_kw, 32, block_n] bit-decode
+    intermediates, and the double-buffered A / packed-B / output
+    blocks."""
+    bk = block_kw * 32
+    scratch = slots * bk * block_n
+    decode = 3 * bk * block_n * 4
+    tiles = 2 * (block_m * bk + block_kw * block_n * 4 + block_m * block_n * 4)
+    return scratch + decode + tiles
 
 
 def _default_pack_rows_block(k, itemsize=4):

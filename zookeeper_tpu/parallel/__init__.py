@@ -26,8 +26,8 @@ from zookeeper_tpu.parallel.rules import (
 from zookeeper_tpu.parallel.sequence import SequenceParallelPartitioner
 from zookeeper_tpu.parallel.distributed import (
     DistributedRuntime,
+    enable_compile_cache,
     initialize_distributed,
-    is_distributed_initialized,
 )
 from zookeeper_tpu.parallel.sharding import (
     activation_sharding_scope,
@@ -47,8 +47,8 @@ __all__ = [
     "SequenceParallelPartitioner",
     "SingleDevicePartitioner",
     "conv_model_tp_rules",
+    "enable_compile_cache",
     "initialize_distributed",
-    "is_distributed_initialized",
     "match_partition_rules",
     "transformer_tp_rules",
 ]

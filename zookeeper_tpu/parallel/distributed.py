@@ -13,62 +13,74 @@ the cluster, and the Experiment restores the latest orbax checkpoint; the
 (seed, epoch)-keyed data pipeline makes the replay exact.
 """
 
+import os
 from typing import Optional
 
 from zookeeper_tpu.core import Field, component
 
 
-def is_distributed_initialized() -> bool:
-    """Whether the JAX distributed runtime is already up.
+#: The one persistent compile-cache directory this program sets itself:
+#: ``<checkout>/.jax_cache`` (git-ignored). The path is part of the
+#: cache key, so it is never derived from a pid, a time or a tempdir.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
-    Prefers the PUBLIC ``jax.distributed.is_initialized()`` (added in
-    recent jax); falls back to probing the private
-    ``jax._src.distributed.global_state`` only when the public API is
-    absent — the private module layout is version-fragile and must not
-    be the first thing this code reaches for."""
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache before the first
+    compile and return its directory. Where ``JAX_COMPILATION_CACHE_DIR``
+    is set the cache is placed from outside: jax reads the variable
+    itself and nothing is touched here. Otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`. Every entry point that compiles (the task
+    CLI, ``chip_smoke.py``, ``bench.py``, the fleet workers) calls this
+    once, so separate processes of one checkout share compiled
+    programs."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
     import jax
 
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        try:
-            return bool(probe())
-        except Exception:  # pragma: no cover - defensive, API churn
-            pass
-    state = getattr(
-        getattr(jax, "_src", None), "distributed", None
-    )
-    state = getattr(state, "global_state", None)
-    return state is not None and getattr(state, "client", None) is not None
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def _enable_cpu_collectives() -> None:
     """Select the gloo collectives implementation for the CPU backend
-    before it is instantiated: without it, current jax rejects every
+    before it is instantiated: without it, jax rejects every
     cross-process computation on CPU clusters ("Multiprocess
     computations aren't implemented on the CPU backend") — the local
     N-process dryrun/chaos legs and any gloo-backed CPU cluster need
     it. Only applies when the CPU platform was explicitly requested
-    (``JAX_PLATFORMS=cpu`` / config), and quietly no-ops on jax
-    versions without the option."""
-    import os
-
+    (``JAX_PLATFORMS=cpu`` / config)."""
     import jax
 
     platforms = (
         str(getattr(jax.config, "jax_platforms", None) or "")
         or os.environ.get("JAX_PLATFORMS", "")
     )
-    if "cpu" not in platforms.lower():
-        return
-    try:
+    if "cpu" in platforms.lower():
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - option absent/renamed
-        import logging
 
-        logging.getLogger(__name__).debug(
-            "jax_cpu_collectives_implementation unavailable; CPU "
-            "cross-process collectives may be unsupported"
-        )
+
+def _single_host_tpu_env() -> bool:
+    """Whether the TPU runtime's own environment says this process is
+    the whole job: ``TPU_WORKER_HOSTNAMES`` names one host and no
+    multislice coordinator is set. jax's auto-detection takes the same
+    variables for a cluster and then asks the metadata server for its
+    coordinator; on a sealed single-host machine (measured on the v5e
+    host of PR 21) that costs 3 s and raises
+    ``requests.exceptions.ConnectionError`` — so a job that is one host
+    by its environment never calls it."""
+    hosts = os.environ.get("TPU_WORKER_HOSTNAMES")
+    return (
+        bool(hosts)
+        and "," not in hosts
+        and not os.environ.get("MEGASCALE_COORDINATOR_ADDRESS")
+    )
 
 
 def initialize_distributed(
@@ -80,7 +92,8 @@ def initialize_distributed(
 
     With no arguments, relies on the TPU environment's auto-detection
     (GCE metadata / megascale env), which is the normal path on Cloud TPU
-    pods. No-op when already initialized or when running single-process.
+    pods. No-op when already initialized, when the TPU environment names
+    a single host, or when no cluster is detected.
 
     ``num_processes``/``process_id`` describe a MANUALLY-specified
     cluster and are meaningless without the coordinator every process
@@ -100,8 +113,10 @@ def initialize_distributed(
             "runtime.coordinator_address=10.0.0.1:8476). On TPU pods, "
             "pass NONE of the three and let auto-detection run."
         )
-    if is_distributed_initialized():
-        return  # Already initialized.
+    if jax.distributed.is_initialized():
+        return
+    if coordinator_address is None and _single_host_tpu_env():
+        return
     if coordinator_address is not None:
         # Only when actually forming a cluster: gloo with NO
         # distributed client breaks single-process CPU backend init.

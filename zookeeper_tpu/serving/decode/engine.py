@@ -115,8 +115,8 @@ DecodeScheduler`.
     #: forces the oracle einsum, "module" defers to the module's own
     #: ``decode_attention`` setting/injected callable. Unsupported
     #: geometry (see ``ops.decode_attention_supported``) degrades
-    #: "auto"/"pallas" to the reference with a warning — the
-    #: compile_forward small-bucket posture.
+    #: "auto" to the reference with a warning; an explicit "pallas"
+    #: that cannot be honoured raises at bind.
     decode_attention: str = Field("auto")
     #: Program-naming prefix for the ProgramLedger / recompile events
     #: (docs/DESIGN.md §18): a speculative-decode DRAFT engine runs the
@@ -474,9 +474,19 @@ DecodeScheduler`.
         heads = int(module.num_heads)
         head_dim = int(module.d_model) // heads
         if not ops.decode_attention_supported(heads, head_dim):
+            if str(self.decode_attention) == "pallas":
+                # Asked for by name: serving the reference under the
+                # kernel's name would hide which program ran.
+                raise ValueError(
+                    f"decode_attention='pallas' cannot be honoured: "
+                    f"head_dim={head_dim} is off the kernel's supported "
+                    "geometry (see ops.decode_attention_supported). Use "
+                    "decode_attention='auto' (degrades to the reference "
+                    "einsum) or 'reference'."
+                )
             logger.warning(
-                "decode_attention='pallas' requested but head_dim=%d is "
-                "off the kernel's supported geometry (see "
+                "decode_attention='auto': head_dim=%d is off the Pallas "
+                "kernel's supported geometry (see "
                 "ops.decode_attention_supported); decoding with the "
                 "REFERENCE einsum instead",
                 head_dim,

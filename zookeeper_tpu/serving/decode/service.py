@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional
 from zookeeper_tpu.core import ComponentField, Field, component, pretty_print
 from zookeeper_tpu.models.base import Model
 from zookeeper_tpu.models.transformer import TransformerLM
+from zookeeper_tpu.observability.device import device_summary
 from zookeeper_tpu.parallel.partitioner import (
     Partitioner,
     SingleDevicePartitioner,
@@ -361,6 +362,7 @@ class LMServingConfig(Experiment):
         result = {
             **{k: round(float(v), 4) for k, v in snapshot.items()},
             "model": type(self.model).__name__,
+            "device": device_summary(),
             "weights": self.weights,
             "slots": int(self.engine.slots),
             "seq_buckets": [int(s) for s in self.engine.seq_buckets],

@@ -343,11 +343,17 @@ def run_fleet_worker(
 
     import jax
 
+    # CPU by construction: the spawning parent may hold the chip, and a
+    # chip belongs to one process — a worker that reached for it would
+    # fail or hang. Whatever a fleet of these measures is a CPU number.
     jax.config.update("jax_platforms", "cpu")
 
     from zookeeper_tpu.core import configure
+    from zookeeper_tpu.parallel.distributed import enable_compile_cache
     from zookeeper_tpu.resilience import faults
     from zookeeper_tpu.serving import LMServingConfig
+
+    enable_compile_cache()
 
     overrides = json.loads(config_json)
     # Chaos seam: a "faults" key in the worker config installs a
@@ -473,7 +479,9 @@ def spawn_fleet_workers(
     timeout_s: float = 300.0,
 ):
     """Spawn ``num_workers`` real OS processes running
-    :func:`run_fleet_worker` and wait for every ready file; returns
+    :func:`run_fleet_worker` ON THE CPU (``JAX_PLATFORMS=cpu`` in the
+    child environment: the parent may hold the chip, and a chip belongs
+    to one process) and wait for every ready file; returns
     the ready documents (feed them to
     ``zookeeper_tpu.serving.fleet.ReplicaHandle.from_worker``). Raises
     with the worker's log tail when any process dies before ready —
@@ -614,7 +622,9 @@ def stop_fleet_workers(workers, timeout_s: float = 30.0) -> None:
 
 def spawn_group_chaos_cluster(workdir: str, num_processes: int = 2):
     """Spawn ``num_processes`` OS processes running
-    :func:`run_group_chaos_worker` as one jax cluster; wait for them
+    :func:`run_group_chaos_worker` as one jax CPU cluster
+    (``JAX_PLATFORMS=cpu``, one virtual device each — CPU by
+    construction, whatever the parent holds); wait for them
     and return the per-process result dicts. Raises with the worker's
     log tail when any process fails — shared by the pytest leg and
     ``__graft_entry__.dryrun_multiprocess`` so the two cannot drift."""

@@ -378,8 +378,8 @@ def host_snapshot(tree):
     hook — ``training.async_checkpoint``).
 
     The device→host copies for ALL leaves are issued asynchronously
-    first (``copy_to_host_async``, best-effort — a leaf that is already
-    host-side or an older jax simply skips the hint), then materialized:
+    first (``copy_to_host_async`` — a leaf that is already host-side
+    has nothing to issue), then materialized:
     the transfers overlap each other and any still-running device work
     queued BEHIND the state's producing computation, so the training
     thread pays one drained-copy wait, not a serialized per-leaf walk.
@@ -388,12 +388,8 @@ def host_snapshot(tree):
 
     leaves, treedef = jax.tree.flatten(tree)
     for leaf in leaves:
-        copy_async = getattr(leaf, "copy_to_host_async", None)
-        if copy_async is not None:
-            try:
-                copy_async()
-            except Exception:
-                pass  # placement/backend without async copies: device_get below
+        if isinstance(leaf, jax.Array):
+            leaf.copy_to_host_async()
     # np.asarray on a jax Array materializes the (already in-flight)
     # host copy; 0-d leaves become 0-d ndarrays (orbax rejects bare
     # numpy scalars, so the asarray wrapper is load-bearing).
