@@ -22,9 +22,12 @@ every Pallas kernel against its reference:
    path bit-exact, every Pallas kernel against its reference.
 
 Any failed check or exception ends the run with a non-zero exit code. The
-last line of standard output is one JSON object with the device as jax
-reports it and each phase's wall, compile and run seconds. The times are
-set-up facts (how long the system takes to start), not performance metrics.
+last line of standard output is one JSON object with exactly two keys,
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``,
+the device as jax reports it. The line before it (``chip_smoke: report
+{...}``) carries each phase's pass/fail and its wall, compile and run
+seconds. The times are set-up facts (how long the system takes to start),
+not performance metrics.
 
     python chip_smoke.py          # on a machine with a TPU
 """
@@ -363,12 +366,11 @@ def main():
         flush=True,
     )
     clock = CompileClock()
-    result = {
-        "ok": False,
-        "device": device,
+    report = {
         "compile_cache": {"dir": cache_dir, "entries_at_start": cached},
         "phases": {},
     }
+    ok = False
     try:
         for name, phase in (
             ("trainer", trainer_phase),
@@ -382,7 +384,7 @@ def main():
             facts = phase()
             wall = time.perf_counter() - wall
             compiling = clock.seconds - compiling
-            result["phases"][name] = {
+            report["phases"][name] = {
                 "ok": True,
                 "wall_s": round(wall, 1),
                 "compile_s": round(compiling, 1),
@@ -390,17 +392,19 @@ def main():
                 "compile_cache_hits": clock.cache_hits - hits,
                 **facts,
             }
-        result["ok"] = True
+        ok = True
     except Exception:
         # The one boundary: say which phase failed, print the result
         # line with ok=false, exit non-zero. Later phases do not run.
         traceback.print_exc()
-        result["phases"][name] = {"ok": False}
-    result["wall_s"] = round(time.perf_counter() - t0, 1)
+        report["phases"][name] = {"ok": False}
+    report["wall_s"] = round(time.perf_counter() - t0, 1)
     # No rate, no utilization: this script makes no performance claim.
-    result["claim"] = None
-    print(json.dumps(result), flush=True)
-    return 0 if result["ok"] else 1
+    report["claim"] = None
+    print(f"chip_smoke: report {json.dumps(report)}", flush=True)
+    # The result line: exactly these two keys, last on standard output.
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":
