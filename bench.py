@@ -76,8 +76,8 @@ def bench_metadata(device_kind=None):
 
 # The peak-anchor machinery (datasheet tables, the measured-peak
 # agreement gate, the datasheet clamp) moved to
-# ``zookeeper_tpu.observability.peaks`` so the LIVE MFU gauges
-# (``zk_train_mfu``/``zk_serve_mfu``, docs/DESIGN.md §14) and this
+# ``zookeeper_tpu.observability.peaks`` so the LIVE MFU gauge
+# (``zk_serve_mfu``, docs/DESIGN.md §14) and this
 # bench divide by the same anchors; re-exported here unchanged (sweep
 # scripts and tests import them as ``bench.*``).
 from zookeeper_tpu.observability.peaks import (  # noqa: E402,F401

@@ -705,3 +705,83 @@ def test_train_split_smaller_than_global_batch_fails_loudly():
         list(batch_iterator(src, None, 8, training=False, shuffle=False))
         == []
     )
+
+
+# -- PR 24: the producer thread's leaf spans ------------------------------
+
+
+@pytest.fixture
+def loader_records():
+    """Records of a tiny traced ``prefetch_to_device`` pass: 5 batches
+    through a queue of 1 with a consumer slower than the producer, so the
+    producer also waits on a full queue."""
+    import time
+
+    from zookeeper_tpu.observability import trace
+
+    pre = PassThroughPreprocessing()
+    configure(pre, {}, name="pre")
+    prior = trace.get_tracer()
+    trace.install(trace.Tracer(1024))
+    try:
+        it = batch_iterator(
+            make_source(20), pre, 4, training=False, shuffle=False
+        )
+        n = 0
+        for _ in prefetch_to_device(it, size=1):
+            time.sleep(0.01)
+            n += 1
+        assert n == 5
+        deadline = time.time() + 5.0
+        while time.time() < deadline and not any(
+            r["name"] == "loader_assemble" and r["step"] == 5
+            for r in trace.get_tracer().snapshot()
+        ):
+            time.sleep(0.01)  # the producer's last pull, after the pass
+        yield trace.get_tracer().snapshot()
+    finally:
+        trace.install(prior)
+
+
+def test_producer_spans_are_leaves_on_the_prefetch_thread(loader_records):
+    from tests.observability.trace_leaves import overlapping_spans
+
+    spans = [r for r in loader_records if r["phase"] == "X"]
+    assert {r["name"] for r in spans} == {
+        "loader_assemble", "loader_stage", "loader_put_wait",
+    }
+    assert {r["thread_name"] for r in spans} == {"zk-prefetch"}
+    assert overlapping_spans(loader_records) == []
+
+
+def test_leaves_of_one_batch_share_its_index_and_do_not_overlap(loader_records):
+    by_step = {}
+    for r in loader_records:
+        by_step.setdefault(r["step"], []).append(r)
+    # batches 0..4 have all three leaves, in order; the pull that found
+    # the iterator exhausted (index 5) made no batch
+    assert sorted(by_step) == [0, 1, 2, 3, 4, 5]
+    assert [r["name"] for r in by_step[5]] == ["loader_assemble"]
+    at = 0
+    for step in range(5):
+        names = [r["name"] for r in by_step[step]]
+        assert names == ["loader_assemble", "loader_stage", "loader_put_wait"]
+        for r in by_step[step]:
+            assert r["ts_ns"] >= at
+            at = r["ts_ns"] + r["dur_ns"]
+    # a slow consumer behind a queue of one: the producer waited to put
+    waited = sum(
+        r["dur_ns"] for r in loader_records if r["name"] == "loader_put_wait"
+    )
+    assert waited > 5_000_000
+
+
+def test_producer_records_nothing_while_tracing_is_off():
+    from zookeeper_tpu.observability import trace
+
+    assert not trace.enabled()
+    pre = PassThroughPreprocessing()
+    configure(pre, {}, name="pre")
+    it = batch_iterator(make_source(8), pre, 4, training=False, shuffle=False)
+    assert len(list(prefetch_to_device(it, size=2))) == 2
+    assert trace.get_tracer() is None

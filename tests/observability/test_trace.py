@@ -161,3 +161,155 @@ def test_span_is_exception_safe():
             raise ValueError("boom")
     (rec,) = tracer.snapshot()
     assert rec["name"] == "failing"  # recorded despite the raise
+
+
+# -- PR 24: the thread-local current step, the profiler's clock -----------
+
+
+def test_current_step_is_taken_by_records_without_a_step_of_their_own():
+    tracer = trace.enable(128)
+    trace.set_current_step(41)
+    with trace.span("sched_sweep"):
+        pass
+    trace.event("token_delivered", rid=5)
+    with trace.span("dispatch", step=7):  # an explicit step wins
+        pass
+    trace.event("host_memory", step=8)
+    trace.set_current_step(None)
+    with trace.span("after"):
+        pass
+    steps = [(r["name"], r["step"]) for r in tracer.snapshot()]
+    assert steps == [
+        ("sched_sweep", 41), ("token_delivered", 41), ("dispatch", 7),
+        ("host_memory", 8), ("after", None),
+    ]
+
+
+def test_current_step_belongs_to_the_thread_that_set_it():
+    tracer = trace.enable(128)
+    trace.set_current_step(3)
+    seen = []
+
+    def other():
+        with trace.span("elsewhere"):
+            pass
+        trace.set_current_step(9)
+        trace.event("elsewhere_event")
+        seen.append(True)
+
+    t = threading.Thread(target=other, name="zk-test-other")
+    t.start()
+    t.join()
+    with trace.span("here"):
+        pass
+    trace.set_current_step(None)
+    by_name = {r["name"]: r["step"] for r in tracer.snapshot()}
+    assert seen and by_name == {
+        "elsewhere": None, "elsewhere_event": 9, "here": 3,
+    }
+
+
+def test_disabled_calls_allocate_nothing_and_store_no_step():
+    """The cost contract with tracing off, for every call a hot loop now
+    makes: the shared no-op comes back, no record is made, no object is
+    allocated per call, and the current step is not even stored."""
+    import tracemalloc
+
+    assert trace.span("sched_sweep") is trace.span("loader_stage", step=1)
+    trace.set_current_step(5)  # off: one global read, no store
+
+    def hot_loop(n):
+        for i in range(n):
+            with trace.span("sched_admit_plan"):
+                pass
+            trace.event("token_delivered", rid=1)
+            trace.set_current_step(1)
+            trace.enabled()
+
+    hot_loop(10)  # warm every code path first
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        hot_loop(2000)
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    here = [
+        s for s in after.compare_to(before, "filename")
+        if s.traceback[0].filename.endswith("observability/trace.py")
+    ]
+    assert sum(s.size_diff for s in here) == 0
+    assert trace.get_tracer() is None
+    tracer = trace.enable(16)
+    with trace.span("first_after_enable"):
+        pass
+    assert tracer.snapshot()[0]["step"] is None  # the 5 was never stored
+
+
+def test_per_token_events_are_exported_and_stay_out_of_the_flow_chain():
+    trace.enable(64)
+    trace.event("decode_request_enqueue", rid=11)
+    for _ in range(4):
+        trace.event("token_delivered", rid=11)
+    trace.event("decode_stream_finish", rid=11)
+    doc = trace.to_chrome_trace()
+    tokens = [e for e in doc["traceEvents"] if e["name"] == "token_delivered"]
+    assert len(tokens) == 4 and all(e["args"]["rid"] == 11 for e in tokens)
+    flow = [e["ph"] for e in doc["traceEvents"] if e.get("cat") == "rid"]
+    assert sorted(flow) == ["f", "s"]
+
+
+def test_spans_land_in_the_xplane_host_plane_on_the_profilers_clock(tmp_path):
+    """While a ``jax.profiler`` session is open the program's spans are
+    written into the trace's host plane next to the device ops, on the
+    profiler's clock. The proof that it is ONE clock: a mark taken the way
+    the benchmark takes it gives the offset between the profiler's clock
+    and ``perf_counter_ns``, and the ring's record plus that offset lands
+    on the plane's event to within 0.2 ms."""
+    import glob
+    import time
+
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    tracer = trace.enable(1024)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test_mark"):
+            mark_host_ns = time.perf_counter_ns()
+        time.sleep(0.002)
+        for i in range(3):
+            with trace.span("pr24_clock_probe", step=i):
+                time.sleep(0.003)
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    marks, probes = [], []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == "test_mark":
+                    marks.append(ev.start_ns)
+                elif ev.name == "pr24_clock_probe":
+                    probes.append((ev.start_ns, ev.duration_ns))
+    assert len(marks) == 1 and len(probes) == 3
+    offset = marks[0] - mark_host_ns
+    ring = [r for r in tracer.snapshot() if r["name"] == "pr24_clock_probe"]
+    assert len(ring) == 3
+    for (start, dur), rec in zip(sorted(probes), ring):
+        assert abs(rec["ts_ns"] + offset - start) < 200_000
+        assert dur >= rec["dur_ns"]  # the ring's interval lies inside
+
+
+def test_a_tracer_without_jax_records_to_the_ring_alone(monkeypatch):
+    monkeypatch.setattr(trace, "_ANNOTATION", None)
+    monkeypatch.setattr(trace, "_resolve_annotation", lambda: None)
+    tracer = trace.enable(8)
+    with trace.span("no_jax_here"):
+        pass
+    assert [r["name"] for r in tracer.snapshot()] == ["no_jax_here"]

@@ -189,12 +189,12 @@ def test_kernel_engine_publishes_hbm_gauges(lm, prompts):
     reg = default_registry()
     # Bind-time: provisioned KV bytes exported (the PR-9 accounting
     # gap); the PER-ENGINE mbu is exactly the -1-unknown sentinel
-    # before this engine's first dispatch (the process-global gauge may
-    # hold another engine's value — that's the export path, not this
-    # engine's number).
+    # before this engine's first dispatch. It has no registry series
+    # (PR 24: static cost-analysis bytes, read by nothing).
     assert reg.gauge("zk_decode_kv_bytes").value == float(
         engine.kv_cache_nbytes
     )
+    assert "zk_decode_mbu" not in {inst.name for inst in reg.collect()}
     assert engine.decode_mbu == -1.0
     serve(engine, prompts[:3], new_tokens=4)
     mbu = engine.decode_mbu

@@ -314,10 +314,15 @@ def test_decode_sync_rid_flow_and_summary(decode_pair, fresh_tracer):
     tokens = stream.result()
     assert tokens.shape[0] == 3
     chain, names, _ = flow_chain(rid)
+    # the flow arrow links the request's phases; its three tokens are
+    # rid-tagged events too, and stay out of the arrow
     assert [e["ph"] for e in chain] == ["s", "t", "f"]
     assert names == [
         "decode_request_enqueue",
         "decode_request_dispatch",
+        "token_delivered",
+        "token_delivered",
+        "token_delivered",
         "decode_stream_finish",
     ]
     rec = sched.request_log.find(rid)
@@ -469,3 +474,127 @@ def test_statusz_requests_section_renders(engine, no_global_recorder):
             svc._teardown_service(suppress=True)
         # Teardown disarms the global recorder.
         assert recorder_mod.get_recorder() is None
+
+
+# -- PR 24: the scheduler's leaf spans ------------------------------------
+
+
+SCHED_LEAVES = {
+    "sched_sweep", "sched_admit_plan", "sched_admit_commit",
+    "sched_decode_plan", "sched_deliver", "sched_bookkeeping",
+}
+DISPATCH_SPANS = {
+    "prefill_dispatch", "prefill_warm_dispatch", "prefill_chunk_dispatch",
+    "decode_dispatch",
+}
+
+
+def traced_serving_run(sched, n_requests=5, max_new_tokens=4):
+    rng = np.random.default_rng(7)
+    streams = [
+        sched.submit(
+            rng.integers(1, 50, size=int(rng.integers(2, 8))).astype(np.int32),
+            max_new_tokens=max_new_tokens,
+        )
+        for _ in range(n_requests)
+    ]
+    for stream in streams:
+        stream.result(timeout=120)
+    return streams
+
+
+@pytest.fixture(scope="module")
+def chunked_pair():
+    from tests.serving.test_chunked_prefill import chunked_engine
+    from tests.serving.test_decode_engine import build_lm
+
+    module, params, state, _ = build_lm()
+    eng = chunked_engine(module, params, state, chunk=4, name="pr24chunk")
+    eng.warmup()
+    return eng, None
+
+
+@pytest.fixture(params=["sync", "worker", "chunked"])
+def leaf_run(request, decode_pair, fresh_tracer):
+    """A tiny traced serving run in each of the scheduler's modes:
+    ``(records, streams)``."""
+    if request.param == "chunked":
+        pair = request.getfixturevalue("chunked_pair")
+        sched = make_scheduler(pair)
+    else:
+        sched = make_scheduler(
+            decode_pair, synchronous=request.param == "sync"
+        )
+    try:
+        streams = traced_serving_run(sched)
+    finally:
+        sched.close()
+    return fresh_tracer.snapshot(), streams
+
+
+def test_no_span_of_the_scheduler_encloses_another(leaf_run):
+    from tests.observability.trace_leaves import overlapping_spans
+
+    records, _ = leaf_run
+    names = {r["name"] for r in records if r["phase"] == "X"}
+    assert SCHED_LEAVES <= names
+    assert names & DISPATCH_SPANS
+    assert overlapping_spans(records) == []
+
+
+def test_leaves_of_one_iteration_share_its_step_and_end_in_one_event(leaf_run):
+    from tests.observability.trace_leaves import iterations
+
+    records, _ = leaf_run
+    groups = iterations(records)
+    assert len(groups) >= 3
+    # every scheduler leaf and every dispatch span belongs to a closed
+    # iteration: none carries no step, none a step that never ended
+    for r in records:
+        if r["name"] in SCHED_LEAVES | DISPATCH_SPANS:
+            assert (r["thread_id"], r["step"]) in groups, r
+    previous_end = {}
+    for (thread, step), recs in sorted(groups.items()):
+        ends = [r for r in recs if r["name"] == "sched_iteration_end"]
+        assert len(ends) == 1
+        assert set(ends[0]["attrs"]) == {"admitted", "decoded", "chunks"}
+        spans = [r for r in recs if r["phase"] == "X"]
+        assert spans[0]["name"] == "sched_sweep"
+        assert spans[-1]["name"] == "sched_bookkeeping"
+        # in order, not overlapping, inside the iteration
+        at = previous_end.get(thread, 0)
+        for r in spans:
+            assert r["ts_ns"] >= at
+            at = r["ts_ns"] + r["dur_ns"]
+        assert at <= ends[0]["ts_ns"]
+        previous_end[thread] = ends[0]["ts_ns"]
+    admitted = sum(
+        r["attrs"]["admitted"] for r in records
+        if r["name"] == "sched_iteration_end"
+    )
+    assert admitted == 5
+
+
+def test_every_delivered_token_has_one_token_delivered_event(leaf_run):
+    records, streams = leaf_run
+    for stream in streams:
+        events = [
+            r for r in records
+            if r["name"] == "token_delivered" and r["rid"] == stream.rid
+        ]
+        tokens = stream.tokens_so_far
+        assert len(events) == len(tokens) == 4
+        assert len(stream.token_times_ns) == len(tokens)
+        assert stream.token_times_ns == sorted(stream.token_times_ns)
+        # stamped by the thread that delivers, inside an iteration
+        assert all(r["step"] is not None for r in events)
+        for stamp, r in zip(stream.token_times_ns, events):
+            assert 0 <= r["ts_ns"] - stamp < 5_000_000
+
+
+def test_token_times_stay_empty_while_tracing_is_off(decode_pair):
+    assert not trace.enabled()
+    sched = make_scheduler(decode_pair)
+    stream = sched.submit(np.arange(1, 5, dtype=np.int32), max_new_tokens=3)
+    assert stream.result().shape[0] == 3
+    assert stream.token_times_ns == []

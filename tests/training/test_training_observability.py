@@ -278,93 +278,76 @@ def test_profiling_window_still_closed_on_clean_run(tmp_path, monkeypatch):
 # -- device-side ledger / step-time watchdog / live MFU (docs §14) -------
 
 
-def test_live_run_publishes_step_time_and_mfu_gauges(tmp_path, monkeypatch):
-    """The acceptance artifact: a real (eager, log_every-synced)
-    training run publishes zk_train_step_time_ms and zk_train_mfu from
-    ledger FLOPs / measured step time / the shared reference peak —
-    and the gauge agrees with the hand computation from its own
-    inputs. The CPU backend has no peak anchor of its own (see
-    test_cpu_run_publishes_unknown_mfu), so the anchor is given by its
-    override here."""
-    from zookeeper_tpu.observability.ledger import default_ledger, mfu
-    from zookeeper_tpu.observability.peaks import reference_peak_flops
+def _gauge_names(reg):
+    return {inst.name for inst in reg.collect()}
 
-    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "184e12")
+
+def test_live_run_publishes_step_time_gauge(tmp_path):
+    """The acceptance artifact: a real (eager, log_every-synced)
+    training run publishes zk_train_step_time_ms from its sync points
+    and ledgers the step it dispatched. The cost_analysis-based
+    zk_train_mfu gauges are gone (PR 24: the flops see no Pallas
+    kernel; a share of a peak comes from the benchmark's trace)."""
+    from zookeeper_tpu.observability.ledger import default_ledger
+
     exp = make_experiment(tmp_path, {"log_every": 2})
     exp.run()
     reg = exp.obs_registry
     step_ms = reg.gauge("zk_train_step_time_ms").value
     assert step_ms > 0
-    mfu_value = reg.gauge("zk_train_mfu").value
     rec = default_ledger().latest("train_step")
     assert rec is not None and rec.dispatches > 0
-    if rec.flops:
-        expected = mfu(rec.flops, step_ms / 1e3, reference_peak_flops()[0])
-        assert mfu_value == pytest.approx(expected, rel=1e-6)
-        assert 0 < mfu_value < 1
-    else:
-        assert mfu_value == -1  # unknown renders as the sentinel
+    assert not {n for n in _gauge_names(reg) if "mfu" in n}
 
 
-def test_cpu_run_publishes_unknown_mfu(tmp_path, monkeypatch):
-    """A backend in no peak table row (the CPU) is never rated against
-    the v5e's peak: the MFU gauge keeps its -1 "unknown"."""
-    monkeypatch.delenv("ZK_BENCH_PEAK_FLOPS", raising=False)
+def test_cpu_run_publishes_step_time_without_a_peak(tmp_path, monkeypatch):
+    """A backend in no peak table row (the CPU) still gets its
+    step-time gauge: it needs no peak anchor and reads no override."""
+    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "not-a-number")
     exp = make_experiment(tmp_path, {"log_every": 2})
     exp.run()
     assert exp.obs_registry.gauge("zk_train_step_time_ms").value > 0
-    assert exp.obs_registry.gauge("zk_train_mfu").value == -1
+    assert "zk_train_mfu" not in _gauge_names(exp.obs_registry)
 
 
-def test_fused_run_ledgers_multi_step_and_divides_flops_by_unroll(
-    tmp_path, monkeypatch,
-):
-    """The fused (unroll>1) loop's MFU divides the slab executable's
-    FLOPs by the unroll factor — per-STEP utilization, same definition
-    as the eager loop."""
-    from zookeeper_tpu.observability.ledger import default_ledger, mfu
-    from zookeeper_tpu.observability.peaks import reference_peak_flops
+def test_fused_run_ledgers_multi_step_and_times_per_step(tmp_path):
+    """The fused (unroll>1) loop ledgers its slab program with the
+    slab's size and publishes PER-STEP time: the sync interval over
+    the steps between two sync points, same definition as the eager
+    loop."""
+    from zookeeper_tpu.observability.ledger import default_ledger
 
-    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "184e12")
     exp = make_experiment(tmp_path, {"unroll": 2, "log_every": 2})
     exp.run()
     rec = default_ledger().latest("multi_step")
     assert rec is not None
     assert rec.compile_ms is not None
+    assert rec.attrs["steps"] == 2
     reg = exp.obs_registry
     step_ms = reg.gauge("zk_train_step_time_ms").value
     assert step_ms > 0
-    if rec.flops:
-        expected = mfu(
-            rec.flops / 2, step_ms / 1e3, reference_peak_flops()[0]
-        )
-        assert reg.gauge("zk_train_mfu").value == pytest.approx(
-            expected, rel=1e-6
-        )
+    ewma_ms = reg.gauge(
+        "zk_step_time_ewma_ms", labels={"stream": "train_step"}
+    ).value
+    # one stream feeds both: the gauge is the last per-step sample of
+    # the series the watchdog averages
+    assert 0 < ewma_ms and step_ms < 50 * ewma_ms
 
 
-def test_mfu_divides_by_recorded_slab_size_not_configured_unroll(
-    tmp_path, monkeypatch,
-):
-    """A partial first slab (mid-epoch resume, spe < unroll) compiles
-    the recorded multi_step program for k < unroll steps; the MFU
-    divisor must be the program's actual slab size, not the config."""
-    from zookeeper_tpu.observability.ledger import ProgramRecord, mfu
-    from zookeeper_tpu.observability.peaks import reference_peak_flops
+def test_step_time_divides_by_the_steps_between_sync_points(tmp_path):
+    """Two sync points three steps apart (a partial slab, a resume):
+    the gauge is the interval over the steps actually completed, not
+    over the configured unroll."""
+    import time
 
-    monkeypatch.setenv("ZK_BENCH_PEAK_FLOPS", "184e12")
     exp = make_experiment(tmp_path, {"unroll": 8})
-
-    class FakeProgram:
-        ledger_entry = ProgramRecord(
-            kind="multi_step", key="k", flops=9e9, attrs={"steps": 3}
-        )
-
-    exp._publish_mfu(0.5, FakeProgram())
-    expected = mfu(9e9 / 3, 0.5, reference_peak_flops()[0])
-    assert exp.obs_registry.gauge("zk_train_mfu").value == pytest.approx(
-        expected, rel=1e-6
-    )
+    exp._obs_reset_timers()
+    exp._obs_timer["sync_t"] = time.perf_counter() - 1.5
+    exp._obs_timer["sync_step"] = 4
+    exp._obs_sync_point(7)
+    assert exp.obs_registry.gauge(
+        "zk_train_step_time_ms"
+    ).value == pytest.approx(500.0, rel=0.02)
 
 
 def test_steady_run_fires_no_step_anomalies(tmp_path):
@@ -500,3 +483,66 @@ def test_nan_halt_and_recovery_each_write_a_bundle(tmp_path):
             if prior is not None
             else recorder_mod.uninstall()
         )
+
+
+# -- PR 24: host_memory at the sync points, leaves on the loop's threads --
+
+
+@pytest.mark.parametrize("unroll", [1, 2])
+def test_traced_run_records_host_memory_at_every_sync_point(tmp_path, unroll):
+    """One ``host_memory`` event per sync point (each log_every readback
+    and the epoch's end), carrying the machine's memory in use and this
+    process's RSS in bytes, ``step`` = the global step."""
+    tracer = trace.enable(4096)
+    exp = make_experiment(tmp_path, {"log_every": 2, "unroll": unroll})
+    exp.run()
+    records = tracer.snapshot()
+    events = [r for r in records if r["name"] == "host_memory"]
+    readbacks = [r for r in records if r["name"] == "readback"]
+    # 8 steps, log_every 2: syncs at 2, 4, 6, 8 and the epoch's end at 8
+    assert [r["step"] for r in events] == [2, 4, 6, 8, 8]
+    assert len(events) == len(readbacks)
+    for r in events:
+        assert r["phase"] == "i" and set(r["attrs"]) == {
+            "in_use_bytes", "rss_bytes",
+        }
+        assert r["attrs"]["in_use_bytes"] > r["attrs"]["rss_bytes"] > 0
+
+
+def test_untraced_run_reads_no_proc_file_at_its_sync_points(
+    tmp_path, monkeypatch
+):
+    from zookeeper_tpu.training import experiment as experiment_module
+
+    def never(*a, **k):
+        raise AssertionError("host memory read with tracing off")
+
+    monkeypatch.setattr(experiment_module, "_host_memory", never)
+    exp = make_experiment(tmp_path, {"log_every": 2})
+    exp.run()
+    assert exp.obs_registry.gauge("zk_train_step_time_ms").value > 0
+
+
+def test_no_span_of_the_train_loop_or_the_loader_encloses_another(tmp_path):
+    from tests.observability.trace_leaves import overlapping_spans
+
+    tracer = trace.enable(4096)
+    exp = make_experiment(tmp_path, {"log_every": 2})
+    exp.run()
+    records = tracer.snapshot()
+    names = {r["name"] for r in records if r["phase"] == "X"}
+    assert {
+        "data_wait", "dispatch", "readback",
+        "loader_assemble", "loader_stage", "loader_put_wait",
+    } <= names
+    threads = {
+        r["name"]: r["thread_name"] for r in records if r["phase"] == "X"
+    }
+    assert threads["loader_stage"] == "zk-prefetch"
+    assert threads["dispatch"] != "zk-prefetch"
+    # the one enclosure the program has kept from before the leaf rule:
+    # the loop's ``checkpoint`` span around the checkpointer's own
+    # (``ckpt_snapshot`` / ``ckpt_sync_save``); no benchmark cell saves
+    found = overlapping_spans(records)
+    assert [v for v in found if v[1] != "checkpoint"] == []
+    assert all(v[2].startswith("ckpt_") for v in found)

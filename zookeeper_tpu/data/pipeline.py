@@ -29,6 +29,7 @@ import numpy as np
 
 from zookeeper_tpu.core import ComponentField, Field, component
 from zookeeper_tpu.data.dataset import Dataset
+from zookeeper_tpu.observability import trace as _trace
 from zookeeper_tpu.observability.registry import default_registry
 from zookeeper_tpu.data.preprocessing import Preprocessing
 from zookeeper_tpu.data.source import DataSource
@@ -403,12 +404,28 @@ def prefetch_to_device(
     )
 
     def producer():
+        # Three leaf spans per batch, sharing ``step`` = the batch's
+        # index in this pass: where this thread's time goes is the
+        # loader's breakdown (assemble: gather/augment or the worker
+        # pool; stage: host-to-device; put_wait: queue full, so the
+        # device is the limit). No-ops while tracing is off.
         try:
-            for batch in iterator:
-                batch = stage(batch)
-                if not put_or_stop(batch):
+            it = iter(iterator)
+            index = 0
+            while True:
+                with _trace.span("loader_assemble", step=index):
+                    try:
+                        batch = next(it)
+                    except StopIteration:
+                        break
+                with _trace.span("loader_stage", step=index):
+                    batch = stage(batch)
+                with _trace.span("loader_put_wait", step=index):
+                    put = put_or_stop(batch)
+                if not put:
                     return  # Consumer gone: drop refs, free device buffers.
                 occupancy.set(q.qsize())
+                index += 1
         except BaseException as e:  # propagate into consumer
             err.append(e)
         finally:

@@ -21,8 +21,8 @@ The device-side half (docs/DESIGN.md §14) rides the same substrate:
 
 - ``ledger`` — the process-global program ledger: every lower/compile
   seam records identity key, XLA cost-analysis FLOPs/bytes, compile
-  wall time and compiled memory analysis; feeds the ``zk_train_mfu`` /
-  ``zk_serve_mfu`` gauges and a ``/statusz`` section.
+  wall time and compiled memory analysis; feeds the ``zk_serve_mfu``
+  gauge and a ``/statusz`` section.
 - ``watchdog`` — EWMA+MAD step-time anomaly detection over the
   slab/step/dispatch duration streams (``step_time_anomaly`` /
   ``recompile_detected`` events + counters).

@@ -30,9 +30,8 @@ lower/compile seam:
   the wrapped ``jit`` callable, which retraces exactly as an
   uninstrumented seam would.
 - :func:`mfu` — FLOPs/time/peak with total guards; the gauge math for
-  ``zk_train_mfu`` / ``zk_serve_mfu`` (peaks from
-  ``observability.peaks`` so the live gauges and bench.py divide by
-  the same anchors).
+  ``zk_serve_mfu`` (peaks from ``observability.peaks`` so the live
+  gauge and bench.py divide by the same anchors).
 
 Identity keys (docs/DESIGN.md §14): ``<kind>`` names the seam
 (``train_step`` / ``multi_step`` / ``eval_step`` / ``serve_forward`` /
@@ -344,8 +343,8 @@ def mbu(
     the roofline lens for MEMORY-bound programs (decode_step reads the
     KV cache and weights every token; its MFU is meaninglessly low by
     construction). Same totality contract as :func:`mfu`: None unless
-    every input is positive and finite, so the ``zk_decode_mbu`` gauge
-    renders -1-unknown instead of raising or lying. NOTE the bytes side
+    every input is positive and finite, so the engine's ``decode_mbu``
+    reads -1-unknown instead of raising or lying. NOTE the bytes side
     is XLA's STATIC cost analysis — with a length-aware kernel the true
     bytes read are lower, so the gauge is an upper bound
     (docs/DESIGN.md §17)."""
@@ -412,6 +411,13 @@ class LedgeredExecutable:
         self._compiled = None
         self._signature = None
         self.ledger_entry: Optional[ProgramRecord] = None
+
+    @property
+    def compiled(self):
+        """The ``jax.stages.Compiled`` this wrapper dispatches (None
+        before the first call): jax's own ``memory_analysis()`` /
+        ``cost_analysis()`` of the very executable that runs."""
+        return self._compiled
 
     def _ledger_obj(self) -> ProgramLedger:
         return self._ledger if self._ledger is not None else default_ledger()

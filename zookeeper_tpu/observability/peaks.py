@@ -5,7 +5,7 @@ MFU is only meaningful relative to a stated roofline, and the roofline
 itself is the easiest number to get wrong: above-physics "measured"
 peaks, generation-specific int8 factors, datasheet clamps. The
 device-side performance ledger (``observability.ledger``) needs the
-SAME anchors for its ``zk_train_mfu`` / ``zk_serve_mfu`` gauges as
+SAME anchors for its ``zk_serve_mfu`` gauge as
 ``bench.py`` — two copies would inevitably diverge — so the tables, the
 datasheet clamp, and the agreement-gated attempt aggregation live HERE;
 ``bench.py`` re-exports them unchanged.
@@ -221,7 +221,7 @@ def _device_kind(device_kind: Optional[str]) -> Optional[str]:
 def reference_hbm_bandwidth(
     device_kind: Optional[str] = None, env=None
 ) -> Tuple[Optional[float], str]:
-    """The HBM-bandwidth anchor for live MBU gauges (``zk_decode_mbu``),
+    """The HBM-bandwidth anchor for the decode engine's ``decode_mbu``,
     resolved WITHOUT touching the device — the bandwidth twin of
     :func:`reference_peak_flops`: ``ZK_BENCH_HBM_BANDWIDTH`` override
     (bytes/s) > the generation's datasheet bandwidth. Returns

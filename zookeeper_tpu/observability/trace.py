@@ -32,7 +32,18 @@ Cost contract (the instrumented call sites are hot loops):
 Records carry thread identity + name (satellite: every background
 thread here is ``zk-``-prefixed named) and optional ``step``/``slab``
 attribution so a span is traceable to the training-loop coordinate
-that produced it.
+that produced it. A loop that records many small spans per iteration
+(the decode scheduler) sets a thread-local current step once
+(:func:`set_current_step`); records made on that thread with no
+``step`` of their own take it, so the LEAVES of one iteration share
+one ``step`` without a span that encloses them.
+
+One clock with the device trace: while the tracer is enabled every
+span also enters a ``jax.profiler.TraceAnnotation`` of the same name
+(resolved once at :func:`enable`; skipped where jax is absent), so an
+open profiler session writes the program's spans into the xplane's
+host plane on the profiler's clock, beside the device ops. The ring
+itself stays on ``perf_counter_ns``.
 
 Request-scoped flow (docs/DESIGN.md §16): records may additionally
 carry a ``rid`` — the monotonically-minted request id from
@@ -60,9 +71,16 @@ __all__ = [
     "export_chrome_trace",
     "get_tracer",
     "install",
+    "set_current_step",
     "span",
     "to_chrome_trace",
 ]
+
+#: Rid-tagged instants recorded once per TOKEN, not once per phase of a
+#: request. The Chrome exporter keeps them as events and leaves them out
+#: of the rid's flow chain: a 256-token answer would bury the request's
+#: submit -> dispatch -> finish arrow under 256 steps.
+PER_TOKEN_EVENTS = frozenset({"token_delivered"})
 
 #: Default ring capacity: ~64k records covers minutes of slab-cadence
 #: training or tens of thousands of serving requests at a few MB of
@@ -91,6 +109,7 @@ class _Span:
 
     __slots__ = (
         "_tracer", "_name", "_step", "_slab", "_attrs", "_rid", "_t0",
+        "_annotation",
     )
 
     def __init__(self, tracer, name, step, slab, attrs, rid):
@@ -101,13 +120,22 @@ class _Span:
         self._attrs = attrs
         self._rid = rid
         self._t0 = 0
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
+        if _ANNOTATION is not None:
+            # The same interval on the profiler's clock (a no-op while
+            # no profiler session is open). Entered first and left
+            # last, so the ring's interval lies inside it.
+            self._annotation = _ANNOTATION(self._name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         thread = threading.current_thread()
         self._tracer._ring.append(
             (
@@ -142,9 +170,13 @@ class Tracer:
         self._ring: deque = deque(maxlen=self.capacity)
 
     def span(self, name, step=None, slab=None, attrs=None, rid=None) -> _Span:
+        if step is None:
+            step = getattr(_CURRENT, "step", None)
         return _Span(self, name, step, slab, attrs, rid)
 
     def event(self, name, step=None, attrs=None, rid=None) -> None:
+        if step is None:
+            step = getattr(_CURRENT, "step", None)
         thread = threading.current_thread()
         self._ring.append(
             (
@@ -221,6 +253,23 @@ class Tracer:
 #: paths read).
 _TRACER: Optional[Tracer] = None
 
+#: ``jax.profiler.TraceAnnotation`` while a tracer is installed and jax
+#: can be imported, else None.
+_ANNOTATION: Any = None
+
+#: Per-thread current step (:func:`set_current_step`).
+_CURRENT = threading.local()
+
+
+def _resolve_annotation() -> None:
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # no jax here: the ring alone
+            return
+        _ANNOTATION = TraceAnnotation
+
 
 def enable(capacity: int = DEFAULT_CAPACITY) -> Tracer:
     """Turn tracing on. Idempotent, first-enable-wins: when a tracer is
@@ -231,6 +280,7 @@ def enable(capacity: int = DEFAULT_CAPACITY) -> Tracer:
     capacity, ``disable()`` first."""
     global _TRACER
     if _TRACER is None:
+        _resolve_annotation()
         _TRACER = Tracer(capacity)
     return _TRACER
 
@@ -249,11 +299,22 @@ def install(tracer: Optional[Tracer]) -> None:
     ring and orphan held references. Normal code uses
     :func:`enable`/:func:`disable`."""
     global _TRACER
+    if tracer is not None:
+        _resolve_annotation()
     _TRACER = tracer
 
 
 def enabled() -> bool:
     return _TRACER is not None
+
+
+def set_current_step(step: Optional[int]) -> None:
+    """Set the calling thread's current step: every span or event
+    recorded on this thread without a ``step`` of its own carries it
+    (None clears it). One global read and no store when tracing is
+    disabled, like :func:`span`."""
+    if _TRACER is not None:
+        _CURRENT.step = step
 
 
 def get_tracer() -> Optional[Tracer]:
@@ -306,7 +367,8 @@ def to_chrome_trace(tracer: Optional[Tracer] = None) -> Dict[str, Any]:
     point timestamped INSIDE its record (mid-span for ``X`` records) so
     Perfetto binds it to the enclosing slice (``bp: "e"``) and draws
     one arrow from the submitting thread through the worker's dispatch
-    to the completion.
+    to the completion. Per-token instants (``PER_TOKEN_EVENTS``) are
+    exported as events and take no part in the chain.
     """
     tracer = tracer if tracer is not None else _TRACER
     records = tracer.snapshot() if tracer is not None else []
@@ -348,7 +410,7 @@ def to_chrome_trace(tracer: Optional[Tracer] = None) -> Dict[str, Any]:
         else:
             out["s"] = "t"  # instant scoped to its thread
         events.append(out)
-        if rid is not None:
+        if rid is not None and rec["name"] not in PER_TOKEN_EVENTS:
             # Flow point INSIDE the record: mid-span for X so the point
             # falls within the slice Perfetto binds the arrow to.
             flows.setdefault(rid, []).append(

@@ -523,30 +523,15 @@ DecodeScheduler`.
         )
 
     def _publish_bind_gauges(self) -> None:
-        """Bind-time decode gauges: the provisioned KV HBM
-        (``zk_decode_kv_bytes`` — computed since PR 9 but never
-        exported) and the MBU gauge registered at its -1 unknown
-        sentinel so a pre-traffic scrape renders the series."""
+        """Bind-time decode gauge: the provisioned KV HBM
+        (``zk_decode_kv_bytes``)."""
         from zookeeper_tpu.observability.registry import default_registry
 
-        reg = default_registry()
-        reg.gauge(
+        default_registry().gauge(
             "zk_decode_kv_bytes",
             help="HBM provisioned for the decode KV cache (k+v, all "
             "layers, full slot capacity)",
         ).set(float(self._cache_nbytes))
-        # Handle kept on the engine: _observe_decode runs once per
-        # decode dispatch and must not pay the registry lock + lookup
-        # per token.
-        object.__setattr__(self, "_mbu_gauge", reg.gauge(
-            "zk_decode_mbu",
-            help="last decode dispatch: ledger cost-analysis bytes / "
-            "wall time / reference HBM bandwidth (-1 = bytes or "
-            "bandwidth unknown); an UPPER bound with the paged kernel "
-            "(static analysis counts full buffers, the kernel reads "
-            "length-bounded blocks)",
-            initial=-1,
-        ))
 
     def decode_mbu_for(self, seconds: float, program: str = "decode_step") -> float:
         """MBU of a decode-path program (default ``decode_step``; the
@@ -554,7 +539,7 @@ DecodeScheduler`.
         given dispatch wall time: ledger cost-analysis bytes /
         ``seconds`` / reference HBM bandwidth, -1 when any input is
         unknown (the ``ledger.mbu`` totality contract — never raises).
-        The live gauge evaluates this at each dispatch's own time; the
+        ``decode_mbu`` evaluates this at each dispatch's own time; the
         bench evaluates it at the run's MEDIAN dispatch time so the
         gated ``decode_mbu`` key is not a single-sample ratio of the
         least-representative (drain-tail) dispatch."""
@@ -579,23 +564,18 @@ DecodeScheduler`.
     def _observe_decode(
         self, seconds: float, program: str = "decode_step"
     ) -> None:
-        """Publish ``zk_decode_mbu`` for one completed (readback-
-        bounded) decode-path dispatch — the memory-bound counterpart of
-        the forward engine's ``zk_serve_mfu`` (the decode loop is
-        HBM-bound, so FLOPs-based MFU is the wrong lens; docs/DESIGN.md
-        §17). Under speculation the hot program is ``verify_step``, not
-        ``decode_step`` — ``verify()`` feeds the gauge too, so the
-        roofline tracks whichever program actually serves. Total: a
-        gauge update never raises."""
+        """Keep THIS engine's ``decode_mbu`` for one completed
+        (readback-bounded) decode-path dispatch (``/statusz`` and
+        bench.py read it; no registry series: static cost-analysis
+        bytes count whole buffers the paged kernel never reads). Under
+        speculation the hot program is ``verify_step``, not
+        ``decode_step`` — ``verify()`` feeds it too. Total: never
+        raises."""
         if seconds <= 0:
             return
-        value = self.decode_mbu_for(seconds, program)
-        # Per-engine copy FIRST: the gauge is process-global (the
-        # export path), so with two engines live the gauge holds
-        # whichever dispatched last — decode_mbu/statusz must report
-        # THIS engine's number.
-        object.__setattr__(self, "_last_decode_mbu", value)
-        self._mbu_gauge.set(value)
+        object.__setattr__(
+            self, "_last_decode_mbu", self.decode_mbu_for(seconds, program)
+        )
 
     @property
     def decode_attention_flavor(self) -> str:
@@ -608,10 +588,9 @@ DecodeScheduler`.
     @property
     def decode_mbu(self) -> float:
         """THIS engine's last decode dispatch's memory-bandwidth
-        utilization (-1 = unknown / no dispatch yet). Deliberately the
-        per-engine copy, not the process-global ``zk_decode_mbu``
-        gauge: with two engines in one process (the bench A/B, flavor
-        tests) the gauge holds whichever engine dispatched last."""
+        utilization (-1 = unknown / no dispatch yet), kept per engine:
+        two engines in one process (the bench A/B, flavor tests) each
+        report their own."""
         return float(getattr(self, "_last_decode_mbu", -1.0))
 
     def _place_variables(self, variables: Any) -> Any:

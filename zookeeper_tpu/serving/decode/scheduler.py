@@ -109,6 +109,11 @@ class DecodeStream:
         self._deadline_at = deadline_at
         self._eos = eos_token
         self._tokens: List[int] = []
+        #: ``perf_counter_ns`` of each token's delivery, stamped by the
+        #: delivering thread. Filled only while tracing is on
+        #: (``observability.trace``), so it may be shorter than the
+        #: token list; empty otherwise.
+        self.token_times_ns: List[int] = []
         self._done = False
         self._error: Optional[BaseException] = None
         self._finish_reason: Optional[str] = None
@@ -185,6 +190,12 @@ class DecodeStream:
             if self._done:
                 return
             self._tokens.append(int(token))
+            if _trace.enabled():
+                # One event per delivered token, on the thread that
+                # delivers it: gaps between a request's tokens are read
+                # from here, with no client polling in between.
+                self.token_times_ns.append(time.perf_counter_ns())
+                _trace.event("token_delivered", rid=self.rid)
             self._cond.notify_all()
 
     def _finish(self, reason: str) -> None:
@@ -384,6 +395,10 @@ class DecodeScheduler:
         object.__setattr__(self, "_worker", None)
         object.__setattr__(self, "_stop", threading.Event())
         object.__setattr__(self, "_swap_pending", None)
+        # Iterations run so far: the ``step`` every trace record of one
+        # iteration shares (the scheduler's leaves and the engine's
+        # dispatch spans, through the tracer's thread-local step).
+        object.__setattr__(self, "_iteration", 0)
         return self
 
     def _require_bound(self) -> None:
@@ -842,162 +857,171 @@ class DecodeScheduler:
                     self._metrics.record_deadline_expired()
                 self._free_slot(slot)
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
         """Refill free slots from the queue head: one bucketed prefill
         dispatch per admitted group. Paused while a weight swap is
         pending (the drain that makes the swap safe). Caller holds
         ``_step_lock``; ``_lock`` is taken per phase so the prefill
         dispatch itself runs unlocked — admitted streams are RESERVED
         into the slot array first, so ``close()``/``_on_crash`` see
-        (and can fail) them mid-dispatch."""
+        (and can fail) them mid-dispatch. Returns the streams
+        admitted.
+
+        Trace leaves (docs: ``observability.trace``): everything up to
+        a group's dispatch is ``sched_admit_plan``, everything after it
+        ``sched_admit_commit``; the engine's dispatch spans lie between
+        and are enclosed by neither."""
         engine = self._engine
+        admitted_total = 0
         while True:
-            with self._lock:
-                if self._swap_pending is not None or not self._queue:
-                    return
-                free = [
-                    i for i, s in enumerate(self._slot_stream) if s is None
-                ]
-                if not free:
-                    return
-                group: List[DecodeStream] = []
-                slots: List[int] = []
-                cap = min(len(free), max(engine._prefill_buckets))
-                while self._queue and len(group) < cap:
-                    stream = self._queue.popleft()
-                    if stream.expired():
-                        if stream._expire() and self._metrics is not None:
-                            self._metrics.record_deadline_expired()
-                        continue
-                    if self._brownout_active:
-                        # Brown-out: every stream admitted while
-                        # engaged gets a capped token budget. Applied
-                        # at ADMISSION only — in-flight budgets are
-                        # never rewritten (docs/DESIGN.md §24).
-                        stream._max_new = min(
-                            stream._max_new,
-                            int(self._guard.brownout_max_new_tokens),
-                        )
-                    group.append(stream)
-                    slots.append(free[len(group) - 1])
-                if not group:
-                    continue
-                t0_ns = time.perf_counter_ns()
-                for stream, slot in zip(group, slots):
-                    self._slot_stream[slot] = stream
-                    self._slot_lengths[slot] = int(stream.prompt.shape[0])
-                    self._slot_last_emit[slot] = 0.0
-                    # Dispatch attribution BEFORE the device work (a
-                    # crash mid-prefill still shows the stream reached
-                    # dispatch), rid-tagged so the exporter links the
-                    # submit event to this slot's prefill.
-                    stream._slot = slot
-                    stream._role = "decode"
-                    if stream._t_dispatch_ns is None:
-                        stream._t_dispatch_ns = t0_ns
-                    if _trace.enabled() and stream.rid is not None:
-                        _trace.event(
-                            "decode_request_dispatch",
-                            rid=stream.rid,
-                            attrs={"slot": slot},
-                        )
-            # Page allocation per admitted stream (docs/DESIGN.md §20;
-            # slot layout: trivial cold plans). The POOL bookkeeping
-            # runs under _lock (close()/crash release pages under the
-            # same lock — the PagePool is lock-guarded scheduler
-            # state); only the rare one-page CoW copy dispatches
-            # outside, like the prefill itself. A pool-exhausted
-            # stream is put back at the QUEUE HEAD (its slot
-            # reservation undone) — it admits as soon as finishing
-            # streams release pages; if the pool cannot serve it even
-            # with every slot idle and the prefix cache evicted, it is
-            # shed with RejectedError (it could never run).
-            plans = []
-            admitted: List[DecodeStream] = []
-            admitted_slots: List[int] = []
-            with self._lock:
-                overflow = []
-                for stream, slot in zip(group, slots):
-                    if self._slot_stream[slot] is not stream:
-                        continue  # failed by close()/crash already
-                    plan = engine.admit_slot(
-                        slot, stream.prompt, copy=False
-                    )
-                    if plan is None:
-                        overflow.append((stream, slot))
-                    else:
-                        stream._shared_tokens = int(
-                            plan.get("shared_tokens") or 0
-                        )
-                        plans.append(plan)
-                        admitted.append(stream)
-                        admitted_slots.append(slot)
-                others_active = any(
-                    s is not None
-                    and i not in [sl for _, sl in overflow]
-                    for i, s in enumerate(self._slot_stream)
-                ) or bool(admitted)
-                for stream, slot in reversed(overflow):
-                    self._slot_stream[slot] = None
-                    if others_active:
-                        # Pages free as streams finish: requeue.
-                        self._queue.appendleft(stream)
-                    else:
-                        # Nothing in flight and the pool still cannot
-                        # hold this prompt: unservable.
-                        if self._metrics is not None:
-                            self._metrics.record_rejected()
-                        stream._fail(RejectedError(
-                            "KV page pool exhausted with no active "
-                            "streams to wait for: the prompt needs "
-                            "more pages than pool_pages can ever free "
-                            "— raise engine.pool_pages or shorten the "
-                            "prompt."
-                        ))
-            if not admitted:
-                if overflow:
-                    return
-                continue
-            group, slots = admitted, admitted_slots
-            # CoW copies outside the lock (device work). A page whose
-            # stream was failed mid-loop just writes bytes into a
-            # released page — unreferenced, overwritten or masked by
-            # any future tenant (the validity invariant).
-            for plan in plans:
-                cow = plan.pop("cow", None)
-                if cow is not None:
-                    engine.copy_page(*cow)
-            if getattr(self, "_chunked", False):
-                # Chunked admission (docs/DESIGN.md §25): pages are
-                # allocated and any warm prefix is already committed
-                # (CoW done above), but NO prefill dispatches here —
-                # the token-budget planner (_prefill_chunks) appends
-                # the prompt chunk by chunk, interleaved with decode
-                # iterations, and TTFT is stamped on the FINAL chunk.
-                # Warm hits start their cursor past the cached prefix,
-                # so fully-warm prompts cost a single 1-token chunk.
+            with _trace.span("sched_admit_plan"):
                 with self._lock:
-                    now = time.perf_counter()
-                    for stream, slot, plan in zip(group, slots, plans):
+                    if self._swap_pending is not None or not self._queue:
+                        return admitted_total
+                    free = [
+                        i for i, s in enumerate(self._slot_stream) if s is None
+                    ]
+                    if not free:
+                        return admitted_total
+                    group: List[DecodeStream] = []
+                    slots: List[int] = []
+                    cap = min(len(free), max(engine._prefill_buckets))
+                    while self._queue and len(group) < cap:
+                        stream = self._queue.popleft()
+                        if stream.expired():
+                            if stream._expire() and self._metrics is not None:
+                                self._metrics.record_deadline_expired()
+                            continue
+                        if self._brownout_active:
+                            # Brown-out: every stream admitted while
+                            # engaged gets a capped token budget. Applied
+                            # at ADMISSION only — in-flight budgets are
+                            # never rewritten (docs/DESIGN.md §24).
+                            stream._max_new = min(
+                                stream._max_new,
+                                int(self._guard.brownout_max_new_tokens),
+                            )
+                        group.append(stream)
+                        slots.append(free[len(group) - 1])
+                    if not group:
+                        continue
+                    t0_ns = time.perf_counter_ns()
+                    for stream, slot in zip(group, slots):
+                        self._slot_stream[slot] = stream
+                        self._slot_lengths[slot] = int(stream.prompt.shape[0])
+                        self._slot_last_emit[slot] = 0.0
+                        # Dispatch attribution BEFORE the device work (a
+                        # crash mid-prefill still shows the stream reached
+                        # dispatch), rid-tagged so the exporter links the
+                        # submit event to this slot's prefill.
+                        stream._slot = slot
+                        stream._role = "decode"
+                        if stream._t_dispatch_ns is None:
+                            stream._t_dispatch_ns = t0_ns
+                        if _trace.enabled() and stream.rid is not None:
+                            _trace.event(
+                                "decode_request_dispatch",
+                                rid=stream.rid,
+                                attrs={"slot": slot},
+                            )
+                # Page allocation per admitted stream (docs/DESIGN.md §20;
+                # slot layout: trivial cold plans). The POOL bookkeeping
+                # runs under _lock (close()/crash release pages under the
+                # same lock — the PagePool is lock-guarded scheduler
+                # state); only the rare one-page CoW copy dispatches
+                # outside, like the prefill itself. A pool-exhausted
+                # stream is put back at the QUEUE HEAD (its slot
+                # reservation undone) — it admits as soon as finishing
+                # streams release pages; if the pool cannot serve it even
+                # with every slot idle and the prefix cache evicted, it is
+                # shed with RejectedError (it could never run).
+                plans = []
+                admitted: List[DecodeStream] = []
+                admitted_slots: List[int] = []
+                with self._lock:
+                    overflow = []
+                    for stream, slot in zip(group, slots):
                         if self._slot_stream[slot] is not stream:
                             continue  # failed by close()/crash already
-                        shared = int(plan.get("shared_tokens") or 0)
-                        # While mid-prefill, _slot_lengths tracks the
-                        # COMMITTED prefix (the chunk cursor), not the
-                        # final prompt length.
-                        self._slot_lengths[slot] = shared
-                        self._chunk_state[slot] = {
-                            "pos": shared,
-                            "admit_t": now,
-                        }
-                continue
-            cold = [
-                i for i, p in enumerate(plans)
-                if not p.get("shared_tokens")
-            ]
-            warm = [
-                i for i, p in enumerate(plans) if p.get("shared_tokens")
-            ]
+                        plan = engine.admit_slot(
+                            slot, stream.prompt, copy=False
+                        )
+                        if plan is None:
+                            overflow.append((stream, slot))
+                        else:
+                            stream._shared_tokens = int(
+                                plan.get("shared_tokens") or 0
+                            )
+                            plans.append(plan)
+                            admitted.append(stream)
+                            admitted_slots.append(slot)
+                    others_active = any(
+                        s is not None
+                        and i not in [sl for _, sl in overflow]
+                        for i, s in enumerate(self._slot_stream)
+                    ) or bool(admitted)
+                    for stream, slot in reversed(overflow):
+                        self._slot_stream[slot] = None
+                        if others_active:
+                            # Pages free as streams finish: requeue.
+                            self._queue.appendleft(stream)
+                        else:
+                            # Nothing in flight and the pool still cannot
+                            # hold this prompt: unservable.
+                            if self._metrics is not None:
+                                self._metrics.record_rejected()
+                            stream._fail(RejectedError(
+                                "KV page pool exhausted with no active "
+                                "streams to wait for: the prompt needs "
+                                "more pages than pool_pages can ever free "
+                                "— raise engine.pool_pages or shorten the "
+                                "prompt."
+                            ))
+                if not admitted:
+                    if overflow:
+                        return admitted_total
+                    continue
+                group, slots = admitted, admitted_slots
+                # CoW copies outside the lock (device work). A page whose
+                # stream was failed mid-loop just writes bytes into a
+                # released page — unreferenced, overwritten or masked by
+                # any future tenant (the validity invariant).
+                for plan in plans:
+                    cow = plan.pop("cow", None)
+                    if cow is not None:
+                        engine.copy_page(*cow)
+                if getattr(self, "_chunked", False):
+                    # Chunked admission (docs/DESIGN.md §25): pages are
+                    # allocated and any warm prefix is already committed
+                    # (CoW done above), but NO prefill dispatches here —
+                    # the token-budget planner (_prefill_chunks) appends
+                    # the prompt chunk by chunk, interleaved with decode
+                    # iterations, and TTFT is stamped on the FINAL chunk.
+                    # Warm hits start their cursor past the cached prefix,
+                    # so fully-warm prompts cost a single 1-token chunk.
+                    with self._lock:
+                        now = time.perf_counter()
+                        for stream, slot, plan in zip(group, slots, plans):
+                            if self._slot_stream[slot] is not stream:
+                                continue  # failed by close()/crash already
+                            shared = int(plan.get("shared_tokens") or 0)
+                            # While mid-prefill, _slot_lengths tracks the
+                            # COMMITTED prefix (the chunk cursor), not the
+                            # final prompt length.
+                            self._slot_lengths[slot] = shared
+                            self._chunk_state[slot] = {
+                                "pos": shared,
+                                "admit_t": now,
+                            }
+                            admitted_total += 1
+                    continue
+                cold = [
+                    i for i, p in enumerate(plans)
+                    if not p.get("shared_tokens")
+                ]
+                warm = [
+                    i for i, p in enumerate(plans) if p.get("shared_tokens")
+                ]
             t0 = time.perf_counter()
             first = np.zeros(len(group), np.int32)
             if cold:
@@ -1031,7 +1055,7 @@ class DecodeScheduler:
                     [s.prompt for s in group], slots
                 )
             dt_ms = (time.perf_counter() - t0) * 1e3
-            with self._lock:
+            with _trace.span("sched_admit_commit"), self._lock:
                 now = time.perf_counter()
                 delivered = 0
                 for stream, slot, token in zip(group, slots, first):
@@ -1052,6 +1076,7 @@ class DecodeScheduler:
                     self._slot_tokens[slot] = int(token)
                     self._finish_or_continue(slot, int(token))
                     delivered += 1
+                admitted_total += delivered
                 if self._metrics is not None:
                     # Count tokens/requests actually DELIVERED (a
                     # stream failed mid-dispatch got no token) — the
@@ -1084,7 +1109,7 @@ class DecodeScheduler:
         slot finishes within a few iterations)."""
         spec = getattr(self, "_speculative", None)
         if spec is not None:
-            with self._lock:
+            with _trace.span("sched_decode_plan"), self._lock:
                 active = [
                     i for i, s in enumerate(self._slot_stream)
                     if s is not None and i not in self._chunk_state
@@ -1108,7 +1133,7 @@ class DecodeScheduler:
             if eligible:
                 return self._decode_spec(spec)
         engine = self._engine
-        with self._lock:
+        with _trace.span("sched_decode_plan"), self._lock:
             self._ensure_active_rows(1)
             snapshot = list(self._slot_stream)
             active = [
@@ -1139,7 +1164,7 @@ class DecodeScheduler:
             # + masking make it invisible, per the refill invariant).
             spec.draft_engine.verify(ctokens, dlengths)
         dt_ms = (time.perf_counter() - t0) * 1e3
-        with self._lock:
+        with _trace.span("sched_deliver"), self._lock:
             delivered = 0
             for slot in active:
                 if self._slot_stream[slot] is not snapshot[slot]:
@@ -1231,7 +1256,7 @@ class DecodeScheduler:
         draft = spec.draft_engine
         k = int(spec.k)
         n = int(engine.slots)
-        with self._lock:
+        with _trace.span("sched_decode_plan"), self._lock:
             # Teacher verify appends the whole window's rows (the
             # accepted prefix advances over them; rejected rows stay
             # masked garbage in allocated pages — rollback never
@@ -1291,7 +1316,7 @@ class DecodeScheduler:
             scored = engine.verify(vtokens, lengths)
         dt_ms = (time.perf_counter() - t0) * 1e3
         # 4. Host accept + commit (greedy = longest prefix match).
-        with self._lock:
+        with _trace.span("sched_deliver"), self._lock:
             delivered = 0
             proposed_total = 0
             accepted_total = 0
@@ -1366,7 +1391,7 @@ class DecodeScheduler:
             self._engine.prefill_chunk_tokens
         )
 
-    def _prefill_chunks(self, decode_spend: int) -> None:
+    def _prefill_chunks(self, decode_spend: int) -> int:
         """Spend the iteration's remaining token budget on pending
         prefill chunks (docs/DESIGN.md §25): after decode took
         ``decode_spend`` tokens, the remainder is dealt to mid-prefill
@@ -1377,9 +1402,13 @@ class DecodeScheduler:
         delivered, the prefix cached, and the slot leaves
         ``_chunk_state`` to decode next iteration. Caller holds
         ``_step_lock``; dispatches run outside ``_lock`` with the
-        same identity-checked commit as prefill/decode."""
+        same identity-checked commit as prefill/decode. Returns the
+        chunks dispatched (lanes summed over dispatches); planning and
+        commit are the admission's trace leaves (``sched_admit_plan``
+        / ``sched_admit_commit``)."""
         if not getattr(self, "_chunked", False):
-            return
+            return 0
+        chunks = 0
         engine = self._engine
         spec = getattr(self, "_speculative", None)
         chunk_cap = int(engine.prefill_chunk_tokens)
@@ -1390,7 +1419,7 @@ class DecodeScheduler:
         budget = max(1, self._iteration_budget() - int(decode_spend))
         while budget > 0:
             group = []  # (slot, stream, chunk, offset, is_final)
-            with self._lock:
+            with _trace.span("sched_admit_plan"), self._lock:
                 for slot in sorted(self._chunk_state):
                     if len(group) >= lane_cap or budget < 1:
                         break
@@ -1412,7 +1441,8 @@ class DecodeScheduler:
                         pos + c >= total,
                     ))
             if not group:
-                return
+                return chunks
+            chunks += len(group)
             t0 = time.perf_counter()
             last = engine.prefill_chunk(
                 [g[2] for g in group],
@@ -1432,7 +1462,7 @@ class DecodeScheduler:
                     [g[0] for g in finals],
                 )
             dt_ms = (time.perf_counter() - t0) * 1e3
-            with self._lock:
+            with _trace.span("sched_admit_commit"), self._lock:
                 now = time.perf_counter()
                 finished = 0
                 stalls = []
@@ -1471,6 +1501,7 @@ class DecodeScheduler:
                             finished, stalls
                         )
                         self._metrics.record_first_tokens(finished)
+        return chunks
 
     def _update_occupancy(self) -> None:
         if self._metrics is None:
@@ -1507,6 +1538,16 @@ class DecodeScheduler:
         from zookeeper_tpu.resilience import faults
 
         with self._step_lock:
+            # Trace: every record of this iteration carries its number
+            # as ``step`` (the engine's dispatch spans through the
+            # tracer's thread-local step). The phases below are LEAF
+            # spans that tile the time between the dispatch spans and
+            # enclose none of them (the staged weight swap has a span
+            # of its own inside the engine, so it stays outside the
+            # leaves); one ``sched_iteration_end`` event closes them.
+            iteration = self._iteration + 1
+            object.__setattr__(self, "_iteration", iteration)
+            _trace.set_current_step(iteration)
             with self._lock:
                 plan = faults.active()
                 if plan is not None and plan.take_decode_worker_crash():
@@ -1515,23 +1556,38 @@ class DecodeScheduler:
                         "(FaultPlan.decode_worker_crash)"
                     )
                 self._maybe_apply_swap()
+            with _trace.span("sched_sweep"), self._lock:
                 self._maybe_apply_brownout()
                 self._expire_queued()
                 self._expire_active()
-            self._admit()
+            # (the disaggregated scheduler's _admit counts nothing)
+            admitted = self._admit() or 0
             spent = self._decode()
             # Chunked prefill rides the SAME iteration after decode:
             # decode spends the budget first, pending chunks get the
             # remainder (docs/DESIGN.md §25). No-op when chunking off.
-            self._prefill_chunks(spent)
+            chunks = self._prefill_chunks(spent)
             with self._lock:
                 self._maybe_apply_swap()  # slot array may have drained
-                self._maybe_apply_brownout()
-                self._update_occupancy()
-        # Wake backpressured submitters and drain()/iterator waiters:
-        # queue room and stream progress both change per iteration.
-        with self._cv:
-            self._cv.notify_all()
+            with _trace.span("sched_bookkeeping"):
+                with self._lock:
+                    self._maybe_apply_brownout()
+                    self._update_occupancy()
+                # Wake backpressured submitters and drain()/iterator
+                # waiters: queue room and stream progress both change
+                # per iteration.
+                with self._cv:
+                    self._cv.notify_all()
+            if _trace.enabled():
+                _trace.event(
+                    "sched_iteration_end",
+                    attrs={
+                        "admitted": admitted,
+                        "decoded": spent,
+                        "chunks": chunks,
+                    },
+                )
+                _trace.set_current_step(None)
         return self._has_work()
 
     def _pump(self) -> bool:
@@ -1547,6 +1603,7 @@ class DecodeScheduler:
             raise
 
     def _on_crash(self, error: BaseException) -> None:
+        _trace.set_current_step(None)  # the iteration never closed
         with self._lock:
             streams = [s for s in self._slot_stream if s is not None]
             streams += list(self._queue)

@@ -14,6 +14,7 @@ north-star metric (BASELINE.md).
 """
 
 import json
+import os
 import time
 from typing import Any, Dict, List, Optional
 
@@ -61,6 +62,29 @@ def _data_wait_iter(iterable, name="data_wait"):
             except StopIteration:
                 return
         yield item
+
+
+def _host_memory() -> Dict[str, int]:
+    """Bytes of machine memory in use (``MemTotal - MemAvailable`` of
+    ``/proc/meminfo``) and of this process's resident set; an empty
+    dict where ``/proc`` is not readable. Called only while tracing is
+    on (the ``host_memory`` event)."""
+    out: Dict[str, int] = {}
+    try:
+        with open("/proc/meminfo") as f:
+            kb = {
+                line.split(":")[0]: int(line.split()[1])
+                for line in f
+                if line.startswith(("MemTotal:", "MemAvailable:"))
+            }
+        out["in_use_bytes"] = (kb["MemTotal"] - kb["MemAvailable"]) * 1024
+        with open("/proc/self/statm") as f:
+            out["rss_bytes"] = int(f.read().split()[1]) * os.sysconf(
+                "SC_PAGE_SIZE"
+            )
+    except (OSError, ValueError, KeyError, IndexError):
+        pass
+    return out
 
 
 def run_weighted_eval(loader, split, eval_step, state, sharding, epoch=0):
@@ -450,7 +474,6 @@ class TrainingExperiment(Experiment):
             "sync_step": None,
             "sync_dirty": False,
         }
-        self._mfu_peaks = None
 
     def _obs_mark_stall(self, sync: bool = True) -> None:
         """Mark the current timing intervals polluted by a known
@@ -485,13 +508,20 @@ class TrainingExperiment(Experiment):
                 (t - prev) / max(1, k), step=global_step
             )
 
-    def _obs_sync_point(self, global_step: int, program: Any) -> None:
+    def _obs_sync_point(self, global_step: int) -> None:
         """A metrics readback just completed — a true completion
         barrier for every step up to ``global_step``. The interval
         since the previous barrier is honest device-throttled time:
-        feed the step-time watchdog and publish the live gauges
-        (``zk_train_step_time_ms``, ``zk_train_mfu`` — ledger FLOPs /
-        measured step time / reference peak, -1 while unknown)."""
+        feed the step-time watchdog and publish the live
+        ``zk_train_step_time_ms`` gauge. While tracing is on, each
+        sync point also records one ``host_memory`` event (the machine's
+        memory in use and this process's RSS): what the loop leaves
+        behind per step can lie outside the process, where nothing
+        else the program prints sees it."""
+        if _obs_trace.enabled():
+            _obs_trace.event(
+                "host_memory", step=global_step, attrs=_host_memory()
+            )
         timer = getattr(self, "_obs_timer", None)
         if timer is None:
             return
@@ -505,51 +535,10 @@ class TrainingExperiment(Experiment):
             return
         per_step = (t - prev_t) / (global_step - prev_step)
         self._watchdog("train_step").observe(per_step, step=global_step)
-        self._publish_mfu(per_step, program)
-
-    def _publish_mfu(self, per_step_seconds: float, program: Any) -> None:
-        from zookeeper_tpu.observability import ledger as _ledger
-
-        reg = self.obs_registry
-        reg.gauge(
+        self.obs_registry.gauge(
             "zk_train_step_time_ms",
             help="measured steady-state seconds/step (readback-bounded)",
-        ).set(per_step_seconds * 1e3)
-        entry = getattr(program, "ledger_entry", None)
-        flops = getattr(entry, "flops", None)
-        per_step_flops = (
-            flops / max(1, int(entry.attrs.get("steps", self.unroll)))
-            if flops is not None and entry.kind == "multi_step"
-            else flops
-        )
-        peaks = getattr(self, "_mfu_peaks", None)
-        if peaks is None:
-            from zookeeper_tpu.observability.peaks import (
-                reference_int8_peak_flops,
-                reference_peak_flops,
-            )
-
-            peaks = (
-                reference_peak_flops()[0],
-                reference_int8_peak_flops()[0]
-                if getattr(self.model, "binary_compute", None) == "int8"
-                else None,
-            )
-            self._mfu_peaks = peaks
-        value = _ledger.mfu(per_step_flops, per_step_seconds, peaks[0])
-        reg.gauge(
-            "zk_train_mfu",
-            help="ledger FLOPs / measured step time / reference bf16 "
-            "peak (-1 = cost analysis or timing unavailable)",
-            initial=-1,
-        ).set(value if value is not None else -1)
-        if peaks[1] is not None:
-            value8 = _ledger.mfu(per_step_flops, per_step_seconds, peaks[1])
-            reg.gauge(
-                "zk_train_mfu_int8",
-                help="same step against the int8 MXU reference peak",
-                initial=-1,
-            ).set(value8 if value8 is not None else -1)
+        ).set(per_step * 1e3)
 
     # -- jax profiler window (device trace) ------------------------------
 
@@ -664,7 +653,8 @@ class TrainingExperiment(Experiment):
         to report restore latency (restart -> first post-resume step).
         The same barrier seeds the step-time stream's baseline — the
         first honest post-compile sync, so a ``log_every=0`` run can
-        still publish ``zk_train_mfu`` from its epoch-end readback."""
+        still publish ``zk_train_step_time_ms`` from its epoch-end
+        readback."""
         if getattr(self, "first_step_at", None) is None:
             import jax
 
@@ -933,9 +923,7 @@ class TrainingExperiment(Experiment):
                     # inter-dispatch interval, which is why the
                     # dispatch stream skips this iteration).
                     self._obs_mark_stall(sync=False)
-                    self._obs_sync_point(
-                        epoch * spe + step_idx + k, multi_step
-                    )
+                    self._obs_sync_point(epoch * spe + step_idx + k)
                     self._check_halt(hm, epoch * spe + step_idx + k)
                     for s in bounds:
                         self._log_step_scalars(
@@ -1197,7 +1185,7 @@ class TrainingExperiment(Experiment):
                                 hm = jax.device_get(metrics)
                             self._obs_mark_stall(sync=False)
                             self._obs_sync_point(
-                                epoch * spe + step_idx + 1, train_step
+                                epoch * spe + step_idx + 1
                             )
                             self._check_halt(hm, epoch * spe + step_idx + 1)
                             self._log_step_scalars(
@@ -1223,8 +1211,7 @@ class TrainingExperiment(Experiment):
                     host_accum = jax.device_get(accum)
                 self._obs_mark_stall(sync=False)
                 self._obs_sync_point(
-                    epoch * spe + start_b + steps_trained,
-                    multi_step if multi_step is not None else train_step,
+                    epoch * spe + start_b + steps_trained
                 )
                 self._check_halt(
                     host_accum, epoch * spe + start_b + steps_trained
