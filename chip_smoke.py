@@ -15,9 +15,11 @@ every Pallas kernel against its reference:
 3. **server** — ``LMServingConfig.build_service()`` at 4 layers / d_model
    512 / 8 heads of 64 / vocab 1024 / bf16 with the paged KV layout and
    ``decode_attention=auto``; requests through ``DecodeScheduler.submit``
-   / ``drain``; the same requests served by the kernel flavor and by the
-   reference flavor must give identical tokens (compared in float32 at
-   the highest matmul precision).
+   / ``drain``; no warmed program's optimised HLO may copy a whole leaf
+   of the page pool (``DecodeEngine.pool_sized_copies``); the same
+   requests served by the kernel flavor and by the reference flavor must
+   give identical tokens (compared in float32 at the highest matmul
+   precision).
 4. **kernels** — ``__graft_entry__.verify_onchip()``: every binary compute
    path bit-exact, every Pallas kernel against its reference.
 
@@ -264,6 +266,9 @@ def serve(flavor, prompts, new_tokens, overrides):
             ),
             "mosaic_call_in_decode_step": "tpu_custom_call"
             in engine._decode_compiled().as_text(),
+            # Per warmed program, the instructions that re-lay-out a
+            # whole leaf of the donated page pool (none may).
+            "pool_sized_copies": engine.pool_sized_copies(),
             # What the compiler's cost analysis says of a step that
             # holds a Pallas call (a fact for the benchmark to come).
             "decode_step_cost_flops": engine._ledger_records[
@@ -317,6 +322,13 @@ def server_phase(
             f"{facts['mosaic_call_in_decode_step']}",
         )
         check(facts["recompiles_after_warmup"] == 0, "compiled after warm-up")
+        # The reference flavor's decode step gathers every slot's pages
+        # into a view as large as the pool: that is what it is.
+        check(
+            expect != "pallas" or not any(facts["pool_sized_copies"].values()),
+            "programs that copy a whole leaf of the page pool: "
+            f"{facts['pool_sized_copies']}",
+        )
         for out in tokens:
             check(out.shape == (new_tokens,), f"{out.shape} tokens answered")
             check(((out >= 0) & (out < vocab)).all(), "token outside the vocab")

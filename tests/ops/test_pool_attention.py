@@ -35,6 +35,17 @@ def scattered_pool(kc, vc, page_size, num_pages, seed=0, poison=1e9):
     return k_pool, v_pool, table
 
 
+def folded(pool, head_shards=1):
+    """A page-shaped ``[num_pages, page_size, heads, head_dim]`` array
+    in the pool's stored form (``ops.fold_kv_pool``)."""
+    return np.asarray(ops.fold_kv_pool(pool, head_shards))
+
+
+def page_shaped(pool, h, d):
+    """The inverse of :func:`folded`."""
+    return np.asarray(ops.unfold_kv_rows(np.swapaxes(pool, 1, 2), h, d))
+
+
 @pytest.fixture(scope="module")
 def operands():
     rng = np.random.default_rng(3)
@@ -46,7 +57,7 @@ def operands():
     # last row.
     lengths = np.array([0, 13, 16, 31], np.int32)
     k_pool, v_pool, table = scattered_pool(kc, vc, ps, 24)
-    return q, kc, vc, k_pool, v_pool, table, lengths, ps
+    return q, kc, vc, folded(k_pool), folded(v_pool), table, lengths, ps
 
 
 def test_pool_reference_bit_identical_to_cached_attention(operands):
@@ -116,8 +127,11 @@ def test_int8_pool_attention_documented_ulp_and_argmax(operands):
     and the per-head argmax over a logits-like projection stays
     stable — the op-level half of the §20 numerics contract."""
     q, kc, vc, k_pool, v_pool, table, lengths, ps = operands
-    kq, ks = ops.quantize_kv_rows(k_pool)
-    vq, vs = ops.quantize_kv_rows(v_pool)
+    h, d = q.shape[2:]
+    kq, ks = ops.quantize_kv_rows(page_shaped(k_pool, h, d))
+    vq, vs = ops.quantize_kv_rows(page_shaped(v_pool, h, d))
+    kq, vq = folded(kq), folded(vq)
+    ks, vs = ops.fold_kv_scales(ks), ops.fold_kv_scales(vs)
     fp = np.asarray(
         ops.pool_decode_attention(q, k_pool, v_pool, table, lengths)
     )
@@ -190,7 +204,7 @@ def test_pool_attention_validation_errors(operands):
     with pytest.raises(ValueError, match="together"):
         ops.pool_paged_decode_attention(
             q, k_pool, v_pool, table, lengths,
-            k_scale=np.ones(k_pool.shape[:3], np.float32),
+            k_scale=np.ones(k_pool.shape[:3] + (4,), np.float32),
         )
 
 
@@ -208,10 +222,14 @@ def test_sharded_pool_kernel_on_mesh(operands):
     single = np.asarray(
         ops.pool_paged_decode_attention(q, k_pool, v_pool, table, lengths)
     )
+    h, d = q.shape[2:]
     with mesh:
         sharded = np.asarray(
             ops.sharded_pool_paged_decode_attention(
-                q, k_pool, v_pool, table, lengths,
+                q,
+                folded(page_shaped(k_pool, h, d), head_shards=2),
+                folded(page_shaped(v_pool, h, d), head_shards=2),
+                table, lengths,
                 mesh=mesh, data_axes=("data",), model_axis="model",
             )
         )

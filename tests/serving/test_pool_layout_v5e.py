@@ -1,0 +1,156 @@
+"""The paged decode path compiled for a described (not attached) TPU
+v5e at GPT-2 XL's widths, as the benchmark's serving cells run it
+(3072 pages x 16 rows, 25 heads of 64, bfloat16, 48 slots): the chip's
+own compiler must accept the pool kernel, and no program may copy a
+whole leaf of the donated page pool (docs/DESIGN.md §20; before PR 25
+every one held four such copies a layer, 110 ms a call on the chip).
+One layer: the layers are alike. No time comes from here."""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from zookeeper_tpu import ops
+from zookeeper_tpu.models.transformer import (
+    TransformerLMModule,
+    _pool_write_rows,
+)
+from zookeeper_tpu.observability.hlo import count_copies_of_size
+from zookeeper_tpu.serving.decode.pages import allocate_page_pool
+
+PAGES, PAGE_SIZE, HEADS, HEAD_DIM, SLOTS, MAX_PAGES = 3072, 16, 25, 64, 48, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def shaped(one_chip):
+    def shaped(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            tree,
+        )
+
+    return shaped
+
+
+@pytest.fixture(scope="module")
+def module():
+    return TransformerLMModule(
+        vocab_size=50257, num_layers=1, d_model=HEADS * HEAD_DIM,
+        num_heads=HEADS, mlp_ratio=4, attention="flash", max_seq_len=1024,
+        dtype=jnp.bfloat16,
+    )
+
+
+def pool(quant="none"):
+    return jax.eval_shape(
+        lambda: allocate_page_pool(
+            1, PAGES, PAGE_SIZE, HEADS, HEAD_DIM, jnp.bfloat16, quant=quant
+        )
+    )
+
+
+def ints(*shape):
+    return jax.ShapeDtypeStruct(shape, np.int32)
+
+
+def whole_leaf_copies(compiled, cache):
+    sizes = {int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(cache)}
+    return count_copies_of_size(compiled.as_text(), sizes)
+
+
+def test_row_width_of_the_cell():
+    assert ops.kv_row_width(HEADS, HEAD_DIM) == 1664
+    assert pool()[0]["k"].shape == (PAGES, 1, PAGE_SIZE, 1664)
+
+
+@pytest.mark.parametrize("method, width", [
+    ("decode_step_paged", None),
+    ("decode_verify_paged", 128),
+])
+def test_model_step_holds_no_pool_sized_copy(shaped, module, method, width):
+    variables = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    )
+    cache = pool()
+    kwargs = {}
+    if method == "decode_step_paged":
+        rows, tokens = SLOTS, ints(SLOTS)
+        kwargs["attention_override"] = partial(
+            ops.pool_paged_decode_attention, interpret=False
+        )
+    else:
+        rows, tokens = 1, ints(1, width)
+
+    def step(variables, cache, tokens, lengths, table):
+        logits, new_cache = module.apply(
+            variables, tokens, lengths, cache, table, method=method, **kwargs
+        )
+        return new_cache, jnp.argmax(logits, axis=-1)
+
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        *shaped((variables, cache, tokens, ints(rows), ints(rows, MAX_PAGES)))
+    ).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (method == "decode_step_paged")
+    assert whole_leaf_copies(compiled, cache) == 0
+
+
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_prefill_write_holds_no_pool_sized_copy(shaped, quant):
+    cache = pool(quant)
+    lead = (1, 1024)
+    rows = {
+        name: jax.ShapeDtypeStruct(lead + (HEADS, HEAD_DIM), jnp.bfloat16)
+        for name in ("k", "v")
+    }
+
+    def write(layer, rows, pages, offsets):
+        return _pool_write_rows(layer, rows, pages, offsets)
+
+    compiled = jax.jit(write, donate_argnums=0).lower(
+        *shaped((cache[0], rows, ints(*lead), ints(*lead)))
+    ).compile()
+    # int8: the float32 scale arrays ([3072, 1, 16, 25], a sixteenth of
+    # the pool's bytes) are still held transposed and copied around
+    # their scatter; the int8 rows are not.
+    rows_only = [{"k": cache[0]["k"], "v": cache[0]["v"]}]
+    assert whole_leaf_copies(compiled, rows_only) == 0
+
+
+def test_int8_pool_kernel_compiles(shaped):
+    cache = pool("int8")[0]
+
+    def attend(q, layer, table, lengths):
+        return ops.pool_paged_decode_attention(
+            q, layer["k"], layer["v"], table, lengths,
+            k_scale=layer["k_scale"], v_scale=layer["v_scale"],
+            interpret=False,
+        )
+
+    q = jax.ShapeDtypeStruct((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    compiled = jax.jit(attend).lower(
+        *shaped((q, cache, ints(SLOTS, MAX_PAGES), ints(SLOTS)))
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert whole_leaf_copies(compiled, [cache["k"]]) == 0
+

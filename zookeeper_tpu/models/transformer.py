@@ -93,29 +93,38 @@ def _resolve_paged_attention(paged_attention):
 
 
 def _pool_write_rows(layer, rows, pages, offsets):
-    """Scatter ``rows [b(, w), heads, head_dim]`` into a page-pool
-    layer dict at ``(pages, offsets)`` (same leading shape; entries
-    with ``page == num_pages`` drop — the OOB sentinel covering
-    inactive slots, unallocated table entries, and padding rows).
-    Quantizes inline when the layer carries scale arrays (int8 pools —
-    see ``ops.quantizers.quantize_kv_rows``). Returns the updated
-    layer dict."""
+    """Write ``rows [b(, w), heads, head_dim]`` into a page-pool layer
+    dict at ``(pages, offsets)`` (same leading shape; entries with
+    ``page == num_pages`` drop — the OOB sentinel covering inactive
+    slots, unallocated table entries, and padding rows). The rows are
+    folded to the pool's stored form first (``ops.fold_kv_rows``: heads
+    end to end, padded to whole 128-lane registers), so the scatter's
+    window is the stored row and the donated pool is updated in place
+    in the row-major layout it is held, gathered and read by the decode
+    kernel in. (A pool of ``[..., heads, head_dim]`` rows is not: the
+    TPU holds it transposed, and this scatter, like every other reader,
+    then re-lays-out the whole pool — docs/DESIGN.md §20.) Quantizes
+    inline when the layer carries scale arrays (int8 pools — see
+    ``ops.quantizers.quantize_kv_rows``); the scales go through the
+    same write. Returns the updated layer dict."""
+    from zookeeper_tpu.ops import fold_kv_rows, quantize_kv_rows
+
+    def write(buf, vals):
+        return buf.at[pages, :, offsets].set(
+            vals.astype(buf.dtype), mode="drop"
+        )
+
     out = dict(layer)
     for name, scale_name in (("k", "k_scale"), ("v", "v_scale")):
         buf = layer[name]
+        shards, width = buf.shape[1], buf.shape[3]
         vals = rows[name]
         if scale_name in layer:
-            from zookeeper_tpu.ops import quantize_kv_rows
-
-            q, s = quantize_kv_rows(vals)
-            out[name] = buf.at[pages, offsets].set(q, mode="drop")
-            out[scale_name] = layer[scale_name].at[pages, offsets].set(
-                s, mode="drop"
+            vals, s = quantize_kv_rows(vals)
+            out[scale_name] = write(
+                layer[scale_name], s.reshape(*s.shape[:-1], shards, -1)
             )
-        else:
-            out[name] = buf.at[pages, offsets].set(
-                vals.astype(buf.dtype), mode="drop"
-            )
+        out[name] = write(buf, fold_kv_rows(vals, shards, width))
     return out
 
 
@@ -300,11 +309,12 @@ class _Block(nn.Module):
         self, x, layer, page_table, lengths, attention_override=None
     ):
         """The page-pool twin of :meth:`decode` (docs/DESIGN.md §20):
-        ``layer`` is a pool dict (``k``/``v`` ``[num_pages, page_size,
-        heads, head_dim]``, plus scale arrays for int8 pools) shared by
-        EVERY slot; the new position's K/V row lands at ``(page_table[
-        slot, lengths // page_size], lengths % page_size)`` — the
-        indirected write — and the attention reads through the table
+        ``layer`` is a pool dict (``k``/``v`` ``[num_pages,
+        head_shards, page_size, row_width]``, plus scale arrays for
+        int8 pools) shared by EVERY slot; the new position's K/V row
+        lands at ``(page_table[slot, lengths // page_size], lengths %
+        page_size)`` — the indirected write, ``_pool_write_rows`` — and
+        the attention reads through the table
         (``ops.pool_decode_attention`` or the injected kernel). A slot
         whose write target is unallocated (``-1`` table entry, or an
         inactive slot past its pages) drops the write via the OOB page
@@ -312,7 +322,7 @@ class _Block(nn.Module):
         only ever taken by slots whose output is discarded."""
         b = x.shape[0]
         head_dim = self.d_model // self.num_heads
-        num_pages, ps = layer["k"].shape[0], layer["k"].shape[1]
+        num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
 
         h = self.ln1(x)
         qkv = self.wqkv(h)
@@ -359,7 +369,7 @@ class _Block(nn.Module):
         guarantees the pages exist). Rollback stays by-length."""
         b, w, _ = x.shape
         head_dim = self.d_model // self.num_heads
-        num_pages, ps = layer["k"].shape[0], layer["k"].shape[1]
+        num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
 
         h = self.ln1(x)
         qkv = self.wqkv(h)
@@ -616,7 +626,7 @@ class TransformerLMModule(nn.Module):
     ):
         """:meth:`decode_step` over a SHARED page pool (docs/DESIGN.md
         §20): ``cache`` is a per-layer tuple of pool dicts (``k``/``v``
-        ``[num_pages, page_size, heads, head_dim]``, plus
+        ``[num_pages, head_shards, page_size, row_width]``, plus
         ``k_scale``/``v_scale`` for int8 pools), ``page_table [b,
         max_pages] int32`` resolves each sequence's logical pages.
         Same contract otherwise — the caller owns lengths, the new K/V

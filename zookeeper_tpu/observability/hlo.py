@@ -1,0 +1,36 @@
+"""Reading a compiled program's optimised HLO text
+(``jax.stages.Compiled.as_text()``) for structure a test or an on-chip
+check wants to hold: no timing, nothing device-specific beyond what the
+compiler wrote."""
+
+import math
+import re
+from typing import Iterable
+
+__all__ = ["count_copies_of_size"]
+
+#: ``%copy.4 = bf16[3072,16,25,64]{3,2,1,0:T(8,128)(2,1)} copy(%buf.1)``,
+#: with or without ``ROOT``; an asynchronous ``copy-start`` returns a
+#: tuple whose first shape is the copy's.
+_COPY = re.compile(
+    r"= \(?\w+\[([\d,]*)\][^ ]* (?:[^=]*\) )?(?:copy|copy-start|transpose)\("
+)
+
+
+def count_copies_of_size(hlo_text: str, element_counts: Iterable[int]) -> int:
+    """How many ``copy`` / ``copy-start`` / ``transpose`` instructions
+    of ``hlo_text`` (fused computations included) produce an array with
+    one of ``element_counts`` elements. The decode engine asks it of
+    every program with its page pool's leaf sizes: such an instruction
+    is a re-layout of a whole pool leaf, which no program should hold
+    (docs/DESIGN.md §20)."""
+    sizes = {int(n) for n in element_counts}
+    count = 0
+    for line in hlo_text.splitlines():
+        m = _COPY.search(line)
+        if m is None:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        if math.prod(dims) in sizes:
+            count += 1
+    return count

@@ -40,6 +40,8 @@ from jax import lax
 from zookeeper_tpu.ops.blocks import (  # noqa: F401  (re-exports)
     _FLASH_VMEM_BUDGET,
     _decode_vmem_estimate,
+    _pool_decode_vmem_estimate,
+    _round_up,
     _default_decode_blocks,
     _default_flash_blocks,
     _flash_bwd_vmem_estimate,
@@ -50,6 +52,9 @@ from zookeeper_tpu.ops.blocks import (  # noqa: F401  (re-exports)
 # underflows to 0 instead of producing -inf - -inf = nan in the online
 # rescale), far below any real fp32 score.
 _MASK_VALUE = -0.5 * float(jnp.finfo(jnp.float32).max)
+
+#: Lanes of one TPU vector register: the quantum a folded KV row pads to.
+_KV_LANES = 128
 
 
 def _mosaic_params(estimate: int):
@@ -209,19 +214,27 @@ def verify_cached_attention(
     ).astype(q.dtype)
 
 
-def decode_attention_supported(num_heads: int, head_dim: int) -> bool:
-    """Whether :func:`paged_decode_attention` serves this geometry.
+def decode_attention_supported(
+    num_heads: int, head_dim: int, *, paged: bool = False
+) -> bool:
+    """Whether :func:`paged_decode_attention` (``paged=True``:
+    :func:`pool_paged_decode_attention`) serves this geometry.
 
-    The kernel's in-VMEM tiles put ``head_dim`` on the lane dimension
-    and the head block on sublanes; Mosaic pads either to the hardware
-    tile, but a head_dim off the fp32 sublane quantum (8) is untested
-    territory on real silicon, so such geometries take the reference
-    einsum instead of risking a Mosaic lowering failure on the serving
-    hot path. Interpret mode has no such constraint, but the predicate
-    is deliberately backend-independent: a config must resolve to the
-    same flavor on the CPU tier-1 runner as on the TPU it deploys to.
+    The slot kernel's in-VMEM tiles put ``head_dim`` on the lane
+    dimension and the head block on sublanes; Mosaic pads either to the
+    hardware tile, but a head_dim off the fp32 sublane quantum (8) is
+    untested territory on real silicon, so such geometries take the
+    reference einsum instead of risking a Mosaic lowering failure on
+    the serving hot path. The pool kernel reads folded rows (heads end
+    to end on the lanes) and sums a head's lanes inside one 128-lane
+    register, so it also needs ``head_dim`` to divide 128. Interpret mode
+    has no such constraint, but the predicate is deliberately
+    backend-independent: a config must resolve to the same flavor on
+    the CPU tier-1 runner as on the TPU it deploys to.
     """
-    return num_heads >= 1 and head_dim >= 8 and head_dim % 8 == 0
+    if num_heads < 1 or head_dim < 8 or head_dim % 8:
+        return False
+    return not paged or _KV_LANES % head_dim == 0
 
 
 # _decode_vmem_estimate / _default_decode_blocks moved to ops/blocks.py
@@ -432,22 +445,87 @@ def sharded_paged_decode_attention(
     return fn(q, k_cache, v_cache, lengths)
 
 
-def _gathered_pool_view(pool, page_table, scale=None):
+def kv_row_width(num_heads: int, head_dim: int, head_shards: int = 1) -> int:
+    """Lanes one head shard's slice of a folded KV row takes: its
+    ``heads * head_dim`` values rounded up to whole 128-lane vector
+    registers (GPT-2 XL's 25 x 64 = 1600 -> 1664)."""
+    if head_shards < 1 or num_heads % head_shards:
+        raise ValueError(
+            f"head_shards={head_shards} does not divide "
+            f"num_heads={num_heads}."
+        )
+    return _round_up((num_heads // head_shards) * head_dim, _KV_LANES)
+
+
+def fold_kv_rows(rows: jax.Array, head_shards: int, row_width: int):
+    """``[..., heads, head_dim]`` K/V rows -> the page pool's storage
+    form ``[..., head_shards, row_width]``: each shard's heads laid end
+    to end on the lane dimension, zero-padded to ``row_width``
+    (:func:`kv_row_width`). The pool keeps its rows this way because of
+    what the TPU does to any other shape: a row-major ``[..., 25, 64]``
+    tile wastes half of every vector register, so XLA gives such an
+    array a transposed device layout and every scatter, gather and
+    Pallas call (which all want row-major) re-lays-out the whole pool
+    (docs/DESIGN.md §20)."""
+    *lead, h, d = rows.shape
+    rows = rows.reshape(*lead, head_shards, (h // head_shards) * d)
+    pad = row_width - rows.shape[-1]
+    if pad < 0:
+        raise ValueError(
+            f"row_width={row_width} cannot hold {rows.shape[-1]} values."
+        )
+    if pad:
+        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+    return rows
+
+
+def unfold_kv_rows(rows: jax.Array, num_heads: int, head_dim: int):
+    """Inverse of :func:`fold_kv_rows`: ``[..., head_shards,
+    row_width]`` -> ``[..., heads, head_dim]`` (the padding dropped)."""
+    *lead, s, _ = rows.shape
+    rows = rows[..., : (num_heads // s) * head_dim]
+    return rows.reshape(*lead, num_heads, head_dim)
+
+
+def fold_kv_pool(pool: jax.Array, head_shards: int = 1) -> jax.Array:
+    """A page-shaped ``[num_pages, page_size, heads, head_dim]`` array
+    -> the pool's storage form ``[num_pages, head_shards, page_size,
+    row_width]`` (for callers that build a pool by hand: tests, the
+    on-chip kernel checks)."""
+    _, _, h, d = pool.shape
+    width = kv_row_width(h, d, head_shards)
+    return jnp.swapaxes(fold_kv_rows(pool, head_shards, width), 1, 2)
+
+
+def fold_kv_scales(scale: jax.Array, head_shards: int = 1) -> jax.Array:
+    """Page-shaped int8 scales ``[num_pages, page_size, heads]`` -> the
+    stored ``[num_pages, head_shards, page_size, heads_per_shard]``."""
+    n, ps, h = scale.shape
+    return jnp.swapaxes(
+        scale.reshape(n, ps, head_shards, h // head_shards), 1, 2
+    )
+
+
+def _gathered_pool_view(pool, page_table, num_heads, head_dim, scale=None):
     """A slot-contiguous view of a shared page pool: gather each slot's
-    pages by ``page_table`` and flatten the (pages, page_size) axes back
-    into the familiar ``[slots, capacity_view, heads, head_dim]`` cache
-    layout, dequantizing int8 pools inline (``scale [num_pages,
-    page_size, heads]`` — see ``ops.quantizers.quantize_kv_rows``).
-    Rows in unallocated table entries (clipped to page 0) and garbage
-    rows beyond a slot's length are harmless by the validity invariant:
-    every pool-attention consumer masks ``j > lengths`` to the finite
-    ``_MASK_VALUE``, whose softmax weight underflows to exactly 0.0 —
-    the same argument the slot-layout refill contract makes."""
+    pages by ``page_table``, unfold the stored rows
+    (:func:`unfold_kv_rows`) and flatten the (pages, page_size) axes
+    back into the familiar ``[slots, capacity_view, heads, head_dim]``
+    cache layout, dequantizing int8 pools inline (``scale [num_pages,
+    head_shards, page_size, heads_per_shard]`` — see
+    ``ops.quantizers.quantize_kv_rows``). Rows in unallocated table
+    entries (clipped to page 0) and garbage rows beyond a slot's length
+    are harmless by the validity invariant: every pool-attention
+    consumer masks ``j > lengths`` to the finite ``_MASK_VALUE``, whose
+    softmax weight underflows to exactly 0.0 — the same argument the
+    slot-layout refill contract makes."""
     idx = jnp.clip(page_table, 0, pool.shape[0] - 1)
-    g = pool[idx]  # [slots, max_pages, page_size, heads, head_dim]
-    if scale is not None:
-        g = g.astype(jnp.float32) * scale[idx][..., None]
+    # [slots, max_pages, head_shards, page_size, row_width]
+    g = unfold_kv_rows(jnp.swapaxes(pool[idx], 2, 3), num_heads, head_dim)
     b, m, ps, h, d = g.shape
+    if scale is not None:
+        sc = jnp.swapaxes(scale[idx], 2, 3).reshape(b, m, ps, h)
+        g = g.astype(jnp.float32) * sc[..., None]
     return g.reshape(b, m * ps, h, d)
 
 
@@ -467,14 +545,15 @@ def pool_decode_attention(
     (docs/DESIGN.md §20).
 
     Shapes: ``q [slots, 1, heads, head_dim]``, ``k_pool/v_pool
-    [num_pages, page_size, heads, head_dim]`` (the device-resident
-    pools every slot's pages live in), ``page_table [slots, max_pages]
-    int32`` (each slot's logical page ``p`` lives at pool index
+    [num_pages, head_shards, page_size, row_width]`` (the
+    device-resident pools every slot's pages live in, rows folded —
+    :func:`fold_kv_rows`), ``page_table [slots, max_pages] int32``
+    (each slot's logical page ``p`` lives at pool index
     ``page_table[slot, p]``; unallocated entries may be negative —
     they are clipped for the gather and masked by ``lengths``),
     ``lengths [slots]`` as in :func:`cached_attention`. Optional
-    ``k_scale/v_scale [num_pages, page_size, heads]`` dequantize int8
-    pools inline.
+    ``k_scale/v_scale [num_pages, head_shards, page_size,
+    heads_per_shard]`` dequantize int8 pools inline.
 
     Numerics: the gathered view holds BIT-identical rows to the
     slot-contiguous cache at every live index (same values, written
@@ -485,8 +564,9 @@ def pool_decode_attention(
     ``int8 × fp32 scale`` multiply before the same einsums
     (documented-ULP, argmax-pinned by the §20 sweep).
     """
-    kc = _gathered_pool_view(k_pool, page_table, k_scale)
-    vc = _gathered_pool_view(v_pool, page_table, v_scale)
+    h, d = q.shape[2], q.shape[3]
+    kc = _gathered_pool_view(k_pool, page_table, h, d, k_scale)
+    vc = _gathered_pool_view(v_pool, page_table, h, d, v_scale)
     return cached_attention(q, kc, vc, lengths, scale=scale)
 
 
@@ -508,8 +588,9 @@ def pool_verify_attention(
     shapes/contract as the slot-layout verify with the pool operands of
     :func:`pool_decode_attention`; at ``w == 1`` it computes exactly
     what :func:`pool_decode_attention` computes."""
-    kc = _gathered_pool_view(k_pool, page_table, k_scale)
-    vc = _gathered_pool_view(v_pool, page_table, v_scale)
+    h, d = q.shape[2], q.shape[3]
+    kc = _gathered_pool_view(k_pool, page_table, h, d, k_scale)
+    vc = _gathered_pool_view(v_pool, page_table, h, d, v_scale)
     return verify_cached_attention(q, kc, vc, lengths, scale=scale)
 
 
@@ -523,7 +604,6 @@ def pool_paged_decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
-    block_h: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Pallas TPU decode attention reading a SHARED page pool through
@@ -532,24 +612,29 @@ def pool_paged_decode_attention(
     to "page-table entry" (docs/DESIGN.md §20).
 
     Same contract as :func:`pool_decode_attention`; different cost
-    model: the grid is (slot, head-block, logical-page) with BOTH
+    model: the grid is (slot, head-shard, logical-page) with BOTH
     ``lengths`` and ``page_table`` as scalar-prefetch operands, so the
     KV index map resolves each logical page to its pool index at DMA
     time — dead pages re-select the slot's last live page (no DMA for
     a repeated index, the §17 length-bounded-read property, now
-    composed with indirection). The KV block is exactly one page: a
-    larger block cannot be contiguous in a pool whose pages are
-    allocator-scattered. int8 pools ride the same grid with the scale
-    pages as a fourth/fifth operand, dequantized in VMEM — resident
-    HBM bytes halve, and the read bound stays page-granular.
+    composed with indirection). The KV block is exactly one page of one
+    head shard, ``[page_size, row_width]``, read in the layout the pool
+    is stored in: a larger block cannot be contiguous in a pool whose
+    pages are allocator-scattered. int8 pools ride the same grid with
+    the scale pages as a fourth/fifth operand, dequantized in VMEM —
+    resident HBM bytes halve, and the read bound stays page-granular.
+
+    The rows are folded (heads end to end on the lanes), so a score is
+    a sum over one head's ``head_dim`` lanes of a register: a masked
+    lane reduction a head inside each 128-lane column; softmax state is
+    kept per lane (every lane of a head carries that head's max and
+    sum). That needs ``head_dim`` to divide 128
+    (:func:`decode_attention_supported` with ``paged=True``).
 
     Numerics: fp32 online-softmax accumulation with the reference's
     finite mask value — same contract (documented-ULP vs the pool
     reference, argmax token-exact) as the §17 kernel.
     """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(
             f"pool_paged_decode_attention expects q [slots, 1, heads, "
@@ -557,13 +642,18 @@ def pool_paged_decode_attention(
         )
     if k_pool.shape != v_pool.shape or k_pool.ndim != 4:
         raise ValueError(
-            f"k_pool/v_pool must be identical [num_pages, page_size, "
-            f"heads, head_dim], got {k_pool.shape} / {v_pool.shape}."
+            f"k_pool/v_pool must be identical [num_pages, head_shards, "
+            f"page_size, row_width], got {k_pool.shape} / {v_pool.shape}."
         )
     b, _, h, d = q.shape
-    num_pages, ps = k_pool.shape[0], k_pool.shape[1]
-    if k_pool.shape[2] != h or k_pool.shape[3] != d:
+    num_pages, shards, ps, width = k_pool.shape
+    if h % shards or width != kv_row_width(h, d, shards):
         raise ValueError(f"pool {k_pool.shape} does not match q {q.shape}.")
+    if not decode_attention_supported(h, d, paged=True):
+        raise ValueError(
+            f"head_dim={d} is off the pool kernel's geometry (a divisor "
+            "of 128)."
+        )
     if page_table.ndim != 2 or page_table.shape[0] != b:
         raise ValueError(
             f"page_table must be [slots={b}, max_pages], got "
@@ -571,40 +661,64 @@ def pool_paged_decode_attention(
         )
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together.")
-    nm = page_table.shape[1]
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # Head-block policy: the §17 VMEM discipline with the KV block
-    # pinned to one page (indirection forbids larger contiguous reads).
-    _, block_h = _default_decode_blocks(
-        ps, h, d, page_size=ps, itemsize=q.dtype.itemsize,
-        block_kv=ps, block_h=block_h,
+    return _pool_paged_decode_call(
+        q, k_pool, v_pool, page_table, lengths, k_scale, v_scale,
+        scale=float(scale), interpret=bool(interpret),
     )
-    nh = h // block_h
-    scale = float(scale)
-    qs = q.reshape(b, h, d)
+
+
+@partial(jax.jit, static_argnames=("scale", "interpret"))
+def _pool_paged_decode_call(
+    q, k_pool, v_pool, page_table, lengths, k_scale, v_scale, *,
+    scale, interpret,
+):
+    """The kernel behind :func:`pool_paged_decode_attention` (operands
+    checked there). Jitted so that a program which attends once a layer
+    traces and lowers the kernel once, and not once a layer: its body is
+    unrolled over the row's 128-lane columns, and 24 lowerings of it
+    were 13 s of an engine's warm-up (PERF.md, PR 25)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, h, d = q.shape
+    num_pages, shards, ps, width = k_pool.shape
+    hs = h // shards
+    nm = page_table.shape[1]
+    qs = fold_kv_rows(q[:, 0], shards, width)[:, :, None, :]
     cap_view = nm * ps
     lens = jnp.clip(lengths.astype(jnp.int32), 0, cap_view - 1)
     table = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
+    columns = width // _KV_LANES
+    heads_per_column = _KV_LANES // d
 
-    def q_index_map(s, hb, kb, lens_ref, table_ref):
-        return (s, hb, 0)
+    def q_index_map(s, sh, kb, lens_ref, table_ref):
+        return (s, sh, 0, 0)
 
-    def kv_index_map(s, hb, kb, lens_ref, table_ref):
+    def kv_index_map(s, sh, kb, lens_ref, table_ref):
         # The indirection step: a logical page resolves through the
         # slot's table row; dead pages re-select the LAST LIVE page's
         # pool index, so a repeated index means no DMA and rows past
         # the length never leave HBM.
         live = jnp.minimum(kb, lens_ref[s] // ps)
-        return (table_ref[s, live], 0, hb, 0)
-
-    def scale_index_map(s, hb, kb, lens_ref, table_ref):
-        live = jnp.minimum(kb, lens_ref[s] // ps)
-        return (table_ref[s, live], 0, hb)
+        return (table_ref[s, live], sh, 0, 0)
 
     quantized = k_scale is not None
+
+    def head_sums(x, heads):
+        # Every lane ends up holding the sum over its own head's
+        # head_dim lanes: one masked lane reduction a head of the
+        # column (``heads``: each head's lanes). (A butterfly of lane
+        # rotations, ``lane ^ stride``, gives the same sums 2.3-3.6
+        # times slower on the v5e: PERF.md, PR 25.)
+        out = jnp.zeros_like(x)
+        for mine in heads:
+            total = jnp.sum(jnp.where(mine, x, 0.0), axis=1, keepdims=True)
+            out = jnp.where(mine, total, out)
+        return out
 
     def kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, *rest):
         if quantized:
@@ -624,70 +738,74 @@ def pool_paged_decode_attention(
 
         @pl.when(kb * ps <= length)
         def _block():
-            qv = q_ref[0].astype(jnp.float32)  # [block_h, d]
-            kv = k_ref[0].astype(jnp.float32)  # [ps, block_h, d]
-            if quantized:
-                kv = kv * ks_ref[0][:, :, None]
-            sc = jnp.sum(qv[None] * kv, axis=-1) * scale  # [ps, block_h]
+            lane = lax.broadcasted_iota(jnp.int32, (ps, _KV_LANES), 1)
+            heads = [(lane // d) == j for j in range(heads_per_column)]
             ki = kb * ps + lax.broadcasted_iota(
-                jnp.int32, (ps, block_h), 0
+                jnp.int32, (ps, _KV_LANES), 0
             )
-            sc = jnp.where(ki <= length, sc, _MASK_VALUE)
-            m = m_ref[...]  # [1, block_h]
-            m_new = jnp.maximum(m, sc.max(axis=0, keepdims=True))
-            p = jnp.exp(sc - m_new)
-            corr = jnp.exp(m - m_new)
-            m_ref[...] = m_new
-            l_ref[...] = l_ref[...] * corr + p.sum(axis=0, keepdims=True)
-            vv = v_ref[0].astype(jnp.float32)
-            if quantized:
-                vv = vv * vs_ref[0][:, :, None]
-            pv = jnp.sum(p[:, :, None] * vv, axis=0)  # [block_h, d]
-            acc_ref[...] = acc_ref[...] * corr[0][:, None] + pv
+            live = ki <= length
+            for c in range(columns):
+                col = pl.ds(c * _KV_LANES, _KV_LANES)
+                qv = q_ref[0, 0, :, col].astype(jnp.float32)  # [1, 128]
+                kv = k_ref[0, 0, :, col].astype(jnp.float32)  # [ps, 128]
+                vv = v_ref[0, 0, :, col].astype(jnp.float32)
+                if quantized:
+                    ke = jnp.ones_like(kv)
+                    ve = jnp.ones_like(vv)
+                    first = c * heads_per_column
+                    for j, mine in enumerate(heads[: hs - first]):
+                        one = pl.ds(first + j, 1)
+                        ke = jnp.where(mine, ks_ref[0, 0, :, one], ke)
+                        ve = jnp.where(mine, vs_ref[0, 0, :, one], ve)
+                    kv = kv * ke
+                    vv = vv * ve
+                sc = head_sums(qv * kv, heads) * scale
+                sc = jnp.where(live, sc, _MASK_VALUE)
+                m = m_ref[:, col]  # [1, 128]
+                m_new = jnp.maximum(m, sc.max(axis=0, keepdims=True))
+                p = jnp.exp(sc - m_new)
+                corr = jnp.exp(m - m_new)
+                m_ref[:, col] = m_new
+                l_ref[:, col] = l_ref[:, col] * corr + p.sum(
+                    axis=0, keepdims=True
+                )
+                acc_ref[:, col] = acc_ref[:, col] * corr + (p * vv).sum(
+                    axis=0, keepdims=True
+                )
 
         @pl.when(kb == nm - 1)
         def _finalize():
-            o_ref[0] = (
-                acc_ref[...] / l_ref[...][0][:, None]
-            ).astype(o_ref.dtype)
+            o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
     in_specs = [
-        pl.BlockSpec((1, block_h, d), q_index_map),
-        pl.BlockSpec((1, ps, block_h, d), kv_index_map),
-        pl.BlockSpec((1, ps, block_h, d), kv_index_map),
+        pl.BlockSpec((1, 1, 1, width), q_index_map),
+        pl.BlockSpec((1, 1, ps, width), kv_index_map),
+        pl.BlockSpec((1, 1, ps, width), kv_index_map),
     ]
     operands = [qs, k_pool, v_pool]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, ps, block_h), scale_index_map),
-            pl.BlockSpec((1, ps, block_h), scale_index_map),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, ps, hs), kv_index_map)] * 2
         operands += [
             k_scale.astype(jnp.float32),
             v_scale.astype(jnp.float32),
         ]
-    out_dtype = q.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nh, nm),
+        grid=(b, shards, nm),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_h, d), q_index_map),
-        scratch_shapes=[
-            pltpu.VMEM((1, block_h), jnp.float32),
-            pltpu.VMEM((1, block_h), jnp.float32),
-            pltpu.VMEM((block_h, d), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, 1, 1, width), q_index_map),
+        scratch_shapes=[pltpu.VMEM((1, width), jnp.float32)] * 3,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((b, shards, 1, width), q.dtype),
         compiler_params=_mosaic_params(
-            _decode_vmem_estimate(ps, block_h, d, q.dtype.itemsize)
+            _pool_decode_vmem_estimate(ps, width, k_pool.dtype.itemsize)
         ),
         interpret=interpret,
     )(lens, table, *operands)
-    return out.reshape(b, 1, h, d)
+    return unfold_kv_rows(out[:, :, 0, :], h, d)[:, None]
 
 
 def sharded_pool_paged_decode_attention(
@@ -709,7 +827,7 @@ def sharded_pool_paged_decode_attention(
     decode path. The POOL differs from the slot-contiguous cache in one
     sharding-relevant way: any slot may reference any page, so pages
     CANNOT shard over the data axes — the pools (and their scale
-    arrays) shard over ``model_axis`` on the heads dimension only,
+    arrays) shard over ``model_axis`` on the head-shard dimension only,
     while q/lengths/page_table shard over ``data_axes`` like batch rows
     (``parallel.rules.page_pool_rules``). Each device then runs the
     kernel over its slot shard against its head shard of every page —
@@ -720,11 +838,10 @@ def sharded_pool_paged_decode_attention(
     from jax.sharding import PartitionSpec as P
 
     if replicated:
-        q_spec = pool_spec = t_spec = l_spec = s_spec = P()
+        q_spec = pool_spec = t_spec = l_spec = P()
     else:
         q_spec = P(tuple(data_axes), None, model_axis, None)
-        pool_spec = P(None, None, model_axis, None)
-        s_spec = P(None, None, model_axis)
+        pool_spec = P(None, model_axis, None, None)
         t_spec = P(tuple(data_axes), None)
         l_spec = P(tuple(data_axes))
     if (k_scale is None) != (v_scale is None):
@@ -753,7 +870,8 @@ def sharded_pool_paged_decode_attention(
         local_q,
         mesh=mesh,
         in_specs=(
-            q_spec, pool_spec, pool_spec, t_spec, l_spec, s_spec, s_spec
+            q_spec, pool_spec, pool_spec, t_spec, l_spec,
+            pool_spec, pool_spec,
         ),
         out_specs=q_spec,
     )

@@ -33,6 +33,7 @@ __all__ = [
     "_default_flash_blocks",
     "_decode_vmem_estimate",
     "_default_decode_blocks",
+    "_pool_decode_vmem_estimate",
     "_resid_blocks",
     "_resid_vmem_estimate",
     "_binary_conv_vmem_estimate",
@@ -220,6 +221,17 @@ def _default_decode_blocks(
             f"{capacity}."
         )
     return int(block_kv), int(block_h)
+
+
+def _pool_decode_vmem_estimate(page_size, row_width, itemsize):
+    """Rough bytes one pool-kernel grid step keeps resident: the
+    double-buffered K and V pages at the pool dtype, the fp32
+    per-register-column intermediates of one 128-lane column, and the
+    three lane-dense accumulators."""
+    tiles = 2 * 2 * page_size * row_width * itemsize
+    intermediates = 8 * page_size * 128 * 4
+    accumulators = 3 * row_width * 4
+    return tiles + intermediates + accumulators
 
 
 # -- 1-bit residual pack/unpack ---------------------------------------------

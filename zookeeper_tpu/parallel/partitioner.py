@@ -180,9 +180,10 @@ class Partitioner:
     def page_pool_sharding(self, pool: Any) -> Any:
         """Sharding pytree for a decode engine's SHARED page-pool state
         (``kv_layout="paged"``, docs/DESIGN.md §20): per-layer
-        ``k``/``v`` pools ``[num_pages, page_size, heads, head_dim]``
-        (+ int8 scale arrays). Pages replicate over the data axes (any
-        slot references any page), heads shard over the model axis via
+        ``k``/``v`` pools ``[num_pages, head_shards, page_size,
+        row_width]`` (+ int8 scale arrays). Pages replicate over the
+        data axes (any slot references any page), the head shards
+        shard over the model axis via
         :func:`zookeeper_tpu.parallel.rules.page_pool_rules`; the
         engine applies the same divisibility check + replicated
         fallback as :meth:`decode_cache_sharding`. None = default

@@ -96,22 +96,25 @@ def page_pool_rules(
 ) -> List[PartitionRule]:
     """Partition rules for a decode engine's SHARED page-pool state
     tree (``serving.decode.pages``, docs/DESIGN.md §20): the per-layer
-    ``k``/``v`` pools are ``[num_pages, page_size, heads, head_dim]``
-    and — unlike the slot-contiguous cache — the PAGES dimension cannot
-    shard over the data axes: any slot may reference any page through
-    its page table, so a data-sharded pool would need a cross-device
-    gather per read. HEADS shard over ``model_axis`` exactly as in
-    :func:`decode_cache_rules` (co-sharded with the column-parallel qkv
-    kernel, zero resharding collectives); the int8 scale arrays
-    ``[num_pages, page_size, heads]`` co-shard their heads dimension.
-    ``data_axes`` is accepted for signature parity (the q/lengths/table
-    OPERANDS shard over it — see
-    ``ops.sharded_pool_paged_decode_attention``) but the pool state
-    itself replicates over it."""
+    ``k``/``v`` pools are ``[num_pages, head_shards, page_size,
+    row_width]`` and — unlike the slot-contiguous cache — the PAGES
+    dimension cannot shard over the data axes: any slot may reference
+    any page through its page table, so a data-sharded pool would need
+    a cross-device gather per read. The HEAD SHARDS dimension (one
+    entry per model-axis device, each holding its heads folded end to
+    end) shards over ``model_axis``, co-sharded with the
+    column-parallel qkv kernel as in :func:`decode_cache_rules`; the
+    int8 scale arrays ``[num_pages, head_shards, page_size,
+    heads_per_shard]`` co-shard the same dimension. ``data_axes`` is
+    accepted for signature parity (the q/lengths/table OPERANDS shard
+    over it — see ``ops.sharded_pool_paged_decode_attention``) but the
+    pool state itself replicates over it."""
     P = PartitionSpec
     return [
-        (r"(^|/)(k|v)$", P(None, None, model_axis, None)),
-        (r"(^|/)(k_scale|v_scale)$", P(None, None, model_axis)),
+        (
+            r"(^|/)(k|v|k_scale|v_scale)$",
+            P(None, model_axis, None, None),
+        ),
     ]
 
 

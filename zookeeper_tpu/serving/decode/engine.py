@@ -47,7 +47,7 @@ ledgered; ``compile_count`` still pins at zero growth under traffic.
 
 import logging
 import time
-from typing import Any, Optional, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 
@@ -65,6 +65,7 @@ from zookeeper_tpu.serving.decode.pages import (
 )
 
 logger = logging.getLogger(__name__)
+
 
 __all__ = ["DecodeEngine"]
 
@@ -367,8 +368,20 @@ DecodeScheduler`.
         )
 
         head_dim = int(module.d_model) // int(module.num_heads)
-        cache = self._allocate_cache()
         mesh = partitioner.mesh
+        # A page pool's rows hold one entry per model-axis device, each
+        # with its own heads folded end to end (ops.fold_kv_rows), so
+        # the pool is laid out for the heads' sharding when it is
+        # allocated. Heads that do not divide keep one shard, which the
+        # divisibility check below turns into the replicated fallback.
+        head_shards = 1
+        if paged and mesh is not None:
+            model_axis = partitioner.decode_cache_axes()[1]
+            tp = int(mesh.shape[model_axis]) if model_axis else 1
+            if int(module.num_heads) % tp == 0:
+                head_shards = tp
+        object.__setattr__(self, "_head_shards", head_shards)
+        cache = self._allocate_cache()
         cache_sharding = None
         cache_replicated = mesh is not None
         if mesh is not None:
@@ -417,6 +430,7 @@ DecodeScheduler`.
                 head_dim,
                 np.dtype(module.dtype).itemsize,
                 quant=str(self.kv_quant),
+                head_shards=head_shards,
             )
         else:
             nbytes = kv_cache_bytes(
@@ -473,7 +487,9 @@ DecodeScheduler`.
             return "reference", reference
         heads = int(module.num_heads)
         head_dim = int(module.d_model) // heads
-        if not ops.decode_attention_supported(heads, head_dim):
+        if not ops.decode_attention_supported(
+            heads, head_dim, paged=paged
+        ):
             if str(self.decode_attention) == "pallas":
                 # Asked for by name: serving the reference under the
                 # kernel's name would hide which program ran.
@@ -627,6 +643,7 @@ DecodeScheduler`.
                 head_dim,
                 module.dtype,
                 quant=str(self.kv_quant),
+                head_shards=self._head_shards,
             )
         return allocate_kv_cache(
             int(module.num_layers),
@@ -1454,6 +1471,33 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                         self._extend_compiled(pb, sb)
         object.__setattr__(self, "_warmed", True)
         return len(self._compiled_cache)
+
+    def pool_sized_copies(self) -> Dict[str, int]:
+        """For every program compiled so far (``decode_step``,
+        ``prefill/8/1024``, ``extend/1/128``, ``copy_page``, ...), how
+        many instructions of its optimised HLO copy or transpose an
+        array as large as a leaf of the KV cache
+        (``observability.hlo.count_copies_of_size``). The cache is
+        donated through every dispatch to be updated in place; a
+        program that holds such an instruction re-lays-out a whole leaf
+        on every call instead, which is what made a decode step cost
+        120 ms before the pool's rows were folded (docs/DESIGN.md §20).
+        ``chip_smoke.py`` holds every count at zero on the chip."""
+        import jax
+
+        from zookeeper_tpu.observability.hlo import count_copies_of_size
+
+        self._require_bound()
+        sizes = {
+            int(np.prod(np.shape(leaf)))
+            for leaf in jax.tree.leaves(self._cache)
+        }
+        return {
+            "/".join(str(part) for part in key[:-1]): count_copies_of_size(
+                compiled.as_text(), sizes
+            )
+            for key, compiled in self._compiled_cache.items()
+        }
 
     # -- dispatch --------------------------------------------------------
 

@@ -5,7 +5,9 @@ The §15 slot layout provisions every slot's WORST case —
 ``slots × capacity`` rows of KV HBM — because one slot's rows must be
 contiguous. This module is the deferred indirection step (ROADMAP item
 4): KV rows live in per-layer POOLS of fixed-size pages
-(``[num_pages, page_size, heads, head_dim]``), any slot's logical page
+(``[num_pages, head_shards, page_size, row_width]``: a token's heads
+folded end to end on the last dimension, ``ops.fold_kv_rows``), any
+slot's logical page
 ``p`` resolves through a ``[slots, max_pages] int32`` PAGE TABLE
 carried as a runtime operand, and three host-side structures make the
 pool a serving system rather than a bag of bytes:
@@ -30,8 +32,8 @@ pool a serving system rather than a bag of bytes:
   so they share by reference forever). Refcount-0 nodes evict LRU
   under pool pressure.
 - int8 quantization hooks — the pool tree optionally stores int8 rows
-  plus page-shaped ``[num_pages, page_size, heads]`` float32 scale
-  arrays (``ops.quantizers.quantize_kv_rows``), dequantized inside the
+  plus page-shaped ``[num_pages, head_shards, page_size,
+  heads_per_shard]`` float32 scale arrays (``ops.quantizers.quantize_kv_rows``), dequantized inside the
   attention read: double the resident tokens per HBM byte.
 
 Validity composes with §15 unchanged: a slot's row ``j`` is meaningful
@@ -73,14 +75,21 @@ def allocate_page_pool(
     head_dim: int,
     dtype: Any,
     quant: str = "none",
+    head_shards: int = 1,
 ) -> Tuple[dict, ...]:
     """Zero-initialized page-pool pytree: a per-layer tuple of
-    ``{"k", "v"}`` pools ``[num_pages, page_size, heads, head_dim]``,
-    plus ``{"k_scale", "v_scale"}`` ``[num_pages, page_size, heads]``
-    float32 when ``quant="int8"`` (rows stored int8). The engine places
-    it under the partitioner's page-pool sharding and donates it
-    through every dispatch, exactly like the slot-layout cache."""
+    ``{"k", "v"}`` pools ``[num_pages, head_shards, page_size,
+    row_width]`` — each token row holds a head shard's heads end to end,
+    zero-padded to whole 128-lane registers (``ops.fold_kv_rows``;
+    ``head_shards`` is the model-axis size the heads shard over, 1 on
+    one device) — plus ``{"k_scale", "v_scale"}`` ``[num_pages,
+    head_shards, page_size, heads_per_shard]`` float32 when
+    ``quant="int8"`` (rows stored int8). The engine places it under the
+    partitioner's page-pool sharding and donates it through every
+    dispatch, exactly like the slot-layout cache."""
     import jax.numpy as jnp
+
+    from zookeeper_tpu.ops import kv_row_width
 
     if num_pages < 1 or page_size < 1:
         raise ValueError(
@@ -89,7 +98,8 @@ def allocate_page_pool(
         )
     if quant not in ("none", "int8"):
         raise ValueError(f"quant={quant!r}: expected 'none' or 'int8'.")
-    shape = (num_pages, page_size, num_heads, head_dim)
+    width = kv_row_width(num_heads, head_dim, head_shards)
+    shape = (num_pages, head_shards, page_size, width)
     row_dtype = jnp.int8 if quant == "int8" else dtype
     layers = []
     for _ in range(num_layers):
@@ -100,8 +110,9 @@ def allocate_page_pool(
         if quant == "int8":
             # Scale 1.0 everywhere: a zeroed int8 page dequantizes to
             # exact zeros, matching the fp pool's initial state.
-            layer["k_scale"] = jnp.ones(shape[:3], jnp.float32)
-            layer["v_scale"] = jnp.ones(shape[:3], jnp.float32)
+            scales = shape[:3] + (num_heads // head_shards,)
+            layer["k_scale"] = jnp.ones(scales, jnp.float32)
+            layer["v_scale"] = jnp.ones(scales, jnp.float32)
         layers.append(layer)
     return tuple(layers)
 
@@ -114,13 +125,18 @@ def page_pool_bytes(
     head_dim: int,
     itemsize: int,
     quant: str = "none",
+    head_shards: int = 1,
 ) -> int:
-    """Total HBM the pool occupies (k + v rows, all layers, plus the
-    scale arrays when quantized) — the §20 capacity-planning number."""
-    rows = 2 * num_layers * num_pages * page_size * num_heads
-    total = rows * head_dim * (1 if quant == "int8" else itemsize)
+    """Total HBM the pool occupies (k + v rows at their padded width,
+    all layers, plus the scale arrays when quantized) — the §20
+    capacity-planning number."""
+    from zookeeper_tpu.ops import kv_row_width
+
+    rows = 2 * num_layers * num_pages * page_size
+    width = head_shards * kv_row_width(num_heads, head_dim, head_shards)
+    total = rows * width * (1 if quant == "int8" else itemsize)
     if quant == "int8":
-        total += rows * 4  # float32 scale per (row, head)
+        total += rows * num_heads * 4  # float32 scale per (row, head)
     return total
 
 
