@@ -27,6 +27,7 @@ Design (TPU-first, standard pre-norm decoder):
   / ``TrainingExperiment`` need no LM-specific fork.
 """
 
+from functools import partial
 from typing import Any, Sequence, Tuple
 
 import flax.linen as nn
@@ -41,6 +42,7 @@ from zookeeper_tpu.ops import (
     flash_attention,
     paged_decode_attention,
 )
+from zookeeper_tpu.ops.moe import sparse_moe
 from zookeeper_tpu.parallel.sharding import constrain_batch_sharded
 
 
@@ -128,6 +130,15 @@ def _pool_write_rows(layer, rows, pages, offsets):
     return out
 
 
+def layer_page_table(page_table, windowed: bool):
+    """A layer's page table out of a dispatch's operand: the table
+    itself for a model of one layer group, else the group's of the two
+    stacked ``[full, window]`` (``PagePool.operand``)."""
+    if page_table.ndim == 2:
+        return page_table
+    return page_table[1 if windowed else 0]
+
+
 def _pool_scales(layer):
     return layer.get("k_scale"), layer.get("v_scale")
 
@@ -160,15 +171,77 @@ class RMSNorm(nn.Module):
 
     dtype: Any = jnp.float32
     eps: float = 1e-6
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         x32 = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype
+        )
         y = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
         )
         return (y * scale).astype(self.dtype)
+
+
+def rope_inv_freq(head_dim: int, theta: float, yarn: Tuple = ()):
+    """``(inv_freq [head_dim // 2] float32, attention_factor)`` of rotary
+    positions over the whole head: ``inv_i = theta ** (-2 i /
+    head_dim)``. ``yarn = (factor, original_len, beta_fast, beta_slow)``
+    stretches the slow dimensions for contexts past ``original_len``
+    (YaRN): with ``d(b) = head_dim ln(original_len / (2 pi b)) / (2 ln
+    theta)`` the dimension that turns ``b`` times over the original
+    length, ``low = floor(d(beta_fast))`` and ``high = ceil(d(beta_slow))``
+    (clipped to the head), dimensions below ``low`` keep ``inv_i``,
+    those above ``high`` take ``inv_i / factor``, and a linear ramp
+    blends between; cos and sin are scaled by ``0.1 ln(factor) + 1``.
+    The table is static: it does not depend on the sequence's length."""
+    import math
+
+    import numpy as np
+
+    half = head_dim // 2
+    inv = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / head_dim)
+    if not yarn:
+        return jnp.asarray(inv, jnp.float32), 1.0
+    factor, original_len, beta_fast, beta_slow = yarn
+
+    def turns_dim(turns):
+        return (
+            head_dim
+            * math.log(original_len / (turns * 2 * math.pi))
+            / (2 * math.log(theta))
+        )
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), head_dim - 1)
+    ramp = np.clip(
+        (np.arange(half, dtype=np.float64) - low) / max(high - low, 1e-3),
+        0.0,
+        1.0,
+    )
+    inv = inv * (1.0 - ramp) + (inv / factor) * ramp
+    return jnp.asarray(inv, jnp.float32), 0.1 * math.log(factor) + 1.0
+
+
+def apply_rope(xs, positions, inv_freq, attention_factor: float = 1.0):
+    """Rotate each ``x [b, s, heads, head_dim]`` of ``xs`` by its
+    ``positions [b, s]`` (rotate-half convention: dimension ``i`` pairs
+    with ``i + head_dim / 2``). Angles, cos and sin in float32, once
+    for all of ``xs``; each result in its ``x``'s dtype."""
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos = (jnp.cos(angles) * attention_factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * attention_factor)[:, :, None, :]
+
+    def rotate(x):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        out = jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        )
+        return out.astype(x.dtype)
+
+    return tuple(rotate(x) for x in xs)
 
 
 class _Block(nn.Module):
@@ -181,6 +254,16 @@ class _Block(nn.Module):
     names the original compact implementation auto-assigned
     (``RMSNorm_0``/``RMSNorm_1``/``qkv``/``proj``/``up``/``down``) so
     every existing checkpoint and partition rule keeps matching.
+
+    What differs by model is a field, and every default is the GPT-2
+    shape: ``num_kv_heads``/``head_dim`` (grouped heads, a head size
+    that is not ``d_model / num_heads``), ``rope_theta`` (rotary
+    positions on q and k, YaRN-scaled where ``rope_yarn`` is given),
+    ``window`` (a sliding-window layer: causal and at most ``window``
+    keys back), ``mlp="moe"`` (sparse SwiGLU experts, ``ops/moe.py``)
+    and ``param_dtype``. The five traced methods share ONE
+    projection-and-positions helper (:meth:`_qkv`) and one attention
+    keyword set (:meth:`_attention_kwargs`).
     """
 
     d_model: int
@@ -193,25 +276,116 @@ class _Block(nn.Module):
     #: oracle), "pallas" (the paged decode kernel), or a callable. A
     #: per-call ``attention_override`` (the engine seam) wins.
     decode_attention: Any = "reference"
+    num_kv_heads: int = 0  # 0: as many as num_heads
+    head_dim: int = 0  # 0: d_model // num_heads
+    rope_theta: float = 0.0  # 0: no rotary positions
+    rope_yarn: Tuple = ()
+    window: int = 0  # 0: full attention
+    mlp: str = "gelu"
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_dim: int = 0
+    param_dtype: Any = jnp.float32
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.d_model // self.num_heads
 
     def setup(self):
         d = self.d_model
-        self.ln1 = RMSNorm(dtype=self.dtype, name="RMSNorm_0")
-        self.wqkv = nn.Dense(
-            3 * d, use_bias=False, dtype=self.dtype, name="qkv"
+        dense = partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype,
         )
-        self.wproj = nn.Dense(d, use_bias=False, dtype=self.dtype, name="proj")
-        self.ln2 = RMSNorm(dtype=self.dtype, name="RMSNorm_1")
-        self.wup = nn.Dense(
-            self.mlp_ratio * d, use_bias=False, dtype=self.dtype, name="up"
+        norm = partial(RMSNorm, dtype=self.dtype, param_dtype=self.param_dtype)
+        self.ln1 = norm(name="RMSNorm_0")
+        # One fused projection: query heads, then key heads, then value
+        # heads (three equal thirds in the GPT-2 shape).
+        self.wqkv = dense(
+            (self.num_heads + 2 * self.kv_heads) * self.head_size, name="qkv"
         )
-        self.wdown = nn.Dense(d, use_bias=False, dtype=self.dtype, name="down")
+        self.wproj = dense(d, name="proj")
+        self.ln2 = norm(name="RMSNorm_1")
+        if self.mlp == "moe":
+            e, f = self.num_experts, self.expert_dim
+            # The experts' matrices lie side by side, expert e the column
+            # block e (ops/moe.py): each leaf a plain [fan_in, out] kernel.
+            kernel = nn.initializers.lecun_normal()
+            for name, shape in (
+                ("router", (d, e)),
+                ("experts_gate", (d, e * f)),
+                ("experts_up", (d, e * f)),
+                ("experts_down", (f, e * d)),
+            ):
+                setattr(
+                    self, name,
+                    self.param(name, kernel, shape, self.param_dtype),
+                )
+        else:
+            self.wup = dense(self.mlp_ratio * d, name="up")
+            self.wdown = dense(d, name="down")
+
+    def _qkv(self, x, positions):
+        """The projection and the positions, once for every traced
+        method: ``x [b, s, d]`` -> ``q [b, s, heads, head_dim]``, ``k``
+        and ``v [b, s, kv_heads, head_dim]``, q and k rotated by
+        ``positions [b, s]`` where the block has rotary positions."""
+        b, s, _ = x.shape
+        h, hkv, hd = self.num_heads, self.kv_heads, self.head_size
+        qkv = self.wqkv(self.ln1(x))
+        q, k, v = jnp.split(qkv, [h * hd, (h + hkv) * hd], axis=-1)
+        q = q.reshape(b, s, h, hd)
+        k = k.reshape(b, s, hkv, hd)
+        v = v.reshape(b, s, hkv, hd)
+        if self.rope_theta:
+            inv_freq, factor = rope_inv_freq(
+                hd, self.rope_theta, self.rope_yarn
+            )
+            q, k = apply_rope((q, k), positions, inv_freq, factor)
+        return q, k, v
+
+    def _attention_kwargs(self, pool: bool = False):
+        """What this block's attention call adds to the plain one: the
+        band of a window layer, and (pool paths) how many heads a pool
+        row holds when they are grouped. Empty in the GPT-2 shape, so
+        a plain ``callable(q, k, v, *, causal)`` still plugs in."""
+        kwargs = {}
+        if self.window:
+            kwargs["window"] = self.window
+        if pool and self.kv_heads != self.num_heads:
+            kwargs["kv_heads"] = self.kv_heads
+        return kwargs
+
+    def _out(self, x, o):
+        b, s = o.shape[:2]
+        return x + self.wproj(o.reshape(b, s, -1))
 
     def _mlp(self, x):
         h = self.ln2(x)
-        h = self.wup(h)
-        h = nn.gelu(h)
-        h = self.wdown(h)
+        if self.mlp == "moe":
+            b, s, d = h.shape
+            h, load = sparse_moe(
+                h.reshape(b * s, d),
+                self.router,
+                self.experts_gate, self.experts_up, self.experts_down,
+                k=self.experts_per_token,
+            )
+            h = h.reshape(b, s, d)
+            # Rows each expert took, for whoever asks (``mutable=
+            # ["moe_load"]``: the decode engine while tracing).
+            self.sow(
+                "moe_load", "tokens_per_expert", load,
+                init_fn=lambda: jnp.zeros_like(load),
+                reduce_fn=lambda a, b: a + b,
+            )
+        else:
+            h = self.wup(h)
+            h = nn.gelu(h)
+            h = self.wdown(h)
         # Pin the residual stream to the canonical layout (batch on the
         # data axes) at every block boundary: without the pin, GSPMD
         # was observed picking an FSDP-axis-spread layout for the
@@ -225,25 +399,19 @@ class _Block(nn.Module):
         return out
 
     def __call__(self, x, training: bool, return_kv: bool = False):
-        b, s, d = x.shape
-        head_dim = d // self.num_heads
-
-        h = self.ln1(x)
-        qkv = self.wqkv(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(b, s, self.num_heads, head_dim)
-        kh, vh = to_heads(k), to_heads(v)
+        b, s, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        q, kh, vh = self._qkv(x, positions)
         attn = _resolve_attention(self.attention)
-        o = attn(to_heads(q), kh, vh, causal=True)
-        x = x + self.wproj(o.reshape(b, s, d))
-        out = self._mlp(x)
+        o = attn(q, kh, vh, causal=True, **self._attention_kwargs())
+        out = self._mlp(self._out(x, o))
         if return_kv:
             return out, (kh, vh)
         return out
 
     def decode(self, x, k_cache, v_cache, lengths, attention_override=None):
         """One cached-attention step: ``x [b, 1, d]`` is the new token's
-        residual stream, ``k_cache/v_cache [b, capacity, heads,
+        residual stream, ``k_cache/v_cache [b, capacity, kv_heads,
         head_dim]`` the slot KV buffers, ``lengths [b]`` the tokens
         already cached. Writes the new position's K/V at index
         ``lengths`` (clamped to the last row — the scheduler never
@@ -255,13 +423,7 @@ class _Block(nn.Module):
         given (the decode engine's flavor seam), else the block's
         ``decode_attention`` setting."""
         b = x.shape[0]
-        head_dim = self.d_model // self.num_heads
-
-        h = self.ln1(x)
-        qkv = self.wqkv(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(b, 1, self.num_heads, head_dim)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
+        q, k, v = self._qkv(x, lengths[:, None])
         write = jnp.clip(lengths, 0, k_cache.shape[1] - 1)
         rows = jnp.arange(b)
         k_cache = k_cache.at[rows, write].set(k[:, 0], mode="drop")
@@ -271,9 +433,8 @@ class _Block(nn.Module):
             if attention_override is not None
             else _resolve_decode_attention(self.decode_attention)
         )
-        o = attn(q, k_cache, v_cache, lengths)
-        x = x + self.wproj(o.reshape(b, 1, self.d_model))
-        return self._mlp(x), k_cache, v_cache
+        o = attn(q, k_cache, v_cache, lengths, **self._attention_kwargs())
+        return self._mlp(self._out(x, o)), k_cache, v_cache
 
     def decode_verify(self, x, k_cache, v_cache, lengths):
         """The multi-token (speculative verify) step: ``x [b, w, d]`` is
@@ -291,19 +452,14 @@ class _Block(nn.Module):
         from zookeeper_tpu.ops import verify_cached_attention
         from zookeeper_tpu.serving.decode.cache import append_kv_rows
 
-        b, w, _ = x.shape
-        head_dim = self.d_model // self.num_heads
-
-        h = self.ln1(x)
-        qkv = self.wqkv(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(b, w, self.num_heads, head_dim)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
+        w = x.shape[1]
+        q, k, v = self._qkv(x, lengths[:, None] + jnp.arange(w)[None, :])
         k_cache = append_kv_rows(k_cache, k, lengths)
         v_cache = append_kv_rows(v_cache, v, lengths)
-        o = verify_cached_attention(q, k_cache, v_cache, lengths)
-        x = x + self.wproj(o.reshape(b, w, self.d_model))
-        return self._mlp(x), k_cache, v_cache
+        o = verify_cached_attention(
+            q, k_cache, v_cache, lengths, **self._attention_kwargs()
+        )
+        return self._mlp(self._out(x, o)), k_cache, v_cache
 
     def decode_paged(
         self, x, layer, page_table, lengths, attention_override=None
@@ -320,15 +476,9 @@ class _Block(nn.Module):
         inactive slot past its pages) drops the write via the OOB page
         sentinel — the paged analogue of the §15 clamp, and like it
         only ever taken by slots whose output is discarded."""
-        b = x.shape[0]
-        head_dim = self.d_model // self.num_heads
+        page_table = layer_page_table(page_table, bool(self.window))
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
-
-        h = self.ln1(x)
-        qkv = self.wqkv(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(b, 1, self.num_heads, head_dim)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
+        q, k, v = self._qkv(x, lengths[:, None])
         row = jnp.clip(lengths // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row[:, None], axis=1)[:, 0]
         page = jnp.where(
@@ -349,9 +499,9 @@ class _Block(nn.Module):
         o = attn(
             q, layer["k"], layer["v"], page_table, lengths,
             k_scale=k_scale, v_scale=v_scale,
+            **self._attention_kwargs(pool=True),
         )
-        x = x + self.wproj(o.reshape(b, 1, self.d_model))
-        return self._mlp(x), layer
+        return self._mlp(self._out(x, o)), layer
 
     def decode_verify_paged(
         self, x, layer, page_table, lengths, valid=None,
@@ -367,16 +517,11 @@ class _Block(nn.Module):
         padding rows write nowhere — OOB sentinel); None = all ``w``
         (the speculative verify, whose eligibility check already
         guarantees the pages exist). Rollback stays by-length."""
-        b, w, _ = x.shape
-        head_dim = self.d_model // self.num_heads
+        page_table = layer_page_table(page_table, bool(self.window))
+        w = x.shape[1]
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
-
-        h = self.ln1(x)
-        qkv = self.wqkv(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(b, w, self.num_heads, head_dim)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
         pos = lengths[:, None] + jnp.arange(w)[None, :]
+        q, k, v = self._qkv(x, pos)
         row = jnp.clip(pos // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row, axis=1)
         dead = (page < 0) | (pos >= page_table.shape[1] * ps)
@@ -396,9 +541,9 @@ class _Block(nn.Module):
         o = attn(
             q, layer["k"], layer["v"], page_table, lengths,
             k_scale=k_scale, v_scale=v_scale,
+            **self._attention_kwargs(pool=True),
         )
-        x = x + self.wproj(o.reshape(b, w, self.d_model))
-        return self._mlp(x), layer
+        return self._mlp(self._out(x, o)), layer
 
 
 def _auto_pin_activations(attention, pin_activations):
@@ -455,18 +600,47 @@ class TransformerLMModule(nn.Module):
     #: callable); a ``decode_step`` per-call override wins — see
     #: ``_resolve_decode_attention``.
     decode_attention: Any = "reference"
+    # What differs by model (``_Block``'s fields of the same names;
+    # every default is the GPT-2 shape):
+    num_kv_heads: int = 0
+    head_size: int = 0  # 0: d_model // num_heads
+    positions: str = "learned"  # or "rope": no position table
+    rope_theta: float = 10000.0
+    rope_yarn: Tuple = ()  # (factor, original_len, beta_fast, beta_slow)
+    #: "full" or "window" a layer; empty: every layer full. Window
+    #: layers attend ``window`` keys back with plain rotary positions,
+    #: full layers everything with the YaRN-scaled table.
+    layer_types: Tuple = ()
+    window: int = 0
+    mlp: str = "gelu"  # or "moe"
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_dim: int = 0
+    tie_embeddings: bool = True
+    param_dtype: Any = jnp.float32
 
     def setup(self):
         self.embed = self.param(
             "embed",
             nn.initializers.normal(0.02),
             (self.vocab_size, self.d_model),
+            self.param_dtype,
         )
-        self.pos = self.param(
-            "pos",
-            nn.initializers.normal(0.02),
-            (self.max_seq_len, self.d_model),
-        )
+        rope = self.positions == "rope"
+        if not rope:
+            self.pos = self.param(
+                "pos",
+                nn.initializers.normal(0.02),
+                (self.max_seq_len, self.d_model),
+                self.param_dtype,
+            )
+        if not self.tie_embeddings:
+            self.head = self.param(
+                "head",
+                nn.initializers.lecun_normal(),
+                (self.d_model, self.vocab_size),
+                self.param_dtype,
+            )
         pin = _auto_pin_activations(self.attention, self.pin_activations)
         self.blocks = [
             _Block(
@@ -477,21 +651,66 @@ class TransformerLMModule(nn.Module):
                 dtype=self.dtype,
                 pin_activations=pin,
                 decode_attention=self.decode_attention,
+                num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_size,
+                rope_theta=self.rope_theta if rope else 0.0,
+                rope_yarn=() if windowed else self.rope_yarn,
+                window=self.window if windowed else 0,
+                mlp=self.mlp,
+                num_experts=self.num_experts,
+                experts_per_token=self.experts_per_token,
+                expert_dim=self.expert_dim,
+                param_dtype=self.param_dtype,
                 name=f"block{i}",
             )
-            for i in range(self.num_layers)
+            for i, windowed in enumerate(self.window_layers)
         ]
-        self.final_norm = RMSNorm(dtype=self.dtype, name="RMSNorm_0")
+        self.final_norm = RMSNorm(
+            dtype=self.dtype, param_dtype=self.param_dtype, name="RMSNorm_0"
+        )
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+        return self.head_size or self.d_model // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def window_layers(self) -> Tuple[bool, ...]:
+        """Per layer, whether it is a sliding-window layer."""
+        if not self.layer_types:
+            return (False,) * self.num_layers
+        return tuple(t == "window" for t in self.layer_types)
+
+    def _embed(self, tokens, positions=None):
+        """The residual stream's start: the token table's rows, plus
+        the position table's where the model has one (rotary positions
+        act inside the blocks). ``positions`` None: a whole sequence
+        from 0, the table's leading slice."""
+        x = self.embed[tokens]
+        if self.positions != "rope":
+            if positions is None:
+                x = x + self.pos[None, : tokens.shape[1]]
+            else:
+                x = x + self.pos[
+                    jnp.clip(positions, 0, self.max_seq_len - 1)
+                ]
+        return x.astype(self.dtype)
 
     def _pin(self) -> bool:
         return _auto_pin_activations(self.attention, self.pin_activations)
 
     def _logits(self, x):
         x = self.final_norm(x)
+        if not self.tie_embeddings:
+            # A head of its own, multiplied as it is held (no float32
+            # copy of a table that may be half a gigabyte), float32 out.
+            return jnp.einsum(
+                "bsd,dv->bsv", x, self.head.astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            )
         # Weight-tied LM head: logits in fp32 (the loss reduction dtype).
         return jnp.einsum(
             "bsd,vd->bsv",
@@ -511,7 +730,7 @@ class TransformerLMModule(nn.Module):
                 f"Sequence length {s} exceeds max_seq_len "
                 f"{self.max_seq_len} (the positional table size)."
             )
-        x = (self.embed[tokens] + self.pos[None, :s]).astype(self.dtype)
+        x = self._embed(tokens)
         if self._pin():
             x = constrain_batch_sharded(x)
         kv = []
@@ -539,10 +758,11 @@ class TransformerLMModule(nn.Module):
         cache. Numerically the same program as ``__call__`` — the
         first emitted token is the full-context oracle's."""
         x, kv = self._backbone(tokens, False, collect_kv=True)
-        logits = self._logits(x)
+        # The head reads the one row that is asked for: every row's
+        # logits would be [s, vocab] float32 a sequence.
         idx = jnp.clip(lengths - 1, 0, tokens.shape[1] - 1)
-        last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
-        return last, tuple(kv)
+        last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+        return self._logits(last)[:, 0], tuple(kv)
 
     def decode_step(self, tokens, lengths, cache, attention_override=None):
         """One incremental token per sequence. ``tokens [b] int`` are
@@ -562,9 +782,7 @@ class TransformerLMModule(nn.Module):
                 f"cache has {len(cache)} layers, model has "
                 f"{self.num_layers}."
             )
-        pos_idx = jnp.clip(lengths, 0, self.max_seq_len - 1)
-        x = (self.embed[tokens] + self.pos[pos_idx]).astype(self.dtype)
-        x = x[:, None, :]
+        x = self._embed(tokens, lengths)[:, None, :]
         if self._pin():
             x = constrain_batch_sharded(x)
         new_cache = []
@@ -605,12 +823,7 @@ class TransformerLMModule(nn.Module):
                 f"shape {tokens.shape}."
             )
         w = tokens.shape[1]
-        pos_idx = jnp.clip(
-            lengths[:, None] + jnp.arange(w)[None, :],
-            0,
-            self.max_seq_len - 1,
-        )
-        x = (self.embed[tokens] + self.pos[pos_idx]).astype(self.dtype)
+        x = self._embed(tokens, lengths[:, None] + jnp.arange(w)[None, :])
         if self._pin():
             x = constrain_batch_sharded(x)
         new_cache = []
@@ -639,9 +852,7 @@ class TransformerLMModule(nn.Module):
                 f"cache has {len(cache)} layers, model has "
                 f"{self.num_layers}."
             )
-        pos_idx = jnp.clip(lengths, 0, self.max_seq_len - 1)
-        x = (self.embed[tokens] + self.pos[pos_idx]).astype(self.dtype)
-        x = x[:, None, :]
+        x = self._embed(tokens, lengths)[:, None, :]
         if self._pin():
             x = constrain_batch_sharded(x)
         new_cache = []
@@ -677,12 +888,7 @@ class TransformerLMModule(nn.Module):
                 f"got shape {tokens.shape}."
             )
         w = tokens.shape[1]
-        pos_idx = jnp.clip(
-            lengths[:, None] + jnp.arange(w)[None, :],
-            0,
-            self.max_seq_len - 1,
-        )
-        x = (self.embed[tokens] + self.pos[pos_idx]).astype(self.dtype)
+        x = self._embed(tokens, lengths[:, None] + jnp.arange(w)[None, :])
         if self._pin():
             x = constrain_batch_sharded(x)
         new_cache = []
@@ -767,6 +973,41 @@ class TransformerLM(Model):
     #: without a table reshape; build() raises if the configured
     #: sequence exceeds an explicit capacity.
     max_seq_len: int = Field(-1)
+    # -- what differs by model; every default is the GPT-2 shape --------
+    #: Key/value heads (grouped-query attention: query head ``j`` reads
+    #: key/value head ``j // (num_heads / num_kv_heads)``). -1: as many
+    #: as ``num_heads``.
+    num_kv_heads: int = Field(-1)
+    #: Size of one head. -1: ``d_model / num_heads``; set it where the
+    #: heads' total is not the model's width (32 x 128 on 2304).
+    head_dim: int = Field(-1)
+    #: "learned" (a position table added to the embedding) or "rope"
+    #: (rotary positions on q and k over the whole head, rotate-half).
+    positions: str = Field("learned")
+    rope_theta: float = Field(10000.0)
+    #: YaRN scaling of the FULL layers' rotary table; 1.0 is plain RoPE.
+    #: Window layers always take the plain table.
+    yarn_factor: float = Field(1.0)
+    yarn_original_len: int = Field(8192)
+    yarn_beta_fast: float = Field(32.0)
+    yarn_beta_slow: float = Field(1.0)
+    #: One of "full" / "window" a layer, repeated cyclically to
+    #: ``num_layers`` (so a period is enough); empty: every layer full.
+    layer_types: Sequence[str] = Field(())
+    #: Keys a window layer attends, the query's own included.
+    window: int = Field(0)
+    #: "gelu" (the dense MLP of ``mlp_ratio``) or "moe" (sparse SwiGLU
+    #: experts: ``num_experts`` of width ``expert_dim``, the
+    #: ``experts_per_token`` largest router weights renormalised).
+    mlp: str = Field("gelu")
+    num_experts: int = Field(0)
+    experts_per_token: int = Field(0)
+    expert_dim: int = Field(0)
+    #: False: a head of its own beside the embedding.
+    tie_embeddings: bool = Field(True)
+    #: The type the parameters are held in ("bfloat16" for a model
+    #: published and served that way: half the bytes, read as they are).
+    param_dtype: str = Field("float32")
 
     def set_attention_override(self, fn) -> None:
         """The partitioner injection seam (``Partitioner.prepare_model``):
@@ -812,10 +1053,61 @@ class TransformerLM(Model):
         if decode_attention is None:
             _resolve_decode_attention(self.decode_attention)
             decode_attention = self.decode_attention
-        if self.d_model % self.num_heads != 0:
+        num_kv_heads = (
+            self.num_heads if self.num_kv_heads == -1 else self.num_kv_heads
+        )
+        if self.head_dim == -1:
+            if self.d_model % self.num_heads != 0:
+                raise ValueError(
+                    f"d_model={self.d_model} not divisible by "
+                    f"num_heads={self.num_heads}; set head_dim for heads "
+                    "whose total is not the model's width."
+                )
+            head_dim = self.d_model // self.num_heads
+        else:
+            head_dim = self.head_dim
+        if head_dim < 1 or num_kv_heads < 1 or (
+            self.num_heads % num_kv_heads
+        ):
             raise ValueError(
-                f"d_model={self.d_model} not divisible by "
-                f"num_heads={self.num_heads}."
+                f"num_heads={self.num_heads}, num_kv_heads={num_kv_heads}, "
+                f"head_dim={head_dim}: the query heads must be a multiple "
+                "of the key/value heads."
+            )
+        if self.positions not in ("learned", "rope"):
+            raise ValueError(
+                f"positions={self.positions!r}: expected 'learned' or "
+                "'rope'."
+            )
+        if self.positions == "rope" and head_dim % 2:
+            raise ValueError(f"rope needs an even head_dim, got {head_dim}.")
+        period = tuple(str(t) for t in self.layer_types)
+        if any(t not in ("full", "window") for t in period):
+            raise ValueError(
+                f"layer_types={period!r}: each is 'full' or 'window'."
+            )
+        layer_types = tuple(
+            period[i % len(period)] for i in range(self.num_layers)
+        ) if period else ()
+        if "window" in layer_types and self.window < 1:
+            raise ValueError("window layers need window >= 1.")
+        if self.mlp not in ("gelu", "moe"):
+            raise ValueError(f"mlp={self.mlp!r}: expected 'gelu' or 'moe'.")
+        if self.mlp == "moe" and not (
+            1 <= self.experts_per_token <= self.num_experts
+            and self.expert_dim >= 1
+        ):
+            raise ValueError(
+                f"mlp='moe' needs 1 <= experts_per_token "
+                f"({self.experts_per_token}) <= num_experts "
+                f"({self.num_experts}) and expert_dim >= 1 "
+                f"({self.expert_dim})."
+            )
+        rope_yarn = ()
+        if self.positions == "rope" and self.yarn_factor != 1.0:
+            rope_yarn = (
+                float(self.yarn_factor), int(self.yarn_original_len),
+                float(self.yarn_beta_fast), float(self.yarn_beta_slow),
             )
         (seq_len,) = input_shape
         if self.max_seq_len == -1:
@@ -843,6 +1135,19 @@ class TransformerLM(Model):
             max_seq_len=max_seq_len,
             dtype=self.dtype(),
             decode_attention=decode_attention,
+            num_kv_heads=num_kv_heads,
+            head_size=head_dim,
+            positions=self.positions,
+            rope_theta=float(self.rope_theta),
+            rope_yarn=rope_yarn,
+            layer_types=layer_types,
+            window=int(self.window),
+            mlp=self.mlp,
+            num_experts=int(self.num_experts),
+            experts_per_token=int(self.experts_per_token),
+            expert_dim=int(self.expert_dim),
+            tie_embeddings=bool(self.tie_embeddings),
+            param_dtype=jnp.dtype(self.param_dtype),
         )
 
     def initialize(

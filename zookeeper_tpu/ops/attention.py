@@ -66,6 +66,21 @@ def _mosaic_params(estimate: int):
     return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes(estimate))
 
 
+def _repeat_kv_heads(q, k, v):
+    """Grouped heads in the oracle paths: key/value head ``j`` serves
+    query heads ``j * g .. j * g + g - 1``, so each is repeated ``g``
+    times (the kernels index instead and repeat nothing in memory).
+    Equal head counts pass through untouched."""
+    h, hkv = q.shape[2], k.shape[2]
+    if h == hkv:
+        return k, v
+    if h % hkv:
+        raise ValueError(
+            f"{h} query heads are not a multiple of {hkv} key/value heads."
+        )
+    return jnp.repeat(k, h // hkv, axis=2), jnp.repeat(v, h // hkv, axis=2)
+
+
 def attention_reference(
     q: jax.Array,
     k: jax.Array,
@@ -73,16 +88,23 @@ def attention_reference(
     *,
     causal: bool = False,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain full softmax attention — the single-device path and the
     oracle the ring implementation is tested against.
 
     Shapes: ``q/k/v [batch, seq, heads, head_dim]`` -> same for the
-    output. Scores accumulate in fp32 regardless of input dtype (the
-    TPU-standard mixed-precision contract); output casts back.
+    output (``k``/``v`` may hold fewer, grouped heads). Scores
+    accumulate in fp32 regardless of input dtype (the TPU-standard
+    mixed-precision contract); output casts back. ``window`` (with
+    ``causal``) keeps a query at ``i`` to the keys ``i - window < p <=
+    i``: the band a sliding-window layer attends.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if window is not None and not causal:
+        raise ValueError("window needs causal=True.")
+    k, v = _repeat_kv_heads(q, k, v)
     # HIGHEST precision: on TPU, f32 einsum at DEFAULT multiplies in
     # bf16; the ring and dense paths reassociate differently, so both
     # pin full-precision multiplies to stay comparable at tight
@@ -97,7 +119,10 @@ def attention_reference(
     if causal:
         qi = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
         ki = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
-        s = jnp.where(ki <= qi, s, _MASK_VALUE)
+        keep = ki <= qi
+        if window is not None:
+            keep = keep & (qi - ki < window)
+        s = jnp.where(keep, s, _MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum(
         "bhqk,bkhd->bqhd",
@@ -114,6 +139,7 @@ def cached_attention(
     lengths: jax.Array,
     *,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Single-position attention over a per-sequence KV cache — the
     incremental-decode counterpart of :func:`attention_reference`.
@@ -134,10 +160,13 @@ def cached_attention(
     oracle's row at the same position is dot-reduction reassociation
     over the (capacity vs sequence) axis — ULP-level, and pinned
     token-exact by the decode parity certification (docs/DESIGN.md
-    §15).
+    §15). ``k_cache``/``v_cache`` may hold fewer, grouped heads;
+    ``window`` keeps the new token (at ``lengths``) to the rows
+    ``lengths - window < j <= lengths``.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    k_cache, v_cache = _repeat_kv_heads(q, k_cache, v_cache)
     s = jnp.einsum(
         "bqhd,bkhd->bhqk",
         q,
@@ -147,6 +176,10 @@ def cached_attention(
     ) * jnp.float32(scale)
     ki = lax.broadcasted_iota(jnp.int32, (k_cache.shape[1],), 0)
     mask = ki[None, None, None, :] <= lengths[:, None, None, None]
+    if window is not None:
+        mask = mask & (
+            ki[None, None, None, :] > lengths[:, None, None, None] - window
+        )
     s = jnp.where(mask, s, _MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum(
@@ -164,6 +197,7 @@ def verify_cached_attention(
     lengths: jax.Array,
     *,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-position attention over a per-sequence KV cache — the
     speculative-decode verify counterpart of :func:`cached_attention`
@@ -186,10 +220,13 @@ def verify_cached_attention(
     the single-position decode step's at the same (sequence, position)
     only by dot-reduction reassociation over the batched-q einsum:
     ULP-level, and pinned TOKEN-exact (speculative greedy == plain
-    greedy) by the speculative-decode certification.
+    greedy) by the speculative-decode certification. Grouped heads and
+    ``window`` as in :func:`cached_attention`, the band taken at each
+    draft position.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    k_cache, v_cache = _repeat_kv_heads(q, k_cache, v_cache)
     s = jnp.einsum(
         "bqhd,bkhd->bhqk",
         q,
@@ -200,10 +237,10 @@ def verify_cached_attention(
     w = q.shape[1]
     ki = lax.broadcasted_iota(jnp.int32, (k_cache.shape[1],), 0)
     qi = lax.broadcasted_iota(jnp.int32, (w,), 0)
-    mask = (
-        ki[None, None, None, :]
-        <= lengths[:, None, None, None] + qi[None, None, :, None]
-    )
+    pos = lengths[:, None, None, None] + qi[None, None, :, None]
+    mask = ki[None, None, None, :] <= pos
+    if window is not None:
+        mask = mask & (ki[None, None, None, :] > pos - window)
     s = jnp.where(mask, s, _MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum(
@@ -539,6 +576,8 @@ def pool_decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    kv_heads: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Single-position decode attention over a SHARED page pool — the
     page-indirected counterpart of :func:`cached_attention`
@@ -563,11 +602,17 @@ def pool_decode_attention(
     full-context oracle. int8 pools add one exactly-representable
     ``int8 × fp32 scale`` multiply before the same einsums
     (documented-ULP, argmax-pinned by the §20 sweep).
+
+    ``kv_heads`` (default: ``q``'s heads) is how many heads a pool row
+    holds when they are grouped; ``window`` as in
+    :func:`cached_attention`. A window layer's table may have released
+    the pages behind the window (``-1`` entries): the gather reads some
+    other page there and the band masks it.
     """
-    h, d = q.shape[2], q.shape[3]
+    h, d = kv_heads or q.shape[2], q.shape[3]
     kc = _gathered_pool_view(k_pool, page_table, h, d, k_scale)
     vc = _gathered_pool_view(v_pool, page_table, h, d, v_scale)
-    return cached_attention(q, kc, vc, lengths, scale=scale)
+    return cached_attention(q, kc, vc, lengths, scale=scale, window=window)
 
 
 def pool_verify_attention(
@@ -580,6 +625,8 @@ def pool_verify_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    kv_heads: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-position (speculative verify / warm-prefix extend)
     attention over a shared page pool — the page-indirected counterpart
@@ -587,11 +634,14 @@ def pool_verify_attention(
     pool rows ``0..lengths+j`` through the slot's page table. Same
     shapes/contract as the slot-layout verify with the pool operands of
     :func:`pool_decode_attention`; at ``w == 1`` it computes exactly
-    what :func:`pool_decode_attention` computes."""
-    h, d = q.shape[2], q.shape[3]
+    what :func:`pool_decode_attention` computes (``kv_heads`` and
+    ``window`` as there)."""
+    h, d = kv_heads or q.shape[2], q.shape[3]
     kc = _gathered_pool_view(k_pool, page_table, h, d, k_scale)
     vc = _gathered_pool_view(v_pool, page_table, h, d, v_scale)
-    return verify_cached_attention(q, kc, vc, lengths, scale=scale)
+    return verify_cached_attention(
+        q, kc, vc, lengths, scale=scale, window=window
+    )
 
 
 def pool_paged_decode_attention(
@@ -605,6 +655,8 @@ def pool_paged_decode_attention(
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    kv_heads: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Pallas TPU decode attention reading a SHARED page pool through
     per-slot page tables — :func:`paged_decode_attention` with its
@@ -612,12 +664,14 @@ def pool_paged_decode_attention(
     to "page-table entry" (docs/DESIGN.md §20).
 
     Same contract as :func:`pool_decode_attention`; different cost
-    model: the grid is (slot, head-shard, logical-page) with BOTH
-    ``lengths`` and ``page_table`` as scalar-prefetch operands, so the
+    model: the grid is (head-shard, live (slot, logical-page step)
+    pair), its second size a runtime value, with ``lengths``,
+    ``page_table`` and the pairs as scalar-prefetch operands, so the
     KV index map resolves each logical page to its pool index at DMA
-    time — dead pages re-select the slot's last live page (no DMA for
-    a repeated index, the §17 length-bounded-read property, now
-    composed with indirection). The KV block is exactly one page of one
+    time and a step past a slot's length is never visited (the §17
+    length-bounded-read property, composed with indirection; within a
+    step of several pages those past the length re-select the last
+    live page: a repeated index is no DMA). The KV block is exactly one page of one
     head shard, ``[page_size, row_width]``, read in the layout the pool
     is stored in: a larger block cannot be contiguous in a pool whose
     pages are allocator-scattered. int8 pools ride the same grid with
@@ -630,6 +684,21 @@ def pool_paged_decode_attention(
     kept per lane (every lane of a head carries that head's max and
     sum). That needs ``head_dim`` to divide 128
     (:func:`decode_attention_supported` with ``paged=True``).
+
+    Grouped heads (``kv_heads`` < ``q``'s heads): the pool's rows hold
+    the key/value heads only and nothing is repeated in memory; the
+    ``g`` query heads of a group ride the sublanes of the query block.
+    Where a head fills a whole 128-lane column (``head_dim`` 128) the
+    group's scores are one matmul a column, ``[g, 128] x [128, keys]``,
+    over ``_POOL_PAGES_PER_STEP`` pages a grid step (each page an
+    operand of its own, resolved through the table); elsewhere the
+    lane reduction runs once a member.
+
+    ``window``: the new token (at ``lengths``) attends rows ``lengths -
+    window < j <= lengths`` only, and the grid covers just the pages
+    that band can touch, from a first page that is not page 0:
+    ``max(lengths - window + 1, 0) // page_size``. Pages behind it are
+    never read, so their table entries may be released (``-1``).
 
     Numerics: fp32 online-softmax accumulation with the reference's
     finite mask value — same contract (documented-ULP vs the pool
@@ -646,10 +715,14 @@ def pool_paged_decode_attention(
             f"page_size, row_width], got {k_pool.shape} / {v_pool.shape}."
         )
     b, _, h, d = q.shape
+    hkv = int(kv_heads or h)
     num_pages, shards, ps, width = k_pool.shape
-    if h % shards or width != kv_row_width(h, d, shards):
-        raise ValueError(f"pool {k_pool.shape} does not match q {q.shape}.")
-    if not decode_attention_supported(h, d, paged=True):
+    if h % hkv or hkv % shards or width != kv_row_width(hkv, d, shards):
+        raise ValueError(
+            f"pool {k_pool.shape} does not match q {q.shape} with "
+            f"{hkv} key/value heads."
+        )
+    if not decode_attention_supported(hkv, d, paged=True):
         raise ValueError(
             f"head_dim={d} is off the pool kernel's geometry (a divisor "
             "of 128)."
@@ -661,20 +734,31 @@ def pool_paged_decode_attention(
         )
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together.")
+    if window is not None and window < 1:
+        raise ValueError(f"window={window} must be >= 1.")
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _pool_paged_decode_call(
         q, k_pool, v_pool, page_table, lengths, k_scale, v_scale,
-        scale=float(scale), interpret=bool(interpret),
+        scale=float(scale), interpret=bool(interpret), kv_heads=hkv,
+        window=None if window is None else int(window),
     )
 
 
-@partial(jax.jit, static_argnames=("scale", "interpret"))
+#: Pages one grid step of the pool kernel's matmul path reads (each an
+#: operand of its own): 8 pages of 16 rows are the 128 keys of one MXU
+#: pass, and an eighth of the grid's steps.
+_POOL_PAGES_PER_STEP = 8
+
+
+@partial(
+    jax.jit, static_argnames=("scale", "interpret", "kv_heads", "window")
+)
 def _pool_paged_decode_call(
     q, k_pool, v_pool, page_table, lengths, k_scale, v_scale, *,
-    scale, interpret,
+    scale, interpret, kv_heads, window,
 ):
     """The kernel behind :func:`pool_paged_decode_attention` (operands
     checked there). Jitted so that a program which attends once a layer
@@ -686,27 +770,68 @@ def _pool_paged_decode_call(
 
     b, _, h, d = q.shape
     num_pages, shards, ps, width = k_pool.shape
-    hs = h // shards
+    group = h // kv_heads
+    hs = kv_heads // shards
     nm = page_table.shape[1]
-    qs = fold_kv_rows(q[:, 0], shards, width)[:, :, None, :]
+    # Query rows by group member: row g of a shard holds, at key/value
+    # head j's lanes, query head j * group + g. One member: q folded.
+    qs = q[:, 0].reshape(b, kv_heads, group, d).swapaxes(1, 2)
+    qs = jnp.swapaxes(fold_kv_rows(qs, shards, width), 1, 2)
     cap_view = nm * ps
     lens = jnp.clip(lengths.astype(jnp.int32), 0, cap_view - 1)
     table = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
     columns = width // _KV_LANES
     heads_per_column = _KV_LANES // d
-
-    def q_index_map(s, sh, kb, lens_ref, table_ref):
-        return (s, sh, 0, 0)
-
-    def kv_index_map(s, sh, kb, lens_ref, table_ref):
-        # The indirection step: a logical page resolves through the
-        # slot's table row; dead pages re-select the LAST LIVE page's
-        # pool index, so a repeated index means no DMA and rows past
-        # the length never leave HBM.
-        live = jnp.minimum(kb, lens_ref[s] // ps)
-        return (table_ref[s, live], sh, 0, 0)
-
     quantized = k_scale is not None
+    # One matmul a column where a head is a column and has a group to
+    # fill the MXU's rows; the lane reduction otherwise.
+    matmul = d == _KV_LANES and group > 1 and not quantized
+    span = nm if window is None else min(nm, (window + ps - 2) // ps + 1)
+    per_step = min(_POOL_PAGES_PER_STEP, span) if matmul else 1
+    steps = -(-span // per_step)
+    dot_precision = _flash_precision(q.dtype)
+
+    def first_page(length):
+        # the first logical page the band can touch
+        if window is None:
+            return 0
+        return jnp.maximum(length - window + 1, 0) // ps
+
+    def live_steps(length):
+        # grid steps from the band's first page through the new token's
+        return (length // ps - first_page(length)) // per_step + 1
+
+    # The grid: the (slot, step) pairs that are LIVE, flattened into one
+    # dimension whose size is a runtime value (each slot from its band's
+    # first page to its last live one; ``item_slot`` / ``item_step`` say
+    # which pair a grid index is), so a step the band or the length
+    # rules out is never visited. A dead step moves no data, but its
+    # operands' bookkeeping was most of the kernel's time on a table of
+    # hundreds of pages (PERF.md, PR 25 and PR 26).
+    counts = live_steps(lens)
+    ends = jnp.cumsum(counts)
+    item = jnp.arange(b * steps, dtype=jnp.int32)
+    item_slot = jnp.minimum(
+        jnp.searchsorted(ends, item, side="right"), b - 1
+    ).astype(jnp.int32)
+    item_step = item - (ends - counts)[item_slot]
+
+    def q_index_map(sh, it, lens_ref, table_ref, slot_ref, step_ref):
+        return (slot_ref[it], sh, 0, 0)
+
+    def kv_index_map(i):
+        def index_map(sh, it, lens_ref, table_ref, slot_ref, step_ref):
+            # The indirection step: a logical page resolves through the
+            # slot's table row; the pages of a step past the length
+            # re-select the LAST LIVE page's pool index, so a repeated
+            # index means no DMA and rows past the length never leave
+            # HBM.
+            s = slot_ref[it]
+            page = first_page(lens_ref[s]) + step_ref[it] * per_step + i
+            live = jnp.minimum(page, lens_ref[s] // ps)
+            return (table_ref[s, live], sh, 0, 0)
+
+        return index_map
 
     def head_sums(x, heads):
         # Every lane ends up holding the sum over its own head's
@@ -720,15 +845,18 @@ def _pool_paged_decode_call(
             out = jnp.where(mine, total, out)
         return out
 
-    def kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(lens_ref, table_ref, slot_ref, step_ref, q_ref, *refs):
+        k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
+        rest = refs[2 * per_step:]
         if quantized:
             ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
         else:
             o_ref, m_ref, l_ref, acc_ref = rest
             ks_ref = vs_ref = None
-        s = pl.program_id(0)
-        kb = pl.program_id(2)
-        length = lens_ref[s]
+        it = pl.program_id(1)
+        kb = step_ref[it]
+        length = lens_ref[slot_ref[it]]
+        first = first_page(length) + kb * per_step
 
         @pl.when(kb == 0)
         def _init():
@@ -736,76 +864,125 @@ def _pool_paged_decode_call(
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        @pl.when(kb * ps <= length)
-        def _block():
+        def in_band(ki):
+            live = ki <= length
+            if window is not None:
+                live = live & (ki > length - window)
+            return live
+
+        def lane_block():
             lane = lax.broadcasted_iota(jnp.int32, (ps, _KV_LANES), 1)
             heads = [(lane // d) == j for j in range(heads_per_column)]
-            ki = kb * ps + lax.broadcasted_iota(
+            ki = first * ps + lax.broadcasted_iota(
                 jnp.int32, (ps, _KV_LANES), 0
             )
-            live = ki <= length
+            live = in_band(ki)
             for c in range(columns):
                 col = pl.ds(c * _KV_LANES, _KV_LANES)
-                qv = q_ref[0, 0, :, col].astype(jnp.float32)  # [1, 128]
-                kv = k_ref[0, 0, :, col].astype(jnp.float32)  # [ps, 128]
-                vv = v_ref[0, 0, :, col].astype(jnp.float32)
+                kv = k_refs[0][0, 0, :, col].astype(jnp.float32)  # [ps, 128]
+                vv = v_refs[0][0, 0, :, col].astype(jnp.float32)
                 if quantized:
                     ke = jnp.ones_like(kv)
                     ve = jnp.ones_like(vv)
-                    first = c * heads_per_column
-                    for j, mine in enumerate(heads[: hs - first]):
-                        one = pl.ds(first + j, 1)
+                    first_head = c * heads_per_column
+                    for j, mine in enumerate(heads[: hs - first_head]):
+                        one = pl.ds(first_head + j, 1)
                         ke = jnp.where(mine, ks_ref[0, 0, :, one], ke)
                         ve = jnp.where(mine, vs_ref[0, 0, :, one], ve)
                     kv = kv * ke
                     vv = vv * ve
-                sc = head_sums(qv * kv, heads) * scale
+                for g in range(group):
+                    row = pl.ds(g, 1)
+                    qv = q_ref[0, 0, row, col].astype(jnp.float32)  # [1, 128]
+                    sc = head_sums(qv * kv, heads) * scale
+                    sc = jnp.where(live, sc, _MASK_VALUE)
+                    m = m_ref[row, col]  # [1, 128]
+                    m_new = jnp.maximum(m, sc.max(axis=0, keepdims=True))
+                    p = jnp.exp(sc - m_new)
+                    corr = jnp.exp(m - m_new)
+                    m_ref[row, col] = m_new
+                    l_ref[row, col] = l_ref[row, col] * corr + p.sum(
+                        axis=0, keepdims=True
+                    )
+                    acc_ref[row, col] = acc_ref[row, col] * corr + (
+                        p * vv
+                    ).sum(axis=0, keepdims=True)
+
+        def matmul_block():
+            keys = per_step * ps
+            ki = first * ps + lax.broadcasted_iota(
+                jnp.int32, (group, keys), 1
+            )
+            live = in_band(ki)
+            for c in range(columns):
+                col = pl.ds(c * _KV_LANES, _KV_LANES)
+                qv = q_ref[0, 0, :, col]  # [group, 128]
+                kv = jnp.concatenate(
+                    [r[0, 0, :, col] for r in k_refs], axis=0
+                )  # [keys, 128]
+                vv = jnp.concatenate(
+                    [r[0, 0, :, col] for r in v_refs], axis=0
+                )
+                sc = lax.dot_general(
+                    qv, kv, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=dot_precision,
+                ) * scale
                 sc = jnp.where(live, sc, _MASK_VALUE)
-                m = m_ref[:, col]  # [1, 128]
-                m_new = jnp.maximum(m, sc.max(axis=0, keepdims=True))
+                m = m_ref[:, col][:, :1]  # [group, 1]
+                m_new = jnp.maximum(m, sc.max(axis=1, keepdims=True))
                 p = jnp.exp(sc - m_new)
                 corr = jnp.exp(m - m_new)
-                m_ref[:, col] = m_new
-                l_ref[:, col] = l_ref[:, col] * corr + p.sum(
-                    axis=0, keepdims=True
+                wide = (group, _KV_LANES)
+                m_ref[:, col] = jnp.broadcast_to(m_new, wide)
+                l_ref[:, col] = l_ref[:, col] * corr + jnp.broadcast_to(
+                    p.sum(axis=1, keepdims=True), wide
                 )
-                acc_ref[:, col] = acc_ref[:, col] * corr + (p * vv).sum(
-                    axis=0, keepdims=True
+                acc_ref[:, col] = acc_ref[:, col] * corr + lax.dot_general(
+                    p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=dot_precision,
                 )
 
-        @pl.when(kb == nm - 1)
+        (matmul_block if matmul else lane_block)()
+
+        @pl.when(kb == live_steps(length) - 1)
         def _finalize():
             o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, width), q_index_map),
-        pl.BlockSpec((1, 1, ps, width), kv_index_map),
-        pl.BlockSpec((1, 1, ps, width), kv_index_map),
+    page_specs = [
+        pl.BlockSpec((1, 1, ps, width), kv_index_map(i))
+        for i in range(per_step)
     ]
-    operands = [qs, k_pool, v_pool]
+    in_specs = [pl.BlockSpec((1, 1, group, width), q_index_map)]
+    in_specs += page_specs + page_specs
+    operands = [qs] + [k_pool] * per_step + [v_pool] * per_step
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, ps, hs), kv_index_map)] * 2
+        in_specs += [pl.BlockSpec((1, 1, ps, hs), kv_index_map(0))] * 2
         operands += [
             k_scale.astype(jnp.float32),
             v_scale.astype(jnp.float32),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, shards, nm),
+        num_scalar_prefetch=4,
+        grid=(shards, ends[-1]),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, width), q_index_map),
-        scratch_shapes=[pltpu.VMEM((1, width), jnp.float32)] * 3,
+        out_specs=pl.BlockSpec((1, 1, group, width), q_index_map),
+        scratch_shapes=[pltpu.VMEM((group, width), jnp.float32)] * 3,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, shards, 1, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, shards, group, width), q.dtype),
         compiler_params=_mosaic_params(
-            _pool_decode_vmem_estimate(ps, width, k_pool.dtype.itemsize)
+            _pool_decode_vmem_estimate(
+                per_step * ps, width, k_pool.dtype.itemsize
+            )
         ),
         interpret=interpret,
-    )(lens, table, *operands)
-    return unfold_kv_rows(out[:, :, 0, :], h, d)[:, None]
+    )(lens, table, item_slot, item_step, *operands)
+    out = unfold_kv_rows(jnp.swapaxes(out, 1, 2), kv_heads, d)
+    return out.swapaxes(1, 2).reshape(b, 1, h, d)
 
 
 def sharded_pool_paged_decode_attention(
@@ -1175,6 +1352,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Single-device flash attention as a Pallas TPU kernel — forward
     AND backward: exact attention with O(block) VMEM residency — only
@@ -1215,6 +1393,13 @@ def flash_attention(
     out, padded query rows are dropped), accumulation in fp32, output
     in the input dtype. ``interpret=None`` auto-selects interpret mode
     off-TPU (the repo's Pallas convention).
+
+    Forward only, for serving: ``window`` (with ``causal``) is the band
+    ``i - window < p <= i`` of a sliding-window layer, and key blocks
+    wholly outside it do no compute; ``k``/``v`` with fewer, grouped
+    heads are indexed by ``query head // group`` and never repeated in
+    memory. Either one makes the result non-differentiable: the
+    backward raises (ROADMAP.md, Reach: the flash backward with a band).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -1224,6 +1409,18 @@ def flash_attention(
         q.shape[1], block_q, block_k,
         head_dim=q.shape[-1], itemsize=q.dtype.itemsize,
     )
+    if window is not None or k.shape[2] != q.shape[2]:
+        if window is not None and not causal:
+            raise ValueError("window needs causal=True.")
+        if q.shape[2] % k.shape[2]:
+            raise ValueError(
+                f"{q.shape[2]} query heads are not a multiple of "
+                f"{k.shape[2]} key/value heads."
+            )
+        return _flash_attention_forward_only(
+            q, k, v, bool(causal), float(scale), int(block_q), int(block_k),
+            bool(interpret), None if window is None else int(window),
+        )
     return _flash_attention(
         q, k, v, bool(causal), float(scale), int(block_q), int(block_k),
         bool(interpret),
@@ -1281,7 +1478,8 @@ def _from_bh(x, b, s, h, d):
 
 
 def _flash_forward(
-    q, k, v, causal, scale, block_q, block_k, interpret, want_lse=False
+    q, k, v, causal, scale, block_q, block_k, interpret, want_lse=False,
+    window=None,
 ):
     """The forward kernel; returns ``out [b,s,h,d]``, or
     ``(out, lse [bh,s_pad,1])`` when ``want_lse`` — lse (the per-row
@@ -1301,10 +1499,17 @@ def _flash_forward(
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
+    hkv = k.shape[2]
+    group = h // hkv
     block_q, block_k, s_pad = _flash_dims(s, block_q, block_k)
     dot_precision = _flash_precision(q.dtype)
     qb, kb, vb = (_to_bh(x, s_pad) for x in (q, k, v))
     nq, nk = s_pad // block_q, s_pad // block_k
+
+    def kv_index_map(i, j, kk):
+        # grouped heads: query head i % h of batch i // h reads
+        # key/value head (i % h) // group; nothing is repeated
+        return ((i // h) * hkv + (i % h) // group, kk, 0)
 
     def kernel(q_ref, k_ref, v_ref, o_ref, *rest):
         if want_lse:
@@ -1327,6 +1532,11 @@ def _flash_forward(
             if causal
             else True
         )
+        if window is not None:
+            # ...and one wholly behind the band of its first row.
+            live = live & (
+                kb_idx * block_k + block_k - 1 > iq * block_q - window
+            )
 
         @pl.when(live)
         def _block():
@@ -1346,10 +1556,17 @@ def _flash_forward(
                     jnp.int32, (block_q, block_k), 0
                 )
                 valid = valid & (ki <= qi)
+                if window is not None:
+                    valid = valid & (qi - ki < window)
             sc = jnp.where(valid, sc, _MASK_VALUE)
             m = m_ref[...]
             m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
             p = jnp.exp(sc - m_new)
+            if window is not None:
+                # a row whose band starts past this block has met no key
+                # yet: its running max is still the mask value and
+                # exp(0) would count the masked keys
+                p = jnp.where(valid, p, 0.0)
             corr = jnp.exp(m - m_new)
             m_ref[...] = m_new
             l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
@@ -1368,7 +1585,11 @@ def _flash_forward(
         def _finalize():
             # Padded query rows attended block 0's valid keys, so l > 0
             # everywhere (rows are sliced off by the wrapper anyway).
-            o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+            # Under a band a padded row can lie past every key's window.
+            l = l_ref[...]
+            if window is not None:
+                l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
             if want_lse:
                 lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])
 
@@ -1399,8 +1620,8 @@ def _flash_forward(
         grid=(b * h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
+            pl.BlockSpec((1, block_k, d), kv_index_map),
+            pl.BlockSpec((1, block_k, d), kv_index_map),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -1633,8 +1854,11 @@ def _flash_backward(
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret):
-    # Primal (pure-inference) path: no lse output at all.
-    return _flash_forward(
+    # Primal (pure-inference) path: no lse output at all, and the
+    # kernel under its own jit, so a forward of many layers traces and
+    # lowers it once (24 lowerings a prefill program were 2 s of a
+    # serving cell's set-up on the chip's host, PERF.md PR 26).
+    return _flash_forward_call(
         q, k, v, causal, scale, block_q, block_k, interpret
     )
 
@@ -1656,6 +1880,51 @@ def _flash_attention_bwd(
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
+#: The forward kernel under a jit of its own, for the serving forward: a
+#: program that attends once a layer traces and lowers the kernel once
+#: (as ``_pool_paged_decode_call`` does), and the device trace names the
+#: kernel's op ``_flash_forward`` and not after the layer that called it.
+_flash_forward_call = jax.jit(
+    _flash_forward,
+    static_argnames=(
+        "causal", "scale", "block_q", "block_k", "interpret", "want_lse",
+        "window",
+    ),
+)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention_forward_only(
+    q, k, v, causal, scale, block_q, block_k, interpret, window
+):
+    """The serving forward with a band and/or grouped heads."""
+    return _flash_forward_call(
+        q, k, v, causal, scale, block_q, block_k, interpret, window=window
+    )
+
+
+def _flash_forward_only_fwd(
+    q, k, v, causal, scale, block_q, block_k, interpret, window
+):
+    out = _flash_attention_forward_only(
+        q, k, v, causal, scale, block_q, block_k, interpret, window
+    )
+    return out, None
+
+
+def _flash_forward_only_bwd(*_):
+    raise NotImplementedError(
+        "flash_attention has no backward with a window or with grouped "
+        "key/value heads yet: train such a layer with attention='dense' "
+        "(ROADMAP.md, Reach)."
+    )
+
+
+_flash_attention_forward_only.defvjp(
+    _flash_forward_only_fwd, _flash_forward_only_bwd
+)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

@@ -302,6 +302,46 @@ DecodeScheduler`.
                     "widen seq_buckets."
                 )
         max_pages = capacity // int(self.page_size)
+        # Layer groups (docs/DESIGN.md §20): a model with sliding-window
+        # layers beside full ones keeps a second, smaller pool group.
+        window_layers = tuple(getattr(module, "window_layers", ())) or (
+            (False,) * int(module.num_layers)
+        )
+        window = int(module.window) if any(window_layers) else 0
+        kv_heads = int(getattr(module, "kv_heads", module.num_heads))
+        if window and not paged:
+            raise ValueError(
+                "kv_layout='slots' cannot serve a model with window "
+                "layers: its decode kernel would run them as full "
+                "attention. Set engine.kv_layout='paged'."
+            )
+        if kv_heads != int(module.num_heads) and not paged:
+            raise ValueError(
+                "kv_layout='slots' cannot serve grouped key/value heads "
+                "(its cache and kernel hold one head a query head). Set "
+                "engine.kv_layout='paged'."
+            )
+        if window and bool(self.prefix_cache):
+            raise ValueError(
+                "prefix_cache=true is not implemented for a model with "
+                "window layers: a hit needs the full group's pages for "
+                "the whole prefix and the window group's for its last "
+                f"{window} tokens (ROADMAP.md, Reach). Set "
+                "engine.prefix_cache=false."
+            )
+        if window and int(self.prefill_chunk_tokens) > 0:
+            raise ValueError(
+                "prefill_chunk_tokens > 0 is not implemented for a model "
+                "with window layers: a chunk would need the window "
+                "group's pages of the chunk before it, which admission "
+                "does not keep. Set engine.prefill_chunk_tokens=0."
+            )
+        # A sequence's live window pages: the window's own, one more
+        # for where the band starts inside a page, one for the row the
+        # next dispatch writes before the iteration's release.
+        window_pages_per_slot = min(
+            max_pages, -(-window // int(self.page_size)) + 2
+        )
         if paged:
             for method in ("decode_step_paged", "decode_verify_paged"):
                 if not hasattr(module, method):
@@ -346,6 +386,12 @@ DecodeScheduler`.
         object.__setattr__(self, "_paged", paged)
         object.__setattr__(self, "_num_pages", num_pages)
         object.__setattr__(self, "_max_pages", max_pages)
+        object.__setattr__(self, "_kv_heads", kv_heads)
+        object.__setattr__(self, "_window_layers", window_layers)
+        object.__setattr__(
+            self, "_window_pages",
+            int(self.slots) * window_pages_per_slot if window else 0,
+        )
         # Host-side page allocator + table + radix prefix cache
         # (docs/DESIGN.md §20). The device pool tree rides _cache.
         object.__setattr__(
@@ -357,6 +403,8 @@ DecodeScheduler`.
                 slots=int(self.slots),
                 max_pages_per_slot=max_pages,
                 prefix_cache=bool(self.prefix_cache),
+                window=window,
+                window_pages=self._window_pages,
             )
             if paged
             else None,
@@ -367,7 +415,7 @@ DecodeScheduler`.
             self, "_variables", self._place_variables(variables)
         )
 
-        head_dim = int(module.d_model) // int(module.num_heads)
+        head_dim = self._head_dim()
         mesh = partitioner.mesh
         # A page pool's rows hold one entry per model-axis device, each
         # with its own heads folded end to end (ops.fold_kv_rows), so
@@ -378,7 +426,7 @@ DecodeScheduler`.
         if paged and mesh is not None:
             model_axis = partitioner.decode_cache_axes()[1]
             tp = int(mesh.shape[model_axis]) if model_axis else 1
-            if int(module.num_heads) % tp == 0:
+            if kv_heads % tp == 0:
                 head_shards = tp
         object.__setattr__(self, "_head_shards", head_shards)
         cache = self._allocate_cache()
@@ -426,18 +474,20 @@ DecodeScheduler`.
                 int(module.num_layers),
                 num_pages,
                 int(self.page_size),
-                int(module.num_heads),
+                kv_heads,
                 head_dim,
                 np.dtype(module.dtype).itemsize,
                 quant=str(self.kv_quant),
                 head_shards=head_shards,
+                window_layers=window_layers,
+                window_pages=self._window_pages,
             )
         else:
             nbytes = kv_cache_bytes(
                 int(module.num_layers),
                 int(self.slots),
                 capacity,
-                int(module.num_heads),
+                kv_heads,
                 head_dim,
                 np.dtype(module.dtype).itemsize,
             )
@@ -485,10 +535,9 @@ DecodeScheduler`.
         )
         if choice == "reference":
             return "reference", reference
-        heads = int(module.num_heads)
-        head_dim = int(module.d_model) // heads
+        head_dim = self._head_dim()
         if not ops.decode_attention_supported(
-            heads, head_dim, paged=paged
+            self._kv_heads, head_dim, paged=paged
         ):
             if str(self.decode_attention) == "pallas":
                 # Asked for by name: serving the reference under the
@@ -609,6 +658,13 @@ DecodeScheduler`.
         report their own."""
         return float(getattr(self, "_last_decode_mbu", -1.0))
 
+    def _head_dim(self) -> int:
+        module = self._module
+        return int(
+            getattr(module, "head_dim", 0)
+            or int(module.d_model) // int(module.num_heads)
+        )
+
     def _place_variables(self, variables: Any) -> Any:
         """One placement path shared by ``bind`` and ``swap_weights`` —
         same contract as the forward engine's."""
@@ -633,23 +689,25 @@ DecodeScheduler`.
         Layout-dispatched: the slot-contiguous buffers or the shared
         page pool (docs/DESIGN.md §20)."""
         module = self._module
-        head_dim = int(module.d_model) // int(module.num_heads)
+        head_dim = self._head_dim()
         if getattr(self, "_paged", False):
             return allocate_page_pool(
                 int(module.num_layers),
                 self._num_pages,
                 int(self.page_size),
-                int(module.num_heads),
+                self._kv_heads,
                 head_dim,
                 module.dtype,
                 quant=str(self.kv_quant),
                 head_shards=self._head_shards,
+                window_layers=self._window_layers,
+                window_pages=self._window_pages,
             )
         return allocate_kv_cache(
             int(module.num_layers),
             int(self.slots),
             self._capacity,
-            int(module.num_heads),
+            self._kv_heads,
             head_dim,
             module.dtype,
         )
@@ -785,6 +843,15 @@ PagePool` (None in the slot layout)."""
         (prefix-cache-shared pages stay resident for warm hits)."""
         if getattr(self, "_paged", False):
             self._pool.release_slot(int(slot))
+
+    def release_behind_window(self, lengths) -> int:
+        """Once a scheduler iteration (docs/DESIGN.md §20): hand back
+        the window group's pages every sequence has left wholly behind
+        ``length - window``. Returns the pages freed; 0 for a model of
+        one layer group, and in the slot layout."""
+        if not getattr(self, "_paged", False):
+            return 0
+        return self._pool.release_behind_window(lengths)
 
     def insert_prefix(self, slot: int, prompt) -> int:
         """Cache the admitted prompt's pages for future warm hits
@@ -969,6 +1036,54 @@ PagePool` (None in the slot layout)."""
         object.__setattr__(self, "_compile_count", self._compile_count + 1)
         return compiled
 
+    def _table_like(self, rows: int):
+        """The page-table operand's shape for ``rows`` sequences: one
+        table, or one a layer group stacked (``PagePool.operand``)."""
+        import jax
+
+        shape = (rows, self._max_pages)
+        if self._pool.window_group is not None:
+            shape = (2,) + shape
+        return jax.ShapeDtypeStruct(shape, np.int32)
+
+    def _apply(self, *args, **kwargs):
+        """``module.apply`` that also brings back what the model's
+        expert layers sowed (``moe_load``: rows per expert, a layer),
+        stacked ``[layers, experts]``; None for a model without them."""
+        import jax.numpy as jnp
+
+        module = self._module
+        if not getattr(module, "num_experts", 0):
+            return module.apply(*args, **kwargs), None
+        out, sown = module.apply(*args, mutable=["moe_load"], **kwargs)
+        load = jnp.stack([
+            sown["moe_load"][f"block{i}"]["tokens_per_expert"]
+            for i in range(int(module.num_layers))
+        ])
+        return out, load
+
+    def _note_moe_load(self, out, program: str):
+        """A dispatch's token output, which for a model with experts is
+        ``(tokens, load)``: returns the tokens and, while tracing,
+        records one ``moe_tokens_per_expert`` event (counts
+        ``[layers][experts]`` as the device summed them; the array came
+        back with the dispatch's own readback)."""
+        if not isinstance(out, tuple):
+            return out
+        out, load = out
+        if not _trace.enabled():
+            return out
+        import jax
+
+        _trace.event(
+            "moe_tokens_per_expert",
+            attrs={
+                "program": program,
+                "counts": np.asarray(jax.device_get(load)).tolist(),
+            },
+        )
+        return out
+
     def _decode_compiled(self, *, during_dispatch: bool = False):
         import jax
         import jax.numpy as jnp
@@ -989,20 +1104,20 @@ PagePool` (None in the slot layout)."""
         if self._paged:
 
             def decode_fn(variables, cache, tokens, lengths, table):
-                logits, new_cache = module.apply(
+                (logits, new_cache), load = self._apply(
                     variables, tokens, lengths, cache, table,
                     method="decode_step_paged",
                     attention_override=attn_override,
                 )
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return new_cache, nxt
+                return new_cache, (nxt if load is None else (nxt, load))
 
             example = (
                 self._variables,
                 self._cache,
                 jax.ShapeDtypeStruct((n,), np.int32),
                 jax.ShapeDtypeStruct((n,), np.int32),
-                jax.ShapeDtypeStruct((n, self._max_pages), np.int32),
+                self._table_like(n),
             )
         else:
 
@@ -1043,14 +1158,15 @@ PagePool` (None in the slot layout)."""
         module = self._module
         if self._paged:
             ps = int(self.page_size)
-            num_pages = int(self._num_pages)
+            window_layers = self._window_layers
 
             def prefill_fn(variables, cache, tokens, lengths, slot_rows):
                 from zookeeper_tpu.models.transformer import (
                     _pool_write_rows,
+                    layer_page_table,
                 )
 
-                last_logits, kv = module.apply(
+                (last_logits, kv), load = self._apply(
                     variables, tokens, lengths, method="prefill"
                 )
                 # Scatter each prompt row through its slot's page-table
@@ -1059,28 +1175,37 @@ PagePool` (None in the slot layout)."""
                 # entries, and a partial group's padding rows (all
                 # -1 rows) take the OOB page sentinel and write
                 # nowhere — the paged twin of the slot-id drop.
+                # A window layer's table holds the prompt's tail
+                # only: the rows before it drop the same way.
                 j = jnp.arange(sb)
-                row = jnp.clip(j // ps, 0, slot_rows.shape[1] - 1)
-                pages = slot_rows[:, row]  # [pb, sb]
-                dead = (j[None, :] >= lengths[:, None]) | (pages < 0)
-                pages = jnp.where(dead, num_pages, pages)
-                offs = jnp.broadcast_to(j % ps, pages.shape)
+                row = jnp.clip(j // ps, 0, slot_rows.shape[-1] - 1)
+                offs = jnp.broadcast_to(j % ps, (pb, sb))
+
+                def targets(table, num_pages):
+                    pages = table[:, row]  # [pb, sb]
+                    dead = (j[None, :] >= lengths[:, None]) | (pages < 0)
+                    return jnp.where(dead, num_pages, pages)
+
                 new_cache = []
-                for layer, (k, v) in zip(cache, kv):
+                for layer, (k, v), windowed in zip(cache, kv, window_layers):
+                    table = layer_page_table(slot_rows, windowed)
                     new_cache.append(
                         _pool_write_rows(
-                            layer, {"k": k, "v": v}, pages, offs
+                            layer, {"k": k, "v": v},
+                            targets(table, layer["k"].shape[0]), offs,
                         )
                     )
                 first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-                return tuple(new_cache), first
+                return tuple(new_cache), (
+                    first if load is None else (first, load)
+                )
 
             example = (
                 self._variables,
                 self._cache,
                 jax.ShapeDtypeStruct((pb, sb), np.int32),
                 jax.ShapeDtypeStruct((pb,), np.int32),
-                jax.ShapeDtypeStruct((pb, self._max_pages), np.int32),
+                self._table_like(pb),
             )
         else:
 
@@ -1160,7 +1285,7 @@ PagePool` (None in the slot layout)."""
                 self._cache,
                 jax.ShapeDtypeStruct((n, int(width)), np.int32),
                 jax.ShapeDtypeStruct((n,), np.int32),
-                jax.ShapeDtypeStruct((n, self._max_pages), np.int32),
+                self._table_like(n),
             )
         else:
 
@@ -1227,7 +1352,7 @@ PagePool` (None in the slot layout)."""
             self._cache,
             jax.ShapeDtypeStruct((int(pb), int(w)), np.int32),
             jax.ShapeDtypeStruct((int(pb),), np.int32),
-            jax.ShapeDtypeStruct((int(pb), self._max_pages), np.int32),
+            self._table_like(int(pb)),
             jax.ShapeDtypeStruct((int(pb),), np.int32),
             jax.ShapeDtypeStruct((int(pb),), np.int32),
         )
@@ -1379,6 +1504,11 @@ PageTransfer` moves between mesh slices. READ-ONLY: the source pool
             raise RuntimeError(
                 "page transfer is a paged-layout program; "
                 "kv_layout='slots' has no page pool to export."
+            )
+        if self._pool.window_group is not None:
+            raise NotImplementedError(
+                "page transfer moves one layer group's pages; a model "
+                "with window layers has two (ROADMAP.md, Reach)."
             )
         return self._pool.pages_for(max(self._seq_buckets))
 
@@ -1533,9 +1663,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         if self._paged:
             # Page-table rows instead of slot ids: padding rows stay
             # all -1 (every write drops via the OOB page sentinel).
-            ids = np.full((pb, self._max_pages), -1, np.int32)
-            for i, s in enumerate(slot_ids):
-                ids[i] = self._pool.table[int(s)]
+            ids = self._pool.operand(slot_ids, pb)
         else:
             ids = np.full((pb,), int(self.slots), np.int32)  # OOB drop
             for i, s in enumerate(slot_ids):
@@ -1560,6 +1688,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 self._reset_cache()
                 raise
             object.__setattr__(self, "_cache", new_cache)
+            first = self._note_moe_load(first, "prefill")
             first = np.asarray(jax.device_get(first))
         return first[:n].astype(np.int32)
 
@@ -1603,7 +1732,6 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         lengths = np.zeros((pb,), np.int32)
         valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
         out_idx = np.zeros((pb,), np.int32)
-        rows = np.full((pb, self._max_pages), -1, np.int32)
         for i, (p, s, sh) in enumerate(zip(prompts, slot_ids, shared_lens)):
             p = np.asarray(p, np.int32)
             suf = p[int(sh):]
@@ -1611,7 +1739,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             lengths[i] = int(sh)
             valid[i] = suf.shape[0]
             out_idx[i] = suf.shape[0] - 1
-            rows[i] = self._pool.table[int(s)]
+        rows = self._pool.operand(slot_ids, pb)
         compiled = self._extend_compiled(pb, w, during_dispatch=True)
         with _trace.span(
             "prefill_warm_dispatch",
@@ -1678,14 +1806,13 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         lengths = np.zeros((pb,), np.int32)
         valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
         out_idx = np.zeros((pb,), np.int32)
-        rows = np.full((pb, self._max_pages), -1, np.int32)
         for i, (c, s, off) in enumerate(zip(chunks, slot_ids, offsets)):
             c = np.asarray(c, np.int32)
             tokens[i, : lens[i]] = c
             lengths[i] = int(off)
             valid[i] = lens[i]
             out_idx[i] = lens[i] - 1
-            rows[i] = self._pool.table[int(s)]
+        rows = self._pool.operand(slot_ids, pb)
         compiled = self._extend_compiled(pb, w, during_dispatch=True)
         with _trace.span(
             "prefill_chunk_dispatch",
@@ -1747,7 +1874,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         compiled = self._decode_compiled(during_dispatch=True)
         args = (tokens, lengths)
         if self._paged:
-            args = (tokens, lengths, np.ascontiguousarray(self._pool.table))
+            args = (tokens, lengths, self._pool.operand())
         with _trace.span(
             "decode_dispatch",
             attrs=(
@@ -1763,6 +1890,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 self._reset_cache()  # donation consumed the buffers
                 raise
             object.__setattr__(self, "_cache", new_cache)
+            nxt = self._note_moe_load(nxt, "decode_step")
             nxt = np.asarray(jax.device_get(nxt))
             # Readback-bounded wall time — the only honest dispatch
             # clock (the compiled call returns un-synced arrays).
@@ -1799,7 +1927,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         compiled = self._verify_compiled(w, during_dispatch=True)
         args = (tokens, lengths)
         if self._paged:
-            args = (tokens, lengths, np.ascontiguousarray(self._pool.table))
+            args = (tokens, lengths, self._pool.operand())
         with _trace.span(
             "verify_dispatch",
             attrs=(

@@ -76,6 +76,8 @@ def allocate_page_pool(
     dtype: Any,
     quant: str = "none",
     head_shards: int = 1,
+    window_layers: Sequence[bool] = (),
+    window_pages: int = 0,
 ) -> Tuple[dict, ...]:
     """Zero-initialized page-pool pytree: a per-layer tuple of
     ``{"k", "v"}`` pools ``[num_pages, head_shards, page_size,
@@ -86,11 +88,18 @@ def allocate_page_pool(
     head_shards, page_size, heads_per_shard]`` float32 when
     ``quant="int8"`` (rows stored int8). The engine places it under the
     partitioner's page-pool sharding and donates it through every
-    dispatch, exactly like the slot-layout cache."""
+    dispatch, exactly like the slot-layout cache. ``num_heads`` is the
+    heads a row holds: the key/value heads where they are grouped.
+
+    Layer groups: the layers marked in ``window_layers`` (sliding-window
+    layers, which keep only the last ``window`` tokens of a sequence)
+    get pools of ``window_pages`` pages, indexed by their own table
+    (:class:`PagePool`); every other layer gets ``num_pages``."""
     import jax.numpy as jnp
 
     from zookeeper_tpu.ops import kv_row_width
 
+    window_layers = _window_layers(num_layers, window_layers, window_pages)
     if num_pages < 1 or page_size < 1:
         raise ValueError(
             f"page pool needs num_pages >= 1 and page_size >= 1, got "
@@ -99,10 +108,11 @@ def allocate_page_pool(
     if quant not in ("none", "int8"):
         raise ValueError(f"quant={quant!r}: expected 'none' or 'int8'.")
     width = kv_row_width(num_heads, head_dim, head_shards)
-    shape = (num_pages, head_shards, page_size, width)
     row_dtype = jnp.int8 if quant == "int8" else dtype
     layers = []
-    for _ in range(num_layers):
+    for windowed in window_layers:
+        pages = window_pages if windowed else num_pages
+        shape = (pages, head_shards, page_size, width)
         layer = {
             "k": jnp.zeros(shape, row_dtype),
             "v": jnp.zeros(shape, row_dtype),
@@ -126,18 +136,39 @@ def page_pool_bytes(
     itemsize: int,
     quant: str = "none",
     head_shards: int = 1,
+    window_layers: Sequence[bool] = (),
+    window_pages: int = 0,
 ) -> int:
     """Total HBM the pool occupies (k + v rows at their padded width,
     all layers, plus the scale arrays when quantized) — the §20
-    capacity-planning number."""
+    capacity-planning number. Layer groups as in
+    :func:`allocate_page_pool`."""
     from zookeeper_tpu.ops import kv_row_width
 
-    rows = 2 * num_layers * num_pages * page_size
+    windowed = sum(_window_layers(num_layers, window_layers, window_pages))
+    pages = (num_layers - windowed) * num_pages + windowed * window_pages
+    rows = 2 * pages * page_size
     width = head_shards * kv_row_width(num_heads, head_dim, head_shards)
     total = rows * width * (1 if quant == "int8" else itemsize)
     if quant == "int8":
         total += rows * num_heads * 4  # float32 scale per (row, head)
     return total
+
+
+def _window_layers(
+    num_layers: int, window_layers: Sequence[bool], window_pages: int
+) -> Tuple[bool, ...]:
+    window_layers = tuple(bool(w) for w in window_layers)
+    if not window_layers:
+        return (False,) * num_layers
+    if len(window_layers) != num_layers:
+        raise ValueError(
+            f"window_layers has {len(window_layers)} entries for "
+            f"{num_layers} layers."
+        )
+    if any(window_layers) and window_pages < 1:
+        raise ValueError("window layers need window_pages >= 1.")
+    return window_layers
 
 
 class _TrieNode:
@@ -311,9 +342,95 @@ PrefixIndex` predicts THIS method's match length with the same code."""
         return dropped
 
 
+class _WindowGroup:
+    """The allocator of a model's sliding-window layers: a table, a free
+    list and a pool size of its own (see :class:`PagePool`). A sequence
+    keeps only the logical pages its next ``window`` keys can lie in;
+    ``first[slot]`` is the first of them, everything before it has been
+    released (``-1`` in the table). Pages here are never shared: the
+    prefix cache does not reach this group."""
+
+    def __init__(self, num_pages, page_size, slots, max_pages_per_slot, window):
+        self.num_pages = int(num_pages)
+        self.page_size = int(page_size)
+        self.window = int(window)
+        self.table = np.full((slots, max_pages_per_slot), -1, np.int32)
+        self.first = np.zeros(slots, np.int32)
+        self.counts = np.zeros(slots, np.int32)  # one past the last page
+        self._free: List[int] = list(range(self.num_pages - 1, -1, -1))
+        self.allocated_pages = 0
+        self.released_behind = 0
+
+    def first_needed(self, length: int) -> int:
+        """The first logical page a query at position ``length`` can
+        reach: keys ``length - window < p <= length``."""
+        return max(int(length) - self.window + 1, 0) // self.page_size
+
+    def missing(self, slot: int, length: int, pages: int) -> int:
+        """Pages :meth:`cover` would have to allocate."""
+        lo = max(self.first_needed(length), int(self.counts[slot]))
+        return max(0, pages - lo)
+
+    def cover(self, slot: int, length: int, pages: int) -> None:
+        """Hold logical pages ``first_needed(length) .. pages - 1``
+        (the caller checked :meth:`missing` against the free list)."""
+        have = int(self.counts[slot])
+        lo = max(self.first_needed(length), have)
+        if not have:
+            self.first[slot] = lo
+        for page in range(lo, pages):
+            self.table[slot, page] = self._free.pop()
+        self.allocated_pages += max(0, pages - lo)
+        self.counts[slot] = max(have, pages)
+
+    def release_behind(self, slot: int, length: int) -> int:
+        """Free the pages wholly behind the window of a query at
+        ``length``; returns how many."""
+        lo, hi = int(self.first[slot]), min(
+            self.first_needed(length), int(self.counts[slot])
+        )
+        for page in range(lo, hi):
+            self._free.append(int(self.table[slot, page]))
+            self.table[slot, page] = -1
+        if hi > lo:
+            self.first[slot] = hi
+            self.released_behind += hi - lo
+        return max(0, hi - lo)
+
+    def release_slot(self, slot: int) -> None:
+        for page in range(int(self.first[slot]), int(self.counts[slot])):
+            self._free.append(int(self.table[slot, page]))
+        self.table[slot] = -1
+        self.first[slot] = 0
+        self.counts[slot] = 0
+
+    def reset(self) -> None:
+        self.table.fill(-1)
+        self.first.fill(0)
+        self.counts.fill(0)
+        self._free = list(range(self.num_pages - 1, -1, -1))
+
+    def leak_check(self) -> int:
+        held = int(np.sum(self.table >= 0))
+        return self.num_pages - len(self._free) - held
+
+
 class PagePool:
     """Host-side page allocator + page table for one decode engine's
     shared device pool (see module docstring).
+
+    Layer groups: a model whose layers are all of one kind has one group
+    and this class is its allocator, table for table. A model with
+    sliding-window layers beside full ones (``window`` and
+    ``window_pages`` given) has a second group for them,
+    :class:`_WindowGroup`: window layers need a sequence's last
+    ``window`` tokens only, so that group's pools are smaller
+    (``slots x (window + a page)`` instead of ``slots x capacity``), a
+    prompt's admission allocates just its tail there, and
+    :meth:`release_behind_window` hands back the pages a sequence has
+    left behind as it grows. Admission, growth and release act on both
+    groups or on neither. The dispatches then carry both tables,
+    stacked ``[full, window]`` (:meth:`operand`).
 
     The DEVICE pool tree is owned by the engine; this object owns the
     indices: the free list, per-page refcounts, the authoritative
@@ -332,7 +449,16 @@ class PagePool:
         slots: int,
         max_pages_per_slot: int,
         prefix_cache: bool = True,
+        window: int = 0,
+        window_pages: int = 0,
     ) -> None:
+        if window and prefix_cache:
+            raise ValueError(
+                "the prefix cache does not reach a window group's pages "
+                "(a hit would need the full group's pages for the whole "
+                "prefix and the window group's for its last `window` "
+                "tokens); build the pool with prefix_cache=False."
+            )
         if num_pages < max_pages_per_slot:
             raise ValueError(
                 f"num_pages={num_pages} below max_pages_per_slot="
@@ -353,6 +479,14 @@ class PagePool:
         self._free: List[int] = list(range(self.num_pages - 1, -1, -1))
         self.cow_pages = 0
         self.exhausted_events = 0
+        self.allocated_pages = 0
+        self.window_group: Optional[_WindowGroup] = (
+            _WindowGroup(
+                window_pages, page_size, slots, max_pages_per_slot, window
+            )
+            if window
+            else None
+        )
         self.prefix: Optional[RadixPrefixCache] = (
             RadixPrefixCache(
                 self.page_size,
@@ -399,7 +533,39 @@ class PagePool:
         out = [self._free.pop() for _ in range(n)]
         for p in out:
             self.refcount[p] += 1
+        self.allocated_pages += n
         return out
+
+    def _window_short(self, slot: int, length: int, pages: int) -> bool:
+        """Whether the window group cannot cover ``pages`` for ``slot``
+        (checked BEFORE the full group allocates: both or neither)."""
+        group = self.window_group
+        if group is None:
+            return False
+        if group.missing(slot, length, pages) <= len(group._free):
+            return False
+        self.exhausted_events += 1
+        return True
+
+    def operand(self, slots: Optional[Sequence[int]] = None, rows: int = 0):
+        """The page-table operand of a dispatch: the whole table, or the
+        rows of ``slots`` padded with all ``-1`` rows to ``rows``; one
+        group: ``[n, max_pages]``, two: ``[2, n, max_pages]`` (full,
+        window)."""
+        tables = [self.table]
+        if self.window_group is not None:
+            tables.append(self.window_group.table)
+        if slots is not None:
+            picked = []
+            for table in tables:
+                out = np.full((rows, table.shape[1]), -1, np.int32)
+                for i, s in enumerate(slots):
+                    out[i] = table[int(s)]
+                picked.append(out)
+            tables = picked
+        if len(tables) == 1:
+            return np.ascontiguousarray(tables[0])
+        return np.stack(tables)
 
     # -- slot lifecycle --------------------------------------------------
 
@@ -438,9 +604,13 @@ class PagePool:
         partial = shared_tokens % self.page_size != 0
         total_pages = self.pages_for(length)
         fresh_needed = total_pages - n_full_shared
+        if self._window_short(slot, length, total_pages):
+            return None
         fresh = self._alloc(fresh_needed)
         if fresh is None:
             return None
+        if self.window_group is not None:
+            self.window_group.cover(slot, length, total_pages)
         row = list(shared_pages[:n_full_shared]) + fresh
         for p in shared_pages[:n_full_shared]:
             self._ref(p)
@@ -464,6 +634,11 @@ class PagePool:
         None when the pool cannot serve it (nothing mutated beyond
         evictions — caller requeues or sheds). Unwind a failed
         transfer with :meth:`release_slot`."""
+        if self.window_group is not None:
+            raise NotImplementedError(
+                "page handoff into a pool with a window group is not "
+                "implemented (the transfer moves one group's pages)."
+            )
         if self.counts[slot]:
             raise AssertionError(
                 f"slot {slot} still holds pages at adoption; release "
@@ -496,12 +671,31 @@ class PagePool:
         have = int(self.counts[slot])
         if needed <= have:
             return True
+        # the row that grows is the last: a query at rows - 1
+        if self._window_short(slot, rows - 1, needed):
+            return False
         fresh = self._alloc(needed - have)
         if fresh is None:
             return False
         self.table[slot, have:needed] = fresh
         self.counts[slot] = needed
+        if self.window_group is not None:
+            self.window_group.cover(slot, rows - 1, needed)
         return True
+
+    def release_behind_window(self, lengths: Sequence[int]) -> int:
+        """Once an iteration: for every slot that holds pages, free the
+        window group's pages that lie wholly behind ``length - window``
+        (the next token, at position ``lengths[slot]``, cannot reach
+        them, and nothing later will). Returns the pages freed; 0 for a
+        model with one group."""
+        group = self.window_group
+        if group is None:
+            return 0
+        return sum(
+            group.release_behind(slot, int(lengths[slot]))
+            for slot in np.flatnonzero(group.counts)
+        )
 
     def release_slot(self, slot: int) -> None:
         """Drop the slot's references (stream finished/failed). Pages
@@ -512,6 +706,8 @@ class PagePool:
             self._unref(int(self.table[slot, i]))
         self.table[slot, :n] = -1
         self.counts[slot] = 0
+        if self.window_group is not None:
+            self.window_group.release_slot(slot)
 
     def insert_prefix(self, slot: int, prompt) -> int:
         """Cache the slot's prompt pages for future warm hits (called
@@ -542,6 +738,8 @@ class PagePool:
         self.counts.fill(0)
         self.refcount.fill(0)
         self._free = list(range(self.num_pages - 1, -1, -1))
+        if self.window_group is not None:
+            self.window_group.reset()
         if self.prefix is not None:
             old = self.prefix
             fresh = RadixPrefixCache(
@@ -570,11 +768,14 @@ class PagePool:
         """Pages absent from the free list that nothing references
         (must be 0 — the chaos tests pin it: a crash path that forgot
         a release would strand pages here forever)."""
-        return (
+        leaked = (
             self.num_pages
             - len(self._free)
             - int(np.sum(self.refcount > 0))
         )
+        if self.window_group is not None:
+            leaked += self.window_group.leak_check()
+        return leaked
 
     def status(self) -> dict:
         """The ``/statusz`` ``kv_pool`` sub-section."""
@@ -591,6 +792,13 @@ class PagePool:
             # live worker process, not just in-process.
             "leaked": self.leak_check(),
         }
+        group = self.window_group
+        if group is not None:
+            out.update(
+                window_num_pages=group.num_pages,
+                window_used_pages=group.num_pages - len(group._free),
+                window_released_behind=group.released_behind,
+            )
         if self.prefix is not None:
             out.update(
                 prefix_nodes=self.prefix.nodes,

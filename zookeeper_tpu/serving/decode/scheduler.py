@@ -1503,6 +1503,36 @@ class DecodeScheduler:
                         self._metrics.record_first_tokens(finished)
         return chunks
 
+    def _release_behind_window(self) -> None:
+        """Once an iteration, for a model with sliding-window layers
+        (docs/DESIGN.md §20; a no-op without a window group): hand the
+        window group's pages that every sequence has left wholly behind
+        ``length - window`` back to its free list, so the group's small
+        pool (``slots x (window + a page)``) keeps serving sequences of
+        any length. While tracing, one ``kv_pages_released`` /
+        ``kv_pages_allocated`` event an iteration says how many pages
+        of each group moved since the last."""
+        pool = self._engine.page_pool
+        group = getattr(pool, "window_group", None)
+        if group is None:
+            return
+        with _trace.span("sched_window_release"), self._lock:
+            released = self._engine.release_behind_window(self._slot_lengths)
+        seen = getattr(self, "_pages_seen", (0, 0))
+        now = (pool.allocated_pages, group.allocated_pages)
+        object.__setattr__(self, "_pages_seen", now)
+        if not _trace.enabled():
+            return
+        if released:
+            _trace.event(
+                "kv_pages_released", attrs={"full": 0, "window": released}
+            )
+        if now != seen:
+            _trace.event(
+                "kv_pages_allocated",
+                attrs={"full": now[0] - seen[0], "window": now[1] - seen[1]},
+            )
+
     def _update_occupancy(self) -> None:
         if self._metrics is None:
             return
@@ -1567,6 +1597,7 @@ class DecodeScheduler:
             # decode spends the budget first, pending chunks get the
             # remainder (docs/DESIGN.md §25). No-op when chunking off.
             chunks = self._prefill_chunks(spent)
+            self._release_behind_window()
             with self._lock:
                 self._maybe_apply_swap()  # slot array may have drained
             with _trace.span("sched_bookkeeping"):
