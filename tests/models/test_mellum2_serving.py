@@ -191,6 +191,59 @@ def test_prefill_then_paged_decode_matches_the_reference(built, decode_attention
         assert float((best - got).max()) < 5e-4
 
 
+@pytest.mark.parametrize("decode_attention", ["pallas", "reference"])
+def test_decode_kv_blocks_event_counts_both_layer_groups(built, decode_attention):
+    """While tracing, every decode dispatch through the pool kernel
+    records what the kernel's page fetch does at the dispatch's lengths,
+    the full and the window group each counted once, by the kernel's own
+    arithmetic; the reference path records nothing."""
+    from zookeeper_tpu import ops
+    from zookeeper_tpu.observability import trace
+
+    _, params = built
+    service = _service(params, decode_attention=decode_attention)
+    engine, scheduler = service.build_service()
+    tracer = trace.enable()
+    try:
+        prompt = np.arange(40, dtype=np.int32) % VOCAB
+        scheduler.submit(prompt, max_new_tokens=6).result(timeout=600)
+        records = tracer.snapshot()
+        tracer.clear()
+        lengths = np.array([0, 45, 17], np.int32)
+        engine._note_kv_blocks(lengths)
+        noted = tracer.snapshot()
+    finally:
+        trace.disable()
+        service._teardown_service(suppress=True)
+    blocks = [r for r in records if r["name"] == "decode_kv_blocks"]
+    dispatches = [r for r in records if r["name"] == "decode_dispatch"]
+    if decode_attention == "reference":
+        assert not blocks and not noted and dispatches
+        return
+    assert len(blocks) == len(dispatches) > 0
+    for record in blocks:
+        attrs = record["attrs"]
+        assert 0 < attrs["work_items"] <= attrs["pages_live"]
+        assert attrs["pages_live"] <= attrs["pages_block_capacity"]
+    # the page size of 4 and rows of 2 heads x 32: both groups' blocks
+    # hold a slot's whole band, one work item a slot and group
+    ps, max_pages = 4, POSITIONS // 4
+    want = np.zeros(3, np.int64)
+    for window in (None, WINDOW):
+        n = ops.pool_decode_block_pages(
+            ps, ops.kv_row_width(FIELDS["num_kv_heads"], FIELDS["head_dim"]),
+            4, max_pages, window,
+        )
+        want += ops.pool_decode_work(
+            lengths, page_size=ps, max_pages=max_pages, block_pages=n,
+            window=window,
+        )
+    (record,) = noted
+    assert record["name"] == "decode_kv_blocks"
+    assert list(record["attrs"].values()) == [int(n) for n in want]
+    assert record["attrs"]["work_items"] == 2 * len(lengths)
+
+
 def test_window_layers_run_as_full_attention_are_caught(built):
     """The planted fault of the benchmark's check: the same weights with
     every layer full attend further back than the published model, and

@@ -156,6 +156,41 @@ def test_pool_kernel_never_reads_behind_the_window():
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(released))
 
 
+@pytest.mark.parametrize(
+    "h,hkv,d", [(16, 2, 128), (4, 2, 64)], ids=["matmul", "lane"]
+)
+@pytest.mark.parametrize("window", [300, 700])
+def test_pool_kernel_window_band_over_blocks_of_pages(h, hkv, d, window):
+    """A band of several of the kernel's page blocks whose first page
+    is not page 0: the released entries behind it are -1, and every
+    page no band touches (behind it, past the length, unallocated) is
+    NaN, so a page fetched that should not be poisons the result."""
+    from tests.ops.test_pool_attention import only_live_pages
+    from zookeeper_tpu.ops import kv_row_width, pool_decode_block_pages
+
+    ps, max_pages = 16, 80
+    n = pool_decode_block_pages(ps, kv_row_width(hkv, d), 4, max_pages, window)
+    assert n * ps < window or n == (window + ps - 2) // ps + 1
+    lengths = [0, window - 1, window, min(window + n * ps + 1, 1200), 1100, 1279]
+    q, kp, vp, table, lens = _pool_case(
+        8, len(lengths), h, hkv, d, ps, max_pages, lengths
+    )
+    want = pool_decode_attention(
+        q, kp, vp, table, lens, kv_heads=hkv, window=window
+    )
+    kp, vp, table = only_live_pages(
+        np.array(kp), np.array(vp), np.array(table), lengths, window
+    )
+    got = pool_paged_decode_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table), lens,
+        kv_heads=hkv, window=window, interpret=True,
+    )
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+    np.testing.assert_array_equal(
+        np.asarray(got).argmax(-1), np.asarray(want).argmax(-1)
+    )
+
+
 @pytest.mark.parametrize("window", [None, 12])
 def test_pool_verify_groups_and_window(window):
     """The gathered path extend and verify use: at one position it is

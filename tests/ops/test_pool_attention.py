@@ -191,6 +191,275 @@ def test_pool_kernel_bf16_matches_reference_argmax(operands):
     )
 
 
+# -- the kernel's own page fetch: a block of pages a work item -------------
+
+PS, MAX_PAGES = 16, 80  # 1,280 rows a slot: two or three blocks
+
+
+def block_pages(hkv, d, itemsize=4, window=None):
+    """``N`` as the kernel derives it for these shapes."""
+    return ops.pool_decode_block_pages(
+        PS, ops.kv_row_width(hkv, d), itemsize, MAX_PAGES, window
+    )
+
+
+def block_case(lengths, h, hkv, d, seed=7):
+    """q, a scattered pool (pages no table names NaN) and its table."""
+    rng = np.random.default_rng(seed)
+    b = len(lengths)
+    kc = rng.normal(size=(b, MAX_PAGES * PS, hkv, d)).astype(np.float32)
+    vc = rng.normal(size=(b, MAX_PAGES * PS, hkv, d)).astype(np.float32)
+    q = rng.normal(size=(b, 1, h, d)).astype(np.float32)
+    k_pool, v_pool, table = scattered_pool(
+        kc, vc, PS, b * MAX_PAGES + 5, seed=seed, poison=np.nan
+    )
+    return q, k_pool, v_pool, table
+
+
+def only_live_pages(k_pool, v_pool, table, lengths, window=None):
+    """The same pool with every page NO slot's band can touch NaN, and
+    those table entries ``-1`` (released, or never allocated): a page
+    that must not be fetched poisons the result if it is."""
+    table = table.copy()
+    keep = np.zeros(len(k_pool), bool)
+    for s, n in enumerate(lengths):
+        first = 0 if window is None else max(n - window + 1, 0) // PS
+        keep[table[s, first:n // PS + 1]] = True
+        table[s, :first] = -1
+        table[s, n // PS + 1:] = -1
+    k_pool, v_pool = k_pool.copy(), v_pool.copy()
+    k_pool[~keep] = np.nan
+    v_pool[~keep] = np.nan
+    return k_pool, v_pool, table
+
+
+LANE, MATMUL = (4, 4, 64), (8, 2, 128)  # (heads, kv heads, head_dim)
+N_ROWS = {shape: block_pages(*shape[1:]) * PS for shape in (LANE, MATMUL)}
+
+
+@pytest.mark.parametrize(
+    "shape,lengths",
+    [
+        # one row, and the block's last row, a block exactly, one more
+        (LANE, lambda n: [0, n - 1, n, n + 1, 2 * n + 5]),
+        (MATMUL, lambda n: [0, n - 1, n, n + 1, 2 * n + 5]),
+        (LANE, lambda n: [0, 0, n + 200, 0]),  # every slot empty but one
+        (MATMUL, lambda n: [0, 0, n + 200, 0]),
+        (LANE, lambda n: [0, 0, 0, 0]),  # all slots empty
+        (MATMUL, lambda n: [0, 0, 0, 0]),
+    ],
+    ids=[
+        "lane-boundaries", "matmul-boundaries", "lane-one-live",
+        "matmul-one-live", "lane-all-empty", "matmul-all-empty",
+    ],
+)
+def test_pool_kernel_fetches_blocks_of_pages(shape, lengths):
+    """Work items of ``N`` pages, double-buffered across items and
+    slots, against the gathered reference: allocator-scattered tables,
+    every page past a slot's length NaN and its table entry -1."""
+    h, hkv, d = shape
+    assert N_ROWS[shape] < MAX_PAGES * PS  # more than one block a slot
+    lengths = np.array(lengths(N_ROWS[shape]), np.int32)
+    q, k_pool, v_pool, table = block_case(lengths, h, hkv, d)
+    ref = np.asarray(
+        ops.pool_decode_attention(
+            q, folded(k_pool), folded(v_pool), table, lengths, kv_heads=hkv
+        )
+    )
+    k_pool, v_pool, table = only_live_pages(k_pool, v_pool, table, lengths)
+    kern = np.asarray(
+        ops.pool_paged_decode_attention(
+            q, folded(k_pool), folded(v_pool), table, lengths, kv_heads=hkv
+        )
+    )
+    np.testing.assert_allclose(kern, ref, atol=ATOL, rtol=0)
+    np.testing.assert_array_equal(kern.argmax(-1), ref.argmax(-1))
+
+
+def finishes_within(seconds, call):
+    """``call()``'s result, or a failure if it has not returned in time:
+    under the TPU interpreter a wait no copy satisfies blocks for ever,
+    as it would on the chip."""
+    import threading
+
+    box = {}
+
+    def run():
+        try:
+            box["result"] = call()
+        except BaseException as e:  # handed to the test's own thread
+            box["error"] = e
+
+    thread = threading.Thread(target=run, daemon=True)
+    thread.start()
+    thread.join(seconds)
+    if thread.is_alive():
+        pytest.fail(
+            f"the kernel has not ended after {seconds} s: it waits for a "
+            "copy that was never started, or for more bytes than were copied"
+        )
+    if "error" in box:
+        raise box["error"]
+    return box["result"]
+
+
+@pytest.mark.parametrize("shape", [LANE, MATMUL], ids=["lane", "matmul"])
+@pytest.mark.parametrize("window", [None, 300, 700])
+def test_pool_kernel_copies_and_semaphores_under_the_tpu_interpreter(
+    shape, window, capfd
+):
+    """Plain interpret mode copies at ``start`` and ignores ``wait``;
+    jax's TPU interpreter models the copies' semaphores and watches for
+    races between a copy and the arithmetic. Every copy the kernel
+    waits for was started and every wait is for the bytes that were
+    copied (else the wait never returns, here as on the chip: a window
+    of 300 makes a block of 20 pages, which no 128-key piece divides,
+    in buffers of 24), every copy started was waited for (the
+    interpreter reports a semaphore left above zero when the kernel
+    ends), none lands in the buffer being computed on, and the result is
+    the reference's."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    from zookeeper_tpu.ops.attention import _pool_paged_decode_call
+
+    h, hkv, d = shape
+    n = block_pages(hkv, d, window=window) * PS
+    lengths = np.array([0, n - 1, n, 2 * n + 5, 1279, 40], np.int32)
+    q, k_pool, v_pool, table = block_case(lengths, h, hkv, d)
+    ref = np.asarray(
+        ops.pool_decode_attention(
+            q, folded(k_pool), folded(v_pool), table, lengths,
+            kv_heads=hkv, window=window,
+        )
+    )
+    k_pool, v_pool, table = only_live_pages(
+        k_pool, v_pool, table, lengths, window
+    )
+    kern = finishes_within(
+        240,
+        lambda: _pool_paged_decode_call.__wrapped__(
+            q, folded(k_pool), folded(v_pool), table, lengths, None, None,
+            scale=d ** -0.5, kv_heads=hkv, window=window,
+            interpret=pltpu.InterpretParams(detect_races=True),
+        ),
+    )
+    kern = np.asarray(kern)
+    assert "non-zero count" not in capfd.readouterr().out
+    assert not interpret_pallas_call.races.races_found
+    np.testing.assert_allclose(kern, ref, atol=ATOL, rtol=0)
+
+
+def test_pool_kernel_repeated_table_entries():
+    """Two slots whose tables name the same pages (a shared prefix),
+    and a slot that names one page twice: a page is fetched wherever a
+    table says it lies, as often as it is named."""
+    h, hkv, d = LANE
+    n = N_ROWS[LANE]
+    lengths = np.array([n + 40, n + 7, 3 * PS], np.int32)
+    q, k_pool, v_pool, table = block_case(lengths, h, hkv, d)
+    table = table.copy()
+    table[1, : n // PS] = table[0, : n // PS]
+    table[2, 1] = table[2, 0]
+    ref = np.asarray(
+        ops.pool_decode_attention(
+            q, folded(k_pool), folded(v_pool), table, lengths
+        )
+    )
+    k_pool, v_pool, table = only_live_pages(k_pool, v_pool, table, lengths)
+    kern = np.asarray(
+        ops.pool_paged_decode_attention(
+            q, folded(k_pool), folded(v_pool), table, lengths
+        )
+    )
+    np.testing.assert_allclose(kern, ref, atol=ATOL, rtol=0)
+
+
+def test_int8_pool_kernel_fetches_blocks_of_pages():
+    """int8 rows ride the block fetch, their scale pages beside them:
+    lengths on both sides of a block's end."""
+    h, d = 8, 64
+    n = block_pages(h, d, itemsize=1) * PS
+    assert n < MAX_PAGES * PS
+    lengths = np.array([0, n - 1, n, n + 1, n + 200], np.int32)
+    q, k_pool, v_pool, table = block_case(lengths, h, h, d)
+    k_pool, v_pool = np.nan_to_num(k_pool), np.nan_to_num(v_pool)
+    kq, ks = ops.quantize_kv_rows(k_pool)
+    vq, vs = ops.quantize_kv_rows(v_pool)
+    operands = (
+        q, folded(kq), folded(vq), table, lengths,
+    )
+    scales = dict(
+        k_scale=np.asarray(ops.fold_kv_scales(ks)),
+        v_scale=np.asarray(ops.fold_kv_scales(vs)),
+    )
+    ref = np.asarray(ops.pool_decode_attention(*operands, **scales))
+    kern = np.asarray(ops.pool_paged_decode_attention(*operands, **scales))
+    np.testing.assert_allclose(kern, ref, atol=2 * ATOL, rtol=0)
+    np.testing.assert_array_equal(kern.argmax(-1), ref.argmax(-1))
+
+
+@pytest.mark.parametrize("window", [None, 20, 700])
+def test_host_work_items_equal_the_kernels_grid(window):
+    """The host function behind the ``decode_kv_blocks`` event counts
+    what the kernel's grid holds on the device."""
+    import jax
+
+    from zookeeper_tpu.ops.attention import _pool_work_items
+
+    rng = np.random.default_rng(2)
+    lengths = np.concatenate(
+        [[0, PS - 1, PS, MAX_PAGES * PS - 1], rng.integers(0, 1280, 28)]
+    ).astype(np.int32)
+    for n in (1, 8, 32):
+        steps = -(-MAX_PAGES // n)
+        slot, step, total = jax.jit(
+            _pool_work_items, static_argnums=(1, 2, 3, 4)
+        )(lengths, PS, window, n, steps)
+        items, live, capacity = ops.pool_decode_work(
+            lengths, page_size=PS, max_pages=MAX_PAGES, block_pages=n,
+            window=window,
+        )
+        assert int(total[0]) == items
+        assert capacity == items * n and items <= live <= capacity
+        # every slot's items, in slot order, steps counted from 0
+        slot, step = np.asarray(slot)[:items], np.asarray(step)[:items]
+        assert (np.diff(slot) >= 0).all() and set(slot) == set(range(32))
+        assert (step[np.r_[True, np.diff(slot) > 0]] == 0).all()
+        first = 0 if window is None else np.maximum(lengths - window + 1, 0) // PS
+        np.testing.assert_array_equal(
+            live, int((lengths // PS - first + 1).sum())
+        )
+
+
+def test_sharded_pool_kernel_two_head_shards():
+    """The mesh twin on a 2-shard mesh: each device fetches its own
+    head shard of every page; blocks past one work item."""
+    import jax
+    from jax.sharding import Mesh
+
+    h, hkv, d = 8, 8, 64
+    n = block_pages(hkv // 2, d) * PS
+    assert n < MAX_PAGES * PS
+    lengths = np.array([0, n - 1, n + 1, n + 300], np.int32)
+    q, k_pool, v_pool, table = block_case(lengths, h, hkv, d)
+    single = np.asarray(
+        ops.pool_paged_decode_attention(
+            q, folded(k_pool), folded(v_pool), table, lengths
+        )
+    )
+    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2), ("data", "model"))
+    with mesh:
+        sharded = np.asarray(
+            ops.sharded_pool_paged_decode_attention(
+                q, folded(k_pool, 2), folded(v_pool, 2), table, lengths,
+                mesh=mesh, data_axes=("data",), model_axis="model",
+            )
+        )
+    assert np.isfinite(single).all()
+    np.testing.assert_allclose(sharded, single, atol=ATOL, rtol=0)
+
+
 def test_pool_attention_validation_errors(operands):
     q, kc, vc, k_pool, v_pool, table, lengths, ps = operands
     with pytest.raises(ValueError, match="slots, 1, heads"):
@@ -241,3 +510,149 @@ def test_sharded_pool_kernel_on_mesh(operands):
         )
     np.testing.assert_allclose(sharded, single, atol=ATOL, rtol=0)
     np.testing.assert_allclose(replicated, single, atol=ATOL, rtol=0)
+
+
+# -- the kernel's size: what a program pays to trace and lower it ----------
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (list, tuple)) else [value]:
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr, those of every jaxpr inside it included."""
+    return sum(
+        1 + sum(_equations(inner) for inner in _sub_jaxprs(eqn))
+        for eqn in jaxpr.eqns
+    )
+
+
+def _named(jaxpr, primitive, found=None):
+    """Every equation of ``primitive`` in a jaxpr, at any depth."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(eqn)
+        for inner in _sub_jaxprs(eqn):
+            _named(inner, primitive, found)
+    return found
+
+
+#: Equations of the kernel's jaxpr at ``gpt2_xl_24l``'s shapes on the tree
+#: before the kernel fetched its own pages (PR 26: one page a grid step,
+#: its 13-column loop traced once). PR 27's kernel, refused for what its
+#: size cost ``setup_s``, counted 3,192: every equation is traced (about
+#: 1.2 ms each inside the serving program on the chip's host) and lowered
+#: by every process that builds a decode program.
+PARENT_KERNEL_EQUATIONS = 770
+
+
+def test_kernel_body_does_not_grow_with_the_block():
+    """At the serving cells' shapes (48 slots, 64 pages of 16, 25 heads
+    of 64: a block of 16 pages, two 128-key sub-blocks, 13 lane columns)
+    the traced kernel holds one copy of the lane path's column a row
+    height (the 128 keys of a whole sub-block, and a page for what is
+    left over), inside loops over the block's rows and the row's
+    columns: no more than 1.5 times the equations of the kernel that
+    read one page a grid step (it holds fewer)."""
+    import jax
+    import jax.numpy as jnp
+
+    slots, heads, d, max_pages, ps = 48, 25, 64, 64, 16
+    width = ops.kv_row_width(heads, d)
+    assert ops.pool_decode_block_pages(ps, width, 2, max_pages) == 16
+    pool = jax.ShapeDtypeStruct((slots * max_pages, 1, ps, width), jnp.bfloat16)
+
+    def attend(q, k, v, table, lengths):
+        return ops.pool_paged_decode_attention(
+            q, k, v, table, lengths, interpret=False
+        )
+
+    program = jax.make_jaxpr(attend)(
+        jax.ShapeDtypeStruct((slots, 1, heads, d), jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((slots, max_pages), np.int32),
+        jax.ShapeDtypeStruct((slots,), np.int32),
+    )
+    (call,) = _named(program.jaxpr, "pallas_call")
+    kernel = call.params["jaxpr"]
+    assert _equations(kernel) <= 1.5 * PARENT_KERNEL_EQUATIONS
+    # A column's masked lane reductions (one a head: two heads of 64
+    # lanes) stand in the kernel once a row height, 128 keys and a page,
+    # not once a column of the row nor once a sub-block of the block.
+    lane_sums = [
+        eqn.outvars[0].aval.shape
+        for eqn in _named(kernel, "reduce_sum") if eqn.params["axes"] == (1,)
+    ]
+    assert sorted(lane_sums) == [(16,), (16,), (128,), (128,)]
+
+
+def test_program_of_24_layers_traces_the_kernel_once(monkeypatch):
+    """``_pool_paged_decode_call`` is a ``jax.jit`` of its own: a program
+    that attends once a layer traces the kernel once and holds the same
+    jaxpr 24 times, so lowering builds it once too."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    traced = []
+    pallas_call = pl.pallas_call
+
+    def counting(kernel, *args, **kwargs):
+        traced.append(kernel)
+        return pallas_call(kernel, *args, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", counting)
+    lengths = np.array([0, 70, 300, 1100], np.int32)
+    q, k_pool, v_pool, table = block_case(lengths, *LANE)
+    k_pool, v_pool = folded(np.nan_to_num(k_pool)), folded(np.nan_to_num(v_pool))
+
+    def layers(q):
+        for _ in range(24):
+            q = ops.pool_paged_decode_attention(q, k_pool, v_pool, table, lengths)
+        return q
+
+    jax.clear_caches()
+    program = jax.make_jaxpr(layers)(q)
+    assert len(traced) == 1
+    calls = [
+        eqn for eqn in _named(program.jaxpr, "jit") + _named(program.jaxpr, "pjit")
+        if eqn.params["name"] == "_pool_paged_decode_call"
+    ]
+    assert len(calls) == 24
+    assert len({id(eqn.params["jaxpr"]) for eqn in calls}) == 1
+
+
+def test_probe_rehearses_both_paths_on_the_cpu():
+    """``tools/probe_pool_decode.py --rehearse`` walks the probe's whole
+    control flow at a tiny size (kernel, copies alone, arithmetic alone,
+    a second block size): every line names the CPU, carries no time, and
+    the kernel's result is finite."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
+    done = subprocess.run(
+        [
+            sys.executable, os.path.join(root, "tools", "probe_pool_decode.py"),
+            "--rehearse", "--halves", "--block-bytes", "65536",
+        ],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    lines = [json.loads(x) for x in done.stdout.splitlines() if x.startswith("{")]
+    assert {(x["shape"], x["variant"]) for x in lines} == {
+        (shape, variant)
+        for shape in ("rehearsal.lane", "rehearsal.matmul")
+        for variant in ("kernel", "copies_only", "arithmetic_only")
+    }
+    assert len({x["block_pages"] for x in lines if "lane" in x["shape"]}) == 2
+    for line in lines:
+        assert line["device"]["platform"] == "cpu"
+        assert line["ms_per_call"] is None and line["trace_s"] > 0
+        assert line.get("finite", True)

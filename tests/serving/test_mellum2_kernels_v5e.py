@@ -58,6 +58,46 @@ def test_grouped_pool_kernel_compiles(shaped, pages, window):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize(
+    "pages,window",
+    [(SLOTS * MAX_PAGES, None), (SLOTS * (WINDOW // PAGE + 2), WINDOW)],
+    ids=["full-group", "window-group"],
+)
+def test_grouped_pool_kernel_fetches_its_own_pages(shaped, pages, window):
+    """The matmul path at the cell's shape (64 slots, rows 512 wide):
+    a work item of the derived block of pages (whole 128-key
+    sub-blocks, never more than a window's band spans) lowers through
+    Mosaic as ONE custom call whose scoped-VMEM request is under what
+    the package ever asks of a v5e core, and no pool is copied on the
+    way in."""
+    from tests.serving.test_pool_layout_v5e import kernel_scoped_vmem_requests
+    from zookeeper_tpu.observability.hlo import count_copies_of_size
+    from zookeeper_tpu.ops.blocks import _VMEM_LIMIT_CAP
+
+    width = KV_HEADS * HEAD_DIM
+    block = ops.pool_decode_block_pages(PAGE, width, 2, MAX_PAGES, window)
+    assert block * PAGE % 128 == 0 and block <= MAX_PAGES
+    if window:
+        assert block <= window // PAGE + 1
+
+    def attend(q, k, v, table, lengths):
+        return ops.pool_paged_decode_attention(
+            q, k, v, table, lengths, kv_heads=KV_HEADS, window=window,
+            interpret=False,
+        )
+
+    pool = shaped((pages, 1, PAGE, width), jnp.bfloat16)
+    compiled = jax.jit(attend).lower(
+        shaped((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16), pool, pool,
+        shaped((SLOTS, MAX_PAGES), np.int32), shaped((SLOTS,), np.int32),
+    ).compile()
+    (request,) = kernel_scoped_vmem_requests(compiled)
+    assert request <= _VMEM_LIMIT_CAP
+    assert count_copies_of_size(
+        compiled.as_text(), {pages * PAGE * width}
+    ) == 0
+
+
 @pytest.mark.parametrize("window", [None, WINDOW], ids=["full", "banded"])
 def test_grouped_flash_forward_compiles(shaped, window):
     def attend(q, k, v):

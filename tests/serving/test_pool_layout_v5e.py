@@ -137,6 +137,49 @@ def test_prefill_write_holds_no_pool_sized_copy(shaped, quant):
     assert whole_leaf_copies(compiled, rows_only) == 0
 
 
+def kernel_scoped_vmem_requests(compiled):
+    """Bytes of scoped VMEM each Pallas custom call of a compiled program
+    asks the chip for (its ``vmem_limit_bytes``)."""
+    import re
+
+    return [
+        int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)[1])
+        for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_pool_kernel_fetches_its_own_pages(shaped, quant):
+    """The kernel alone at the serving cells' shape (48 slots x 64
+    pages, rows 1664 wide, the lane path): Mosaic lowers the page
+    copies into the double-buffered block, the program holds ONE custom
+    call that asks for less scoped VMEM than the package ever requests
+    of a v5e core, and no pool leaf is copied on the way in."""
+    from zookeeper_tpu.ops.blocks import _VMEM_LIMIT_CAP
+
+    cache = pool(quant)[0]
+    block = ops.pool_decode_block_pages(
+        PAGE_SIZE, 1664, cache["k"].dtype.itemsize, MAX_PAGES
+    )
+    assert block * PAGE_SIZE in (128, 256)  # whole 128-key sub-blocks
+
+    def attend(q, layer, table, lengths):
+        return ops.pool_paged_decode_attention(
+            q, layer["k"], layer["v"], table, lengths,
+            k_scale=layer.get("k_scale"), v_scale=layer.get("v_scale"),
+            interpret=False,
+        )
+
+    q = jax.ShapeDtypeStruct((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    compiled = jax.jit(attend).lower(
+        *shaped((q, cache, ints(SLOTS, MAX_PAGES), ints(SLOTS)))
+    ).compile()
+    (request,) = kernel_scoped_vmem_requests(compiled)
+    assert request <= _VMEM_LIMIT_CAP
+    assert whole_leaf_copies(compiled, [cache["k"]]) == 0
+
+
 def test_int8_pool_kernel_compiles(shaped):
     cache = pool("int8")[0]
 

@@ -40,6 +40,7 @@ from jax import lax
 from zookeeper_tpu.ops.blocks import (  # noqa: F401  (re-exports)
     _FLASH_VMEM_BUDGET,
     _decode_vmem_estimate,
+    _pool_decode_block_pages,
     _pool_decode_vmem_estimate,
     _round_up,
     _default_decode_blocks,
@@ -659,50 +660,68 @@ def pool_paged_decode_attention(
     window: Optional[int] = None,
 ) -> jax.Array:
     """Pallas TPU decode attention reading a SHARED page pool through
-    per-slot page tables — :func:`paged_decode_attention` with its
-    scalar-prefetch index map extended from "clamped contiguous block"
-    to "page-table entry" (docs/DESIGN.md §20).
+    per-slot page tables (docs/DESIGN.md §20, §26).
 
     Same contract as :func:`pool_decode_attention`; different cost
-    model: the grid is (head-shard, live (slot, logical-page step)
-    pair), its second size a runtime value, with ``lengths``,
-    ``page_table`` and the pairs as scalar-prefetch operands, so the
-    KV index map resolves each logical page to its pool index at DMA
-    time and a step past a slot's length is never visited (the §17
-    length-bounded-read property, composed with indirection; within a
-    step of several pages those past the length re-select the last
-    live page: a repeated index is no DMA). The KV block is exactly one page of one
-    head shard, ``[page_size, row_width]``, read in the layout the pool
-    is stored in: a larger block cannot be contiguous in a pool whose
-    pages are allocator-scattered. int8 pools ride the same grid with
-    the scale pages as a fourth/fifth operand, dequantized in VMEM —
-    resident HBM bytes halve, and the read bound stays page-granular.
+    model. The pools stay whole in HBM and the kernel fetches its own
+    pages. A work item is (slot, block of ``N`` consecutive logical
+    pages of that slot's band); the grid is (head-shard, LIVE work
+    item), its second size a runtime value, with ``lengths``,
+    ``page_table`` and the items as scalar-prefetch operands, so a block
+    past a slot's length or behind its band is never visited, and the
+    only pipelined operands of a grid step are the query block and the
+    output block, whatever a block holds. Inside an item the kernel
+    starts one async copy a LIVE page and pool, from
+    ``pool[table[slot, page], shard]`` (wherever the allocator put the
+    page) to the page's rows of a ``[2, N * page_size, row_width]``
+    VMEM block a pool: the block is assembled in VMEM, so it need not
+    be contiguous in the pool. A page past the length or behind the
+    band starts no copy (the §17 length-bounded read, composed with
+    indirection); its rows are masked. The block is double-buffered
+    ACROSS work items: the copies of the next item (of this slot, or the
+    first block of the next one) fly while this one is computed on.
+    ``N`` is derived, not set (:func:`pool_decode_block_pages`): from a
+    page's bytes, the pages a band can span and the scoped-VMEM budget.
 
     The rows are folded (heads end to end on the lanes), so a score is
     a sum over one head's ``head_dim`` lanes of a register: a masked
     lane reduction a head inside each 128-lane column; softmax state is
     kept per lane (every lane of a head carries that head's max and
     sum). That needs ``head_dim`` to divide 128
-    (:func:`decode_attention_supported` with ``paged=True``).
+    (:func:`decode_attention_supported` with ``paged=True``). The
+    fetched block is walked by loops whose trip counts come from the
+    live length: 128 keys at a time over the whole 128-key pieces of
+    the block's live pages, then a page at a time over the pages left
+    over (an almost empty slot pays for one page), the row's columns by
+    a loop inside. The traced kernel therefore holds one copy of a
+    column a row height, whatever ``N`` and however wide the row: what
+    a process pays to trace and lower the kernel follows the equations
+    it holds, and four copies of a 13-column body cost every serving
+    process 4 s of start-up (PERF.md, PR 27-28). int8 pools ride the
+    same fetch, dequantized in VMEM; their ``[page_size, heads]``
+    float32 scale pages stay pipelined operands, one a page of the
+    block (Mosaic refuses to slice an HBM operand whose lane dimension
+    pads to 128).
 
     Grouped heads (``kv_heads`` < ``q``'s heads): the pool's rows hold
     the key/value heads only and nothing is repeated in memory; the
     ``g`` query heads of a group ride the sublanes of the query block.
     Where a head fills a whole 128-lane column (``head_dim`` 128) the
     group's scores are one matmul a column, ``[g, 128] x [128, keys]``,
-    over ``_POOL_PAGES_PER_STEP`` pages a grid step (each page an
-    operand of its own, resolved through the table); elsewhere the
-    lane reduction runs once a member.
+    over the whole fetched block where every page of it is live and
+    over 128 keys at a time in a slot's last block; elsewhere the lane
+    reduction runs once a member.
 
     ``window``: the new token (at ``lengths``) attends rows ``lengths -
     window < j <= lengths`` only, and the grid covers just the pages
     that band can touch, from a first page that is not page 0:
     ``max(lengths - window + 1, 0) // page_size``. Pages behind it are
-    never read, so their table entries may be released (``-1``).
+    never fetched, so their table entries may be released (``-1``).
 
     Numerics: fp32 online-softmax accumulation with the reference's
     finite mask value — same contract (documented-ULP vs the pool
-    reference, argmax token-exact) as the §17 kernel.
+    reference, argmax token-exact) as the §17 kernel; the result
+    depends on ``N`` only through the order of float32 sums.
     """
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(
@@ -747,24 +766,96 @@ def pool_paged_decode_attention(
     )
 
 
-#: Pages one grid step of the pool kernel's matmul path reads (each an
-#: operand of its own): 8 pages of 16 rows are the 128 keys of one MXU
-#: pass, and an eighth of the grid's steps.
-_POOL_PAGES_PER_STEP = 8
+def pool_decode_block_pages(
+    page_size: int, row_width: int, itemsize: int, max_pages: int,
+    window: Optional[int] = None,
+) -> int:
+    """``N``: the consecutive logical pages one work item of the pool
+    decode kernel fetches, derived from what the call can see — a
+    page's bytes, the pages the band can span (the whole table, or a
+    window's ``window / page_size + 1``) and the scoped-VMEM budget
+    (``ops.blocks._pool_decode_block_pages``). Also the engine's: the
+    ``decode_kv_blocks`` counter is reckoned with the kernel's ``N``."""
+    return _pool_decode_block_pages(
+        page_size, row_width, itemsize,
+        _pool_band_span(max_pages, page_size, window),
+    )
+
+
+def _pool_band_span(max_pages, page_size, window):
+    """Logical pages the rows a token attends can touch."""
+    if window is None:
+        return max_pages
+    return min(max_pages, (window + page_size - 2) // page_size + 1)
+
+
+def _pool_first_page(lengths, page_size, window):
+    """The first logical page the band of the token at ``lengths`` can
+    touch (numpy or jax, array or scalar)."""
+    if window is None:
+        return lengths * 0
+    return (lengths - window + 1).clip(0) // page_size
+
+
+def _pool_live_items(lengths, page_size, window, block_pages):
+    """Work items a slot at ``lengths`` takes: blocks of ``block_pages``
+    pages from its band's first page through the new token's (numpy or
+    jax, array or scalar). The kernel's grid is their sum."""
+    first = _pool_first_page(lengths, page_size, window)
+    return (lengths // page_size - first) // block_pages + 1
+
+
+def _pool_work_items(lens, page_size, window, block_pages, steps):
+    """The pool kernel's grid, on the device: the LIVE (slot, block of
+    ``block_pages`` pages) work items flattened in slot order, each slot
+    from its band's first page to its last live one. ``(item_slot,
+    item_step, total)``: which item a grid index is (``steps``: the most
+    a slot can have, which sizes the arrays) and how many there are."""
+    counts = _pool_live_items(lens, page_size, window, block_pages)
+    ends = jnp.cumsum(counts)
+    item = jnp.arange(lens.shape[0] * steps, dtype=jnp.int32)
+    item_slot = jnp.minimum(
+        jnp.searchsorted(ends, item, side="right"), lens.shape[0] - 1
+    ).astype(jnp.int32)
+    return item_slot, item - (ends - counts)[item_slot], ends[-1:]
+
+
+def pool_decode_work(
+    lengths, *, page_size: int, max_pages: int, block_pages: int,
+    window: Optional[int] = None,
+):
+    """What one call of the pool decode kernel does at ``lengths``
+    (numpy): ``(work_items, pages_live, pages_block_capacity)`` — the
+    size of the kernel's grid, the pages it fetches, and the pages its
+    fetched blocks have room for (``work_items x block_pages``). The
+    same arithmetic sizes the grid on the device."""
+    import numpy as np
+
+    lens = np.clip(
+        np.asarray(lengths, np.int64), 0, max_pages * page_size - 1
+    )
+    first = _pool_first_page(lens, page_size, window)
+    items = int(_pool_live_items(lens, page_size, window, block_pages).sum())
+    live = int((lens // page_size - first + 1).sum())
+    return items, live, items * block_pages
 
 
 @partial(
-    jax.jit, static_argnames=("scale", "interpret", "kv_heads", "window")
+    jax.jit,
+    static_argnames=("scale", "interpret", "kv_heads", "window", "halves"),
 )
 def _pool_paged_decode_call(
     q, k_pool, v_pool, page_table, lengths, k_scale, v_scale, *,
-    scale, interpret, kv_heads, window,
+    scale, interpret, kv_heads, window, halves=("copies", "arithmetic"),
 ):
     """The kernel behind :func:`pool_paged_decode_attention` (operands
     checked there). Jitted so that a program which attends once a layer
-    traces and lowers the kernel once, and not once a layer: its body is
-    unrolled over the row's 128-lane columns, and 24 lowerings of it
-    were 13 s of an engine's warm-up (PERF.md, PR 25)."""
+    traces and lowers the kernel once, and not once a layer: 24
+    lowerings of it were 13 s of an engine's warm-up (PERF.md, PR 25).
+
+    ``halves``: what of a work item the kernel does. Only
+    ``tools/probe_pool_decode.py`` passes less than both, to time the
+    copies or the arithmetic alone (the result is then meaningless)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -786,52 +877,51 @@ def _pool_paged_decode_call(
     # One matmul a column where a head is a column and has a group to
     # fill the MXU's rows; the lane reduction otherwise.
     matmul = d == _KV_LANES and group > 1 and not quantized
-    span = nm if window is None else min(nm, (window + ps - 2) // ps + 1)
-    per_step = min(_POOL_PAGES_PER_STEP, span) if matmul else 1
-    steps = -(-span // per_step)
+    per_item = pool_decode_block_pages(
+        ps, width, k_pool.dtype.itemsize, nm, window
+    )
+    keys = per_item * ps
+    # The 128 keys of one MXU pass (16 registers of a column): the rows
+    # both paths take of a block at a time. The buffers' rows round up
+    # to whole pieces, so that the last piece of a band that is no
+    # multiple of it reads zeros (masked) and not the other buffer.
+    piece = min(ps * max(1, _KV_LANES // ps), keys)
+    buffer_rows = -(-keys // piece) * piece
+    steps = -(-_pool_band_span(nm, ps, window) // per_item)
     dot_precision = _flash_precision(q.dtype)
 
-    def first_page(length):
-        # the first logical page the band can touch
-        if window is None:
-            return 0
-        return jnp.maximum(length - window + 1, 0) // ps
+    # The grid: the (slot, block of ``per_item`` pages) work items that
+    # are LIVE, flattened into one dimension whose size is a runtime
+    # value (each slot from its band's first page to its last live one;
+    # ``item_slot`` / ``item_step`` say which item a grid index is), so
+    # a block the band or the length rules out is never visited.
+    item_slot, item_step, total = _pool_work_items(
+        lens, ps, window, per_item, steps
+    )
 
-    def live_steps(length):
-        # grid steps from the band's first page through the new token's
-        return (length // ps - first_page(length)) // per_step + 1
-
-    # The grid: the (slot, step) pairs that are LIVE, flattened into one
-    # dimension whose size is a runtime value (each slot from its band's
-    # first page to its last live one; ``item_slot`` / ``item_step`` say
-    # which pair a grid index is), so a step the band or the length
-    # rules out is never visited. A dead step moves no data, but its
-    # operands' bookkeeping was most of the kernel's time on a table of
-    # hundreds of pages (PERF.md, PR 25 and PR 26).
-    counts = live_steps(lens)
-    ends = jnp.cumsum(counts)
-    item = jnp.arange(b * steps, dtype=jnp.int32)
-    item_slot = jnp.minimum(
-        jnp.searchsorted(ends, item, side="right"), b - 1
-    ).astype(jnp.int32)
-    item_step = item - (ends - counts)[item_slot]
-
-    def q_index_map(sh, it, lens_ref, table_ref, slot_ref, step_ref):
+    def q_index_map(sh, it, lens_ref, table_ref, slot_ref, step_ref, n_ref):
         return (slot_ref[it], sh, 0, 0)
 
-    def kv_index_map(i):
-        def index_map(sh, it, lens_ref, table_ref, slot_ref, step_ref):
-            # The indirection step: a logical page resolves through the
-            # slot's table row; the pages of a step past the length
-            # re-select the LAST LIVE page's pool index, so a repeated
-            # index means no DMA and rows past the length never leave
-            # HBM.
-            s = slot_ref[it]
-            page = first_page(lens_ref[s]) + step_ref[it] * per_step + i
-            live = jnp.minimum(page, lens_ref[s] // ps)
+    def first_of(i, lens_ref, slot_ref, step_ref):
+        # item i's slot, and the first logical page of its block
+        s = slot_ref[i]
+        first = _pool_first_page(lens_ref[s], ps, window)
+        return s, first + step_ref[i] * per_item
+
+    def scale_index_map(j):
+        # An int8 pool's scale pages stay pipelined operands, one a
+        # page of the block: Mosaic refuses to slice an HBM operand
+        # whose lane dimension (the shard's heads) pads to 128. A page
+        # past the length re-selects the last live one (a repeated
+        # index is no DMA).
+        def index_map(sh, it, lens_ref, table_ref, slot_ref, step_ref, n_ref):
+            s, first = first_of(it, lens_ref, slot_ref, step_ref)
+            live = jnp.minimum(first + j, lens_ref[s] // ps)
             return (table_ref[s, live], sh, 0, 0)
 
         return index_map
+
+    scale_pages = per_item if quantized else 0
 
     def head_sums(x, heads):
         # Every lane ends up holding the sum over its own head's
@@ -845,18 +935,92 @@ def _pool_paged_decode_call(
             out = jnp.where(mine, total, out)
         return out
 
-    def kernel(lens_ref, table_ref, slot_ref, step_ref, q_ref, *refs):
-        k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
-        rest = refs[2 * per_step:]
-        if quantized:
-            ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        else:
-            o_ref, m_ref, l_ref, acc_ref = rest
-            ks_ref = vs_ref = None
-        it = pl.program_id(1)
+    def kernel(lens_ref, table_ref, slot_ref, step_ref, n_ref, q_ref, *refs):
+        hbm, refs = refs[:2], refs[2:]
+        ks_refs = refs[:scale_pages]
+        vs_refs = refs[scale_pages:2 * scale_pages]
+        o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sem, *scale_bufs = refs[
+            2 * scale_pages:
+        ]
+        ks_buf, vs_buf = scale_bufs or (None, None)
+        bufs = (k_buf, v_buf)
+        sh, it = pl.program_id(0), pl.program_id(1)
+        buf = lax.rem(it, 2)
+
+        def item(i):
+            # item i's slot, the first logical page of its block and how
+            # many of the block's pages are live (through the length's)
+            s, first = first_of(i, lens_ref, slot_ref, step_ref)
+            return s, first, jnp.minimum(
+                lens_ref[s] // ps - first + 1, per_item
+            )
+
+        def page_copies(s, first, j, into):
+            # page j of a block: from wherever the slot's table says it
+            # lies to its rows of buffer ``into``, K and V
+            if "copies" not in halves:
+                return []
+            index = table_ref[s, first + j]
+            rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            return [
+                pltpu.make_async_copy(
+                    src.at[index, sh], dst.at[into, rows], sem.at[into, p]
+                )
+                for p, (src, dst) in enumerate(zip(hbm, bufs))
+            ]
+
+        def start(block, into):
+            # One async copy a LIVE page and pool of an item's block
+            # (:func:`item`); a page past the length starts none.
+            s, first, live = block
+
+            def page(j, carry):
+                for copy in page_copies(s, first, j, into):
+                    copy.start()
+                return carry
+
+            lax.fori_loop(0, live, page, 0)
+
+        def wait(block, into):
+            s, first, live = block
+
+            @pl.when(live == per_item)
+            def _whole():
+                # the semaphore counts bytes: one wait the size of the
+                # block is the wait for every page of a whole block
+                for p, dst in enumerate(bufs if "copies" in halves else ()):
+                    block = dst.at[into, pl.ds(0, keys)]
+                    pltpu.make_async_copy(block, block, sem.at[into, p]).wait()
+
+            @pl.when(live < per_item)
+            def _pages():
+                def page(j, carry):
+                    for copy in page_copies(s, first, j, into):
+                        copy.wait()
+                    return carry
+
+                lax.fori_loop(0, live, page, 0)
+
+        mine = s, first, live = item(it)
+        length = lens_ref[s]
         kb = step_ref[it]
-        length = lens_ref[slot_ref[it]]
-        first = first_page(length) + kb * per_step
+
+        @pl.when(it == 0)
+        def _prime():
+            # Rows no copy ever lands on are masked, and 0 x garbage
+            # must still be 0: the buffers start as zeros.
+            for dst in bufs:
+                dst[...] = jnp.zeros_like(dst)
+            start(mine, buf)
+
+        # Double-buffered across work items: the next item's pages (of
+        # this slot, or the next one's first block) fly while this one's
+        # are computed on.
+        @pl.when(it + 1 < n_ref[0])
+        def _prefetch():
+            start(item(it + 1), 1 - buf)
+
+        wait(mine, buf)
 
         @pl.when(kb == 0)
         def _init():
@@ -870,117 +1034,182 @@ def _pool_paged_decode_call(
                 live = live & (ki > length - window)
             return live
 
-        def lane_block():
-            lane = lax.broadcasted_iota(jnp.int32, (ps, _KV_LANES), 1)
-            heads = [(lane // d) == j for j in range(heads_per_column)]
-            ki = first * ps + lax.broadcasted_iota(
-                jnp.int32, (ps, _KV_LANES), 0
-            )
-            live = in_band(ki)
-            for c in range(columns):
-                col = pl.ds(c * _KV_LANES, _KV_LANES)
-                kv = k_refs[0][0, 0, :, col].astype(jnp.float32)  # [ps, 128]
-                vv = v_refs[0][0, 0, :, col].astype(jnp.float32)
-                if quantized:
-                    ke = jnp.ones_like(kv)
-                    ve = jnp.ones_like(vv)
-                    first_head = c * heads_per_column
-                    for j, mine in enumerate(heads[: hs - first_head]):
-                        one = pl.ds(first_head + j, 1)
-                        ke = jnp.where(mine, ks_ref[0, 0, :, one], ke)
-                        ve = jnp.where(mine, vs_ref[0, 0, :, one], ve)
-                    kv = kv * ke
-                    vv = vv * ve
-                for g in range(group):
-                    row = pl.ds(g, 1)
-                    qv = q_ref[0, 0, row, col].astype(jnp.float32)  # [1, 128]
-                    sc = head_sums(qv * kv, heads) * scale
-                    sc = jnp.where(live, sc, _MASK_VALUE)
-                    m = m_ref[row, col]  # [1, 128]
-                    m_new = jnp.maximum(m, sc.max(axis=0, keepdims=True))
-                    p = jnp.exp(sc - m_new)
-                    corr = jnp.exp(m - m_new)
-                    m_ref[row, col] = m_new
-                    l_ref[row, col] = l_ref[row, col] * corr + p.sum(
-                        axis=0, keepdims=True
-                    )
-                    acc_ref[row, col] = acc_ref[row, col] * corr + (
-                        p * vv
-                    ).sum(axis=0, keepdims=True)
+        def aligned(start, size, to):
+            # rows or lanes from a start that may be a runtime value
+            if not isinstance(start, int):
+                start = pl.multiple_of(start, to)
+            return pl.ds(start, size)
 
-        def matmul_block():
-            keys = per_step * ps
-            ki = first * ps + lax.broadcasted_iota(
-                jnp.int32, (group, keys), 1
-            )
-            live = in_band(ki)
-            for c in range(columns):
-                col = pl.ds(c * _KV_LANES, _KV_LANES)
-                qv = q_ref[0, 0, :, col]  # [group, 128]
-                kv = jnp.concatenate(
-                    [r[0, 0, :, col] for r in k_refs], axis=0
-                )  # [keys, 128]
-                vv = jnp.concatenate(
-                    [r[0, 0, :, col] for r in v_refs], axis=0
-                )
-                sc = lax.dot_general(
-                    qv, kv, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=dot_precision,
-                ) * scale
-                sc = jnp.where(live, sc, _MASK_VALUE)
-                m = m_ref[:, col][:, :1]  # [group, 1]
-                m_new = jnp.maximum(m, sc.max(axis=1, keepdims=True))
+        def lane_column(c, rows, band, heads):
+            # one 128-lane column of the block's ``rows``: the parent's
+            # masked lane reductions and per-lane softmax state
+            col = aligned(c * _KV_LANES, _KV_LANES, _KV_LANES)
+            kv = k_buf[buf, rows, col].astype(jnp.float32)  # [size, 128]
+            vv = v_buf[buf, rows, col].astype(jnp.float32)
+            if quantized:  # ``c`` is a Python int here
+                ke = jnp.ones_like(kv)
+                ve = jnp.ones_like(vv)
+                first_head = c * heads_per_column
+                for j, mine in enumerate(heads[: hs - first_head]):
+                    one = pl.ds(first_head + j, 1)
+                    ke = jnp.where(mine, ks_buf[rows, one], ke)
+                    ve = jnp.where(mine, vs_buf[rows, one], ve)
+                kv = kv * ke
+                vv = vv * ve
+            for g in range(group):
+                row = pl.ds(g, 1)
+                qv = q_ref[0, 0, row, col].astype(jnp.float32)  # [1, 128]
+                sc = head_sums(qv * kv, heads) * scale
+                sc = jnp.where(band, sc, _MASK_VALUE)
+                m = m_ref[row, col]  # [1, 128]
+                m_new = jnp.maximum(m, sc.max(axis=0, keepdims=True))
                 p = jnp.exp(sc - m_new)
                 corr = jnp.exp(m - m_new)
-                wide = (group, _KV_LANES)
-                m_ref[:, col] = jnp.broadcast_to(m_new, wide)
-                l_ref[:, col] = l_ref[:, col] * corr + jnp.broadcast_to(
-                    p.sum(axis=1, keepdims=True), wide
+                m_ref[row, col] = m_new
+                l_ref[row, col] = l_ref[row, col] * corr + p.sum(
+                    axis=0, keepdims=True
                 )
-                acc_ref[:, col] = acc_ref[:, col] * corr + lax.dot_general(
-                    p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=dot_precision,
+                acc_ref[row, col] = acc_ref[row, col] * corr + (
+                    p * vv
+                ).sum(axis=0, keepdims=True)
+
+        def matmul_column(c, rows, band):
+            col = aligned(c * _KV_LANES, _KV_LANES, _KV_LANES)
+            qv = q_ref[0, 0, :, col]  # [group, 128]
+            kv = k_buf[buf, rows, col]  # [size, 128]
+            vv = v_buf[buf, rows, col]
+            sc = lax.dot_general(
+                qv, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=dot_precision,
+            ) * scale
+            sc = jnp.where(band, sc, _MASK_VALUE)
+            m = m_ref[:, col][:, :1]  # [group, 1]
+            m_new = jnp.maximum(m, sc.max(axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m - m_new)
+            wide = (group, _KV_LANES)
+            m_ref[:, col] = jnp.broadcast_to(m_new, wide)
+            l_ref[:, col] = l_ref[:, col] * corr + jnp.broadcast_to(
+                p.sum(axis=1, keepdims=True), wide
+            )
+            acc_ref[:, col] = acc_ref[:, col] * corr + lax.dot_general(
+                p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=dot_precision,
+            )
+
+        def attend(at, size):
+            # ``size`` rows of the block from row ``at`` (a multiple of
+            # the page size, static or not), every column of them; rows
+            # past the length or behind the band are masked, whatever
+            # lies in the buffer there
+            if "arithmetic" not in halves:
+                return
+            rows = aligned(at, size, ps)
+            shape = (group, size) if matmul else (size, _KV_LANES)
+            ki = first * ps + at + lax.broadcasted_iota(
+                jnp.int32, shape, 1 if matmul else 0
+            )
+            band = in_band(ki)
+            if matmul:
+                column = partial(matmul_column, rows=rows, band=band)
+            else:
+                lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+                heads = [(lane // d) == j for j in range(heads_per_column)]
+                column = partial(
+                    lane_column, rows=rows, band=band, heads=heads
                 )
+            if quantized:  # a column's heads and their scales: static
+                for c in range(columns):
+                    column(c)
+                return
 
-        (matmul_block if matmul else lane_block)()
+            def one(c, carry):
+                column(c)
+                return carry
 
-        @pl.when(kb == live_steps(length) - 1)
+            # ONE traced copy of a column, unrolled when it is lowered:
+            # what a process pays to trace the kernel follows the
+            # equations it holds (PERF.md, PR 28).
+            lax.fori_loop(0, columns, one, 0, unroll=True)
+
+        def walk(height, at, trips):
+            # ``trips`` times ``height`` rows from row ``at``: one traced
+            # copy of :func:`attend` whatever the trip count
+            def trip(t, carry):
+                attend(at + t * height, height)
+                return carry
+
+            lax.fori_loop(0, trips, trip, 0)
+
+        if quantized:
+            # the scale pages, operand by operand, into the block's rows
+            for j in range(per_item):
+                rows = pl.ds(j * ps, ps)
+                ks_buf[rows, :] = ks_refs[j][0, 0]
+                vs_buf[rows, :] = vs_refs[j][0, 0]
+
+        if matmul:
+            # A block whose every page is live is one matmul a column:
+            # the MXU's weight loads and the softmax state once a
+            # column, not once every 128 keys (0.53 -> 0.32 ms of
+            # arithmetic a full layer of ``mellum2_8l``: PERF.md,
+            # PR 27). A slot's last block goes a piece at a time.
+            @pl.when(live == per_item)
+            def _whole():
+                attend(0, keys)
+
+            @pl.when(live < per_item)
+            def _partial():
+                walk(piece, 0, -(-(live * ps) // piece))
+        else:
+            # The lane reductions cost what the rows they run over
+            # cost: the whole pieces of the live pages, then the pages
+            # left over one at a time, so an almost empty slot pays for
+            # a page and not for 128 keys (the chat cell's calls are a
+            # few short slots beside 45 empty ones).
+            whole = live * ps // piece
+            walk(piece, 0, whole)
+            if piece > ps:
+                walk(ps, whole * piece, live - whole * (piece // ps))
+
+        @pl.when(kb == _pool_live_items(length, ps, window, per_item) - 1)
         def _finalize():
             o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
-    page_specs = [
-        pl.BlockSpec((1, 1, ps, width), kv_index_map(i))
-        for i in range(per_step)
-    ]
-    in_specs = [pl.BlockSpec((1, 1, group, width), q_index_map)]
-    in_specs += page_specs + page_specs
-    operands = [qs] + [k_pool] * per_step + [v_pool] * per_step
-    if quantized:
-        in_specs += [pl.BlockSpec((1, 1, ps, hs), kv_index_map(0))] * 2
-        operands += [
-            k_scale.astype(jnp.float32),
-            v_scale.astype(jnp.float32),
-        ]
+    q_spec = pl.BlockSpec((1, 1, group, width), q_index_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(shards, ends[-1]),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, width), q_index_map),
-        scratch_shapes=[pltpu.VMEM((group, width), jnp.float32)] * 3,
+        num_scalar_prefetch=5,
+        grid=(shards, total[0]),
+        # The pools stay whole in HBM: the kernel fetches its own pages,
+        # so a grid step's pipelined operands are q and the output
+        # whatever a block holds.
+        in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2 + [
+            pl.BlockSpec((1, 1, ps, hs), scale_index_map(j))
+            for j in range(scale_pages)
+        ] * 2,
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((group, width), jnp.float32)] * 3
+        + [pltpu.VMEM((2, buffer_rows, width), k_pool.dtype)] * 2
+        + [pltpu.SemaphoreType.DMA((2, 2))]
+        + [pltpu.VMEM((keys, hs), jnp.float32)] * (2 if quantized else 0),
     )
+    operands = [qs, k_pool, v_pool]
+    if quantized:
+        operands += [k_scale.astype(jnp.float32)] * scale_pages
+        operands += [v_scale.astype(jnp.float32)] * scale_pages
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, shards, group, width), q.dtype),
         compiler_params=_mosaic_params(
             _pool_decode_vmem_estimate(
-                per_step * ps, width, k_pool.dtype.itemsize
+                buffer_rows, width, k_pool.dtype.itemsize
             )
         ),
         interpret=interpret,
-    )(lens, table, item_slot, item_step, *operands)
+    )(lens, table, item_slot, item_step, total, *operands)
     out = unfold_kv_rows(jnp.swapaxes(out, 1, 2), kv_heads, d)
     return out.swapaxes(1, 2).reshape(b, 1, h, d)
 

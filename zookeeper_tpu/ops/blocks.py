@@ -34,6 +34,8 @@ __all__ = [
     "_decode_vmem_estimate",
     "_default_decode_blocks",
     "_pool_decode_vmem_estimate",
+    "_POOL_BLOCK_BYTES",
+    "_pool_decode_block_pages",
     "_resid_blocks",
     "_resid_vmem_estimate",
     "_binary_conv_vmem_estimate",
@@ -223,15 +225,44 @@ def _default_decode_blocks(
     return int(block_kv), int(block_h)
 
 
-def _pool_decode_vmem_estimate(page_size, row_width, itemsize):
-    """Rough bytes one pool-kernel grid step keeps resident: the
-    double-buffered K and V pages at the pool dtype, the fp32
-    per-register-column intermediates of one 128-lane column, and the
-    three lane-dense accumulators."""
-    tiles = 2 * 2 * page_size * row_width * itemsize
-    intermediates = 8 * page_size * 128 * 4
+def _pool_decode_vmem_estimate(block_rows, row_width, itemsize):
+    """Rough bytes one pool-kernel work item keeps resident: the two
+    buffers of ``block_rows`` K and V rows at the pool dtype (the one
+    computed on and the one the next item's pages land in), the fp32
+    intermediates of one 128-key sub-block of one 128-lane column, and
+    the three lane-dense accumulators."""
+    tiles = 2 * 2 * block_rows * row_width * itemsize
+    intermediates = 8 * min(block_rows, 128) * 128 * 4
     accumulators = 3 * row_width * 4
     return tiles + intermediates + accumulators
+
+
+#: Bytes of one pool's pages a work item of the pool decode kernel
+#: fetches into one buffer: the block derivation's constant. A few
+#: hundred KB keeps a work item's fixed cost (two pipelined operands,
+#: the copies' wait, the softmax state's read-modify-write) small beside
+#: its copies, and two buffers of K and V far inside the scoped VMEM.
+#: Measured on the v5e at 256 KB, 512 KB and 1 MB (PERF.md, PR 27 and
+#: PR 28: ``mellum2_8l``'s full layer 0.73, 0.56, 0.58 ms a call).
+_POOL_BLOCK_BYTES = 512 * 1024
+
+
+def _pool_decode_block_pages(page_size, row_width, itemsize, span):
+    """Pages one work item of the pool decode kernel fetches: as many
+    as ``_POOL_BLOCK_BYTES`` hold, in whole 128-key sub-blocks (the
+    kernel's arithmetic runs 128 keys at a time) and never fewer than
+    two of them (wide rows: a work item's fixed cost once every 256
+    keys at the most); never more than the ``span`` of pages a slot's
+    band can touch; inside the VMEM budget."""
+    sub = max(1, 128 // page_size)
+    pages = _POOL_BLOCK_BYTES // (page_size * row_width * itemsize)
+    pages = max(pages - pages % sub, 2 * sub)
+    pages = min(pages, max(1, span))
+    while pages > 1 and _pool_decode_vmem_estimate(
+        pages * page_size, row_width, itemsize
+    ) > _FLASH_VMEM_BUDGET:
+        pages //= 2
+    return int(pages)
 
 
 # -- 1-bit residual pack/unpack ---------------------------------------------

@@ -1084,6 +1084,49 @@ PagePool` (None in the slot layout)."""
         )
         return out
 
+    def _note_kv_blocks(self, lengths: np.ndarray) -> None:
+        """While tracing, and where the decode step attends through the
+        pool kernel: one ``decode_kv_blocks`` event a dispatch, how the
+        kernel's block fetch engages at these lengths, summed over the
+        layer groups (a full and a window group, each counted once):
+        ``work_items`` (the kernel's grid), ``pages_live`` (the pages
+        it fetches) and ``pages_block_capacity`` (``work_items`` times
+        the pages a fetched block holds). Live over capacity is the
+        share of a fetched block's rows that are real. The arithmetic
+        is the kernel's own (``ops.pool_decode_work``)."""
+        if not (
+            _trace.enabled()
+            and self._paged
+            and self._decode_attention_flavor == "pallas"
+        ):
+            return
+        from zookeeper_tpu import ops
+
+        totals = np.zeros(3, np.int64)
+        for windowed in sorted(set(self._window_layers)):
+            pool = self._cache[self._window_layers.index(windowed)]["k"]
+            page_size, width = (int(n) for n in pool.shape[2:])
+            window = int(self._module.window) if windowed else None
+            totals += ops.pool_decode_work(
+                lengths,
+                page_size=page_size,
+                max_pages=self._max_pages,
+                block_pages=ops.pool_decode_block_pages(
+                    page_size, width, pool.dtype.itemsize,
+                    self._max_pages, window,
+                ),
+                window=window,
+            )
+        _trace.event(
+            "decode_kv_blocks",
+            attrs=dict(
+                zip(
+                    ("work_items", "pages_live", "pages_block_capacity"),
+                    (int(n) for n in totals),
+                )
+            ),
+        )
+
     def _decode_compiled(self, *, during_dispatch: bool = False):
         import jax
         import jax.numpy as jnp
@@ -1875,6 +1918,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         args = (tokens, lengths)
         if self._paged:
             args = (tokens, lengths, self._pool.operand())
+        self._note_kv_blocks(lengths)
         with _trace.span(
             "decode_dispatch",
             attrs=(
