@@ -56,7 +56,6 @@ LM_CONFIG = {
     "model.compute_dtype": "bfloat16",
     "vocab_size": 1024,
     "seq_len": 2048,
-    "engine.kv_layout": "paged",
     "engine.slots": 8,
     "engine.seq_buckets": (64, 512),
 }

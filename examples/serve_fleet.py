@@ -103,7 +103,6 @@ class ServeFleet(Experiment):
             "seq_len": seq_len,
             "vocab_size": self.vocab_size,
             "seed": self.seed,
-            "engine.kv_layout": "paged",
             "engine.page_size": self.page_size,
             "engine.slots": self.slots,
             "engine.seq_buckets": (16, max_prompt),

@@ -23,7 +23,7 @@ weights -> paged-KV continuous-batching decode) in two commands::
         metrics_port=8080
 
     # Decode-attention flavor (docs/DESIGN.md §17): auto = the
-    # length-aware Pallas paged decode kernel on TPU, the reference
+    # length-aware Pallas pool decode kernel on TPU, the reference
     # einsum elsewhere; force either for an A/B:
     python examples/serve_lm.py ServeLM engine.decode_attention=pallas
 
@@ -36,12 +36,12 @@ weights -> paged-KV continuous-batching decode) in two commands::
         speculative.draft_checkpoint=/tmp/lm_student_ckpt \\
         speculative.draft_model.num_layers=1
 
-    # True paged KV (docs/DESIGN.md §20): shared page pool + per-slot
-    # page tables — pooled capacity, warm-prefix reuse through the
-    # radix prefix cache (CoW at the divergence point), optional int8
-    # rows; the result line gains kv_layout / kv_pool_fill /
-    # prefix_cache_hit_rate:
-    python examples/serve_lm.py ServeLM engine.kv_layout=paged \\
+    # The KV cache is a shared page pool + per-slot page tables
+    # (docs/DESIGN.md §20): pooled capacity, warm-prefix reuse through
+    # the radix prefix cache (CoW at the divergence point), optional
+    # int8 rows; the result line reports kv_pool_fill and
+    # prefix_cache_hit_rate. A smaller pool than the worst case, int8:
+    python examples/serve_lm.py ServeLM engine.pool_pages=64 \\
         engine.kv_quant=int8   # int8 optional; fp stays token-exact
 
 Every request rides the REAL serving path — bucketed prefill into a

@@ -264,7 +264,7 @@ def test_window_layers_run_as_full_attention_are_caught(built):
 
 def test_engine_refuses_what_it_cannot_serve(built):
     _, params = built
-    with pytest.raises(ValueError, match="window layers"):
+    with pytest.raises(ValueError, match="only KV layout"):
         _service(params, kv_layout="slots").build_service()
     with pytest.raises(ValueError, match="prefix_cache=true is not implemented"):
         _service(params, prefix_cache=True).build_service()

@@ -1,14 +1,12 @@
 """Unit tests for the shared VMEM-aware block policies (ops/blocks.py).
 
-The flash / decode / resid policies moved here from attention.py and
+The flash / resid policies moved here from attention.py and
 binary_compute.py in docs/DESIGN.md §21 with behavior pinned by their
-pre-existing tests (test_ring_attention.py, test_paged_decode_attention.py,
-test_pack_residuals.py); this file covers the re-export identity (the
+pre-existing tests (test_ring_attention.py, test_pack_residuals.py; the
+pool decode kernel's by test_pool_attention.py); this file covers the re-export identity (the
 historical import sites must resolve to the SAME objects, not copies),
 the pure-shape-arithmetic contract, and the new §21 binary policies.
 """
-
-import pytest
 
 from zookeeper_tpu.ops import blocks
 
@@ -24,8 +22,13 @@ def test_attention_reexports_are_the_blocks_objects():
 
     assert attention._default_flash_blocks is blocks._default_flash_blocks
     assert attention._flash_bwd_vmem_estimate is blocks._flash_bwd_vmem_estimate
-    assert attention._default_decode_blocks is blocks._default_decode_blocks
-    assert attention._decode_vmem_estimate is blocks._decode_vmem_estimate
+    assert (
+        attention._pool_decode_block_pages is blocks._pool_decode_block_pages
+    )
+    assert (
+        attention._pool_decode_vmem_estimate
+        is blocks._pool_decode_vmem_estimate
+    )
     assert attention._FLASH_VMEM_BUDGET == blocks._FLASH_VMEM_BUDGET
 
 
@@ -80,10 +83,14 @@ def test_flash_policy_headline_cases():
     assert blocks._default_flash_blocks(4096, 256, 512) == (256, 512)
 
 
-def test_decode_policy_headline_cases():
-    assert blocks._default_decode_blocks(2048, 8, 128, page_size=16)[0] == 256
-    with pytest.raises(ValueError):
-        blocks._default_decode_blocks(64, 4, 64, block_kv=24)
+def test_pool_decode_policy_headline_cases():
+    # gpt2_xl_24l: 53 KB pages (16 rows of 1664 bf16 lanes) get the
+    # floor of two 128-key sub-blocks; mellum2_8l's 16 KB pages fill
+    # the 512 KB block; a window's band caps the block at its span.
+    assert blocks._pool_decode_block_pages(16, 1664, 2, 64) == 16
+    assert blocks._pool_decode_block_pages(16, 512, 2, 512) == 32
+    assert blocks._pool_decode_block_pages(16, 512, 2, 5) == 5
+    assert blocks._pool_decode_block_pages(16, 512, 2, 0) == 1
 
 
 def test_resid_blocks_divide_and_fit_budget():
@@ -181,15 +188,17 @@ def test_no_policy_returns_a_block_past_the_limit_its_call_passes():
                 est = blocks._flash_bwd_vmem_estimate(bq, bk, d, itemsize)
                 assert est <= limit(est), (s, d, itemsize)
 
-    for capacity in (64, 2048, 32768):
-        for heads in (1, 8, 64):
-            for d in (8, 64, 256):
-                for itemsize in (1, 2, 4):
-                    bkv, bh = blocks._default_decode_blocks(
-                        capacity, heads, d, page_size=16, itemsize=itemsize
+    for page_size in (8, 16, 128):
+        for width in (128, 512, 1664, 8192):
+            for itemsize in (1, 2, 4):
+                for span in (1, 66, 512):
+                    pages = blocks._pool_decode_block_pages(
+                        page_size, width, itemsize, span
                     )
-                    est = blocks._decode_vmem_estimate(bkv, bh, d, itemsize)
-                    assert est <= limit(est), (capacity, heads, d)
+                    est = blocks._pool_decode_vmem_estimate(
+                        pages * page_size, width, itemsize
+                    )
+                    assert est <= limit(est), (page_size, width, itemsize)
 
     for m, n, kw in [(1, 1, 1), (8192, 512, 144), (100000, 4096, 16)]:
         est = blocks._binary_gemm_vmem_estimate(

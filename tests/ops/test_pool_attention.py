@@ -1,9 +1,13 @@
 """Op-level certification of the page-pool attention family
 (docs/DESIGN.md §20): the gathered-pool reference must be BIT-identical
-to the slot-contiguous ``cached_attention`` oracle on every live row
-(the gather is pure indirection — same values, same einsums), the
-page-table scalar-prefetch kernel rides the §17 tolerance contract
-against that reference, and the int8 path's dequantize-inside-the-read
+to the ``cached_attention`` mathematics over contiguous rows on every
+live row (the gather is pure indirection — same values, same einsums),
+the page-fetching kernel rides the §17 tolerance contract against that
+reference (fp32 within ``2e-6`` absolute for O(1)-scale inputs: the
+online softmax's reassociation is the ONLY divergence; argmax exact),
+over every cache state the scheduler can produce (lengths are runtime
+data: empty, full, partial final page, ragged, page boundaries, garbage
+past ``lengths``), and the int8 path's dequantize-inside-the-read
 stays within the documented quantization bound with argmax stability.
 All CPU (interpret-mode Pallas)."""
 
@@ -86,8 +90,34 @@ def test_pool_verify_bit_identical_to_verify_cached(operands):
     np.testing.assert_array_equal(ref, pool)
 
 
-def test_pool_kernel_matches_reference_within_tolerance(operands):
-    q, kc, vc, k_pool, v_pool, table, lengths, ps = operands
+CAP = 32  # the fixture's rows a slot: 4 pages of 8
+
+
+@pytest.mark.parametrize(
+    "lengths",
+    [
+        # The fixture's own: empty, mid-page, page boundary, last row.
+        [0, 13, 16, 31],
+        # length=0: only row 0 (the just-written token) is attended —
+        # the first decode step after a 1-token prefill.
+        [0, 0, 0, 0],
+        # length=capacity-1: every row live, the capacity edge the
+        # scheduler truncates at.
+        [CAP - 1] * 4,
+        # Partial final page: 17 lands 2 rows into the third page.
+        [17, 17, 17, 17],
+        # Ragged: every slot bounds its own page walk differently.
+        [0, CAP - 1, 17, 5],
+        # Page boundaries themselves (last row of a page / first row of
+        # the next one).
+        [7, 8, 23, 24],
+    ],
+)
+def test_pool_kernel_matches_reference_within_tolerance(operands, lengths):
+    """Lengths are runtime data: one kernel serves every cache state
+    the scheduler can produce."""
+    q, kc, vc, k_pool, v_pool, table, _, ps = operands
+    lengths = np.asarray(lengths, np.int32)
     ref = np.asarray(
         ops.pool_decode_attention(q, k_pool, v_pool, table, lengths)
     )
@@ -95,6 +125,8 @@ def test_pool_kernel_matches_reference_within_tolerance(operands):
         ops.pool_paged_decode_attention(q, k_pool, v_pool, table, lengths)
     )
     np.testing.assert_allclose(kern, ref, atol=ATOL, rtol=0)
+    # Token-exactness proxy: per-(slot, head) argmax over head_dim.
+    np.testing.assert_array_equal(kern.argmax(axis=-1), ref.argmax(axis=-1))
 
 
 def test_pool_kernel_dead_table_entries_harmless(operands):
@@ -119,6 +151,54 @@ def test_pool_kernel_dead_table_entries_harmless(operands):
     )
     np.testing.assert_array_equal(ref, got_ref)
     np.testing.assert_allclose(got_kern, ref, atol=ATOL, rtol=0)
+
+
+def test_pool_kernel_garbage_rows_inside_a_live_page_never_leak(operands):
+    """The slot-refill validity invariant: rows past ``lengths`` inside
+    a LIVE page hold a previous occupant's K/V (or prefill padding).
+    The kernel on a garbage-poisoned pool must equal the reference on a
+    ZEROED one — masked rows contribute exactly nothing, not merely
+    approximately."""
+    q, kc, vc, _, _, _, _, ps = operands
+    lengths = np.array([5, 20, 0, CAP - 1], np.int32)
+    live = np.arange(CAP)[None, :, None, None] <= lengths[:, None, None, None]
+    # Huge finite garbage: if any masked row leaked it would dominate.
+    k_dirty, v_dirty, table = scattered_pool(
+        np.where(live, kc, 1e9), np.where(live, vc, -1e9), ps, 24
+    )
+    k_clean, v_clean, _ = scattered_pool(
+        np.where(live, kc, 0.0), np.where(live, vc, 0.0), ps, 24
+    )
+    ref = np.asarray(
+        ops.pool_decode_attention(
+            q, folded(k_clean), folded(v_clean), table, lengths
+        )
+    )
+    got = np.asarray(
+        ops.pool_paged_decode_attention(
+            q, folded(k_dirty), folded(v_dirty), table, lengths
+        )
+    )
+    np.testing.assert_allclose(got, ref, atol=ATOL, rtol=0)
+
+
+def test_pool_kernel_lengths_at_or_past_capacity_clamp_like_reference(
+    operands,
+):
+    """The reference mask ``ki <= lengths`` attends every row when
+    lengths >= capacity; the kernel's clamp must agree (the scheduler
+    never sends such lengths, but an idle slot's ride-along must not be
+    able to produce NaN)."""
+    q, kc, vc, k_pool, v_pool, table, _, ps = operands
+    lengths = np.array([CAP, CAP + 7, CAP - 1, 2 * CAP], np.int32)
+    ref = np.asarray(
+        ops.pool_decode_attention(q, k_pool, v_pool, table, lengths)
+    )
+    got = np.asarray(
+        ops.pool_paged_decode_attention(q, k_pool, v_pool, table, lengths)
+    )
+    np.testing.assert_allclose(got, ref, atol=ATOL, rtol=0)
+    assert not np.isnan(got).any()
 
 
 def test_int8_pool_attention_documented_ulp_and_argmax(operands):
@@ -168,19 +248,37 @@ def test_quantize_kv_rows_roundtrip_bound():
     np.testing.assert_array_equal(back[0, 0], 0.0)
 
 
-def test_pool_kernel_bf16_matches_reference_argmax(operands):
+@pytest.mark.parametrize(
+    "case",
+    [
+        {},
+        # An explicit softmax scale reaches the kernel's closure.
+        {"scale": 0.25},
+        # A table of ONE page a slot: the band's first block, the only
+        # block and the finalisation all land on one work item.
+        {"pages": 1},
+    ],
+    ids=["default", "explicit_scale", "single_block_capacity"],
+)
+def test_pool_kernel_bf16_matches_reference_argmax(operands, case):
     import jax.numpy as jnp
 
     q, kc, vc, k_pool, v_pool, table, lengths, ps = operands
+    kwargs = {"scale": case["scale"]} if "scale" in case else {}
+    if "pages" in case:
+        table = table[:, : case["pages"]]
+        lengths = np.array([0, 3, ps - 1, 5], np.int32)
     qb = jnp.asarray(q, jnp.bfloat16)
     kb = jnp.asarray(np.nan_to_num(k_pool, posinf=0, neginf=0), jnp.bfloat16)
     vb = jnp.asarray(np.nan_to_num(v_pool, posinf=0, neginf=0), jnp.bfloat16)
     ref = np.asarray(
-        ops.pool_decode_attention(qb, kb, vb, table, lengths),
+        ops.pool_decode_attention(qb, kb, vb, table, lengths, **kwargs),
         np.float32,
     )
     kern = np.asarray(
-        ops.pool_paged_decode_attention(qb, kb, vb, table, lengths),
+        ops.pool_paged_decode_attention(
+            qb, kb, vb, table, lengths, **kwargs
+        ),
         np.float32,
     )
     # bf16 output grid is coarse; the two paths must agree to the
@@ -460,21 +558,56 @@ def test_sharded_pool_kernel_two_head_shards():
     np.testing.assert_allclose(sharded, single, atol=ATOL, rtol=0)
 
 
-def test_pool_attention_validation_errors(operands):
+@pytest.mark.parametrize(
+    "case, match",
+    [
+        ("q_rank", "slots, 1, heads"),
+        ("pools_differ", "must be identical"),
+        ("pool_heads", "does not match q"),
+        ("head_dim", "off the pool kernel's geometry"),
+        ("table_rows", "page_table"),
+        ("one_scale", "together"),
+        ("window", "window=0"),
+    ],
+)
+def test_pool_attention_validation_errors(operands, case, match):
     q, kc, vc, k_pool, v_pool, table, lengths, ps = operands
-    with pytest.raises(ValueError, match="slots, 1, heads"):
+    kwargs = {}
+    if case == "q_rank":
+        q = q[:, 0]
+    elif case == "pools_differ":
+        v_pool = v_pool[:, :, :4]
+    elif case == "pool_heads":
+        # 4 query heads are no multiple of 3 key/value heads.
+        kwargs["kv_heads"] = 3
+    elif case == "head_dim":
+        # 20 is on the sublane quantum's wrong side and divides no 128.
+        q = np.zeros((4, 1, 4, 20), np.float32)
+        k_pool = v_pool = np.zeros((24, 1, ps, 128), np.float32)
+    elif case == "table_rows":
+        table = table[:2]
+    elif case == "one_scale":
+        kwargs["k_scale"] = np.ones(k_pool.shape[:3] + (4,), np.float32)
+    elif case == "window":
+        kwargs["window"] = 0
+    with pytest.raises(ValueError, match=match):
         ops.pool_paged_decode_attention(
-            q[:, 0], k_pool, v_pool, table, lengths
+            q, k_pool, v_pool, table, lengths, **kwargs
         )
-    with pytest.raises(ValueError, match="page_table"):
-        ops.pool_paged_decode_attention(
-            q, k_pool, v_pool, table[:2], lengths
-        )
-    with pytest.raises(ValueError, match="together"):
-        ops.pool_paged_decode_attention(
-            q, k_pool, v_pool, table, lengths,
-            k_scale=np.ones(k_pool.shape[:3] + (4,), np.float32),
-        )
+
+
+def test_supported_predicate():
+    """One geometry rule, the pool kernel's: a head's lanes are summed
+    inside one 128-lane register, so head_dim divides 128 (and sits on
+    the fp32 sublane quantum); off it the engine degrades to the
+    reference einsum (``DecodeEngine``)."""
+    assert ops.decode_attention_supported(4, 64)
+    assert ops.decode_attention_supported(1, 8)
+    assert ops.decode_attention_supported(4, 128)
+    assert not ops.decode_attention_supported(4, 20)
+    assert not ops.decode_attention_supported(4, 7)
+    assert not ops.decode_attention_supported(4, 24)  # 8 | 24, 24 ∤ 128
+    assert not ops.decode_attention_supported(0, 64)
 
 
 @pytest.mark.slow

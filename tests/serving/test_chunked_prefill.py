@@ -1,9 +1,9 @@
-"""Chunked-prefill certification (docs/DESIGN.md §25): the paged
+"""Chunked-prefill certification (docs/DESIGN.md §25): the decode
 engine's ``prefill_chunk_tokens`` splits every admitted prompt into
 bounded chunk dispatches the scheduler's token-budget planner
 interleaves with decode steps — and the whole mode is pinned
 TOKEN-IDENTICAL to monolithic prefill (which test_paged_kv.py pins
-against the slot layout and the full-context greedy oracle, so
+against the full-context greedy oracle, so
 chunked == monolithic composes into chunked == oracle; the headline
 test re-pins the oracle directly anyway) through real mid-prefill slot
 refill, prefix-cache warm partial-chunk hits, chunk == page boundary
@@ -38,7 +38,7 @@ from tests.serving.test_decode_engine import (
     make_scheduler,
     oracle,
 )
-from tests.serving.test_paged_kv import paged_engine, serve, slots_engine
+from tests.serving.test_paged_kv import paged_engine, serve
 
 pytestmark = pytest.mark.serving
 
@@ -49,6 +49,14 @@ pytestmark = pytest.mark.serving
 # accounting, planner floor, statusz, crash-mid-chunk) are slow-marked
 # and run UNFILTERED in the dedicated CI step — the same split as the
 # disagg suite.
+
+
+def monolithic_engine(module, params, state, *, name):
+    """The reference: prompts prefilled whole and cold (its own parity
+    with the full-context oracle is test_paged_kv.py's)."""
+    return paged_engine(
+        module, params, state, name=name, prefix_cache=False
+    )
 
 
 def chunked_engine(module, params, state, *, chunk=4, name="chunked",
@@ -191,7 +199,7 @@ def test_chunked_int8_token_identical(lm):
 def test_warm_prefix_hit_skips_cached_chunks(lm):
     """A warm admission starts its chunk cursor PAST the cached prefix
     (shared pages are never re-prefilled), CoW fires exactly at the
-    divergence, and streams stay identical to the slot layout. The
+    divergence, and streams stay identical to monolithic prefill. The
     12-token shared prefix with chunk=5 puts the cursor mid-chunk —
     the partial-chunk resume case."""
     module, params, state, _ = lm
@@ -203,7 +211,7 @@ def test_warm_prefix_hit_skips_cached_chunks(lm):
         )
         for _ in range(4)
     ] + [shared.copy()]  # an exact repeat of the shared prefix
-    ref = slots_engine(module, params, state, name="warmchunkref")
+    ref = monolithic_engine(module, params, state, name="warmchunkref")
     ref.warmup()
     want = serve(ref, ps, new_tokens=6)
 
@@ -226,10 +234,9 @@ def test_warm_prefix_hit_skips_cached_chunks(lm):
 def test_chunked_speculative_full_acceptance(lm, prompts):
     """Draft IS the teacher (acceptance ~1.0): the draft cache seeds
     on each FINAL chunk, then every window commits k+1 tokens —
-    token-identical to the unchunked speculative run and to the slot
-    layout."""
+    token-identical to the unchunked run."""
     module, params, state, _ = lm
-    ref = slots_engine(module, params, state, name="chunkspecref")
+    ref = monolithic_engine(module, params, state, name="chunkspecref")
     ref.warmup()
     want = serve(ref, prompts)
 
@@ -261,7 +268,7 @@ def test_chunked_speculative_low_acceptance(lm, prompts):
     d_module, d_params, d_state, _ = build_lm(
         num_layers=1, d_model=32, num_heads=4, seed=99
     )
-    ref = slots_engine(module, params, state, name="chunkrndref")
+    ref = monolithic_engine(module, params, state, name="chunkrndref")
     ref.warmup()
     want = serve(ref, prompts)
     teacher = chunked_engine(
@@ -339,16 +346,23 @@ def test_decode_never_stalls_behind_long_prompt(lm):
 # -- config seam -----------------------------------------------------------
 
 
-def test_chunking_requires_paged_layout(lm):
+def test_chunking_needs_no_layout_key(lm):
+    """Chunks append through the page table, and the pool is every
+    engine's layout: ``prefill_chunk_tokens`` alone binds, and warms the
+    extend grid it rides even with the prefix cache off."""
     module, params, state, _ = lm
     engine = DecodeEngine()
     configure(
         engine,
-        {"slots": 2, "seq_buckets": (8,), "prefill_chunk_tokens": 4},
-        name="chunk_slots_seam",
+        {
+            "slots": 2, "seq_buckets": (8,), "prefill_chunk_tokens": 4,
+            "prefix_cache": False,
+        },
+        name="chunk_default_seam",
     )
-    with pytest.raises(ValueError, match="kv_layout='paged'"):
-        engine.bind(module, params, state)
+    engine.bind(module, params, state)
+    engine.warmup()
+    assert any(key[0] == "extend" for key in engine._compiled_cache)
 
 
 def test_chunking_rejects_bad_sizes(lm):
@@ -357,7 +371,7 @@ def test_chunking_rejects_bad_sizes(lm):
     configure(
         engine,
         {
-            "slots": 2, "seq_buckets": (8,), "kv_layout": "paged",
+            "slots": 2, "seq_buckets": (8,),
             "kv_capacity": 64, "prefill_chunk_tokens": -1,
         },
         name="chunk_neg_seam",
@@ -368,7 +382,7 @@ def test_chunking_rejects_bad_sizes(lm):
     configure(
         wide,
         {
-            "slots": 2, "seq_buckets": (8, 16), "kv_layout": "paged",
+            "slots": 2, "seq_buckets": (8, 16),
             "kv_capacity": 64, "prefill_chunk_tokens": 32,
         },
         name="chunk_wide_seam",
@@ -404,12 +418,10 @@ def test_disagg_config_warn_degrades_chunking(caplog):
             "seq_len": 64, "vocab_size": 61,
             "engine.slots": 2, "engine.seq_buckets": (8,),
             "engine.prefill_buckets": (1,),
-            "engine.kv_layout": "paged",
             "engine.prefill_chunk_tokens": 4,
             "prefill_engine.slots": 2,
             "prefill_engine.seq_buckets": (8,),
             "prefill_engine.prefill_buckets": (1, 2),
-            "prefill_engine.kv_layout": "paged",
             "prefill_engine.prefill_chunk_tokens": 4,
             "requests": 0, "max_prompt": 6, "new_tokens": 2,
             "warmup": False, "verbose": False,
@@ -562,7 +574,7 @@ def test_crash_mid_chunk_releases_pages(lm):
     assert pool.leak_check() == 0
     assert sched.status()["chunked_prefill"]["pending_prefills"] == 0
     got = sched.generate(p)  # restarted scheduler
-    ref = slots_engine(module, params, state, name="crashchunkref")
+    ref = monolithic_engine(module, params, state, name="crashchunkref")
     ref.warmup()
     np.testing.assert_array_equal(
         got, make_scheduler(ref, max_new_tokens=6).generate(p)

@@ -6,7 +6,9 @@ The parity pin is the subsystem's load-bearing claim: every token the
 incremental cached-attention path emits must equal the token
 ``greedy_decode`` (full-context recompute, the oracle) emits from the
 same weights — including mid-stream slot refill (a new occupant's
-prefill overwrites a retired stream's rows) and the capacity boundary.
+prefill lands in pages a retired stream gave back) and the capacity
+boundary. The engines here run a worst-case pool with the prefix cache
+off (``make_engine``): prefix reuse is ``test_paged_kv.py``'s.
 All CPU, thread-free (synchronous scheduler).
 """
 
@@ -18,9 +20,8 @@ from zookeeper_tpu.models.transformer import TransformerLM, greedy_decode
 from zookeeper_tpu.serving.decode import (
     DecodeEngine,
     DecodeScheduler,
-    allocate_kv_cache,
-    kv_cache_bytes,
-    pages_in_use,
+    allocate_page_pool,
+    page_pool_bytes,
 )
 
 pytestmark = pytest.mark.serving
@@ -58,6 +59,7 @@ def make_engine(module, params, state, *, slots=3, seq_buckets=(8, 16),
             "slots": slots,
             "seq_buckets": tuple(seq_buckets),
             "kv_capacity": kv_capacity,
+            "prefix_cache": False,
             **conf,
         },
         name="engine",
@@ -188,7 +190,8 @@ def test_grouped_prefill_parity(lm):
         module, params, state, slots=4, prefill_buckets=(2, 4)
     )
     warm = engine.warmup()
-    assert warm == 2 * 2 + 1  # (prefill buckets x seq buckets) + decode
+    # (prefill buckets x seq buckets) + decode + the page copy
+    assert warm == 2 * 2 + 2
     sched = make_scheduler(engine)
     rng = np.random.default_rng(2)
     prompts = [
@@ -239,17 +242,31 @@ def test_cached_attention_matches_reference_row():
 # -- cache state ----------------------------------------------------------
 
 
-def test_cache_allocation_and_accounting():
-    cache = allocate_kv_cache(2, 3, 16, 4, 8, np.float32)
+def test_cache_allocation_and_accounting(lm):
+    # 12 pages of 4 rows; a row folds 4 heads of 8 into one 128-lane
+    # register.
+    cache = allocate_page_pool(2, 12, 4, 4, 8, np.float32)
     assert len(cache) == 2
-    assert cache[0]["k"].shape == (3, 16, 4, 8)
-    assert kv_cache_bytes(2, 3, 16, 4, 8, 4) == 2 * 2 * 3 * 16 * 4 * 8 * 4
-    # ceil(5/4) + ceil(8/4) + (0 skipped)
-    assert pages_in_use([5, 8, 0], 4) == 2 + 2
-    with pytest.raises(ValueError, match="slots >= 1"):
-        allocate_kv_cache(2, 0, 16, 4, 8, np.float32)
-    with pytest.raises(ValueError, match="page_size"):
-        pages_in_use([1], 0)
+    assert cache[0]["k"].shape == (12, 1, 4, 128)
+    assert page_pool_bytes(2, 12, 4, 4, 8, 4) == 2 * 2 * 12 * 4 * 128 * 4
+    with pytest.raises(ValueError, match="num_pages >= 1"):
+        allocate_page_pool(2, 0, 4, 4, 8, np.float32)
+    # The engine's accounting is the allocator's: the worst-case pool
+    # (slots x capacity / page_size), pages counted as handed out.
+    module, params, state, _ = lm
+    engine = make_engine(
+        module, params, state, slots=3, kv_capacity=16, page_size=4,
+        seq_buckets=(8,),
+    )
+    assert engine.page_pool.num_pages == 3 * 4
+    assert engine.kv_cache_nbytes == page_pool_bytes(2, 12, 4, 4, 8, 4)
+    assert engine.kv_pages_in_use() == 0
+    # ceil(5/4) + ceil(8/4)
+    assert engine.admit_slot(0, np.arange(1, 6, dtype=np.int32))
+    assert engine.admit_slot(1, np.arange(1, 9, dtype=np.int32))
+    assert engine.kv_pages_in_use() == 2 + 2
+    engine.release_slot(0)
+    assert engine.kv_pages_in_use() == 2
 
 
 def test_capacity_page_alignment(lm):
@@ -283,6 +300,8 @@ def test_bind_validation(lm):
     expect("kv_capacity", kv_capacity=0)
     expect("exceeds the KV capacity", seq_buckets=(32,), kv_capacity=16)
     expect("positional table", seq_buckets=(128,), kv_capacity=256)
+    expect("only KV layout", kv_layout="slots")
+    expect("decode_attention", decode_attention="module")
 
     class NotALM:
         pass
@@ -349,7 +368,8 @@ def test_swap_weights_changes_tokens_without_recompiling(lm):
 
 @pytest.mark.slow
 def test_decode_parity_on_dp_tp_mesh():
-    """KV cache sharded (slots on data, heads on model) on a 2x2 mesh:
+    """Page pool sharded (heads on model; per-slot operands on data) on a
+    2x4 mesh:
     token-exact vs the single-device oracle, zero post-warmup
     compiles. The dryrun_multichip leg re-certifies this under the
     clean-SPMD harness."""
@@ -386,9 +406,9 @@ def test_decode_parity_on_dp_tp_mesh():
 
 @pytest.mark.slow
 def test_decode_kernel_parity_on_dp_tp_mesh():
-    """The PALLAS paged decode kernel under the sharded path: slots on
+    """The PALLAS pool decode kernel under the sharded path: slots on
     'data', heads on 'model' via the shard_map-composed
-    ``sharded_paged_decode_attention`` (docs/DESIGN.md §17) — still
+    ``sharded_pool_paged_decode_attention`` (docs/DESIGN.md §17) — still
     token-exact vs the full-context oracle, zero post-warmup compiles.
     The dryrun_multichip decode leg re-certifies this with the SPMD log
     asserted clean."""

@@ -1,4 +1,4 @@
-"""Engine-level paged-decode-kernel certification (docs/DESIGN.md §17):
+"""Engine-level pool-decode-kernel certification (docs/DESIGN.md §17):
 the ``decode_attention="pallas"`` decode_step program must be
 TOKEN-EXACT against the reference flavor through the real
 continuous-batching path (mid-stream slot refill included), degrade to
@@ -39,6 +39,7 @@ def kernel_engine(module, params, state, *, flavor, slots=2,
             "seq_buckets": (8, 16),
             "kv_capacity": kv_capacity,
             "decode_attention": flavor,
+            "prefix_cache": False,
             **conf,
         },
         name=f"kengine_{flavor}",
@@ -123,33 +124,45 @@ def test_unsupported_geometry_auto_degrades_explicit_pallas_raises(
 
 
 def test_module_level_override_logits_pinned(lm):
-    """decode_step's ``attention_override`` seam at the module level:
-    kernel logits within documented-ULP of the reference trace and
-    argmax token-exact (the tolerance contract of
-    tests/ops/test_paged_decode_attention.py, composed through the
-    whole block stack)."""
+    """decode_step_paged's ``attention_override`` seam at the module
+    level: kernel logits within documented-ULP of the reference trace
+    and argmax token-exact (the tolerance contract of
+    tests/ops/test_pool_attention.py, composed through the whole block
+    stack)."""
     import jax.numpy as jnp
 
-    from zookeeper_tpu.ops import cached_attention, paged_decode_attention
+    from zookeeper_tpu.ops import (
+        pool_decode_attention,
+        pool_paged_decode_attention,
+    )
+    from zookeeper_tpu.serving.decode import allocate_page_pool
 
     module, params, state, variables = lm
-    slots, cap = 2, 64
-    cache = tuple(
-        {
-            "k": jnp.zeros((slots, cap, 4, 8), jnp.float32),
-            "v": jnp.zeros((slots, cap, 4, 8), jnp.float32),
-        }
-        for _ in range(module.num_layers)
+    slots, page_size, max_pages = 2, 16, 4
+    cache = allocate_page_pool(
+        module.num_layers, slots * max_pages, page_size, 4, 8, jnp.float32
     )
+    # Slot 1's pages first, slot 0's after: any table serves.
+    table = jnp.asarray([[4, 5, 6, 7], [0, 1, 2, 3]], jnp.int32)
     tokens = jnp.asarray([3, 41], jnp.int32)
     lengths = jnp.asarray([0, 17], jnp.int32)
     ref_logits, ref_cache = module.apply(
-        variables, tokens, lengths, cache, method="decode_step",
-        attention_override=cached_attention,
+        variables, tokens, lengths, cache, table,
+        method="decode_step_paged",
+        attention_override=pool_decode_attention,
+    )
+    # No override is the reference.
+    default_logits, _ = module.apply(
+        variables, tokens, lengths, cache, table,
+        method="decode_step_paged",
+    )
+    np.testing.assert_array_equal(
+        np.asarray(default_logits), np.asarray(ref_logits)
     )
     pal_logits, pal_cache = module.apply(
-        variables, tokens, lengths, cache, method="decode_step",
-        attention_override=paged_decode_attention,
+        variables, tokens, lengths, cache, table,
+        method="decode_step_paged",
+        attention_override=pool_paged_decode_attention,
     )
     np.testing.assert_allclose(
         np.asarray(pal_logits), np.asarray(ref_logits), atol=1e-4, rtol=1e-5

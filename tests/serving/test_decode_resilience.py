@@ -190,8 +190,8 @@ def test_dispatch_failure_resets_cache_and_serves_resubmits(lm):
     key = ("decode_step", engine._partitioner.mesh)
     real = engine._compiled_cache[key]
 
-    def dying(variables_, cache, tokens, lengths):
-        real(variables_, cache, tokens, lengths)  # donation happens
+    def dying(variables_, cache, *operands):
+        real(variables_, cache, *operands)  # donation happens
         raise RuntimeError("injected dispatch-time device failure")
 
     engine._compiled_cache[key] = dying

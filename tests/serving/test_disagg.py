@@ -54,7 +54,6 @@ def role_engine(module, params, state, *, name, slots=2,
             "slots": slots,
             "seq_buckets": tuple(seq_buckets),
             "kv_capacity": kv_capacity,
-            "kv_layout": "paged",
             **conf,
         },
         name=f"dg_{name}",
@@ -341,17 +340,17 @@ def test_close_fails_parked_and_lane_streams_without_leaks(lm):
 def test_transfer_bind_rejects_bad_geometry(lm):
     module, params, state, _ = lm
     paged = role_engine(module, params, state, name="v_paged")
-    ring = DecodeEngine()
-    configure(
-        ring,
-        {"slots": 2, "seq_buckets": (8, 16), "kv_capacity": 64},
-        name="dg_v_ring",
-    )
-    ring.bind(module, params, state)
     t = PageTransfer()
     configure(t, {}, name="dg_v_t")
-    with pytest.raises(ValueError, match="paged"):
-        t.bind(ring, paged)
+    # An engine configured with no layout key is a pool engine: a role.
+    plain = DecodeEngine()
+    configure(
+        plain,
+        {"slots": 2, "seq_buckets": (8, 16), "kv_capacity": 64},
+        name="dg_v_plain",
+    )
+    plain.bind(module, params, state)
+    t.bind(plain, paged)
     other = role_engine(
         module, params, state, name="v_ps", page_size=8
     )

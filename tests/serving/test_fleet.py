@@ -441,7 +441,6 @@ FLEET_CONF = {
     "seq_len": 64,
     "vocab_size": 61,
     "seed": 0,
-    "engine.kv_layout": "paged",
     "engine.page_size": 8,
     "engine.slots": 2,
     "engine.seq_buckets": (16, 64),

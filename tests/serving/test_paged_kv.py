@@ -1,11 +1,9 @@
-"""True-paged-KV certification (docs/DESIGN.md §20): the
-``kv_layout="paged"`` engine — shared device page pool, per-slot page
-tables as runtime operands, radix prefix cache with copy-on-write,
-int8 quantization — pinned token-identical to the slot layout (whose
-own parity against the full-context greedy oracle is pinned by
-tests/serving/test_decode_engine.py, so paged == slots composes into
-paged == oracle; the headline test re-pins the oracle directly anyway)
-through real slot refill, warm-prefix admission, divergence CoW,
+"""Page-pool certification (docs/DESIGN.md §20): the engine's one KV
+layout — shared device page pool, per-slot page tables as runtime
+operands, radix prefix cache with copy-on-write, int8 quantization —
+pinned token-identical to the full-context greedy oracle
+(``greedy_decode``; ``want``, computed once a module) through real slot
+refill, warm-prefix admission, divergence CoW,
 LRU eviction under pool pressure, pool exhaustion, and the chaos legs
 (crash with a live pool, staged hot-swap invalidation). All CPU,
 synchronous scheduler.
@@ -43,21 +41,9 @@ def paged_engine(module, params, state, *, slots=2, seq_buckets=(8, 16),
             "slots": slots,
             "seq_buckets": tuple(seq_buckets),
             "kv_capacity": kv_capacity,
-            "kv_layout": "paged",
             **conf,
         },
         name=f"pengine_{name}",
-    )
-    engine.bind(module, params, state)
-    return engine
-
-
-def slots_engine(module, params, state, *, name="slots", **conf):
-    engine = DecodeEngine()
-    configure(
-        engine,
-        {"slots": 2, "seq_buckets": (8, 16), "kv_capacity": 64, **conf},
-        name=f"sengine_{name}",
     )
     engine.bind(module, params, state)
     return engine
@@ -89,29 +75,26 @@ def prompts():
     ]
 
 
+@pytest.fixture(scope="module")
+def want(lm, prompts):
+    """The full-context greedy oracle's 8 tokens after each prompt."""
+    module, _, _, variables = lm
+    return [oracle(module, variables, p, 8) for p in prompts]
+
+
 # -- the parity certification ---------------------------------------------
 
 
-def test_paged_token_identical_to_slots_and_oracle_with_refill(
-    lm, prompts
-):
+def test_paged_token_identical_to_oracle_with_refill(lm, prompts, want):
     module, params, state, variables = lm
-    ref = slots_engine(module, params, state, name="parity")
     pag = paged_engine(module, params, state, name="parity")
-    ref_warm, pag_warm = ref.warmup(), pag.warmup()
-    ref_out = serve(ref, prompts)
+    pag_warm = pag.warmup()
     pag_out = serve(pag, prompts)
-    for a, b in zip(ref_out, pag_out):
+    # The acceptance pin, including the streams that rode recycled
+    # pages.
+    for a, b in zip(want, pag_out):
         np.testing.assert_array_equal(a, b)
-    # And directly against the full-context greedy oracle (the
-    # acceptance pin), including the streams that rode recycled pages.
-    for p, out in zip(prompts[:3], pag_out[:3]):
-        np.testing.assert_array_equal(
-            out, oracle(module, variables, p, out.shape[0])
-        )
-    # Refill happened (7 requests, 2 slots) with zero recompiles on
-    # either layout.
-    assert ref.compile_count == ref_warm
+    # Refill happened (7 requests, 2 slots) with zero recompiles.
     assert pag.compile_count == pag_warm
     assert pag.recompiles_detected == 0
 
@@ -149,28 +132,22 @@ def test_poisoned_free_page_equality(lm, prompts):
         np.testing.assert_array_equal(a, b)
 
 
-def test_paged_capacity_truncation_matches_slots(lm):
+def test_paged_capacity_truncation_matches_oracle(lm):
     """The truncate-at-EXACTLY-token_limit contract over page
     boundaries: a stream that exhausts its capacity fills its LAST
-    page to the final row and stops, identical to the slot layout."""
-    module, params, state, _ = lm
+    page to the final row and stops, every token the oracle's."""
+    module, params, state, variables = lm
     pag = paged_engine(
         module, params, state, name="cap", kv_capacity=16,
         page_size=4, slots=1,
     )
     pag.warmup()
-    ref = slots_engine(
-        module, params, state, name="capref", kv_capacity=16
-    )
-    ref.warmup()
     p = np.arange(1, 9, dtype=np.int32)
     sched = make_scheduler(pag, max_new_tokens=32)
     stream = sched.submit(p)
     sched.drain()
     got = stream.result()
-    want_stream = make_scheduler(ref, max_new_tokens=32).submit(p)
-    want_stream._scheduler.drain()
-    np.testing.assert_array_equal(got, want_stream.result())
+    np.testing.assert_array_equal(got, oracle(module, variables, p, 8))
     assert stream.finish_reason == "capacity"
     assert got.shape[0] == 16 - 8  # total EXACTLY token_limit
     assert pag.page_pool.leak_check() == 0
@@ -183,8 +160,8 @@ def test_warm_prefix_hit_cow_and_parity(lm):
     """Warm repeats and a mid-page divergence: the second admission of
     a shared prefix reuses cached pages (hit rate > 0), copies exactly
     the divergence page (CoW), and every stream stays token-identical
-    to the slot layout (which never shares anything)."""
-    module, params, state, _ = lm
+    to the oracle (which never shares anything)."""
+    module, params, state, variables = lm
     rng = np.random.default_rng(11)
     shared = rng.integers(1, VOCAB, size=12).astype(np.int32)
     ps = [
@@ -193,9 +170,7 @@ def test_warm_prefix_hit_cow_and_parity(lm):
         )
         for _ in range(4)
     ] + [shared.copy()]  # an exact repeat of the shared prefix
-    ref = slots_engine(module, params, state, name="warmref")
-    ref.warmup()
-    want = serve(ref, ps, new_tokens=6)
+    want = [oracle(module, variables, p, 6) for p in ps]
 
     pag = paged_engine(module, params, state, name="warm")
     warm = pag.warmup()
@@ -210,17 +185,13 @@ def test_warm_prefix_hit_cow_and_parity(lm):
     assert pool.leak_check() == 0
 
 
-def test_prefix_cache_off_serves_cold(lm, prompts):
+def test_prefix_cache_off_serves_cold(lm, prompts, want):
     module, params, state, _ = lm
     pag = paged_engine(
         module, params, state, name="nocache", prefix_cache=False
     )
     pag.warmup()
-    ref = slots_engine(module, params, state, name="nocacheref")
-    ref.warmup()
-    a = serve(pag, prompts[:4])
-    b = serve(ref, prompts[:4])
-    for x, y in zip(a, b):
+    for x, y in zip(serve(pag, prompts[:4]), want[:4]):
         np.testing.assert_array_equal(x, y)
     assert pag.page_pool.prefix is None
     assert pag.pool_status()["used_pages"] == 0  # all released cold
@@ -229,8 +200,8 @@ def test_prefix_cache_off_serves_cold(lm, prompts):
 def test_prefix_eviction_under_pool_pressure(lm):
     """A pool too small to cache everything: LRU eviction frees
     refcount-1 nodes, admissions keep serving, tokens stay identical
-    to the slot layout."""
-    module, params, state, _ = lm
+    to the oracle."""
+    module, params, state, variables = lm
     rng = np.random.default_rng(13)
     # 6 distinct 14-token prompts at page_size 16 = one page each;
     # pool of 3 pages forces eviction after every admission.
@@ -242,14 +213,8 @@ def test_prefix_eviction_under_pool_pressure(lm):
         pool_pages=3, page_size=16, kv_capacity=48,
     )
     pag.warmup()
-    ref = slots_engine(
-        module, params, state, name="evictref", kv_capacity=48
-    )
-    ref.warmup()
-    a = serve(pag, ps, new_tokens=4)
-    b = serve(ref, ps, new_tokens=4)
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(x, y)
+    for p, got in zip(ps, serve(pag, ps, new_tokens=4)):
+        np.testing.assert_array_equal(got, oracle(module, variables, p, 4))
     assert pag.page_pool.prefix.evicted_pages > 0
     assert pag.page_pool.leak_check() == 0
 
@@ -261,7 +226,7 @@ def test_pool_serves_more_than_its_worst_case_and_requeues(lm):
     """The overcommit claim: a pool provisioned BELOW slots × capacity
     serves a workload whose PER-SLOT worst case would not fit, by
     requeueing admissions until finishing streams release pages."""
-    module, params, state, _ = lm
+    module, params, state, variables = lm
     rng = np.random.default_rng(17)
     ps = [
         rng.integers(1, VOCAB, size=6).astype(np.int32) for _ in range(6)
@@ -275,12 +240,8 @@ def test_pool_serves_more_than_its_worst_case_and_requeues(lm):
         prefix_cache=False,
     )
     pag.warmup()
-    ref = slots_engine(module, params, state, name="overcommitref")
-    ref.warmup()
-    a = serve(pag, ps, new_tokens=4)
-    b = serve(ref, ps, new_tokens=4)
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(x, y)
+    for p, got in zip(ps, serve(pag, ps, new_tokens=4)):
+        np.testing.assert_array_equal(got, oracle(module, variables, p, 4))
     assert pag.page_pool.leak_check() == 0
 
 
@@ -349,16 +310,23 @@ def test_int8_argmax_token_exact_sweep(lm):
             np.testing.assert_array_equal(x, y)
 
 
-def test_int8_requires_paged_layout(lm):
+def test_int8_needs_no_layout_key(lm):
+    """Quantization lives with the page pool, and the pool is the
+    layout of every engine: ``kv_quant`` alone binds int8 rows beside
+    their scale pages."""
     module, params, state, _ = lm
     engine = DecodeEngine()
     configure(
         engine,
         {"slots": 2, "seq_buckets": (8,), "kv_quant": "int8"},
-        name="int8_slots",
+        name="int8_default",
     )
-    with pytest.raises(ValueError, match="kv_layout='paged'"):
-        engine.bind(module, params, state)
+    engine.bind(module, params, state)
+    layer = engine._cache[0]
+    assert layer["k"].dtype == np.int8
+    assert set(layer) == {"k", "v", "k_scale", "v_scale"}
+    with pytest.raises(ValueError, match="kv_quant"):
+        paged_engine(module, params, state, name="q4", kv_quant="int4")
 
 
 # -- accounting / observability --------------------------------------------
@@ -380,7 +348,7 @@ def test_pool_accounting_gauges_and_statusz(lm, prompts):
     pool = pag.page_pool
     # Real allocator counts, not the length estimate: after the drain
     # only prefix-cache-retained pages remain in use.
-    assert pag.kv_pages_in_use([]) == pool.used_pages
+    assert pag.kv_pages_in_use() == pool.used_pages
     gauges = metrics._obs()["gauges"]
     assert gauges["kv_pool_free_pages"].value == pool.free_pages
     assert (
@@ -404,32 +372,41 @@ def test_pool_accounting_gauges_and_statusz(lm, prompts):
     assert "zk_prefix_cache_hit_rate" in body
 
 
-def test_slots_layout_reports_no_pool(lm):
+def test_one_layout_every_engine_reports_its_pool(lm):
+    """``kv_layout`` is a constant: an engine configured without the
+    key serves from a pool and says so; the removed ``"slots"`` value
+    is refused at bind by name."""
     module, params, state, _ = lm
-    ref = slots_engine(module, params, state, name="nopool")
-    ref.warmup()
-    assert not ref.paged
-    assert ref.page_pool is None
-    assert ref.pool_status() is None
-    sched = make_scheduler(ref, max_new_tokens=2)
+    engine = DecodeEngine()
+    configure(
+        engine,
+        {"slots": 2, "seq_buckets": (8,), "kv_capacity": 64},
+        name="default_layout",
+    )
+    engine.bind(module, params, state)
+    engine.warmup()
+    assert engine.page_pool.num_pages == 2 * 64 // 16  # the worst case
+    sched = make_scheduler(engine, max_new_tokens=2)
     sched.generate(np.arange(1, 5, dtype=np.int32))
-    assert sched.status()["kv_layout"] == "slots"
-    assert "kv_pool" not in sched.status()
+    status = sched.status()
+    assert status["kv_layout"] == "paged"
+    assert status["kv_pool"] == engine.pool_status()
+    with pytest.raises(ValueError, match="only KV layout.*PR 29"):
+        paged_engine(module, params, state, name="gone", kv_layout="slots")
 
 
 # -- speculative over pages ------------------------------------------------
 
 
-def test_speculative_paged_token_identical_high_acceptance(lm, prompts):
-    """The speculative window append/rollback over PAGE BOUNDARIES:
-    teacher on the paged layout, draft = the teacher itself (acceptance
-    1.0 — every window commits k+1 tokens through the page table),
-    certified token-identical to plain paged and to the slot layout."""
+def test_speculative_paged_token_identical_high_acceptance(
+    lm, prompts, want
+):
+    """The speculative window append/rollback over PAGE BOUNDARIES,
+    teacher and draft each on a pool of its own, the teacher's with
+    the prefix cache on: draft = the teacher itself (acceptance 1.0 —
+    every window commits k+1 tokens through the page table), certified
+    token-identical to the oracle; neither pool leaks a page."""
     module, params, state, _ = lm
-    ref = slots_engine(module, params, state, name="specref")
-    ref.warmup()
-    want = serve(ref, prompts)
-
     teacher = paged_engine(module, params, state, name="specteacher")
     teacher.warmup()
     spec = SpeculativeDecoding()
@@ -445,10 +422,14 @@ def test_speculative_paged_token_identical_high_acceptance(lm, prompts):
         np.testing.assert_array_equal(a, b)
     assert spec.acceptance_rate > 0.9  # draft IS the teacher
     assert teacher.page_pool.leak_check() == 0
+    assert spec.draft_engine.page_pool.used_pages == 0
+    assert spec.draft_engine.page_pool.leak_check() == 0
 
 
 @pytest.mark.slow
-def test_speculative_paged_token_identical_random_draft(lm, prompts):
+def test_speculative_paged_token_identical_random_draft(
+    lm, prompts, want
+):
     """The pure-rejection extreme: an independently-initialized draft
     disagrees almost always, so every window exercises rollback-by-
     length over allocated-but-rejected page rows."""
@@ -456,9 +437,6 @@ def test_speculative_paged_token_identical_random_draft(lm, prompts):
     d_module, d_params, d_state, _ = build_lm(
         num_layers=1, d_model=32, num_heads=4, seed=99
     )
-    ref = slots_engine(module, params, state, name="specrndref")
-    ref.warmup()
-    want = serve(ref, prompts)
     teacher = paged_engine(module, params, state, name="specrnd")
     teacher.warmup()
     spec = SpeculativeDecoding()
@@ -483,7 +461,7 @@ def test_crash_with_live_pool_resets_cleanly(lm, prompts):
     no page leaks, the prefix trie holds no stale references, and a
     resubmit on the restarted scheduler serves token-identically —
     the ``_reset_cache``-equivalent pool reallocation leg."""
-    module, params, state, _ = lm
+    module, params, state, variables = lm
     pag = paged_engine(module, params, state, name="crash")
     warm = pag.warmup()
     sched = make_scheduler(pag, max_new_tokens=6)
@@ -495,11 +473,7 @@ def test_crash_with_live_pool_resets_cleanly(lm, prompts):
     pool = pag.page_pool
     assert pool.leak_check() == 0
     got = sched.generate(p)  # restarted scheduler
-    ref = slots_engine(module, params, state, name="crashref")
-    ref.warmup()
-    np.testing.assert_array_equal(
-        got, make_scheduler(ref, max_new_tokens=6).generate(p)
-    )
+    np.testing.assert_array_equal(got, oracle(module, variables, p, 6))
     assert pag.compile_count == warm
     assert pool.leak_check() == 0
 
@@ -511,7 +485,7 @@ def test_dispatch_failure_resets_pool_and_trie(lm):
     reset the HOST allocator together — refcounts zeroed, trie
     dropped (its nodes indexed bytes that no longer exist), zero
     leaked pages — and the restarted scheduler serves resubmits."""
-    module, params, state, _ = lm
+    module, params, state, variables = lm
     pag = paged_engine(module, params, state, name="reset")
     pag.warmup()
     sched = make_scheduler(pag, max_new_tokens=4)
@@ -526,13 +500,9 @@ def test_dispatch_failure_resets_pool_and_trie(lm):
     assert pool.prefix.nodes == 0
     assert pool.prefix.invalidations == invalidations_before + 1
     assert pool.leak_check() == 0
-    out = sched.generate(np.arange(1, 10, dtype=np.int32))
-    ref = slots_engine(module, params, state, name="resetref")
-    ref.warmup()
+    p = np.arange(1, 10, dtype=np.int32)
     np.testing.assert_array_equal(
-        out, make_scheduler(ref, max_new_tokens=4).generate(
-            np.arange(1, 10, dtype=np.int32)
-        )
+        sched.generate(p), oracle(module, variables, p, 4)
     )
 
 
@@ -601,7 +571,6 @@ def test_paged_dp_tp_mesh_leg_token_identical(lm, prompts):
             "slots": 2,
             "seq_buckets": (8, 16),
             "kv_capacity": 64,
-            "kv_layout": "paged",
         },
         name="pengine_mesh",
     )

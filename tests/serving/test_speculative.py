@@ -274,33 +274,45 @@ def test_mixed_accept_lengths_without_drain(lm, random_draft):
 # -- module-level units ----------------------------------------------------
 
 
+def pool_cache(module, slots, page_size, max_pages):
+    """A zeroed pool of ``slots x max_pages`` pages and the table that
+    gives slot ``s`` the pages ``s * max_pages ...`` in order."""
+    import jax.numpy as jnp
+
+    from zookeeper_tpu.serving.decode import allocate_page_pool
+
+    cache = allocate_page_pool(
+        int(module.num_layers), slots * max_pages, page_size,
+        int(module.num_heads), int(module.head_dim), jnp.float32,
+    )
+    table = jnp.arange(slots * max_pages, dtype=jnp.int32).reshape(
+        slots, max_pages
+    )
+    return cache, table
+
+
 def test_multi_token_append_and_rollback_module_unit(lm):
-    """``decode_verify`` vs the same window fed token-by-token through
-    ``decode_step``: argmax-identical logits at every position and
-    ULP-identical cache rows; then ROLLBACK — committing only a prefix
-    (advancing lengths short of the window) and decoding onward equals
-    a run that never wrote the rejected rows, i.e. garbage rows beyond
-    length are invisible (the §17 poisoned-row contract, exercised
-    through the append path)."""
+    """``decode_verify_paged`` vs the same window fed token-by-token
+    through ``decode_step_paged``: argmax-identical logits at every
+    position and ULP-identical cache rows; then ROLLBACK — committing
+    only a prefix (advancing lengths short of the window) and decoding
+    onward equals a run that never wrote the rejected rows, i.e.
+    garbage rows beyond length are invisible (the §17 poisoned-row
+    contract, exercised through the append path)."""
     import jax.numpy as jnp
 
     module, params, state, variables = lm
-    b, cap, layers = 2, 32, int(module.num_layers)
-    heads, head_dim = int(module.num_heads), int(module.head_dim)
-    shape = (b, cap, heads, head_dim)
-    cache = tuple(
-        {"k": jnp.zeros(shape), "v": jnp.zeros(shape)}
-        for _ in range(layers)
-    )
+    b, ps, max_pages = 2, 8, 4
+    cache, table = pool_cache(module, b, ps, max_pages)
     rng = np.random.default_rng(4)
     toks = rng.integers(1, VOCAB, size=(b, 12)).astype(np.int32)
-    L, w = 5, 4
+    L, w = 5, 4  # the window crosses the first page boundary
 
     def step(c, j):
         lens = jnp.full((b,), j, jnp.int32)
         return module.apply(
-            variables, jnp.asarray(toks[:, j]), lens, c,
-            method="decode_step",
+            variables, jnp.asarray(toks[:, j]), lens, c, table,
+            method="decode_step_paged",
         )
 
     c = cache
@@ -317,7 +329,8 @@ def test_multi_token_append_and_rollback_module_unit(lm):
         jnp.asarray(toks[:, L : L + w]),
         jnp.full((b,), L, jnp.int32),
         c,
-        method="decode_verify",
+        table,
+        method="decode_verify_paged",
     )
     assert np.array_equal(
         np.argmax(np.asarray(v_logits), -1),
@@ -326,17 +339,26 @@ def test_multi_token_append_and_rollback_module_unit(lm):
     np.testing.assert_allclose(
         np.asarray(v_logits), np.stack(seq_logits, 1), rtol=0, atol=2e-6
     )
+    for seq_layer, ver_layer in zip(c_seq, c_ver):
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(ver_layer[name]), np.asarray(seq_layer[name]),
+                rtol=0, atol=2e-6,
+            )
     # Rollback-by-length as an EQUALITY (the §17 poisoned-row idiom):
     # accept only the first window token (lengths advance to L+1) and
     # poison every row past it with +-1e9 garbage — the next
-    # decode_step must be BIT-identical to the step over the
+    # decode step must be BIT-identical to the step over the
     # un-poisoned rolled-back cache, i.e. rejected rows have exactly
     # zero influence once lengths never advanced over them.
     lg_rolled, _ = step(c_ver, L + 1)
+    # Row j of slot s lives at (page s * max_pages + j // ps, j % ps).
+    dead = np.arange(max_pages * ps) >= L + 2
+    dead = np.tile(dead.reshape(max_pages, 1, ps, 1), (b, 1, 1, 1))
     poisoned = tuple(
         {
-            "k": layer["k"].at[:, L + 2 :].set(1e9),
-            "v": layer["v"].at[:, L + 2 :].set(-1e9),
+            "k": jnp.where(dead, 1e9, layer["k"]),
+            "v": jnp.where(dead, -1e9, layer["v"]),
         }
         for layer in c_ver
     )
@@ -388,17 +410,30 @@ def test_verify_attention_width_one_is_cached_attention():
         )
 
 
-def test_append_kv_rows_clamps_and_writes():
+def test_window_write_crosses_pages_and_drops_past_the_table(lm):
+    """The multi-row append through the page table: a window that
+    crosses a page boundary lands in two pages, and the rows of a
+    window that runs past a slot's table write NOWHERE (idle-slot
+    safety: no other slot's page is touched)."""
     import jax.numpy as jnp
 
-    from zookeeper_tpu.serving.decode import append_kv_rows
-
-    buf = jnp.zeros((2, 8, 1, 2))
-    rows = jnp.ones((2, 3, 1, 2))
-    out = np.asarray(append_kv_rows(buf, rows, jnp.asarray([2, 99])))
-    assert out[0, 2:5].sum() == 3 * 2 and out[0, :2].sum() == 0
-    # Out-of-range start clamps to capacity - w (idle-slot safety).
-    assert out[1, 5:8].sum() == 3 * 2 and out[1, :5].sum() == 0
+    module, params, state, variables = lm
+    b, ps, max_pages, w = 2, 8, 2, 3
+    cache, table = pool_cache(module, b, ps, max_pages)
+    # Slot 0 writes rows 6, 7, 8 (pages 0 and 1); slot 1 starts at its
+    # last row, 15: rows 16 and 17 lie past its two pages.
+    lengths = jnp.asarray([6, 15], jnp.int32)
+    _, out = module.apply(
+        variables, jnp.ones((b, w), jnp.int32), lengths, cache, table,
+        method="decode_verify_paged",
+    )
+    k = np.asarray(out[0]["k"])[:, 0]  # [pages, page_size, row_width]
+    written = np.abs(k).sum(axis=-1) > 0
+    want = np.zeros_like(written)
+    want[0, 6:8] = True
+    want[1, 0] = True
+    want[3, 7] = True
+    np.testing.assert_array_equal(written, want)
 
 
 # -- engine/config validation ----------------------------------------------
@@ -445,6 +480,67 @@ def test_spec_bind_validation(lm, random_draft):
 
     with pytest.raises(RuntimeError, match="not bound"):
         SpeculativeDecoding().status()
+
+    # A draft with window layers: its slot takes the whole share of ONE
+    # page group.
+    windowed = TransformerLM()
+    configure(
+        windowed,
+        {
+            "num_layers": 2, "d_model": 32, "num_heads": 4,
+            "max_seq_len": 64, "attention": "dense", "positions": "rope",
+            "layer_types": ("window", "full"), "window": 8,
+        },
+        name="windowed_draft",
+    )
+    w_module = windowed.build((64,), VOCAB)
+    w_params, w_state = windowed.initialize(w_module, (64,), seed=0)
+    spec4 = SpeculativeDecoding()
+    configure(spec4, {"enabled": True}, name="windowed_draft_spec")
+    with pytest.raises(ValueError, match="window layers"):
+        spec4.bind(engine, w_module, w_params, w_state)
+
+
+def test_draft_pool_full_share_a_slot_and_leak_free(lm, random_draft):
+    """The draft engine is a pool engine provisioned for the worst case
+    with the prefix cache off: a slot takes its FULL share of pages at
+    its draft prefill and hands it back when its stream retires, so no
+    draft dispatch can wait on a page; after a run with refills (more
+    requests than slots, some shed mid-window by their budgets) neither
+    pool holds or leaks a page."""
+    module, params, state, variables = lm
+    engine = make_engine(module, params, state, slots=2)
+    engine.warmup()
+    spec = make_spec(engine, random_draft, k=3)
+    draft = spec.draft_engine
+    pool = draft.page_pool
+    share = pool.max_pages_per_slot
+    assert not draft.prefix_cache
+    assert pool.num_pages == 2 * share
+    sched, _ = make_sched(engine, spec)
+    rng = np.random.default_rng(9)
+    prompts = [
+        rng.integers(1, VOCAB, size=int(rng.integers(1, 17))).astype(np.int32)
+        for _ in range(7)
+    ]
+    budgets = [8, 3, 11, 1, 6, 9, 2]
+    streams = [
+        sched.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)
+    ]
+    sched._step_once()
+    # Both slots admitted: each owns every page of its share, whatever
+    # its prompt's length.
+    assert sched.active_slots == 2
+    assert pool.counts.tolist() == [share, share]
+    assert pool.free_pages == 0
+    sched.drain()
+    for p, b, s in zip(prompts, budgets, streams):
+        np.testing.assert_array_equal(
+            s.result(), oracle(module, variables, p, b)
+        )
+    for engine_pool in (pool, engine.page_pool):
+        assert engine_pool.used_pages == 0
+        assert engine_pool.leak_check() == 0
 
 
 def test_verify_width_validation(lm):
@@ -580,7 +676,7 @@ def test_lm_serving_config_speculative_end_to_end(tmp_path):
 
 @pytest.mark.slow
 def test_speculative_parity_on_dp_tp_mesh():
-    """Both caches sharded through the same decode_cache_sharding seam
+    """Both pools sharded through the same page_pool_sharding seam
     (slots on 'data', heads on 'model', 2x4 mesh): the speculative
     schedule stays token-exact vs the single-device oracle with zero
     post-warmup compiles on either engine."""

@@ -97,8 +97,8 @@ def test_draft_dispatch_failure_resets_draft_cache_and_serves_resubmits():
     key = ("verify", 2, draft_engine._partitioner.mesh)
     real = draft_engine._compiled_cache[key]
 
-    def dying(variables_, cache, tokens, lengths):
-        real(variables_, cache, tokens, lengths)  # donation happens
+    def dying(variables_, cache, *operands):
+        real(variables_, cache, *operands)  # donation happens
         raise RuntimeError("injected draft dispatch-time failure")
 
     draft_engine._compiled_cache[key] = dying
@@ -108,6 +108,10 @@ def test_draft_dispatch_failure_resets_draft_cache_and_serves_resubmits():
         sched.drain()
     with pytest.raises(WorkerCrashedError):
         doomed.result()
+    # The draft's pool was reset with its cache, the teacher's slot
+    # released: neither holds a page of the failed stream.
+    for pool in (draft_engine.page_pool, engine.page_pool):
+        assert pool.used_pages == 0 and pool.leak_check() == 0
     draft_engine._compiled_cache[key] = real
     revived = sched.submit(p, max_new_tokens=6)
     sched.drain()

@@ -38,9 +38,9 @@ from zookeeper_tpu.core import Field, component
 from zookeeper_tpu.models.base import Model
 from zookeeper_tpu.ops import (
     attention_reference,
-    cached_attention,
     flash_attention,
-    paged_decode_attention,
+    pool_decode_attention,
+    pool_verify_attention,
 )
 from zookeeper_tpu.ops.moe import sparse_moe
 from zookeeper_tpu.parallel.sharding import constrain_batch_sharded
@@ -64,33 +64,6 @@ def _resolve_attention(attention):
     raise ValueError(
         f"attention={attention!r}: expected 'flash', 'dense', or an "
         "attention callable."
-    )
-
-
-def _resolve_paged_attention(paged_attention):
-    """``"reference"`` / ``"pallas"`` / any ``callable(q, k_pool,
-    v_pool, page_table, lengths, *, k_scale=None, v_scale=None)`` — the
-    page-pool analogue of :func:`_resolve_decode_attention`
-    (docs/DESIGN.md §20). ``"reference"`` is the
-    :func:`~zookeeper_tpu.ops.pool_decode_attention` gather+einsum
-    oracle; ``"pallas"`` the page-table scalar-prefetch kernel; the
-    callable form is how the decode engine injects the mesh-composed
-    sharded wrapper."""
-    from zookeeper_tpu.ops import (
-        pool_decode_attention,
-        pool_paged_decode_attention,
-    )
-
-    if callable(paged_attention):
-        return paged_attention
-    if paged_attention == "reference":
-        return pool_decode_attention
-    if paged_attention == "pallas":
-        return pool_paged_decode_attention
-    raise ValueError(
-        f"paged attention={paged_attention!r}: expected 'reference', "
-        "'pallas', or a callable(q, k_pool, v_pool, page_table, "
-        "lengths)."
     )
 
 
@@ -141,27 +114,6 @@ def layer_page_table(page_table, windowed: bool):
 
 def _pool_scales(layer):
     return layer.get("k_scale"), layer.get("v_scale")
-
-
-def _resolve_decode_attention(decode_attention):
-    """``"reference"`` / ``"pallas"`` / any ``callable(q, k_cache,
-    v_cache, lengths)`` — the decode-path analogue of
-    :func:`_resolve_attention`. ``"reference"`` is the
-    :func:`cached_attention` oracle einsum; ``"pallas"`` the
-    length-aware paged decode kernel (auto interpret off-TPU); the
-    callable form is how the decode engine injects the mesh-composed
-    ``sharded_paged_decode_attention`` (or any future flavor) without
-    rebuilding the module — see ``DecodeEngine.decode_attention``."""
-    if callable(decode_attention):
-        return decode_attention
-    if decode_attention == "reference":
-        return cached_attention
-    if decode_attention == "pallas":
-        return paged_decode_attention
-    raise ValueError(
-        f"decode_attention={decode_attention!r}: expected 'reference', "
-        "'pallas', or a callable(q, k_cache, v_cache, lengths)."
-    )
 
 
 class RMSNorm(nn.Module):
@@ -248,9 +200,10 @@ class _Block(nn.Module):
     """One pre-norm decoder block.
 
     ``setup()``-structured (not ``nn.compact``) so the SAME weights
-    serve two traced programs: the full-context ``__call__`` (training
-    / prefill) and the single-position :meth:`decode` (cached
-    attention over a KV buffer). Submodule names are pinned to the
+    serve three traced programs: the full-context ``__call__`` (training
+    / prefill), the single-position :meth:`decode_paged` and the
+    multi-position :meth:`decode_verify_paged` (attention over the
+    page pool). Submodule names are pinned to the
     names the original compact implementation auto-assigned
     (``RMSNorm_0``/``RMSNorm_1``/``qkv``/``proj``/``up``/``down``) so
     every existing checkpoint and partition rule keeps matching.
@@ -261,7 +214,7 @@ class _Block(nn.Module):
     positions on q and k, YaRN-scaled where ``rope_yarn`` is given),
     ``window`` (a sliding-window layer: causal and at most ``window``
     keys back), ``mlp="moe"`` (sparse SwiGLU experts, ``ops/moe.py``)
-    and ``param_dtype``. The five traced methods share ONE
+    and ``param_dtype``. The three traced methods share ONE
     projection-and-positions helper (:meth:`_qkv`) and one attention
     keyword set (:meth:`_attention_kwargs`).
     """
@@ -272,10 +225,6 @@ class _Block(nn.Module):
     attention: Any
     dtype: Any
     pin_activations: bool = True
-    #: Decode-path attention flavor: "reference" (the cached_attention
-    #: oracle), "pallas" (the paged decode kernel), or a callable. A
-    #: per-call ``attention_override`` (the engine seam) wins.
-    decode_attention: Any = "reference"
     num_kv_heads: int = 0  # 0: as many as num_heads
     head_dim: int = 0  # 0: d_model // num_heads
     rope_theta: float = 0.0  # 0: no rotary positions
@@ -409,73 +358,25 @@ class _Block(nn.Module):
             return out, (kh, vh)
         return out
 
-    def decode(self, x, k_cache, v_cache, lengths, attention_override=None):
-        """One cached-attention step: ``x [b, 1, d]`` is the new token's
-        residual stream, ``k_cache/v_cache [b, capacity, kv_heads,
-        head_dim]`` the slot KV buffers, ``lengths [b]`` the tokens
-        already cached. Writes the new position's K/V at index
-        ``lengths`` (clamped to the last row — the scheduler never
-        decodes past capacity; the clamp only keeps an inactive slot's
-        idle write in bounds), attends rows ``0..lengths``, and returns
-        ``(x_out, k_cache, v_cache)``. Same projections/norms as
-        ``__call__`` — the weights are literally the same submodules.
-        The attention over the cache runs ``attention_override`` when
-        given (the decode engine's flavor seam), else the block's
-        ``decode_attention`` setting."""
-        b = x.shape[0]
-        q, k, v = self._qkv(x, lengths[:, None])
-        write = jnp.clip(lengths, 0, k_cache.shape[1] - 1)
-        rows = jnp.arange(b)
-        k_cache = k_cache.at[rows, write].set(k[:, 0], mode="drop")
-        v_cache = v_cache.at[rows, write].set(v[:, 0], mode="drop")
-        attn = (
-            attention_override
-            if attention_override is not None
-            else _resolve_decode_attention(self.decode_attention)
-        )
-        o = attn(q, k_cache, v_cache, lengths, **self._attention_kwargs())
-        return self._mlp(self._out(x, o)), k_cache, v_cache
-
-    def decode_verify(self, x, k_cache, v_cache, lengths):
-        """The multi-token (speculative verify) step: ``x [b, w, d]`` is
-        the residual stream of ``w`` draft positions (position ``j`` is
-        the token at sequence index ``lengths + j``), appended to the
-        cache in ONE dispatch — all ``w`` new K/V rows land via a
-        per-slot dynamic-update-slice at ``lengths``
-        (``cache.append_kv_rows``) and every position attends
-        cache+window causally (``ops.verify_cached_attention``: row
-        ``j`` sees cache rows ``0..lengths+j``). Same submodules as
-        ``__call__``/``decode`` — one weight set, three traced programs.
-        Rollback-by-length: the caller commits only the accepted prefix
-        by advancing ``lengths`` that far; rejected rows stay masked
-        garbage (docs/DESIGN.md §18)."""
-        from zookeeper_tpu.ops import verify_cached_attention
-        from zookeeper_tpu.serving.decode.cache import append_kv_rows
-
-        w = x.shape[1]
-        q, k, v = self._qkv(x, lengths[:, None] + jnp.arange(w)[None, :])
-        k_cache = append_kv_rows(k_cache, k, lengths)
-        v_cache = append_kv_rows(v_cache, v, lengths)
-        o = verify_cached_attention(
-            q, k_cache, v_cache, lengths, **self._attention_kwargs()
-        )
-        return self._mlp(self._out(x, o)), k_cache, v_cache
-
     def decode_paged(
         self, x, layer, page_table, lengths, attention_override=None
     ):
-        """The page-pool twin of :meth:`decode` (docs/DESIGN.md §20):
-        ``layer`` is a pool dict (``k``/``v`` ``[num_pages,
-        head_shards, page_size, row_width]``, plus scale arrays for
-        int8 pools) shared by EVERY slot; the new position's K/V row
-        lands at ``(page_table[slot, lengths // page_size], lengths %
+        """One decode step over the page pool (docs/DESIGN.md §20):
+        ``x [b, 1, d]`` is the new token's residual stream, ``lengths
+        [b]`` the tokens already cached, ``layer`` a pool dict
+        (``k``/``v`` ``[num_pages, head_shards, page_size,
+        row_width]``, plus scale arrays for int8 pools) shared by EVERY
+        slot. The new position's K/V row lands at
+        ``(page_table[slot, lengths // page_size], lengths %
         page_size)`` — the indirected write, ``_pool_write_rows`` — and
-        the attention reads through the table
-        (``ops.pool_decode_attention`` or the injected kernel). A slot
-        whose write target is unallocated (``-1`` table entry, or an
-        inactive slot past its pages) drops the write via the OOB page
-        sentinel — the paged analogue of the §15 clamp, and like it
-        only ever taken by slots whose output is discarded."""
+        the attention reads rows ``0..lengths`` through the table:
+        ``attention_override`` when given (the decode engine's kernel),
+        else the reference ``ops.pool_decode_attention``. A slot whose
+        write target is unallocated (``-1`` table entry, or an inactive
+        slot past its pages) drops the write via the OOB page sentinel,
+        only ever taken by slots whose output is discarded. Same
+        projections and norms as ``__call__``: the weights are
+        literally the same submodules."""
         page_table = layer_page_table(page_table, bool(self.window))
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
         q, k, v = self._qkv(x, lengths[:, None])
@@ -490,11 +391,7 @@ class _Block(nn.Module):
         layer = _pool_write_rows(
             layer, {"k": k[:, 0], "v": v[:, 0]}, page, off
         )
-        attn = (
-            attention_override
-            if attention_override is not None
-            else _resolve_paged_attention(self.decode_attention)
-        )
+        attn = attention_override or pool_decode_attention
         k_scale, v_scale = _pool_scales(layer)
         o = attn(
             q, layer["k"], layer["v"], page_table, lengths,
@@ -507,16 +404,22 @@ class _Block(nn.Module):
         self, x, layer, page_table, lengths, valid=None,
         attention_override=None,
     ):
-        """The page-pool twin of :meth:`decode_verify`: all ``w``
-        window rows scatter through the page table in one dispatch
-        (position ``lengths + j`` → its table-resolved page/offset, so
-        a window crossing a page boundary just lands in two pages), and
-        every position attends cache+window through
-        ``ops.pool_verify_attention``. ``valid [b]`` bounds how many
-        window rows are REAL per slot (the warm-prefix extend program's
-        padding rows write nowhere — OOB sentinel); None = all ``w``
-        (the speculative verify, whose eligibility check already
-        guarantees the pages exist). Rollback stays by-length."""
+        """The multi-token step (speculative verify, warm-prefix
+        extend, prefill chunk; docs/DESIGN.md §18, §20): ``x [b, w,
+        d]`` is the residual stream of ``w`` positions (position ``j``
+        is the token at sequence index ``lengths + j``). All ``w`` rows
+        scatter through the page table in one dispatch (position
+        ``lengths + j`` → its table-resolved page/offset, so a window
+        crossing a page boundary just lands in two pages), and every
+        position attends cache+window causally through
+        ``ops.pool_verify_attention`` (row ``j`` sees rows
+        ``0..lengths+j``). ``valid [b]`` bounds how many window rows
+        are REAL per slot (the warm-prefix extend program's padding
+        rows write nowhere — OOB sentinel); None = all ``w`` (the
+        speculative verify, whose eligibility check already guarantees
+        the pages exist). Rollback-by-length: the caller commits only
+        the accepted prefix by advancing ``lengths`` that far; rejected
+        rows stay masked garbage."""
         page_table = layer_page_table(page_table, bool(self.window))
         w = x.shape[1]
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
@@ -531,13 +434,7 @@ class _Block(nn.Module):
         off = pos % ps
         layer = _pool_write_rows(layer, {"k": k, "v": v}, page, off)
         k_scale, v_scale = _pool_scales(layer)
-        from zookeeper_tpu.ops import pool_verify_attention
-
-        attn = (
-            attention_override
-            if attention_override is not None
-            else pool_verify_attention
-        )
+        attn = attention_override or pool_verify_attention
         o = attn(
             q, layer["k"], layer["v"], page_table, lengths,
             k_scale=k_scale, v_scale=v_scale,
@@ -567,7 +464,7 @@ def _auto_pin_activations(attention, pin_activations):
 
 
 class TransformerLMModule(nn.Module):
-    """The causal LM module. ``setup()``-structured so three methods
+    """The causal LM module. ``setup()``-structured so four methods
     share one weight set and one param tree (names unchanged from the
     original compact layout):
 
@@ -576,9 +473,11 @@ class TransformerLMModule(nn.Module):
     - ``prefill`` — full-context forward that ALSO returns every
       layer's K/V heads (to seed a decode engine's KV cache) and the
       next-token logits at each sequence's true last position.
-    - ``decode_step`` — one token per sequence through the cached-
-      attention path (``ops.cached_attention``) over caller-owned KV
-      buffers.
+    - ``decode_step_paged`` — one token per sequence, attending the
+      caller-owned page pool through its page table
+      (``ops.pool_decode_attention`` or the engine's kernel).
+    - ``decode_verify_paged`` — ``w`` tokens per sequence over the same
+      pool (speculative verify, warm-prefix extend, prefill chunks).
 
     Prefill/decode share weights AND numerics with ``__call__`` by
     construction — same submodules, same einsum/precision discipline —
@@ -596,10 +495,6 @@ class TransformerLMModule(nn.Module):
     dtype: Any
     #: None = auto (see ``_auto_pin_activations``); bool overrides.
     pin_activations: Any = None
-    #: Decode-path attention flavor ("reference" | "pallas" |
-    #: callable); a ``decode_step`` per-call override wins — see
-    #: ``_resolve_decode_attention``.
-    decode_attention: Any = "reference"
     # What differs by model (``_Block``'s fields of the same names;
     # every default is the GPT-2 shape):
     num_kv_heads: int = 0
@@ -650,7 +545,6 @@ class TransformerLMModule(nn.Module):
                 attention=self.attention,
                 dtype=self.dtype,
                 pin_activations=pin,
-                decode_attention=self.decode_attention,
                 num_kv_heads=self.num_kv_heads,
                 head_dim=self.head_size,
                 rope_theta=self.rope_theta if rope else 0.0,
@@ -764,89 +658,25 @@ class TransformerLMModule(nn.Module):
         last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
         return self._logits(last)[:, 0], tuple(kv)
 
-    def decode_step(self, tokens, lengths, cache, attention_override=None):
-        """One incremental token per sequence. ``tokens [b] int`` are
-        the CURRENT input tokens (each sits at position ``lengths``),
-        ``cache`` is a per-layer tuple of ``{"k", "v"}`` buffers
-        ``[b, capacity, heads, head_dim]``. Returns ``(logits [b,
-        vocab], new_cache)`` — the caller owns length bookkeeping and
-        feeds ``argmax(logits)`` back as the next step's ``tokens``.
-        ``attention_override`` (a ``callable(q, k_cache, v_cache,
-        lengths)``) selects the cache-attention flavor for THIS trace,
-        overriding the module's ``decode_attention`` — the seam the
-        decode engine threads its config-selected kernel (or the
-        mesh-composed sharded wrapper) through without rebuilding the
-        module."""
-        if len(cache) != self.num_layers:
-            raise ValueError(
-                f"cache has {len(cache)} layers, model has "
-                f"{self.num_layers}."
-            )
-        x = self._embed(tokens, lengths)[:, None, :]
-        if self._pin():
-            x = constrain_batch_sharded(x)
-        new_cache = []
-        for block, layer in zip(self.blocks, cache):
-            x, kc, vc = block.decode(
-                x, layer["k"], layer["v"], lengths,
-                attention_override=attention_override,
-            )
-            new_cache.append({"k": kc, "v": vc})
-        return self._logits(x)[:, 0], tuple(new_cache)
-
-    def decode_verify(self, tokens, lengths, cache):
-        """``w`` tokens per sequence through the cached-attention path
-        in ONE dispatch — the speculative-decode verify/append program
-        (docs/DESIGN.md §18). ``tokens [b, w] int`` are the window's
-        input tokens (token ``j`` sits at position ``lengths + j``),
-        ``cache`` the per-layer ``{"k", "v"}`` buffers. Returns
-        ``(logits [b, w, vocab], new_cache)`` with all ``w`` K/V rows
-        appended per layer (``cache.append_kv_rows``); ``logits[:, j]``
-        is the next-token distribution AFTER consuming token ``j`` —
-        the verify scores for greedy acceptance. The caller owns length
-        bookkeeping: advancing ``lengths`` by only the accepted prefix
-        is the whole rollback contract (rejected rows stay at
-        ``j >= length`` where every attention path masks them).
-        Positions past the table clamp like ``decode_step``'s — the
-        scheduler never COMMITS past ``token_limit``, so a clamped row
-        is never attended. At ``w == 1`` this computes exactly what
-        ``decode_step`` computes (same ops, ``verify_cached_attention``
-        reduces to ``cached_attention``)."""
-        if len(cache) != self.num_layers:
-            raise ValueError(
-                f"cache has {len(cache)} layers, model has "
-                f"{self.num_layers}."
-            )
-        if tokens.ndim != 2:
-            raise ValueError(
-                f"decode_verify expects [batch, w] int tokens, got "
-                f"shape {tokens.shape}."
-            )
-        w = tokens.shape[1]
-        x = self._embed(tokens, lengths[:, None] + jnp.arange(w)[None, :])
-        if self._pin():
-            x = constrain_batch_sharded(x)
-        new_cache = []
-        for block, layer in zip(self.blocks, cache):
-            x, kc, vc = block.decode_verify(
-                x, layer["k"], layer["v"], lengths
-            )
-            new_cache.append({"k": kc, "v": vc})
-        return self._logits(x), tuple(new_cache)
-
     def decode_step_paged(
         self, tokens, lengths, cache, page_table, attention_override=None
     ):
-        """:meth:`decode_step` over a SHARED page pool (docs/DESIGN.md
-        §20): ``cache`` is a per-layer tuple of pool dicts (``k``/``v``
-        ``[num_pages, head_shards, page_size, row_width]``, plus
+        """One incremental token per sequence over a SHARED page pool
+        (docs/DESIGN.md §20). ``tokens [b] int`` are the CURRENT input
+        tokens (each sits at position ``lengths``), ``cache`` is a
+        per-layer tuple of pool dicts (``k``/``v`` ``[num_pages,
+        head_shards, page_size, row_width]``, plus
         ``k_scale``/``v_scale`` for int8 pools), ``page_table [b,
         max_pages] int32`` resolves each sequence's logical pages.
-        Same contract otherwise — the caller owns lengths, the new K/V
-        row is written (through the table) before attending, and
-        ``attention_override`` is the engine's paged-flavor seam
+        Returns ``(logits [b, vocab], new_cache)`` — the caller owns
+        length bookkeeping and feeds ``argmax(logits)`` back as the
+        next step's ``tokens``; the new K/V row is written (through
+        the table) before attending. ``attention_override``
         (``callable(q, k_pool, v_pool, page_table, lengths, *,
-        k_scale=None, v_scale=None)``)."""
+        k_scale=None, v_scale=None)``) selects the attention for THIS
+        trace — the seam the decode engine threads its kernel (or the
+        mesh-composed sharded wrapper) through without rebuilding the
+        module; None is the reference."""
         if len(cache) != self.num_layers:
             raise ValueError(
                 f"cache has {len(cache)} layers, model has "
@@ -868,15 +698,27 @@ class TransformerLMModule(nn.Module):
         self, tokens, lengths, cache, page_table, valid=None,
         attention_override=None,
     ):
-        """:meth:`decode_verify` over a shared page pool: ``w`` window
-        tokens per sequence scatter through the page table in one
-        dispatch (windows cross page boundaries freely) and every
-        position's logits come back for acceptance scoring — ALSO the
-        warm-prefix extend program (docs/DESIGN.md §20): a prompt whose
-        prefix is cache-resident enters here with the SUFFIX as the
-        window (``valid [b]`` = true suffix lengths; padding rows write
-        nowhere), each suffix position attending the shared prefix
-        pages it never recomputed — which is the entire TTFT win."""
+        """``w`` tokens per sequence over the page pool in ONE
+        dispatch — the speculative-decode verify/append program
+        (docs/DESIGN.md §18). ``tokens [b, w] int`` are the window's
+        input tokens (token ``j`` sits at position ``lengths + j``);
+        they scatter through the page table (windows cross page
+        boundaries freely). Returns ``(logits [b, w, vocab],
+        new_cache)``; ``logits[:, j]`` is the next-token distribution
+        AFTER consuming token ``j`` — the verify scores for greedy
+        acceptance. The caller owns length bookkeeping: advancing
+        ``lengths`` by only the accepted prefix is the whole rollback
+        contract (rejected rows stay at ``j >= length`` where every
+        attention path masks them). Positions past the table clamp
+        like ``decode_step_paged``'s — the scheduler never COMMITS past
+        ``token_limit``, so a clamped row is never attended. At ``w ==
+        1`` this computes exactly what ``decode_step_paged`` computes.
+        ALSO the warm-prefix extend program (docs/DESIGN.md §20): a
+        prompt whose prefix is cache-resident enters here with the
+        SUFFIX as the window (``valid [b]`` = true suffix lengths;
+        padding rows write nowhere), each suffix position attending the
+        shared prefix pages it never recomputed — which is the entire
+        TTFT win."""
         if len(cache) != self.num_layers:
             raise ValueError(
                 f"cache has {len(cache)} layers, model has "
@@ -958,14 +800,6 @@ class TransformerLM(Model):
     #: "flash" (Pallas kernels, long-context default) or "dense" (the
     #: oracle path).
     attention: str = Field("flash")
-    #: Decode-path (KV-cache) attention flavor: "reference" (the
-    #: ``cached_attention`` oracle einsum — reads the full capacity
-    #: axis every step) or "pallas" (the length-aware paged decode
-    #: kernel). The DEFAULT stays the reference so direct module users
-    #: keep oracle numerics; the serving engine's own
-    #: ``decode_attention="auto"`` Field selects the kernel on TPU —
-    #: see ``DecodeEngine``.
-    decode_attention: str = Field("reference")
     #: Positional-table capacity. -1 (the default) sizes it to the
     #: sequence length ``build()`` receives — the common case, and it
     #: keeps one ``seq_len`` knob sufficient in CLI tasks. Set
@@ -1023,18 +857,6 @@ class TransformerLM(Model):
             )
         object.__setattr__(self, "_attention_override", fn)
 
-    def set_decode_attention_override(self, fn) -> None:
-        """The decode-path twin of :meth:`set_attention_override`: a
-        mesh-owning caller installs a ``callable(q, k_cache, v_cache,
-        lengths)`` here before ``build()`` and it takes precedence over
-        the string ``decode_attention`` Field. ``None`` clears."""
-        if fn is not None and not callable(fn):
-            raise ValueError(
-                f"decode attention override must be callable(q, k_cache, "
-                f"v_cache, lengths) or None, got {fn!r}."
-            )
-        object.__setattr__(self, "_decode_attention_override", fn)
-
     def build(self, input_shape: Sequence[int], num_classes: int) -> nn.Module:
         if len(input_shape) != 1:
             raise ValueError(
@@ -1049,10 +871,6 @@ class TransformerLM(Model):
         if attention is None:
             _resolve_attention(self.attention)
             attention = self.attention
-        decode_attention = getattr(self, "_decode_attention_override", None)
-        if decode_attention is None:
-            _resolve_decode_attention(self.decode_attention)
-            decode_attention = self.decode_attention
         num_kv_heads = (
             self.num_heads if self.num_kv_heads == -1 else self.num_kv_heads
         )
@@ -1134,7 +952,6 @@ class TransformerLM(Model):
             attention=attention,
             max_seq_len=max_seq_len,
             dtype=self.dtype(),
-            decode_attention=decode_attention,
             num_kv_heads=num_kv_heads,
             head_size=head_dim,
             positions=self.positions,

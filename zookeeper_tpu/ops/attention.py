@@ -39,11 +39,9 @@ from jax import lax
 
 from zookeeper_tpu.ops.blocks import (  # noqa: F401  (re-exports)
     _FLASH_VMEM_BUDGET,
-    _decode_vmem_estimate,
     _pool_decode_block_pages,
     _pool_decode_vmem_estimate,
     _round_up,
-    _default_decode_blocks,
     _default_flash_blocks,
     _flash_bwd_vmem_estimate,
     vmem_limit_bytes,
@@ -252,235 +250,22 @@ def verify_cached_attention(
     ).astype(q.dtype)
 
 
-def decode_attention_supported(
-    num_heads: int, head_dim: int, *, paged: bool = False
-) -> bool:
-    """Whether :func:`paged_decode_attention` (``paged=True``:
-    :func:`pool_paged_decode_attention`) serves this geometry.
+def decode_attention_supported(num_heads: int, head_dim: int) -> bool:
+    """Whether :func:`pool_paged_decode_attention` serves this geometry.
 
-    The slot kernel's in-VMEM tiles put ``head_dim`` on the lane
-    dimension and the head block on sublanes; Mosaic pads either to the
-    hardware tile, but a head_dim off the fp32 sublane quantum (8) is
+    The kernel reads folded rows (heads end to end on the lanes) and
+    sums a head's lanes inside one 128-lane register, so ``head_dim``
+    has to divide 128; a head_dim off the fp32 sublane quantum (8) is
     untested territory on real silicon, so such geometries take the
     reference einsum instead of risking a Mosaic lowering failure on
-    the serving hot path. The pool kernel reads folded rows (heads end
-    to end on the lanes) and sums a head's lanes inside one 128-lane
-    register, so it also needs ``head_dim`` to divide 128. Interpret mode
-    has no such constraint, but the predicate is deliberately
-    backend-independent: a config must resolve to the same flavor on
-    the CPU tier-1 runner as on the TPU it deploys to.
+    the serving hot path. Interpret mode has no such constraint, but the
+    predicate is deliberately backend-independent: a config must resolve
+    to the same flavor on the CPU tier-1 runner as on the TPU it deploys
+    to.
     """
     if num_heads < 1 or head_dim < 8 or head_dim % 8:
         return False
-    return not paged or _KV_LANES % head_dim == 0
-
-
-# _decode_vmem_estimate / _default_decode_blocks moved to ops/blocks.py
-# (shared with the flash, residual, and §21 binary policies); imported at
-# the top of this module so historical import sites keep working.
-
-
-def paged_decode_attention(
-    q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
-    lengths: jax.Array,
-    *,
-    scale: Optional[float] = None,
-    page_size: int = 1,
-    block_kv: Optional[int] = None,
-    block_h: Optional[int] = None,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Pallas TPU single-position decode attention over a paged KV
-    cache — the length-aware replacement for :func:`cached_attention`
-    in the decode hot loop.
-
-    Same contract and shapes as the reference (``q [slots, 1, heads,
-    head_dim]``, ``k_cache/v_cache [slots, capacity, heads,
-    head_dim]``, ``lengths [slots] int32 >= 0``; rows ``0..lengths``
-    inclusive attended, everything past them masked), different cost
-    model: the reference einsum streams the ENTIRE ``capacity`` axis
-    from HBM every step, while this kernel grids over (slot,
-    head-block, kv-block) with ``lengths`` as a scalar-prefetch operand
-    so the kv-block index map CLAMPS dead blocks to the slot's last
-    live block — Pallas issues no DMA when the block index repeats, so
-    rows past ``ceil((lengths[slot]+1) / block_kv) * block_kv`` are
-    never fetched. Decode is memory-bound; bytes actually read is the
-    tokens/s lever (docs/DESIGN.md §17).
-
-    Numerics: fp32 accumulation with the same finite ``_MASK_VALUE``
-    masking as the reference; scores and the p@V product are computed
-    as broadcast-multiply-reduce on the VPU (a one-row matmul per head
-    would waste 127/128 of the MXU anyway), so bf16 operands promote
-    exactly like the reference's fp32-HIGHEST einsums and the only
-    divergence is online-softmax reassociation across kv blocks —
-    ULP-level, pinned by the kernel-vs-reference property sweep
-    (token-exact argmax; see tests/ops/test_paged_decode_attention.py
-    for the stated tolerance).
-
-    Composes with the sharded decode path via
-    :func:`sharded_paged_decode_attention` (slots over the data axes,
-    heads over the model axis). ``interpret=None`` auto-selects
-    interpret mode off-TPU (the repo's Pallas convention — tier-1 runs
-    the kernel on CPU this way).
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if q.ndim != 4 or q.shape[1] != 1:
-        raise ValueError(
-            f"paged_decode_attention expects q [slots, 1, heads, "
-            f"head_dim], got {q.shape}."
-        )
-    if k_cache.shape != v_cache.shape or k_cache.ndim != 4:
-        raise ValueError(
-            f"k_cache/v_cache must be identical [slots, capacity, "
-            f"heads, head_dim], got {k_cache.shape} / {v_cache.shape}."
-        )
-    b, _, h, d = q.shape
-    cap = k_cache.shape[1]
-    if k_cache.shape[0] != b or k_cache.shape[2] != h or k_cache.shape[3] != d:
-        raise ValueError(
-            f"cache {k_cache.shape} does not match q {q.shape}."
-        )
-    if scale is None:
-        scale = d ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    block_kv, block_h = _default_decode_blocks(
-        cap, h, d, page_size=page_size, itemsize=q.dtype.itemsize,
-        block_kv=block_kv, block_h=block_h,
-    )
-    nk = cap // block_kv
-    nh = h // block_h
-    scale = float(scale)  # kernel closure constant, not a traced array
-    qs = q.reshape(b, h, d)
-    # Clamp to the last row: identical semantics to the reference mask
-    # (lengths >= capacity attends every row), and the clamped value is
-    # what the index map divides by.
-    lens = jnp.clip(lengths.astype(jnp.int32), 0, cap - 1)
-
-    def q_index_map(s, hb, kb, lens_ref):
-        return (s, hb, 0)
-
-    def kv_index_map(s, hb, kb, lens_ref):
-        # Dead kv blocks re-select the slot's LAST LIVE block: Pallas
-        # issues no DMA for a repeated block index, so their rows never
-        # leave HBM — the length-aware read.
-        return (s, jnp.minimum(kb, lens_ref[s] // block_kv), hb, 0)
-
-    def kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
-        s = pl.program_id(0)
-        kb = pl.program_id(2)
-        length = lens_ref[s]
-
-        @pl.when(kb == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, _MASK_VALUE)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        # Block 0 is always live (lengths >= 0 attends row 0), so the
-        # accumulators never finalize empty.
-        @pl.when(kb * block_kv <= length)
-        def _block():
-            qv = q_ref[0].astype(jnp.float32)  # [block_h, d]
-            kv = k_ref[0].astype(jnp.float32)  # [block_kv, block_h, d]
-            # Per-head q.k as broadcast-multiply + lane reduce (VPU):
-            # exact fp32 products, same promotion as the reference's
-            # HIGHEST-precision einsum.
-            sc = jnp.sum(qv[None] * kv, axis=-1) * scale  # [block_kv, block_h]
-            ki = kb * block_kv + lax.broadcasted_iota(
-                jnp.int32, (block_kv, block_h), 0
-            )
-            sc = jnp.where(ki <= length, sc, _MASK_VALUE)
-            m = m_ref[...]  # [1, block_h]
-            m_new = jnp.maximum(m, sc.max(axis=0, keepdims=True))
-            p = jnp.exp(sc - m_new)
-            corr = jnp.exp(m - m_new)
-            m_ref[...] = m_new
-            l_ref[...] = l_ref[...] * corr + p.sum(axis=0, keepdims=True)
-            pv = jnp.sum(
-                p[:, :, None] * v_ref[0].astype(jnp.float32), axis=0
-            )  # [block_h, d]
-            acc_ref[...] = acc_ref[...] * corr[0][:, None] + pv
-
-        @pl.when(kb == nk - 1)
-        def _finalize():
-            o_ref[0] = (
-                acc_ref[...] / l_ref[...][0][:, None]
-            ).astype(o_ref.dtype)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, nh, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_h, d), q_index_map),
-            pl.BlockSpec((1, block_kv, block_h, d), kv_index_map),
-            pl.BlockSpec((1, block_kv, block_h, d), kv_index_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_h, d), q_index_map),
-        scratch_shapes=[
-            pltpu.VMEM((1, block_h), jnp.float32),
-            pltpu.VMEM((1, block_h), jnp.float32),
-            pltpu.VMEM((block_h, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_mosaic_params(
-            _decode_vmem_estimate(block_kv, block_h, d, q.dtype.itemsize)
-        ),
-        interpret=interpret,
-    )(lens, qs, k_cache, v_cache)
-    return out.reshape(b, 1, h, d)
-
-
-def sharded_paged_decode_attention(
-    q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
-    lengths: jax.Array,
-    *,
-    mesh,
-    data_axes=("data",),
-    model_axis: Optional[str] = None,
-    replicated: bool = False,
-    **kernel_kwargs,
-) -> jax.Array:
-    """:func:`paged_decode_attention` wrapped for the sharded decode
-    path: slots shard over ``data_axes`` and heads over ``model_axis``
-    (exactly ``parallel.rules.decode_cache_rules`` — the cache layout
-    the decode engine already serves under), so each device runs the
-    kernel on its local (slots, heads) shard with ZERO collectives —
-    decode attention is elementwise over both sharded dimensions.
-    ``replicated=True`` is the engine's indivisible-geometry posture
-    (the cache fell back to a replicated placement): every device runs
-    the whole kernel on replicated operands, correct and
-    collective-free, redundant by construction. GSPMD cannot partition
-    an opaque pallas custom call (it would gather the full cache —
-    precisely the bytes this kernel exists not to read), which is why
-    the mesh path is an explicit shard_map rather than trust in
-    sharding propagation."""
-    from jax.sharding import PartitionSpec as P
-
-    if replicated:
-        spec = l_spec = P()
-    else:
-        spec = P(tuple(data_axes), None, model_axis, None)
-        l_spec = P(tuple(data_axes))
-    local = partial(paged_decode_attention, **kernel_kwargs)
-    # check_vma off: Pallas' interpret-mode lowering is not
-    # vma-annotated (the ring_flash workaround); correctness is pinned
-    # by the kernel-vs-reference parity sweep instead.
-    fn = _shard_map_no_vma_check(
-        local, mesh=mesh, in_specs=(spec, spec, spec, l_spec),
-        out_specs=spec,
-    )
-    return fn(q, k_cache, v_cache, lengths)
+    return _KV_LANES % head_dim == 0
 
 
 def kv_row_width(num_heads: int, head_dim: int, head_shards: int = 1) -> int:
@@ -555,8 +340,8 @@ def _gathered_pool_view(pool, page_table, num_heads, head_dim, scale=None):
     entries (clipped to page 0) and garbage rows beyond a slot's length
     are harmless by the validity invariant: every pool-attention
     consumer masks ``j > lengths`` to the finite ``_MASK_VALUE``, whose
-    softmax weight underflows to exactly 0.0 — the same argument the
-    slot-layout refill contract makes."""
+    softmax weight underflows to exactly 0.0 — which is also why a
+    refilled slot may find its previous occupant's rows in a page."""
     idx = jnp.clip(page_table, 0, pool.shape[0] - 1)
     # [slots, max_pages, head_shards, page_size, row_width]
     g = unfold_kv_rows(jnp.swapaxes(pool[idx], 2, 3), num_heads, head_dim)
@@ -595,12 +380,11 @@ def pool_decode_attention(
     ``k_scale/v_scale [num_pages, head_shards, page_size,
     heads_per_shard]`` dequantize int8 pools inline.
 
-    Numerics: the gathered view holds BIT-identical rows to the
-    slot-contiguous cache at every live index (same values, written
-    once), and the math below IS :func:`cached_attention` op for op —
-    so fp paged decode is bit-identical to slots-mode decode, and the
-    token-parity certification composes transitively through the
-    full-context oracle. int8 pools add one exactly-representable
+    Numerics: the gathered view holds, at every live index, the row
+    that was written once, and the math below IS
+    :func:`cached_attention` op for op, so the token-parity
+    certification composes through the full-context oracle
+    (docs/DESIGN.md §15). int8 pools add one exactly-representable
     ``int8 × fp32 scale`` multiply before the same einsums
     (documented-ULP, argmax-pinned by the §20 sweep).
 
@@ -632,8 +416,8 @@ def pool_verify_attention(
     """Multi-position (speculative verify / warm-prefix extend)
     attention over a shared page pool — the page-indirected counterpart
     of :func:`verify_cached_attention`: window position ``j`` attends
-    pool rows ``0..lengths+j`` through the slot's page table. Same
-    shapes/contract as the slot-layout verify with the pool operands of
+    pool rows ``0..lengths+j`` through the slot's page table (``q [slots,
+    w, heads, head_dim]``), with the pool operands of
     :func:`pool_decode_attention`; at ``w == 1`` it computes exactly
     what :func:`pool_decode_attention` computes (``kv_heads`` and
     ``window`` as there)."""
@@ -676,7 +460,7 @@ def pool_paged_decode_attention(
     page) to the page's rows of a ``[2, N * page_size, row_width]``
     VMEM block a pool: the block is assembled in VMEM, so it need not
     be contiguous in the pool. A page past the length or behind the
-    band starts no copy (the §17 length-bounded read, composed with
+    band starts no copy (the length-bounded read of §17, composed with
     indirection); its rows are masked. The block is double-buffered
     ACROSS work items: the copies of the next item (of this slot, or the
     first block of the next one) fly while this one is computed on.
@@ -688,7 +472,7 @@ def pool_paged_decode_attention(
     lane reduction a head inside each 128-lane column; softmax state is
     kept per lane (every lane of a head carries that head's max and
     sum). That needs ``head_dim`` to divide 128
-    (:func:`decode_attention_supported` with ``paged=True``). The
+    (:func:`decode_attention_supported`). The
     fetched block is walked by loops whose trip counts come from the
     live length: 128 keys at a time over the whole 128-key pieces of
     the block's live pages, then a page at a time over the pages left
@@ -719,8 +503,8 @@ def pool_paged_decode_attention(
     never fetched, so their table entries may be released (``-1``).
 
     Numerics: fp32 online-softmax accumulation with the reference's
-    finite mask value — same contract (documented-ULP vs the pool
-    reference, argmax token-exact) as the §17 kernel; the result
+    finite mask value: documented-ULP against the pool reference,
+    argmax token-exact (docs/DESIGN.md §17); the result
     depends on ``N`` only through the order of float32 sums.
     """
     if q.ndim != 4 or q.shape[1] != 1:
@@ -741,7 +525,7 @@ def pool_paged_decode_attention(
             f"pool {k_pool.shape} does not match q {q.shape} with "
             f"{hkv} key/value heads."
         )
-    if not decode_attention_supported(hkv, d, paged=True):
+    if not decode_attention_supported(hkv, d):
         raise ValueError(
             f"head_dim={d} is off the pool kernel's geometry (a divisor "
             "of 128)."
@@ -1230,17 +1014,20 @@ def sharded_pool_paged_decode_attention(
     **kernel_kwargs,
 ) -> jax.Array:
     """:func:`pool_paged_decode_attention` wrapped for the sharded
-    decode path. The POOL differs from the slot-contiguous cache in one
-    sharding-relevant way: any slot may reference any page, so pages
-    CANNOT shard over the data axes — the pools (and their scale
-    arrays) shard over ``model_axis`` on the head-shard dimension only,
-    while q/lengths/page_table shard over ``data_axes`` like batch rows
+    decode path. Any slot may reference any page, so pages CANNOT
+    shard over the data axes: the pools (and their scale arrays) shard
+    over ``model_axis`` on the head-shard dimension only, while
+    q/lengths/page_table shard over ``data_axes`` like batch rows
     (``parallel.rules.page_pool_rules``). Each device then runs the
-    kernel over its slot shard against its head shard of every page —
-    still ZERO collectives. ``replicated=True`` is the indivisible-
-    geometry fallback, as in §17. Explicit shard_map for the same
-    reason as :func:`sharded_paged_decode_attention`: GSPMD cannot
-    partition an opaque pallas call."""
+    kernel over its slot shard against its head shard of every page,
+    with ZERO collectives: decode attention is elementwise over both
+    sharded dimensions. ``replicated=True`` is the engine's
+    indivisible-geometry posture (the pool fell back to a replicated
+    placement): every device runs the whole kernel on replicated
+    operands, correct and redundant. An explicit shard_map because
+    GSPMD cannot partition an opaque pallas custom call: it would
+    gather the whole pool around it, precisely the bytes this kernel
+    exists not to read."""
     from jax.sharding import PartitionSpec as P
 
     if replicated:

@@ -3,16 +3,12 @@
 Every kernel family in the repo sizes its grid blocks the same way: pick
 the LARGEST aligned candidate whose working set fits a VMEM budget and
 whose padding waste stays bounded, then let explicit caller overrides
-pass through untouched. Until docs/DESIGN.md §21 that discipline lived
-in three private copies — ``_default_flash_blocks`` (flash attention,
-also reused by the pool kernels), ``_default_decode_blocks`` (paged
-decode), and ``_resid_blocks`` (1-bit residual pack/unpack). This module
-is the single home for all of them plus the binary xnor-popcount GEMM /
-conv-as-gemm policies they share with §21. The moved functions are
-byte-for-byte the attention.py / binary_compute.py versions (behavior is
-pinned by the pre-existing block-policy unit tests); attention.py and
-binary_compute.py re-export them so historical import sites keep
-working.
+pass through untouched. This module is the single home of those
+policies: ``_default_flash_blocks`` (flash attention),
+``_pool_decode_block_pages`` (the pool decode kernel),
+``_resid_blocks`` (1-bit residual pack/unpack) and the binary
+xnor-popcount GEMM / conv-as-gemm policies of docs/DESIGN.md §21;
+attention.py and binary_compute.py re-export the ones they use.
 
 Pure shape arithmetic only: nothing here imports jax, so the policies
 are usable from tests and tools without pulling in a backend.
@@ -31,8 +27,6 @@ __all__ = [
     "_divisor_at_most",
     "_flash_bwd_vmem_estimate",
     "_default_flash_blocks",
-    "_decode_vmem_estimate",
-    "_default_decode_blocks",
     "_pool_decode_vmem_estimate",
     "_POOL_BLOCK_BYTES",
     "_pool_decode_block_pages",
@@ -152,77 +146,7 @@ def _default_flash_blocks(s, block_q, block_k, head_dim=None, itemsize=4):
     return block_q, block_k
 
 
-# -- paged decode attention -------------------------------------------------
-
-
-def _decode_vmem_estimate(block_kv, block_h, head_dim, itemsize):
-    """Rough bytes one decode-kernel grid step keeps resident: the
-    double-buffered K and V tiles at the operand dtype plus the fp32
-    broadcast intermediates (scores and the p*v product both
-    materialize ``[block_kv, block_h, head_dim]``) and the per-head
-    accumulators."""
-    tiles = 2 * 2 * block_kv * block_h * head_dim * itemsize
-    intermediates = 2 * block_kv * block_h * head_dim * 4
-    accumulators = (block_h * head_dim + 2 * block_h) * 4
-    return tiles + intermediates + accumulators
-
-
-def _default_decode_blocks(
-    capacity, num_heads, head_dim, page_size=1, itemsize=4,
-    block_kv=None, block_h=None,
-):
-    """Auto block policy for the decode kernel — the
-    ``_default_flash_blocks`` discipline applied to the KV-read axis:
-    the LARGEST aligned candidate that divides ``capacity``, nests with
-    the KV page size (equal, multiple, or divisor — so a block never
-    straddles a page boundary and the per-slot read bound stays
-    page-granular), and fits the VMEM budget. Large blocks amortize the
-    sequential grid iteration; small blocks tighten the length-bounded
-    read (expected overshoot is block/2 rows per slot) — 256 caps the
-    candidates because decode is memory-bound and past that the read
-    overshoot costs more HBM than the grid overhead saves. Falls back
-    to ``page_size`` (capacity is page-aligned by the engine) and
-    finally to a single ``capacity`` block — which, for a capacity no
-    candidate divides at ``page_size=1``, is taken WITHOUT a VMEM check
-    (there is no smaller legal block to demote to): such geometries are
-    unreachable through the engine (page-aligned capacity, nesting
-    page_size), and a direct op caller with a huge indivisible capacity
-    should pass ``block_kv`` explicitly. Explicit ``block_kv`` /
-    ``block_h`` pass through unchecked except for divisibility."""
-    if block_h is None:
-        block_h = num_heads
-        while block_h > 1 and _decode_vmem_estimate(
-            8, block_h, head_dim, itemsize
-        ) > _FLASH_VMEM_BUDGET:
-            block_h = block_h // 2
-    if num_heads % block_h != 0:
-        raise ValueError(
-            f"block_h={block_h} does not divide num_heads={num_heads}."
-        )
-    if block_kv is None:
-        block_kv = capacity
-        for cand in (256, 128, 64, 32, 16, 8):
-            if capacity % cand:
-                continue
-            if cand % page_size and page_size % cand:
-                continue  # block/page must nest (page-granular reads)
-            if _decode_vmem_estimate(
-                cand, block_h, head_dim, itemsize
-            ) > _FLASH_VMEM_BUDGET:
-                continue
-            block_kv = cand
-            break
-        if block_kv == capacity and page_size > 1 and capacity % page_size == 0:
-            if capacity > page_size and _decode_vmem_estimate(
-                capacity, block_h, head_dim, itemsize
-            ) > _FLASH_VMEM_BUDGET:
-                block_kv = page_size
-    if capacity % block_kv != 0:
-        raise ValueError(
-            f"block_kv={block_kv} does not divide the KV capacity "
-            f"{capacity}."
-        )
-    return int(block_kv), int(block_h)
+# -- pool decode attention -------------------------------------------------
 
 
 def _pool_decode_vmem_estimate(block_rows, row_width, itemsize):

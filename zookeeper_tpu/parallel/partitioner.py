@@ -155,39 +155,27 @@ class Partitioner:
         raise NotImplementedError
 
     def decode_cache_axes(self) -> Tuple[Tuple[str, ...], Optional[str]]:
-        """``(data_axes, model_axis)`` the decode KV cache shards over
-        — the ONE derivation both :meth:`decode_cache_sharding` and the
-        decode engine's sharded attention wrapper
-        (``ops.sharded_paged_decode_attention``) consume: if the two
-        disagreed, GSPMD would reshard/gather the cache around the
+        """``(data_axes, model_axis)`` the decode engine's per-slot
+        operands and its page pool's heads shard over — the ONE
+        derivation both :meth:`page_pool_sharding` and the decode
+        engine's sharded attention wrapper
+        (``ops.sharded_pool_paged_decode_attention``) consume: if the
+        two disagreed, GSPMD would reshard/gather the pool around the
         kernel on every decode step — token-correct output, silently
         wrong bytes. Default (no mesh): nothing to shard over."""
         return (), None
 
-    def decode_cache_sharding(self, cache: Any) -> Any:
-        """Sharding pytree for a decode engine's KV-cache state
-        (``serving.decode``): per-layer ``k``/``v`` buffers ``[slots,
-        capacity, heads, head_dim]``. None = default placement (single
-        device); mesh partitioners shard slots over the data axes and
-        heads over the model axis via
-        :func:`zookeeper_tpu.parallel.rules.decode_cache_rules`. The
-        ENGINE checks divisibility (slots vs the data-axis product,
-        heads vs the model axis) and falls back to replicated cache
-        state when the shapes cannot split — the same degrade-don't-die
-        posture ``compile_forward``'s small buckets take."""
-        return None
-
     def page_pool_sharding(self, pool: Any) -> Any:
         """Sharding pytree for a decode engine's SHARED page-pool state
-        (``kv_layout="paged"``, docs/DESIGN.md §20): per-layer
-        ``k``/``v`` pools ``[num_pages, head_shards, page_size,
-        row_width]`` (+ int8 scale arrays). Pages replicate over the
-        data axes (any slot references any page), the head shards
-        shard over the model axis via
-        :func:`zookeeper_tpu.parallel.rules.page_pool_rules`; the
-        engine applies the same divisibility check + replicated
-        fallback as :meth:`decode_cache_sharding`. None = default
-        placement."""
+        (docs/DESIGN.md §20): per-layer ``k``/``v`` pools ``[num_pages,
+        head_shards, page_size, row_width]`` (+ int8 scale arrays).
+        Pages replicate over the data axes (any slot references any
+        page), the head shards shard over the model axis via
+        :func:`zookeeper_tpu.parallel.rules.page_pool_rules`. The
+        ENGINE checks divisibility and falls back to replicated pool
+        state when the shapes cannot split — the same degrade-don't-die
+        posture ``compile_forward``'s small buckets take. None =
+        default placement (single device)."""
         return None
 
 
@@ -492,13 +480,6 @@ partition.DisaggPartitioner` uses to put its prefill and decode roles
             a for a in self.mesh_axes if a not in set(data_axes)
         )
         return data_axes, (model_axes[0] if model_axes else None)
-
-    def decode_cache_sharding(self, cache: Any) -> Any:
-        from zookeeper_tpu.parallel.rules import decode_cache_rules
-
-        data_axes, model_axis = self.decode_cache_axes()
-        rules = decode_cache_rules(data_axes, model_axis)
-        return self._sharding_from_rules(cache, rules)
 
     def page_pool_sharding(self, pool: Any) -> Any:
         from zookeeper_tpu.parallel.rules import page_pool_rules

@@ -69,27 +69,6 @@ def transformer_tp_rules(model_axis: str = "model") -> List[PartitionRule]:
     ]
 
 
-def decode_cache_rules(
-    data_axes: Sequence[str] = ("data",),
-    model_axis: str = None,
-) -> List[PartitionRule]:
-    """Partition rules for a decode engine's KV-cache state tree
-    (``serving.decode``): the per-layer ``k``/``v`` buffers are
-    ``[slots, capacity, heads, head_dim]`` — SLOTS shard over the data
-    axes (each device owns a contiguous run of sequence slots, exactly
-    how a training batch shards) and HEADS over ``model_axis`` when one
-    exists (matching :func:`transformer_tp_rules`, whose column-
-    parallel qkv kernel produces head-sharded K/V in the first place —
-    co-sharding the cache means the decode program writes and reads
-    K/V without any resharding collective). Everything else in the
-    tree replicates.
-    """
-    P = PartitionSpec
-    return [
-        (r"(^|/)(k|v)$", P(tuple(data_axes), None, model_axis, None)),
-    ]
-
-
 def page_pool_rules(
     data_axes: Sequence[str] = ("data",),
     model_axis: str = None,
@@ -97,15 +76,17 @@ def page_pool_rules(
     """Partition rules for a decode engine's SHARED page-pool state
     tree (``serving.decode.pages``, docs/DESIGN.md §20): the per-layer
     ``k``/``v`` pools are ``[num_pages, head_shards, page_size,
-    row_width]`` and — unlike the slot-contiguous cache — the PAGES
-    dimension cannot shard over the data axes: any slot may reference
-    any page through its page table, so a data-sharded pool would need
-    a cross-device gather per read. The HEAD SHARDS dimension (one
-    entry per model-axis device, each holding its heads folded end to
-    end) shards over ``model_axis``, co-sharded with the
-    column-parallel qkv kernel as in :func:`decode_cache_rules`; the
-    int8 scale arrays ``[num_pages, head_shards, page_size,
-    heads_per_shard]`` co-shard the same dimension. ``data_axes`` is
+    row_width]`` and the PAGES dimension cannot shard over the data
+    axes: any slot may reference any page through its page table, so a
+    data-sharded pool would need a cross-device gather per read. The
+    HEAD SHARDS dimension (one entry per model-axis device, each
+    holding its heads folded end to end) shards over ``model_axis``
+    when one exists, matching :func:`transformer_tp_rules`, whose
+    column-parallel qkv kernel produces head-sharded K/V in the first
+    place: the decode program writes and reads K/V without any
+    resharding collective. The int8 scale arrays ``[num_pages,
+    head_shards, page_size, heads_per_shard]`` co-shard the same
+    dimension. ``data_axes`` is
     accepted for signature parity (the q/lengths/table OPERANDS shard
     over it — see ``ops.sharded_pool_paged_decode_attention``) but the
     pool state itself replicates over it."""

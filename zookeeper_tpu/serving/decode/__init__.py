@@ -3,15 +3,11 @@
 The token-streaming half of the serving stack — the ROADMAP's
 "millions-of-users" interactive workload:
 
-- :mod:`~zookeeper_tpu.serving.decode.cache` — paged/ring KV-cache
-  state: per-layer ``[slots, capacity, heads, head_dim]`` buffers,
-  device-resident, slots sharded on the data axes and heads on the
-  model axis via the Partitioner rule tables.
-- :class:`DecodeEngine` — the two compiled programs: a bucketed
-  ``prefill`` (writes a request's KV pages, emits its first token) and
-  ONE ``decode_step`` (one token per slot over the full slot array),
-  AOT-warmed with the forward engine's zero-recompile discipline and
-  ledgered in the ProgramLedger.
+- :class:`DecodeEngine` — the compiled programs over the device page
+  pool: a bucketed ``prefill`` (writes a request's KV pages, emits its
+  first token) and ONE ``decode_step`` (one token per slot over the
+  full slot array), AOT-warmed with the forward engine's
+  zero-recompile discipline and ledgered in the ProgramLedger.
 - :class:`DecodeScheduler` — slot-refill continuous batching: a
   finished sequence's slot is refilled from the queue without draining
   or recompiling; deadlines/shedding/crash-recovery reuse the PR 4
@@ -23,8 +19,8 @@ The token-streaming half of the serving stack — the ROADMAP's
 - :class:`LMServingConfig` — the config-system citizen tying model +
   checkpoint + engine + scheduler into a CLI task
   (``examples/serve_lm.py``).
-- :mod:`~zookeeper_tpu.serving.decode.pages` — TRUE paged KV
-  (docs/DESIGN.md §20, ``engine.kv_layout="paged"``): a SHARED device
+- :mod:`~zookeeper_tpu.serving.decode.pages` — the KV layout
+  (docs/DESIGN.md §20): a SHARED device
   page pool with per-slot page tables as runtime operands
   (:class:`PagePool` — free-list/refcount allocator), a radix prefix
   cache over prompt prefixes with copy-on-write at the divergence
@@ -33,18 +29,12 @@ The token-streaming half of the serving stack — the ROADMAP's
   per-row scales dequantized inside the attention read.
 - :class:`SpeculativeDecoding` — the draft/verify schedule
   (docs/DESIGN.md §18): a small draft model proposes ``k`` tokens per
-  slot, one teacher ``decode_verify`` dispatch scores the whole window
+  slot, one teacher ``decode_verify_paged`` dispatch scores the whole window
   (multi-token KV append + rollback-by-length), greedy acceptance
   keeps the longest prefix match — certified token-identical to plain
   greedy decode at up to ``k + 1`` tokens per teacher dispatch.
 """
 
-from zookeeper_tpu.serving.decode.cache import (
-    allocate_kv_cache,
-    append_kv_rows,
-    kv_cache_bytes,
-    pages_in_use,
-)
 from zookeeper_tpu.serving.decode.engine import DecodeEngine
 from zookeeper_tpu.serving.decode.pages import (
     PagePool,
@@ -69,10 +59,6 @@ __all__ = [
     "PagePool",
     "RadixPrefixCache",
     "SpeculativeDecoding",
-    "allocate_kv_cache",
     "allocate_page_pool",
-    "append_kv_rows",
-    "kv_cache_bytes",
     "page_pool_bytes",
-    "pages_in_use",
 ]

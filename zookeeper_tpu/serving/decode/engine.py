@@ -1,20 +1,24 @@
-"""The autoregressive decode engine: two compiled programs over
-device-resident KV-cache state.
+"""The autoregressive decode engine: compiled programs over a
+device-resident page pool.
 
 The forward-only serving engine re-runs the full context per token —
 O(s^2) work per emitted token and no sequence state between requests.
 This engine is the real decode path (ROADMAP item 1): the KV cache
-lives on device as engine state (``cache.py`` — per-layer
-``[slots, capacity, heads, head_dim]`` buffers, slots sharded on the
-data axes and heads on the model axis via the Partitioner rule
-tables), and exactly TWO program families serve all traffic:
+lives on device as engine state, ONE layout (docs/DESIGN.md §20): a
+page pool shared by every slot (``pages.py`` — per-layer
+``[num_pages, head_shards, page_size, row_width]`` buffers, head
+shards on the model axis via the Partitioner rule tables) with
+per-slot page tables as runtime operands. A pool of ``slots x
+capacity / page_size`` pages (``pool_pages=-1``) with the prefix cache
+off is the worst-case-provisioned slot cache. Two program families
+serve all cold traffic:
 
 - **prefill** — bucketed like the forward engine (``prefill_buckets``
   x ``seq_buckets`` shape buckets, one AOT compile each at
   ``warmup()``): runs the ordinary full-context forward over a
-  right-padded prompt group, scatters every layer's K/V heads into the
-  group's slots, and emits each request's FIRST token (the TTFT
-  token). Ledgered as ``prefill`` in the ProgramLedger.
+  right-padded prompt group, scatters every layer's K/V rows through
+  the group's page-table rows, and emits each request's FIRST token
+  (the TTFT token). Ledgered as ``prefill`` in the ProgramLedger.
 - **decode_step** — ONE program regardless of traffic: one token for
   every slot in the slot array per dispatch (inactive slots compute
   masked garbage that is never delivered — the fixed shape is what
@@ -35,14 +39,13 @@ dispatch — ``swap_weights`` is therefore atomic per dispatch exactly
 like the forward engine's (the per-SEQUENCE weight-version contract
 lives a level up, in ``DecodeScheduler.request_swap``).
 
-Two eras extend the grid without changing the discipline: the
+Further families extend the grid without changing the discipline: the
 speculative ``verify_step`` family (docs/DESIGN.md §18, one compile
-per window width), and ``kv_layout="paged"`` (§20) — the same program
-shapes re-expressed over a SHARED page pool with per-slot page tables
-as runtime operands, plus the warm-prefix ``prefill_extend`` family
-(suffix-only admission over cache-resident prefix pages) and the
-one-page ``copy_page`` CoW primitive. Every member is AOT-warmed and
-ledgered; ``compile_count`` still pins at zero growth under traffic.
+per window width), the warm-prefix ``prefill_extend`` family (§20:
+suffix-only admission over cache-resident prefix pages; also the
+chunked prefill's program, §25) and the one-page ``copy_page`` CoW
+primitive. Every member is AOT-warmed and ledgered; ``compile_count``
+still pins at zero growth under traffic.
 """
 
 import logging
@@ -53,11 +56,6 @@ import numpy as np
 
 from zookeeper_tpu.core import Field, component
 from zookeeper_tpu.observability import trace as _trace
-from zookeeper_tpu.serving.decode.cache import (
-    allocate_kv_cache,
-    kv_cache_bytes,
-    pages_in_use,
-)
 from zookeeper_tpu.serving.decode.pages import (
     PagePool,
     allocate_page_pool,
@@ -72,9 +70,10 @@ __all__ = ["DecodeEngine"]
 
 @component
 class DecodeEngine:
-    """Paged/ring KV-cache decode engine over a cached-attention LM
-    module (``TransformerLMModule``-shaped: ``prefill`` and
-    ``decode_step`` apply methods sharing the ``__call__`` weights).
+    """Page-pool decode engine over a cached-attention LM module
+    (``TransformerLMModule``-shaped: ``prefill``, ``decode_step_paged``
+    and ``decode_verify_paged`` apply methods sharing the ``__call__``
+    weights).
 
     Configure the slot array and buckets as Fields; bind the runtime
     objects with :meth:`bind`. The engine is the DEVICE half only —
@@ -84,9 +83,9 @@ DecodeScheduler`.
     """
 
     #: Concurrent sequence slots — the decode program's fixed batch.
-    #: More slots = more sequences per dispatch (throughput) at
-    #: slots x capacity KV HBM; keep it a multiple of the mesh's
-    #: data-axis product to serve with a sharded cache.
+    #: More slots = more sequences per dispatch (throughput); keep it
+    #: a multiple of the mesh's data-axis product so the per-slot
+    #: operands shard.
     slots: int = Field(8)
     #: Prompt-length buckets for the prefill program (ascending). One
     #: compile per (prefill_bucket, seq_bucket) pair at warmup; a
@@ -103,21 +102,19 @@ DecodeScheduler`.
     #: anyway); an explicit smaller value caps memory and truncates
     #: generation at capacity. Rounded up to a ``page_size`` multiple.
     kv_capacity: int = Field(-1)
-    #: KV page granularity (tokens): the accounting/alignment unit for
-    #: capacity and the ``kv_pages_in_use`` occupancy numbers, and the
-    #: nesting unit for the paged decode kernel's KV read blocks.
+    #: KV page granularity (tokens): the pool's allocation unit, the
+    #: alignment unit for capacity, and what the decode kernel fetches.
     page_size: int = Field(16)
     #: Cache-attention flavor for the decode_step program
-    #: (docs/DESIGN.md §17): "auto" runs the length-aware Pallas paged
+    #: (docs/DESIGN.md §17): "auto" runs the length-aware Pallas pool
     #: decode kernel on TPU and the reference einsum elsewhere
     #: (interpret-mode Pallas on CPU is a numerics vehicle, not a
     #: serving path — the same posture the bench takes for flash);
     #: "pallas" forces the kernel (interpret off-TPU), "reference"
-    #: forces the oracle einsum, "module" defers to the module's own
-    #: ``decode_attention`` setting/injected callable. Unsupported
-    #: geometry (see ``ops.decode_attention_supported``) degrades
-    #: "auto" to the reference with a warning; an explicit "pallas"
-    #: that cannot be honoured raises at bind.
+    #: forces the oracle einsum (tests compare the two through these).
+    #: Unsupported geometry (see ``ops.decode_attention_supported``)
+    #: degrades "auto" to the reference with a warning; an explicit
+    #: "pallas" that cannot be honoured raises at bind.
     decode_attention: str = Field("auto")
     #: Program-naming prefix for the ProgramLedger / recompile events
     #: (docs/DESIGN.md §18): a speculative-decode DRAFT engine runs the
@@ -127,38 +124,32 @@ DecodeScheduler`.
     #: programs ledger as ``draft_prefill`` / ``draft_decode_step`` /
     #: ``draft_verify_step`` next to the teacher's.
     ledger_prefix: str = Field("")
-    #: KV storage layout (docs/DESIGN.md §20): "slots" (the §15
-    #: per-slot contiguous buffers — worst-case provisioned, zero
-    #: indirection on the hot path; the certified default) or "paged"
-    #: (a SHARED device page pool + per-slot page tables as runtime
-    #: operands: capacity is pooled across slots, warm prompt prefixes
-    #: share pages through the radix prefix cache with copy-on-write,
-    #: and admission sheds on pool exhaustion instead of slot count
-    #: alone). Token-parity discipline is identical in both layouts.
-    kv_layout: str = Field("slots")
-    #: Total pool pages per layer (paged layout only). -1 sizes the
-    #: pool to ``slots × capacity/page_size`` — worst-case parity with
-    #: the slot layout, useful for certification; production sets it
-    #: SMALLER than worst case (that is the entire point of pooling:
-    #: resident tokens are bounded by actual lengths, not slot count ×
-    #: capacity) with admission shedding as the backstop.
+    #: A constant: "paged" is the one KV layout (docs/DESIGN.md §20),
+    #: kept as a field because two benchmark configurations spell it out.
+    kv_layout: str = Field("paged")
+    #: Total pool pages per layer. -1 sizes the pool to ``slots ×
+    #: capacity/page_size`` — worst case: no dispatch can wait on a
+    #: page (with the prefix cache off this IS a contiguous cache a
+    #: slot); production sets it SMALLER (that is the entire point of
+    #: pooling: resident tokens are bounded by actual lengths, not
+    #: slot count × capacity) with admission shedding as the backstop.
     pool_pages: int = Field(-1)
-    #: KV quantization for the paged pool: "none" (rows in the model
+    #: KV quantization for the pool: "none" (rows in the model
     #: compute dtype) or "int8" (rows stored int8 with page-shaped
     #: per-(row, head) float32 scales, dequantized inside the attention
     #: read — double the resident tokens per HBM byte, documented-ULP
     #: numerics; docs/DESIGN.md §20).
     kv_quant: str = Field("none")
-    #: Radix prefix cache over prompt prefixes (paged layout only):
-    #: warm-prefix admissions skip prefill for cache-resident pages
+    #: Radix prefix cache over prompt prefixes: warm-prefix
+    #: admissions skip prefill for cache-resident pages
     #: (the warm-extend program computes only the suffix) with
     #: copy-on-write at the divergence point and LRU eviction under
     #: pool pressure. Off = every admission prefills cold (the pool
     #: still pools capacity).
     prefix_cache: bool = Field(True)
-    #: Chunked prefill (docs/DESIGN.md §25; paged layout only, like
-    #: ``kv_quant``): > 0 splits every admitted prompt into chunks of
-    #: at most this many tokens, each a :meth:`prefill_chunk` dispatch
+    #: Chunked prefill (docs/DESIGN.md §25): > 0 splits every admitted
+    #: prompt into chunks of at most this many tokens, each a
+    #: :meth:`prefill_chunk` dispatch
     #: the scheduler interleaves with decode steps under its token
     #: budget — a long prompt stops freezing in-flight streams for its
     #: whole prefill. 0 (default) keeps the monolithic prefill. Must
@@ -177,22 +168,24 @@ DecodeScheduler`.
         partitioner: Any = None,
     ) -> "DecodeEngine":
         """Attach the LM module to decode. ``module`` must expose the
-        cached-attention seam (``prefill`` / ``decode_step`` methods
-        plus the ``num_layers/num_heads/d_model/max_seq_len/dtype``
-        geometry attributes — ``TransformerLMModule`` does).
-        ``partitioner`` defaults to single-device; pass the training
-        partitioner to decode under the training dp/tp layout (KV slots
-        shard over the data axes, heads over the model axis)."""
+        page-pool decode seam (``prefill`` / ``decode_step_paged`` /
+        ``decode_verify_paged`` methods plus the
+        ``num_layers/num_heads/d_model/max_seq_len/dtype`` geometry
+        attributes — ``TransformerLMModule`` does). ``partitioner``
+        defaults to single-device; pass the training partitioner to
+        decode under the training dp/tp layout (per-slot operands shard
+        over the data axes, the pool's heads over the model axis)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
-        for method in ("prefill", "decode_step"):
+        for method in (
+            "prefill", "decode_step_paged", "decode_verify_paged"
+        ):
             if not hasattr(module, method):
                 raise ValueError(
                     f"DecodeEngine needs a module with a {method!r} "
-                    "apply method (the cached-attention decode seam — "
-                    "see TransformerLMModule); got "
-                    f"{type(module).__name__}."
+                    "apply method (the page-pool decode seam — see "
+                    f"TransformerLMModule); got {type(module).__name__}."
                 )
         seq_buckets = tuple(int(s) for s in self.seq_buckets)
         if not seq_buckets or any(s < 1 for s in seq_buckets) or list(
@@ -230,7 +223,7 @@ DecodeScheduler`.
                 f"kv_capacity={self.kv_capacity}: expected a positive "
                 "token capacity or -1 (size to the positional table)."
             )
-        # Page-align up: the layout unit a paged kernel would gather.
+        # Page-align up: a slot's table holds whole pages.
         capacity = -(-capacity // self.page_size) * self.page_size
         if max(seq_buckets) > capacity:
             raise ValueError(
@@ -249,27 +242,22 @@ DecodeScheduler`.
             )
 
         if str(self.decode_attention) not in (
-            "auto", "pallas", "reference", "module"
+            "auto", "pallas", "reference"
         ):
             raise ValueError(
                 f"decode_attention={self.decode_attention!r}: expected "
-                "'auto', 'pallas', 'reference', or 'module'."
+                "'auto', 'pallas' or 'reference'."
             )
-        if str(self.kv_layout) not in ("slots", "paged"):
+        if str(self.kv_layout) != "paged":
             raise ValueError(
-                f"kv_layout={self.kv_layout!r}: expected 'slots' or "
-                "'paged'."
+                f"kv_layout={self.kv_layout!r}: 'paged' is the only KV "
+                "layout (the per-slot contiguous 'slots' cache was "
+                "removed in PR 29: pool_pages=-1 with prefix_cache=false "
+                "provisions the same worst case)."
             )
         if str(self.kv_quant) not in ("none", "int8"):
             raise ValueError(
                 f"kv_quant={self.kv_quant!r}: expected 'none' or 'int8'."
-            )
-        paged = str(self.kv_layout) == "paged"
-        if not paged and str(self.kv_quant) != "none":
-            raise ValueError(
-                "kv_quant='int8' requires kv_layout='paged' (the slot "
-                "layout stores rows in the compute dtype; quantization "
-                "lives with the page pool — docs/DESIGN.md §20)."
             )
         if int(self.prefill_chunk_tokens) < 0:
             raise ValueError(
@@ -277,30 +265,15 @@ DecodeScheduler`.
                 "expected 0 (monolithic prefill) or a positive chunk "
                 "size in tokens."
             )
-        if int(self.prefill_chunk_tokens) > 0:
-            # The same loud paged-only seam as kv_quant: the chunk
-            # program appends through the page table at arbitrary row
-            # offsets — the slot layout has no such program, and a
-            # silent fall-back to monolithic prefill would misreport
-            # every ITL plan built on chunking (docs/DESIGN.md §25).
-            if not paged:
-                raise ValueError(
-                    "prefill_chunk_tokens requires kv_layout='paged' "
-                    "(chunks append through the page table via the "
-                    "prefill_extend program family; the slot layout "
-                    "always prefills monolithically — docs/DESIGN.md "
-                    "§25). Set engine.kv_layout='paged' or "
-                    "prefill_chunk_tokens=0."
-                )
-            if int(self.prefill_chunk_tokens) > max(seq_buckets):
-                raise ValueError(
-                    f"prefill_chunk_tokens={self.prefill_chunk_tokens} "
-                    f"exceeds the largest seq bucket {max(seq_buckets)}"
-                    ": chunks ride the warmed prefill_extend width "
-                    "grid, so a chunk wider than every bucket would "
-                    "compile on the dispatch path; shrink the chunk or "
-                    "widen seq_buckets."
-                )
+        if int(self.prefill_chunk_tokens) > max(seq_buckets):
+            raise ValueError(
+                f"prefill_chunk_tokens={self.prefill_chunk_tokens} "
+                f"exceeds the largest seq bucket {max(seq_buckets)}"
+                ": chunks ride the warmed prefill_extend width "
+                "grid, so a chunk wider than every bucket would "
+                "compile on the dispatch path; shrink the chunk or "
+                "widen seq_buckets."
+            )
         max_pages = capacity // int(self.page_size)
         # Layer groups (docs/DESIGN.md §20): a model with sliding-window
         # layers beside full ones keeps a second, smaller pool group.
@@ -309,18 +282,6 @@ DecodeScheduler`.
         )
         window = int(module.window) if any(window_layers) else 0
         kv_heads = int(getattr(module, "kv_heads", module.num_heads))
-        if window and not paged:
-            raise ValueError(
-                "kv_layout='slots' cannot serve a model with window "
-                "layers: its decode kernel would run them as full "
-                "attention. Set engine.kv_layout='paged'."
-            )
-        if kv_heads != int(module.num_heads) and not paged:
-            raise ValueError(
-                "kv_layout='slots' cannot serve grouped key/value heads "
-                "(its cache and kernel hold one head a query head). Set "
-                "engine.kv_layout='paged'."
-            )
         if window and bool(self.prefix_cache):
             raise ValueError(
                 "prefix_cache=true is not implemented for a model with "
@@ -342,34 +303,23 @@ DecodeScheduler`.
         window_pages_per_slot = min(
             max_pages, -(-window // int(self.page_size)) + 2
         )
-        if paged:
-            for method in ("decode_step_paged", "decode_verify_paged"):
-                if not hasattr(module, method):
-                    raise ValueError(
-                        f"kv_layout='paged' needs a module with a "
-                        f"{method!r} apply method (the page-pool decode "
-                        "seam — see TransformerLMModule); got "
-                        f"{type(module).__name__}."
-                    )
-            if self.pool_pages == -1:
-                num_pages = int(self.slots) * max_pages
-            elif self.pool_pages > 0:
-                num_pages = int(self.pool_pages)
-            else:
-                raise ValueError(
-                    f"pool_pages={self.pool_pages}: expected a positive "
-                    "page count or -1 (worst-case parity with the slot "
-                    "layout)."
-                )
-            if num_pages < max_pages:
-                raise ValueError(
-                    f"pool_pages={num_pages} below capacity/page_size="
-                    f"{max_pages}: one full-capacity sequence could "
-                    "never be served; raise pool_pages or shrink "
-                    "kv_capacity."
-                )
+        if self.pool_pages == -1:
+            num_pages = int(self.slots) * max_pages
+        elif self.pool_pages > 0:
+            num_pages = int(self.pool_pages)
         else:
-            num_pages = 0
+            raise ValueError(
+                f"pool_pages={self.pool_pages}: expected a positive "
+                "page count or -1 (worst case: slots x "
+                "capacity/page_size)."
+            )
+        if num_pages < max_pages:
+            raise ValueError(
+                f"pool_pages={num_pages} below capacity/page_size="
+                f"{max_pages}: one full-capacity sequence could "
+                "never be served; raise pool_pages or shrink "
+                "kv_capacity."
+            )
         if partitioner is None:
             from zookeeper_tpu.parallel.partitioner import (
                 SingleDevicePartitioner,
@@ -383,7 +333,6 @@ DecodeScheduler`.
         object.__setattr__(self, "_prefill_buckets", prefill_buckets)
         object.__setattr__(self, "_capacity", capacity)
         object.__setattr__(self, "_position_cap", position_cap)
-        object.__setattr__(self, "_paged", paged)
         object.__setattr__(self, "_num_pages", num_pages)
         object.__setattr__(self, "_max_pages", max_pages)
         object.__setattr__(self, "_kv_heads", kv_heads)
@@ -405,9 +354,7 @@ DecodeScheduler`.
                 prefix_cache=bool(self.prefix_cache),
                 window=window,
                 window_pages=self._window_pages,
-            )
-            if paged
-            else None,
+            ),
         )
 
         variables = {"params": params, **dict(model_state or {})}
@@ -423,8 +370,8 @@ DecodeScheduler`.
         # allocated. Heads that do not divide keep one shard, which the
         # divisibility check below turns into the replicated fallback.
         head_shards = 1
-        if paged and mesh is not None:
-            model_axis = partitioner.decode_cache_axes()[1]
+        if mesh is not None:
+            data_axes, model_axis = partitioner.decode_cache_axes()
             tp = int(mesh.shape[model_axis]) if model_axis else 1
             if kv_heads % tp == 0:
                 head_shards = tp
@@ -433,18 +380,20 @@ DecodeScheduler`.
         cache_sharding = None
         cache_replicated = mesh is not None
         if mesh is not None:
-            cache_sharding = (
-                partitioner.page_pool_sharding(cache)
-                if paged
-                else partitioner.decode_cache_sharding(cache)
-            )
+            cache_sharding = partitioner.page_pool_sharding(cache)
             if cache_sharding is not None:
-                # Divisibility: slots over the data axes, heads over the
-                # model axis. When the shapes cannot split, fall back to
-                # a fully-replicated cache (correct, memory-redundant)
-                # rather than dying — the compile_forward small-bucket
-                # posture.
+                # Divisibility: the pool's head shards over the model
+                # axis, and the per-slot operands of the sharded kernel
+                # over the data axes. When the shapes cannot split,
+                # fall back to a fully-replicated cache (correct,
+                # memory-redundant) rather than dying — the
+                # compile_forward small-bucket posture.
                 try:
+                    dp = int(np.prod([mesh.shape[a] for a in data_axes]))
+                    if int(self.slots) % dp:
+                        raise ValueError(
+                            f"{self.slots} slots over {dp} data shards"
+                        )
                     jax.tree.map(
                         lambda x, s: s.shard_shape(np.shape(x)),
                         cache,
@@ -469,28 +418,18 @@ DecodeScheduler`.
         object.__setattr__(self, "_cache_sharding", cache_sharding)
         object.__setattr__(self, "_cache_replicated", cache_replicated)
         object.__setattr__(self, "_cache", self._place_cache(cache))
-        if paged:
-            nbytes = page_pool_bytes(
-                int(module.num_layers),
-                num_pages,
-                int(self.page_size),
-                kv_heads,
-                head_dim,
-                np.dtype(module.dtype).itemsize,
-                quant=str(self.kv_quant),
-                head_shards=head_shards,
-                window_layers=window_layers,
-                window_pages=self._window_pages,
-            )
-        else:
-            nbytes = kv_cache_bytes(
-                int(module.num_layers),
-                int(self.slots),
-                capacity,
-                kv_heads,
-                head_dim,
-                np.dtype(module.dtype).itemsize,
-            )
+        nbytes = page_pool_bytes(
+            int(module.num_layers),
+            num_pages,
+            int(self.page_size),
+            kv_heads,
+            head_dim,
+            np.dtype(module.dtype).itemsize,
+            quant=str(self.kv_quant),
+            head_shards=head_shards,
+            window_layers=window_layers,
+            window_pages=self._window_pages,
+        )
         object.__setattr__(self, "_cache_nbytes", nbytes)
         object.__setattr__(self, "_compiled_cache", {})
         object.__setattr__(self, "_compile_count", 0)
@@ -505,40 +444,31 @@ DecodeScheduler`.
 
     def _resolve_decode_attention(self):
         """Resolve the ``decode_attention`` Field into ``(flavor_tag,
-        override_fn)`` — the callable threaded into the decode_step
-        trace (None = defer to the module's own setting).
+        attention_fn)`` — the callable threaded into the decode_step
+        trace.
 
-        "auto" selects the paged kernel only on a real TPU backend:
+        "auto" selects the pool kernel only on a real TPU backend:
         interpret-mode Pallas on CPU is a grid-loop INTERPRETER, orders
         of magnitude slower than the fused einsum — the same reason the
         bench runs dense prefill off-TPU. On a mesh the kernel is
-        wrapped in ``sharded_paged_decode_attention`` (slots over the
-        data axes, heads over the model axis — or fully replicated
-        specs when the cache took the replicated fallback), because
-        GSPMD would otherwise gather the whole cache around the opaque
-        pallas call."""
+        wrapped in ``sharded_pool_paged_decode_attention`` (slots over
+        the data axes, the pool's heads over the model axis — or fully
+        replicated specs when the pool took the replicated fallback),
+        because GSPMD would otherwise gather the whole pool around the
+        opaque pallas call."""
         import jax
 
         from zookeeper_tpu import ops
 
-        module = self._module
-        paged = bool(getattr(self, "_paged", False))
         choice = str(self.decode_attention)
-        if choice == "module":
-            return "module", None
         if choice == "auto":
             choice = (
                 "pallas" if jax.default_backend() == "tpu" else "reference"
             )
-        reference = (
-            ops.pool_decode_attention if paged else ops.cached_attention
-        )
         if choice == "reference":
-            return "reference", reference
+            return "reference", ops.pool_decode_attention
         head_dim = self._head_dim()
-        if not ops.decode_attention_supported(
-            self._kv_heads, head_dim, paged=paged
-        ):
+        if not ops.decode_attention_supported(self._kv_heads, head_dim):
             if str(self.decode_attention) == "pallas":
                 # Asked for by name: serving the reference under the
                 # kernel's name would hide which program ran.
@@ -556,35 +486,23 @@ DecodeScheduler`.
                 "REFERENCE einsum instead",
                 head_dim,
             )
-            return "reference", reference
+            return "reference", ops.pool_decode_attention
         from functools import partial
 
         mesh = self._partitioner.mesh
         if mesh is None:
-            if paged:
-                # Page size / block policy come from the pool shapes.
-                return "pallas", ops.pool_paged_decode_attention
-            return "pallas", partial(
-                ops.paged_decode_attention, page_size=int(self.page_size)
-            )
-        # The SAME axis derivation the cache placement used: a
-        # disagreement here would make GSPMD reshard the cache around
+            # Page size / block policy come from the pool shapes.
+            return "pallas", ops.pool_paged_decode_attention
+        # The SAME axis derivation the pool's placement used: a
+        # disagreement here would make GSPMD reshard the pool around
         # the kernel every dispatch.
         data_axes, model_axis = self._partitioner.decode_cache_axes()
-        sharded_kwargs = dict(
+        return "pallas", partial(
+            ops.sharded_pool_paged_decode_attention,
             mesh=mesh,
             data_axes=data_axes,
             model_axis=model_axis,
             replicated=bool(self._cache_replicated),
-        )
-        if paged:
-            return "pallas", partial(
-                ops.sharded_pool_paged_decode_attention, **sharded_kwargs
-            )
-        return "pallas", partial(
-            ops.sharded_paged_decode_attention,
-            page_size=int(self.page_size),
-            **sharded_kwargs,
         )
 
     def _publish_bind_gauges(self) -> None:
@@ -594,8 +512,8 @@ DecodeScheduler`.
 
         default_registry().gauge(
             "zk_decode_kv_bytes",
-            help="HBM provisioned for the decode KV cache (k+v, all "
-            "layers, full slot capacity)",
+            help="HBM provisioned for the decode KV page pool (k+v and "
+            "scales, all layers)",
         ).set(float(self._cache_nbytes))
 
     def decode_mbu_for(self, seconds: float, program: str = "decode_step") -> float:
@@ -632,7 +550,7 @@ DecodeScheduler`.
         """Keep THIS engine's ``decode_mbu`` for one completed
         (readback-bounded) decode-path dispatch (``/statusz`` and
         bench.py read it; no registry series: static cost-analysis
-        bytes count whole buffers the paged kernel never reads). Under
+        bytes count whole buffers the pool kernel never reads). Under
         speculation the hot program is ``verify_step``, not
         ``decode_step`` — ``verify()`` feeds it too. Total: never
         raises."""
@@ -645,7 +563,7 @@ DecodeScheduler`.
     @property
     def decode_attention_flavor(self) -> str:
         """The RESOLVED decode-attention flavor this engine serves with
-        ("pallas" / "reference" / "module") — after auto-selection and
+        ("pallas" / "reference") — after auto-selection and
         any unsupported-geometry degrade."""
         self._require_bound()
         return self._decode_attention_flavor
@@ -685,31 +603,20 @@ DecodeScheduler`.
     def _allocate_cache(self):
         """The ONE cache-geometry call (``bind`` and ``_reset_cache``
         must allocate identical trees — a layout change made in one
-        place would serve post-crash resubmits from a diverged cache).
-        Layout-dispatched: the slot-contiguous buffers or the shared
-        page pool (docs/DESIGN.md §20)."""
+        place would serve post-crash resubmits from a diverged cache):
+        the shared page pool (docs/DESIGN.md §20)."""
         module = self._module
-        head_dim = self._head_dim()
-        if getattr(self, "_paged", False):
-            return allocate_page_pool(
-                int(module.num_layers),
-                self._num_pages,
-                int(self.page_size),
-                self._kv_heads,
-                head_dim,
-                module.dtype,
-                quant=str(self.kv_quant),
-                head_shards=self._head_shards,
-                window_layers=self._window_layers,
-                window_pages=self._window_pages,
-            )
-        return allocate_kv_cache(
+        return allocate_page_pool(
             int(module.num_layers),
-            int(self.slots),
-            self._capacity,
+            self._num_pages,
+            int(self.page_size),
             self._kv_heads,
-            head_dim,
+            self._head_dim(),
             module.dtype,
+            quant=str(self.kv_quant),
+            head_shards=self._head_shards,
+            window_layers=self._window_layers,
+            window_pages=self._window_pages,
         )
 
     def _place_cache(self, cache):
@@ -732,16 +639,14 @@ DecodeScheduler`.
         dispatch would die on deleted arrays, breaking the scheduler's
         resubmit-after-restart contract. A zeroed cache is consistent:
         a crash fails every in-flight stream, so no slot's previous
-        contents are live. In the paged layout the HOST allocator is
-        reset with the device pool (refcounts zeroed, every page free,
-        prefix trie dropped — its nodes indexed bytes that no longer
-        exist): the chaos suite pins zero leaked pages across this
-        path."""
+        contents are live. The HOST allocator is reset with the device
+        pool (refcounts zeroed, every page free, prefix trie dropped —
+        its nodes indexed bytes that no longer exist): the chaos suite
+        pins zero leaked pages across this path."""
         object.__setattr__(
             self, "_cache", self._place_cache(self._allocate_cache())
         )
-        if getattr(self, "_pool", None) is not None:
-            self._pool.reset()
+        self._pool.reset()
 
     # -- geometry --------------------------------------------------------
 
@@ -774,32 +679,18 @@ DecodeScheduler`.
         self._require_bound()
         return self._cache_nbytes
 
-    def kv_pages_in_use(self, lengths) -> int:
-        """Occupancy accounting for the gauge/statusz. The paged layout
-        reports the REAL allocator count (pages the free list has
-        handed out — prefix-cache-retained pages included, because they
-        genuinely occupy pool HBM); the slot layout keeps the §15
-        host-side estimate ``Σ ceil(len/page)`` over the ACTIVE slots'
-        ``lengths``."""
-        if getattr(self, "_paged", False):
-            return int(self._pool.used_pages)
-        return pages_in_use(lengths, int(self.page_size))
+    def kv_pages_in_use(self) -> int:
+        """Occupancy for the gauge/statusz: the allocator's count (pages
+        the free list has handed out — prefix-cache-retained pages
+        included, because they genuinely occupy pool HBM)."""
+        return int(self._pool.used_pages)
 
-    # -- page lifecycle (the scheduler-facing paged surface) -------------
-    #
-    # Every method is callable in BOTH layouts so the scheduler never
-    # branches on kv_layout: the slot layout answers with the trivial
-    # (always-cold, always-fits, nothing-to-release) degenerate.
-
-    @property
-    def paged(self) -> bool:
-        self._require_bound()
-        return bool(self._paged)
+    # -- page lifecycle (the scheduler-facing surface) -------------------
 
     @property
     def page_pool(self):
         """The host-side :class:`~zookeeper_tpu.serving.decode.pages.\
-PagePool` (None in the slot layout)."""
+PagePool`."""
         self._require_bound()
         return self._pool
 
@@ -814,10 +705,7 @@ PagePool` (None in the slot layout)."""
         ``copy=False`` — the scheduler's split: host bookkeeping under
         its lock, the device copy outside via :meth:`copy_page`) or
         None when the pool is exhausted (nothing allocated — the
-        caller requeues or sheds). Slot layout: always the trivial
-        cold plan."""
-        if not getattr(self, "_paged", False):
-            return {"shared_tokens": 0, "cow": None}
+        caller requeues or sheds)."""
         plan = self._pool.assign_prompt(int(slot), prompt)
         if plan is None:
             return None
@@ -832,53 +720,40 @@ PagePool` (None in the slot layout)."""
         """Pre-dispatch guarantee that ``slot``'s pages cover ``rows``
         total KV rows (decode needs ``length + 1``; a verify window
         ``length + w``). False = pool exhausted after prefix-cache
-        eviction. Slot layout: trivially True (capacity is
-        pre-provisioned)."""
-        if not getattr(self, "_paged", False):
-            return True
+        eviction."""
         return self._pool.ensure_rows(int(slot), int(rows))
 
     def release_slot(self, slot: int) -> None:
         """Stream finished/failed: drop the slot's page references
         (prefix-cache-shared pages stay resident for warm hits)."""
-        if getattr(self, "_paged", False):
-            self._pool.release_slot(int(slot))
+        self._pool.release_slot(int(slot))
 
     def release_behind_window(self, lengths) -> int:
         """Once a scheduler iteration (docs/DESIGN.md §20): hand back
         the window group's pages every sequence has left wholly behind
         ``length - window``. Returns the pages freed; 0 for a model of
-        one layer group, and in the slot layout."""
-        if not getattr(self, "_paged", False):
-            return 0
+        one layer group."""
         return self._pool.release_behind_window(lengths)
 
     def insert_prefix(self, slot: int, prompt) -> int:
         """Cache the admitted prompt's pages for future warm hits
         (called after the prefill/extend dispatch landed them)."""
-        if not getattr(self, "_paged", False):
-            return 0
         return self._pool.insert_prefix(int(slot), prompt)
 
     def invalidate_prefix_cache(self) -> int:
         """Drop every cached prefix page (weight hot-swap: cached K/V
         belongs to the OLD weights). Returns nodes dropped."""
-        if not getattr(self, "_paged", False):
-            return 0
         return self._pool.invalidate_prefix()
 
-    def pool_status(self) -> Optional[dict]:
-        """The ``/statusz`` ``kv_pool`` sub-section (None in the slot
-        layout)."""
-        if not getattr(self, "_paged", False):
-            return None
+    def pool_status(self) -> dict:
+        """The ``/statusz`` ``kv_pool`` sub-section."""
         return self._pool.status()
 
     @property
     def compile_count(self) -> int:
-        """XLA compiles so far. After ``warmup()`` this is exactly
-        ``len(prefill_buckets) * len(seq_buckets) + 1`` and continuous
-        slot refill must never move it."""
+        """XLA compiles so far. After ``warmup()`` this is the warmed
+        grid's size (``warmup``'s return) and continuous slot refill
+        must never move it."""
         return getattr(self, "_compile_count", 0)
 
     @property
@@ -1095,9 +970,7 @@ PagePool` (None in the slot layout)."""
         share of a fetched block's rows that are real. The arithmetic
         is the kernel's own (``ops.pool_decode_work``)."""
         if not (
-            _trace.enabled()
-            and self._paged
-            and self._decode_attention_flavor == "pallas"
+            _trace.enabled() and self._decode_attention_flavor == "pallas"
         ):
             return
         from zookeeper_tpu import ops
@@ -1138,47 +1011,28 @@ PagePool` (None in the slot layout)."""
             return cached
         if during_dispatch and self._warmed:
             self._note_dispatch_compile("decode_step")
-        module = self._module
         # Static by closure: the resolved decode-attention flavor (the
-        # paged kernel, its sharded wrapper, or the reference einsum)
+        # pool kernel, its sharded wrapper, or the reference einsum)
         # is part of THIS compiled program's identity.
-        attn_override = getattr(self, "_decode_attention_fn", None)
+        attn_override = self._decode_attention_fn
         n = int(self.slots)
-        if self._paged:
 
-            def decode_fn(variables, cache, tokens, lengths, table):
-                (logits, new_cache), load = self._apply(
-                    variables, tokens, lengths, cache, table,
-                    method="decode_step_paged",
-                    attention_override=attn_override,
-                )
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return new_cache, (nxt if load is None else (nxt, load))
-
-            example = (
-                self._variables,
-                self._cache,
-                jax.ShapeDtypeStruct((n,), np.int32),
-                jax.ShapeDtypeStruct((n,), np.int32),
-                self._table_like(n),
+        def decode_fn(variables, cache, tokens, lengths, table):
+            (logits, new_cache), load = self._apply(
+                variables, tokens, lengths, cache, table,
+                method="decode_step_paged",
+                attention_override=attn_override,
             )
-        else:
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return new_cache, (nxt if load is None else (nxt, load))
 
-            def decode_fn(variables, cache, tokens, lengths):
-                logits, new_cache = module.apply(
-                    variables, tokens, lengths, cache,
-                    method="decode_step",
-                    attention_override=attn_override,
-                )
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return new_cache, nxt
-
-            example = (
-                self._variables,
-                self._cache,
-                jax.ShapeDtypeStruct((n,), np.int32),
-                jax.ShapeDtypeStruct((n,), np.int32),
-            )
+        example = (
+            self._variables,
+            self._cache,
+            jax.ShapeDtypeStruct((n,), np.int32),
+            jax.ShapeDtypeStruct((n,), np.int32),
+            self._table_like(n),
+        )
         compiled = self._aot(
             "decode_step", decode_fn, example, donate_cache_at=1
         )
@@ -1198,88 +1052,55 @@ PagePool` (None in the slot layout)."""
             return cached
         if during_dispatch and self._warmed:
             self._note_dispatch_compile(f"prefill/b{pb}s{sb}")
-        module = self._module
-        if self._paged:
-            ps = int(self.page_size)
-            window_layers = self._window_layers
+        ps = int(self.page_size)
+        window_layers = self._window_layers
 
-            def prefill_fn(variables, cache, tokens, lengths, slot_rows):
-                from zookeeper_tpu.models.transformer import (
-                    _pool_write_rows,
-                    layer_page_table,
-                )
+        def prefill_fn(variables, cache, tokens, lengths, slot_rows):
+            from zookeeper_tpu.models.transformer import (
+                _pool_write_rows,
+                layer_page_table,
+            )
 
-                (last_logits, kv), load = self._apply(
-                    variables, tokens, lengths, method="prefill"
-                )
-                # Scatter each prompt row through its slot's page-table
-                # row: position j lands at (slot_rows[:, j // ps],
-                # j % ps). Rows past the true length, unallocated table
-                # entries, and a partial group's padding rows (all
-                # -1 rows) take the OOB page sentinel and write
-                # nowhere — the paged twin of the slot-id drop.
-                # A window layer's table holds the prompt's tail
-                # only: the rows before it drop the same way.
-                j = jnp.arange(sb)
-                row = jnp.clip(j // ps, 0, slot_rows.shape[-1] - 1)
-                offs = jnp.broadcast_to(j % ps, (pb, sb))
+            (last_logits, kv), load = self._apply(
+                variables, tokens, lengths, method="prefill"
+            )
+            # Scatter each prompt row through its slot's page-table
+            # row: position j lands at (slot_rows[:, j // ps], j % ps).
+            # Rows past the true length, unallocated table entries, and
+            # a partial group's padding rows (all -1 rows) take the OOB
+            # page sentinel and write nowhere. A window layer's table
+            # holds the prompt's tail only: the rows before it drop the
+            # same way.
+            j = jnp.arange(sb)
+            row = jnp.clip(j // ps, 0, slot_rows.shape[-1] - 1)
+            offs = jnp.broadcast_to(j % ps, (pb, sb))
 
-                def targets(table, num_pages):
-                    pages = table[:, row]  # [pb, sb]
-                    dead = (j[None, :] >= lengths[:, None]) | (pages < 0)
-                    return jnp.where(dead, num_pages, pages)
+            def targets(table, num_pages):
+                pages = table[:, row]  # [pb, sb]
+                dead = (j[None, :] >= lengths[:, None]) | (pages < 0)
+                return jnp.where(dead, num_pages, pages)
 
-                new_cache = []
-                for layer, (k, v), windowed in zip(cache, kv, window_layers):
-                    table = layer_page_table(slot_rows, windowed)
-                    new_cache.append(
-                        _pool_write_rows(
-                            layer, {"k": k, "v": v},
-                            targets(table, layer["k"].shape[0]), offs,
-                        )
+            new_cache = []
+            for layer, (k, v), windowed in zip(cache, kv, window_layers):
+                table = layer_page_table(slot_rows, windowed)
+                new_cache.append(
+                    _pool_write_rows(
+                        layer, {"k": k, "v": v},
+                        targets(table, layer["k"].shape[0]), offs,
                     )
-                first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-                return tuple(new_cache), (
-                    first if load is None else (first, load)
                 )
-
-            example = (
-                self._variables,
-                self._cache,
-                jax.ShapeDtypeStruct((pb, sb), np.int32),
-                jax.ShapeDtypeStruct((pb,), np.int32),
-                self._table_like(pb),
+            first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+            return tuple(new_cache), (
+                first if load is None else (first, load)
             )
-        else:
 
-            def prefill_fn(variables, cache, tokens, lengths, slot_ids):
-                last_logits, kv = module.apply(
-                    variables, tokens, lengths, method="prefill"
-                )
-                new_cache = []
-                for layer, (k, v) in zip(cache, kv):
-                    # Scatter the group's K/V heads into its slots'
-                    # first sb rows. mode="drop": the PADDING rows of a
-                    # partial group carry slot id == slots (out of
-                    # bounds) and must write nowhere.
-                    new_cache.append({
-                        "k": layer["k"].at[slot_ids, :sb].set(
-                            k, mode="drop"
-                        ),
-                        "v": layer["v"].at[slot_ids, :sb].set(
-                            v, mode="drop"
-                        ),
-                    })
-                first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-                return tuple(new_cache), first
-
-            example = (
-                self._variables,
-                self._cache,
-                jax.ShapeDtypeStruct((pb, sb), np.int32),
-                jax.ShapeDtypeStruct((pb,), np.int32),
-                jax.ShapeDtypeStruct((pb,), np.int32),
-            )
+        example = (
+            self._variables,
+            self._cache,
+            jax.ShapeDtypeStruct((pb, sb), np.int32),
+            jax.ShapeDtypeStruct((pb,), np.int32),
+            self._table_like(pb),
+        )
         compiled = self._aot(
             f"prefill/b{pb}s{sb}", prefill_fn, example, donate_cache_at=1
         )
@@ -1288,7 +1109,7 @@ PagePool` (None in the slot layout)."""
 
     def _verify_compiled(self, width: int, *, during_dispatch: bool = False):
         """The multi-token verify/append program (docs/DESIGN.md §18):
-        ``width`` tokens per slot through ``decode_verify`` in one
+        ``width`` tokens per slot through ``decode_verify_paged`` in one
         dispatch — the speculative teacher runs it at ``k + 1``, the
         draft at its catch-up width. One compile per width, part of the
         warmed grid (``warmup_verify``); ledgered as ``verify_step``
@@ -1313,39 +1134,22 @@ PagePool` (None in the slot layout)."""
             self._note_dispatch_compile(f"verify_step/w{width}")
         module = self._module
         n = int(self.slots)
-        if self._paged:
 
-            def verify_fn(variables, cache, tokens, lengths, table):
-                logits, new_cache = module.apply(
-                    variables, tokens, lengths, cache, table,
-                    method="decode_verify_paged",
-                )
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return new_cache, nxt
-
-            example = (
-                self._variables,
-                self._cache,
-                jax.ShapeDtypeStruct((n, int(width)), np.int32),
-                jax.ShapeDtypeStruct((n,), np.int32),
-                self._table_like(n),
+        def verify_fn(variables, cache, tokens, lengths, table):
+            logits, new_cache = module.apply(
+                variables, tokens, lengths, cache, table,
+                method="decode_verify_paged",
             )
-        else:
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return new_cache, nxt
 
-            def verify_fn(variables, cache, tokens, lengths):
-                logits, new_cache = module.apply(
-                    variables, tokens, lengths, cache,
-                    method="decode_verify",
-                )
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return new_cache, nxt
-
-            example = (
-                self._variables,
-                self._cache,
-                jax.ShapeDtypeStruct((n, int(width)), np.int32),
-                jax.ShapeDtypeStruct((n,), np.int32),
-            )
+        example = (
+            self._variables,
+            self._cache,
+            jax.ShapeDtypeStruct((n, int(width)), np.int32),
+            jax.ShapeDtypeStruct((n,), np.int32),
+            self._table_like(n),
+        )
         compiled = self._aot(
             f"verify_step/w{width}", verify_fn, example, donate_cache_at=1
         )
@@ -1355,8 +1159,8 @@ PagePool` (None in the slot layout)."""
     def _extend_compiled(
         self, pb: int, w: int, *, during_dispatch: bool = False
     ):
-        """The WARM-prefix prefill program (paged layout + prefix
-        cache, docs/DESIGN.md §20): a group whose prompts share
+        """The WARM-prefix prefill program (prefix cache,
+        docs/DESIGN.md §20): a group whose prompts share
         cache-resident prefixes enters ``decode_verify_paged`` with
         each prompt's SUFFIX as the window — the shared pages are read
         through the page table, never recomputed, and the emitted first
@@ -1487,7 +1291,7 @@ PageTransfer` moves between mesh slices. READ-ONLY: the source pool
         """The page-IMPORT program (docs/DESIGN.md §22): scatter a
         transferred page block into this engine's pool at the adopted
         page ids. Padding ids carry the OOB page sentinel
-        (``num_pages``) and write nowhere (``mode="drop"`` — the paged
+        (``num_pages``) and write nowhere (``mode="drop"`` — the
         prefill's idiom); the pool is donated like every other
         cache-writing dispatch."""
         import jax
@@ -1543,11 +1347,6 @@ PageTransfer` moves between mesh slices. READ-ONLY: the source pool
         max-seq-bucket prompt writes — every handoff rides this ONE
         compiled shape (shorter prompts pad; docs/DESIGN.md §22)."""
         self._require_bound()
-        if not self._paged:
-            raise RuntimeError(
-                "page transfer is a paged-layout program; "
-                "kv_layout='slots' has no page pool to export."
-            )
         if self._pool.window_group is not None:
             raise NotImplementedError(
                 "page transfer moves one layer group's pages; a model "
@@ -1621,8 +1420,8 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
 
     def warmup(self) -> int:
         """Pre-compile the full program grid (every prefill bucket pair
-        + the decode step; the paged layout adds the warm-extend grid
-        when the prefix cache is on, and the copy-on-write page copy)
+        + the decode step + the copy-on-write page copy, and the
+        warm-extend grid when the prefix cache or chunked prefill is on)
         so no stream ever waits on XLA; a speculative bind extends the
         grid with its verify widths via :meth:`warmup_verify`. Returns
         the number of cached executables."""
@@ -1631,17 +1430,16 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             for sb in self._seq_buckets:
                 self._prefill_compiled(pb, sb)
         self._decode_compiled()
-        if self._paged:
-            self._copy_page_compiled()
-            # The extend grid serves BOTH warm-prefix admissions and
-            # chunked prefill (docs/DESIGN.md §25) — chunk dispatches
-            # bucket their width into the same (pb, sb) pairs, so a
-            # chunked engine with the prefix cache off still needs the
-            # full grid warmed.
-            if self.prefix_cache or int(self.prefill_chunk_tokens) > 0:
-                for pb in self._prefill_buckets:
-                    for sb in self._seq_buckets:
-                        self._extend_compiled(pb, sb)
+        self._copy_page_compiled()
+        # The extend grid serves BOTH warm-prefix admissions and
+        # chunked prefill (docs/DESIGN.md §25) — chunk dispatches
+        # bucket their width into the same (pb, sb) pairs, so a
+        # chunked engine with the prefix cache off still needs the
+        # full grid warmed.
+        if self.prefix_cache or int(self.prefill_chunk_tokens) > 0:
+            for pb in self._prefill_buckets:
+                for sb in self._seq_buckets:
+                    self._extend_compiled(pb, sb)
         object.__setattr__(self, "_warmed", True)
         return len(self._compiled_cache)
 
@@ -1703,14 +1501,9 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         for i, (p, _) in enumerate(zip(prompts, slot_ids)):
             tokens[i, : lens[i]] = np.asarray(p, np.int32)
             lengths[i] = lens[i]
-        if self._paged:
-            # Page-table rows instead of slot ids: padding rows stay
-            # all -1 (every write drops via the OOB page sentinel).
-            ids = self._pool.operand(slot_ids, pb)
-        else:
-            ids = np.full((pb,), int(self.slots), np.int32)  # OOB drop
-            for i, s in enumerate(slot_ids):
-                ids[i] = int(s)
+        # The slots' page-table rows: padding rows stay all -1 (every
+        # write drops via the OOB page sentinel).
+        rows = self._pool.operand(slot_ids, pb)
         compiled = self._prefill_compiled(pb, sb, during_dispatch=True)
         with _trace.span(
             "prefill_dispatch",
@@ -1722,7 +1515,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         ):
             try:
                 new_cache, first = compiled(
-                    self._variables, self._cache, tokens, lengths, ids
+                    self._variables, self._cache, tokens, lengths, rows
                 )
             except BaseException:
                 # Donation already consumed the old buffers: restore a
@@ -1741,7 +1534,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         slot_ids: Sequence[int],
         shared_lens: Sequence[int],
     ):
-        """Warm-prefix admission (paged layout, docs/DESIGN.md §20):
+        """Warm-prefix admission (docs/DESIGN.md §20):
         each prompt's first ``shared_lens[i]`` tokens are already
         resident in cache-shared pages, so only the SUFFIX rides the
         device — through the ``prefill_extend`` program at the smallest
@@ -1751,11 +1544,6 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         import jax
 
         self._require_bound()
-        if not self._paged:
-            raise RuntimeError(
-                "prefill_warm is a paged-layout dispatch; slots-mode "
-                "admissions always run the cold prefill."
-            )
         n = len(prompts)
         if n == 0:
             return np.zeros((0,), np.int32)
@@ -1810,7 +1598,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         slot_ids: Sequence[int],
         offsets: Sequence[int],
     ):
-        """Chunked-prefill append (paged layout, docs/DESIGN.md §25):
+        """Chunked-prefill append (docs/DESIGN.md §25):
         write each lane's ``chunks[i]`` KV rows at positions
         ``offsets[i]..offsets[i] + len(chunks[i]) - 1`` of its slot,
         through the slot's page-table row. This is the warm-extend
@@ -1829,11 +1617,6 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         import jax
 
         self._require_bound()
-        if not self._paged:
-            raise RuntimeError(
-                "prefill_chunk is a paged-layout dispatch; slots-mode "
-                "admissions always run the monolithic prefill."
-            )
         n = len(chunks)
         if n == 0:
             return np.zeros((0,), np.int32)
@@ -1915,9 +1698,6 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 f"arrays, got {tokens.shape} / {lengths.shape}."
             )
         compiled = self._decode_compiled(during_dispatch=True)
-        args = (tokens, lengths)
-        if self._paged:
-            args = (tokens, lengths, self._pool.operand())
         self._note_kv_blocks(lengths)
         with _trace.span(
             "decode_dispatch",
@@ -1928,7 +1708,8 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             t0 = time.perf_counter()
             try:
                 new_cache, nxt = compiled(
-                    self._variables, self._cache, *args
+                    self._variables, self._cache, tokens, lengths,
+                    self._pool.operand(),
                 )
             except BaseException:
                 self._reset_cache()  # donation consumed the buffers
@@ -1969,9 +1750,6 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             )
         w = int(tokens.shape[1])
         compiled = self._verify_compiled(w, during_dispatch=True)
-        args = (tokens, lengths)
-        if self._paged:
-            args = (tokens, lengths, self._pool.operand())
         with _trace.span(
             "verify_dispatch",
             attrs=(
@@ -1983,7 +1761,8 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             t0 = time.perf_counter()
             try:
                 new_cache, nxt = compiled(
-                    self._variables, self._cache, *args
+                    self._variables, self._cache, tokens, lengths,
+                    self._pool.operand(),
                 )
             except BaseException:
                 self._reset_cache()  # donation consumed the buffers

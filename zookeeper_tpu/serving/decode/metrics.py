@@ -21,8 +21,8 @@ The tracked quantities are the decode cost model's levers
 - ``zk_decode_active_slots`` / ``zk_decode_slot_occupancy`` — how full
   the slot array runs; sustained occupancy 1.0 with queue depth > 0
   means the slot array, not the chip, is the bottleneck (add slots).
-- ``zk_decode_kv_pages_in_use`` — live KV pages across active slots
-  (page-granular occupancy of the provisioned cache HBM).
+- ``zk_decode_kv_pages_in_use`` — pages the pool's allocator has
+  handed out (active slots and prefix-cache-retained pages).
 
 The speculative-decode family (docs/DESIGN.md §18) deliberately renders
 under its own ``zk_spec_*`` prefix (the schedule spans two engines, not
@@ -198,15 +198,15 @@ class DecodeMetrics:
                     "fraction (-1 = no speculative window yet)",
                     initial=-1,
                 ),
-                # Paged-KV family (docs/DESIGN.md §20): REAL pool
-                # allocator counts, not the host-side length estimate —
+                # Page-pool family (docs/DESIGN.md §20): the pool
+                # allocator's counts —
                 # deliberately outside the zk_decode_ prefix like the
                 # zk_spec_ family (the pool is engine state the
                 # prefix cache and every slot share).
                 "kv_pool_free_pages": registry.gauge(
                     "zk_kv_pool_free_pages",
                     help="free pages in the shared KV page pool (-1 = "
-                    "slot layout, no pool)",
+                    "no occupancy recorded yet)",
                     initial=-1,
                 ),
                 "prefix_cache_hit_rate": registry.gauge(

@@ -1,10 +1,9 @@
-"""True paged KV: the shared device page pool, its host-side
+"""The KV layout: the shared device page pool, its host-side
 allocator, and the radix prefix cache (docs/DESIGN.md §20).
 
-The §15 slot layout provisions every slot's WORST case —
-``slots × capacity`` rows of KV HBM — because one slot's rows must be
-contiguous. This module is the deferred indirection step (ROADMAP item
-4): KV rows live in per-layer POOLS of fixed-size pages
+A cache of contiguous rows a slot provisions every slot's WORST case —
+``slots × capacity`` rows of KV HBM. Here KV rows live in per-layer
+POOLS of fixed-size pages
 (``[num_pages, head_shards, page_size, row_width]``: a token's heads
 folded end to end on the last dimension, ``ops.fold_kv_rows``), any
 slot's logical page
@@ -49,7 +48,7 @@ pages never outlive a swap.
 
 Everything here is HOST state. The device half (the pool tree itself)
 is allocated by :func:`allocate_page_pool` and owned/donated by the
-``DecodeEngine`` exactly like the slot-layout cache.
+``DecodeEngine``.
 """
 
 import math
@@ -88,7 +87,7 @@ def allocate_page_pool(
     head_shards, page_size, heads_per_shard]`` float32 when
     ``quant="int8"`` (rows stored int8). The engine places it under the
     partitioner's page-pool sharding and donates it through every
-    dispatch, exactly like the slot-layout cache. ``num_heads`` is the
+    dispatch. ``num_heads`` is the
     heads a row holds: the key/value heads where they are grouped.
 
     Layer groups: the layers marked in ``window_layers`` (sliding-window

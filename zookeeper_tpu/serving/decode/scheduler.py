@@ -126,7 +126,7 @@ class DecodeStream:
         self.ttft_ms: Optional[float] = None
         # Prompt tokens served from the radix prefix cache at admission
         # (stamped by _admit from the page-pool plan; stays 0 for cold
-        # admissions and the slot layout).
+        # admissions).
         self._shared_tokens = 0
         #: Request id minted at submit (docs/DESIGN.md §16); its trace
         #: records render as one Perfetto flow and its terminal summary
@@ -163,7 +163,7 @@ class DecodeStream:
     @property
     def shared_tokens(self) -> int:
         """Prompt tokens whose KV came warm from the radix prefix
-        cache at admission (0 = cold admission or slot layout) — the
+        cache at admission (0 = cold admission) — the
         per-request observability hook behind the fleet router's
         affinity certification (docs/DESIGN.md §23)."""
         return self._shared_tokens
@@ -377,7 +377,7 @@ class DecodeScheduler:
         # admission} while a prompt is mid-prefill. A slot in
         # _chunk_state owns pages + a stream but must NOT decode —
         # its KV prefix is still being appended chunk by chunk.
-        chunked = bool(engine.paged) and int(engine.prefill_chunk_tokens) > 0
+        chunked = int(engine.prefill_chunk_tokens) > 0
         object.__setattr__(self, "_chunked", chunked)
         object.__setattr__(self, "_chunk_state", {})
         # Wall-clock of each slot's most recent token delivery, for
@@ -791,10 +791,36 @@ class DecodeScheduler:
     def _free_slot(self, slot: int) -> None:
         self._slot_stream[slot] = None
         self._chunk_state.pop(slot, None)
-        # Paged layout: drop the slot's page references (prefix-cache-
-        # shared pages stay resident); slot layout: no-op. Every slot
+        # Drop the slot's page references (prefix-cache-shared pages
+        # stay resident), and the draft's share with them. Every slot
         # retirement path funnels here so pages can never leak.
         self._engine.release_slot(slot)
+        spec = getattr(self, "_speculative", None)
+        if spec is not None:
+            spec.draft_engine.release_slot(slot)
+
+    def _seed_draft(self, spec, streams, slots) -> None:
+        """Seed the DRAFT cache with the prompts of ``streams`` (its
+        first-token output is discarded — the teacher's is
+        authoritative and already delivered). One extra dispatch per
+        admission, amortized over the stream. Always the cold
+        monolithic prefill, and each slot takes its FULL share of the
+        draft's worst-case pool here and keeps it until
+        :meth:`_free_slot`: no draft dispatch can ever wait on a page
+        (pooling a private, correctness-irrelevant cache buys nothing).
+        A stream failed by close()/crash meanwhile takes nothing."""
+        pool = spec.draft_engine.page_pool
+        with self._lock:
+            live = [
+                i for i, (stream, slot) in enumerate(zip(streams, slots))
+                if self._slot_stream[slot] is stream
+            ]
+            for i in live:
+                pool.adopt_slot(slots[i], pool.max_pages_per_slot)
+        if live:
+            spec.draft_engine.prefill(
+                [streams[i].prompt for i in live], [slots[i] for i in live]
+            )
 
     def _finish_or_continue(self, slot: int, token: int) -> None:
         """Deliver ``token`` to the slot's stream and retire the slot
@@ -924,8 +950,8 @@ class DecodeScheduler:
                                 rid=stream.rid,
                                 attrs={"slot": slot},
                             )
-                # Page allocation per admitted stream (docs/DESIGN.md §20;
-                # slot layout: trivial cold plans). The POOL bookkeeping
+                # Page allocation per admitted stream (docs/DESIGN.md
+                # §20). The POOL bookkeeping
                 # runs under _lock (close()/crash release pages under the
                 # same lock — the PagePool is lock-guarded scheduler
                 # state); only the rare one-page CoW copy dispatches
@@ -1044,16 +1070,7 @@ class DecodeScheduler:
                     first[i] = tok
             spec = getattr(self, "_speculative", None)
             if spec is not None:
-                # Seed the DRAFT cache for the same group/slots (its
-                # first-token output is discarded — the teacher's is
-                # authoritative and already delivered). One extra
-                # dispatch per admission, amortized over the stream.
-                # Always the cold prefill: the draft keeps its own
-                # slot-layout cache (never prefix-shared — pooling a
-                # private, correctness-irrelevant cache buys nothing).
-                spec.draft_engine.prefill(
-                    [s.prompt for s in group], slots
-                )
+                self._seed_draft(spec, group, slots)
             dt_ms = (time.perf_counter() - t0) * 1e3
             with _trace.span("sched_admit_commit"), self._lock:
                 now = time.perf_counter()
@@ -1157,11 +1174,9 @@ class DecodeScheduler:
             # gap-is-at-most-one invariant the speculative window
             # relies on holds across any mix of plain and speculative
             # iterations. At draft length == capacity-1 the width-2
-            # write clamps one row early and scribbles a live draft
-            # row — harmless by exhaustion: that slot's stream is at
-            # token_limit - 1 and finishes THIS iteration, so the
-            # scribbled row dies with it (the next occupant's prefill
-            # + masking make it invisible, per the refill invariant).
+            # window's second row lies past the table and is dropped;
+            # that slot's stream is at token_limit - 1 and finishes
+            # THIS iteration.
             spec.draft_engine.verify(ctokens, dlengths)
         dt_ms = (time.perf_counter() - t0) * 1e3
         with _trace.span("sched_deliver"), self._lock:
@@ -1184,9 +1199,8 @@ class DecodeScheduler:
         return len(active)
 
     def _ensure_active_rows(self, extra: int) -> None:
-        """Pre-dispatch page guarantee (paged layout; slot layout:
-        no-op): every active slot must hold pages covering ``length +
-        extra`` rows before the next decode (``extra=1``) or verify
+        """Pre-dispatch page guarantee: every active slot must hold
+        pages covering ``length + extra`` rows before the next decode (``extra=1``) or verify
         window (``extra=w``) writes them. A slot the pool cannot grow
         — even after prefix-cache eviction — fails its stream with
         :class:`RejectedError` (partial tokens stay readable; the
@@ -1451,15 +1465,11 @@ class DecodeScheduler:
             )
             finals = [g for g in group if g[4]]
             if spec is not None and finals:
-                # Seed the DRAFT cache only once the full prompt is
-                # committed — the draft keeps its own slot-layout
-                # cache and prefills monolithically, exactly like the
-                # unchunked admission path (its first-token output is
-                # discarded; the teacher's final-chunk token is
-                # authoritative).
-                spec.draft_engine.prefill(
-                    [g[1].prompt for g in finals],
-                    [g[0] for g in finals],
+                # Only once the full prompt is committed, exactly like
+                # the unchunked admission path (the teacher's
+                # final-chunk token is authoritative).
+                self._seed_draft(
+                    spec, [g[1] for g in finals], [g[0] for g in finals]
                 )
             dt_ms = (time.perf_counter() - t0) * 1e3
             with _trace.span("sched_admit_commit"), self._lock:
@@ -1536,24 +1546,14 @@ class DecodeScheduler:
     def _update_occupancy(self) -> None:
         if self._metrics is None:
             return
-        active_lengths = [
-            int(self._slot_lengths[i])
-            for i, s in enumerate(self._slot_stream)
-            if s is not None
-        ]
         self._metrics.record_occupancy(
-            len(active_lengths),
+            sum(1 for s in self._slot_stream if s is not None),
             int(self._engine.slots),
             len(self._queue),
-            self._engine.kv_pages_in_use(active_lengths),
+            self._engine.kv_pages_in_use(),
         )
         pool = self._engine.page_pool
-        if pool is not None:
-            # Real allocator counts (docs/DESIGN.md §20), not the
-            # host-side length estimate the slot layout reports.
-            self._metrics.record_pool(
-                pool.free_pages, pool.prefix_hit_rate
-            )
+        self._metrics.record_pool(pool.free_pages, pool.prefix_hit_rate)
 
     def _step_once(self) -> bool:
         """One scheduler iteration: swap boundary, deadline sweeps,
@@ -1640,13 +1640,12 @@ class DecodeScheduler:
             streams += list(self._queue)
             self._queue.clear()
             for i in range(len(self._slot_stream)):
-                self._slot_stream[i] = None
-                # Paged layout: drop the failed streams' page
-                # references (a dispatch-failure crash already reset
-                # the pool wholesale inside the engine — releasing an
-                # empty row is a no-op, so both crash shapes leave
-                # zero leaked pages, which the chaos suite pins).
-                self._engine.release_slot(i)
+                # Drop the failed streams' page references (a
+                # dispatch-failure crash already reset the pool
+                # wholesale inside the engine — releasing an empty row
+                # is a no-op, so both crash shapes leave zero leaked
+                # pages, which the chaos suite pins).
+                self._free_slot(i)
                 # Draft bookkeeping dies with the streams: the next
                 # occupant's draft prefill re-seeds it.
                 self._draft_lengths[i] = 0
@@ -1846,16 +1845,13 @@ class DecodeScheduler:
         before trusting the stream metrics."""
         engine = self._engine
         with self._lock:
-            active_lengths = [
-                int(self._slot_lengths[i])
-                for i, s in enumerate(self._slot_stream)
-                if s is not None
-            ]
             return {
                 "slots": int(engine.slots),
-                "active_slots": len(active_lengths),
+                "active_slots": sum(
+                    1 for s in self._slot_stream if s is not None
+                ),
                 "queue_depth": len(self._queue),
-                "kv_pages_in_use": engine.kv_pages_in_use(active_lengths),
+                "kv_pages_in_use": engine.kv_pages_in_use(),
                 "kv_capacity_tokens": engine.capacity,
                 "kv_cache_mb": round(engine.kv_cache_nbytes / 2**20, 2),
                 # HBM accounting (docs/DESIGN.md §17): the provisioned
@@ -1866,15 +1862,11 @@ class DecodeScheduler:
                     engine.kv_cache_nbytes // max(1, int(engine.slots))
                 ),
                 "decode_attention": engine.decode_attention_flavor,
-                # Paged-KV vitals (docs/DESIGN.md §20): layout, pool
-                # fill, prefix-cache hits, CoW count — absent pool
-                # section means the slot layout.
+                # Page-pool vitals (docs/DESIGN.md §20): pool fill,
+                # prefix-cache hits, CoW count. ``kv_layout`` is a
+                # constant that CI and operators still read.
                 "kv_layout": str(engine.kv_layout),
-                **(
-                    {"kv_pool": engine.pool_status()}
-                    if engine.paged
-                    else {}
-                ),
+                "kv_pool": engine.pool_status(),
                 # Last dispatch's memory-bandwidth utilization (-1 =
                 # unknown) — the roofline lens for the memory-bound
                 # decode step.

@@ -12,8 +12,8 @@ compile counts) through the same MetricsWriter sinks — so
 end-to-end smoke of the whole decode subsystem.
 
 The decode-attention flavor threads through the engine component
-(``engine.decode_attention=auto|pallas|reference|module`` on the CLI —
-docs/DESIGN.md §17): "auto" serves with the length-aware Pallas paged
+(``engine.decode_attention=auto|pallas|reference`` on the CLI —
+docs/DESIGN.md §17): "auto" serves with the length-aware Pallas pool
 decode kernel on TPU and the reference einsum elsewhere; the result
 line and ``/statusz`` report the RESOLVED flavor plus the
 ``decode_mbu`` memory-bandwidth roofline.
@@ -368,28 +368,22 @@ class LMServingConfig(Experiment):
             "seq_buckets": [int(s) for s in self.engine.seq_buckets],
             "kv_capacity": self.engine.capacity,
             # The RESOLVED cache-attention flavor (docs/DESIGN.md §17):
-            # "pallas" = the length-aware paged decode kernel,
+            # "pallas" = the length-aware pool decode kernel,
             # "reference" = the oracle einsum (auto-selected off-TPU or
             # degraded on unsupported geometry).
             "decode_attention": self.engine.decode_attention_flavor,
             "decode_mbu": round(self.engine.decode_mbu, 4),
-            # Paged-KV vitals (docs/DESIGN.md §20): the layout that
-            # actually served, pool fill and prefix-cache hit rate
-            # (both -1/absent under the slot layout).
+            # Page-pool vitals (docs/DESIGN.md §20): pool fill and
+            # prefix-cache hit rate (``kv_layout`` is a constant that
+            # CI still reads).
             "kv_layout": str(self.engine.kv_layout),
-            **(
-                {
-                    "kv_pool_fill": round(
-                        self.engine.page_pool.used_pages
-                        / self.engine.page_pool.num_pages,
-                        4,
-                    ),
-                    "prefix_cache_hit_rate": round(
-                        self.engine.page_pool.prefix_hit_rate, 4
-                    ),
-                }
-                if self.engine.paged
-                else {}
+            "kv_pool_fill": round(
+                self.engine.page_pool.used_pages
+                / self.engine.page_pool.num_pages,
+                4,
+            ),
+            "prefix_cache_hit_rate": round(
+                self.engine.page_pool.prefix_hit_rate, 4
             ),
             # Speculative schedule (docs/DESIGN.md §18): the RESOLVED
             # state (config-enabled but draft-unavailable degrades to

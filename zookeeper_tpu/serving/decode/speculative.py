@@ -5,8 +5,8 @@ The decode engine's throughput is bounded by one teacher ``decode_step``
 dispatch per emitted token. Greedy speculative decoding amortizes that
 to one ``verify_step`` dispatch per window: a small DRAFT model
 autoregressively proposes ``k`` tokens per slot, one batched teacher
-``decode_verify`` scores all ``k + 1`` window positions in a single
-dispatch (multi-token KV append, ``cache.append_kv_rows``), and the
+``decode_verify_paged`` scores all ``k + 1`` window positions in a
+single dispatch (multi-token KV append through the page table), and the
 scheduler keeps the longest prefix where the draft's proposals match
 the teacher's greedy argmax — plus the teacher's own token at the first
 mismatch, which the verify already computed for free. Greedy
@@ -19,10 +19,11 @@ length are already certified harmless by the §17 poisoned-row tests).
 
 This component owns the DRAFT half: a second :class:`DecodeEngine`
 mirroring the teacher's slot/bucket/capacity geometry (same
-``decode_cache_sharding`` seam, same partitioner, its own KV cache and
-AOT program family, ledgered ``draft_*`` with ``compile_count`` pinned
-zero post-warmup). The repo uniquely already owns both model halves:
-``training/distill.py`` produces aligned student/teacher pairs — point
+``page_pool_sharding`` seam, same partitioner, its own worst-case page
+pool — every slot owns its full share for a stream's lifetime, so no
+draft dispatch waits on a page — and AOT program family, ledgered
+``draft_*`` with ``compile_count`` pinned zero post-warmup). The repo
+uniquely already owns both model halves: ``training/distill.py`` produces aligned student/teacher pairs — point
 ``draft_checkpoint`` at the distilled student's export. The two-model
 slot SCHEDULE lives in :class:`DecodeScheduler` (``_decode_spec``);
 the config surface is ``LMServingConfig.speculative``.
@@ -90,8 +91,8 @@ class SpeculativeDecoding:
         """Attach the draft: builds + warms an internal
         :class:`DecodeEngine` over ``draft_module`` mirroring the
         TEACHER ``engine``'s slot/bucket/capacity geometry (so admission
-        groups and slot ids map 1:1 and the draft cache shards through
-        the same ``decode_cache_sharding`` seam), and pre-compiles the
+        groups and slot ids map 1:1 and the draft pool shards through
+        the same ``page_pool_sharding`` seam), and pre-compiles the
         verify widths — the teacher's ``k + 1`` window and the draft's
         width-2 catch-up/append program. Raises ``ValueError`` on
         config bugs (bad k, vocab mismatch, draft positional table too
@@ -116,6 +117,13 @@ class SpeculativeDecoding:
                 "be silently meaningless. Build the draft at the "
                 "teacher's vocabulary."
             )
+        if any(getattr(draft_module, "window_layers", ())):
+            raise ValueError(
+                "a draft model with window layers is not implemented: a "
+                "draft slot takes its whole share of ONE page group at "
+                "its prefill (PagePool.adopt_slot). Draft with full "
+                "layers only."
+            )
         draft = DecodeEngine()
         configure(
             draft,
@@ -130,6 +138,10 @@ class SpeculativeDecoding:
                 "page_size": int(engine.page_size),
                 "decode_attention": str(engine.decode_attention),
                 "ledger_prefix": "draft_",
+                # A private cache nobody shares, provisioned for the
+                # worst case (pool_pages=-1): the scheduler hands each
+                # slot its full share at the draft prefill.
+                "prefix_cache": False,
             },
             name="speculative_draft_engine",
         )

@@ -187,8 +187,5 @@ class DisaggPartitioner(Partitioner):
     def decode_cache_axes(self) -> Tuple[Tuple[str, ...], Optional[str]]:
         return self.decode.decode_cache_axes()
 
-    def decode_cache_sharding(self, cache: Any) -> Any:
-        return self.decode.decode_cache_sharding(cache)
-
     def page_pool_sharding(self, pool: Any) -> Any:
         return self.decode.page_pool_sharding(pool)

@@ -90,11 +90,6 @@ class DisaggScheduler(DecodeScheduler):
         decode) pair."""
         prefill_engine._require_bound()
         decode_engine._require_bound()
-        if not prefill_engine.paged or not decode_engine.paged:
-            raise ValueError(
-                "disaggregated serving needs kv_layout='paged' on BOTH "
-                "roles — the handoff unit is the page."
-            )
         transfer._require_bound()
         if (
             transfer._src is not prefill_engine
@@ -414,11 +409,10 @@ class DisaggScheduler(DecodeScheduler):
                     stream._fail(e)
                 continue
             if spec is not None:
-                # Seed the draft cache at DECODE admission (cold
-                # prefill — the draft lives with the decode role; its
-                # first-token output is discarded, the teacher's was
-                # already delivered at the prefill role).
-                spec.draft_engine.prefill([stream.prompt], [slot])
+                # At DECODE admission: the draft lives with the decode
+                # role (the teacher's first token was already delivered
+                # at the prefill role).
+                self._seed_draft(spec, [stream], [slot])
             with self._lock:
                 if self._slot_stream[slot] is not stream:
                     # Failed by close()/crash mid-transfer; its slot

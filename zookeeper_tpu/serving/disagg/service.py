@@ -54,17 +54,17 @@ class DisaggServingConfig(LMServingConfig):
     partitioner: Partitioner = ComponentField(DisaggPartitioner)
     #: The DECODE role (the inherited ``engine`` slot, so every
     #: downstream report key keeps meaning "the serving engine"):
-    #: paged by construction; prefill programs unused (admission
+    #: prefill programs unused (admission
     #: arrives by page transfer), prefix cache off (adopted pages are
     #: private to their stream).
     engine: DecodeEngine = ComponentField(
-        DecodeEngine, kv_layout="paged", prefix_cache=False
+        DecodeEngine, prefix_cache=False
     )
     #: The PREFILL role: few lanes batched wide, prefix cache on (warm
     #: prompts skip prefill BEFORE the transfer, so shared pages are
     #: computed once and shipped many times).
     prefill_engine: DecodeEngine = ComponentField(
-        DecodeEngine, kv_layout="paged", slots=4, prefill_buckets=(1, 2, 4)
+        DecodeEngine, slots=4, prefill_buckets=(1, 2, 4)
     )
     #: The page mover (``transfer.host_bounce=True`` forces the
     #: portable host path for A/B).

@@ -61,17 +61,11 @@ class PageTransfer:
     def bind(
         self, src_engine, dst_engine, metrics=None
     ) -> "PageTransfer":
-        """Attach the two engines. Both must run the paged layout with
+        """Attach the two engines. Both must have
         the SAME transfer block geometry (page size and pages-per-block
         — one compiled shape serves every handoff in each direction)."""
         src_engine._require_bound()
         dst_engine._require_bound()
-        if not src_engine.paged or not dst_engine.paged:
-            raise ValueError(
-                "page transfer needs kv_layout='paged' on BOTH roles; "
-                f"got src={src_engine.kv_layout!r} "
-                f"dst={dst_engine.kv_layout!r}."
-            )
         if int(src_engine.page_size) != int(dst_engine.page_size):
             raise ValueError(
                 f"page_size mismatch across roles: src="

@@ -373,7 +373,6 @@ def run_fleet_worker(
         "seq_len": 128,
         "vocab_size": 61,
         "seed": 0,
-        "engine.kv_layout": "paged",
         "engine.page_size": 16,
         "engine.slots": 4,
         "engine.seq_buckets": (16, 128),
