@@ -65,6 +65,41 @@ def test_forward_shapes_and_fp32_logits():
     assert logits.dtype == jnp.float32
 
 
+@pytest.mark.parametrize("extra", [
+    {},
+    {"mlp": "moe", "num_experts": 4, "experts_per_token": 2,
+     "expert_dim": 16, "tie_embeddings": False, "positions": "rope"},
+], ids=["gpt2", "experts_untied_head"])
+def test_apply_checks_parameter_shapes_without_tracing_initializers(
+    extra, monkeypatch
+):
+    """flax re-evaluates every parameter's initializer abstractly on
+    every apply to compare shapes (``Scope.param``); this model compares
+    the shape it already holds (``_param``): no ``eval_shape`` a trace,
+    and a leaf of another shape is still refused by flax's own error."""
+    from flax.errors import ScopeParamShapeError
+
+    _, module, params, _ = make_model({"attention": "dense", **extra})
+    tokens = lm_batch()["input"]
+    calls = []
+    eval_shape = jax.eval_shape
+    monkeypatch.setattr(
+        jax, "eval_shape",
+        lambda *a, **k: calls.append(1) or eval_shape(*a, **k),
+    )
+    jax.jit(lambda p: module.apply({"params": p}, tokens)).lower(params)
+    assert not calls
+    monkeypatch.undo()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        wrong = jax.tree_util.tree_map_with_path(
+            lambda p, x: jnp.zeros((x.shape[0] + 1, *x.shape[1:]), x.dtype)
+            if p == path else x,
+            params,
+        )
+        with pytest.raises(ScopeParamShapeError):
+            module.apply({"params": wrong}, tokens)
+
+
 def test_flash_and_dense_attention_agree():
     """The model-level parity check: identical params, the two
     attention tiers produce the same logits (flash is exact; fp32 on

@@ -31,7 +31,7 @@ SEQ_LEN = 64
 
 
 def build_lm(num_layers=2, d_model=32, num_heads=4, max_seq_len=SEQ_LEN,
-             seed=0):
+             seed=0, **fields):
     model = TransformerLM()
     configure(
         model,
@@ -41,6 +41,7 @@ def build_lm(num_layers=2, d_model=32, num_heads=4, max_seq_len=SEQ_LEN,
             "num_heads": num_heads,
             "max_seq_len": max_seq_len,
             "attention": "dense",
+            **fields,
         },
         name="lm",
     )
@@ -333,6 +334,35 @@ def test_prompt_dispatch_validation(lm):
         engine.prefill([np.zeros((0,), np.int32)], [0])
     with pytest.raises(ValueError, match="slots"):
         engine.decode(np.zeros((5,), np.int32), np.zeros((5,), np.int32))
+
+
+def test_decode_step_takes_its_tpu_options_on_a_tpu_only(lm, monkeypatch):
+    """The decode step, and no other program, is compiled with the
+    engine's TPU compiler options, and only where the backend is a TPU
+    (no other compiler knows them)."""
+    import jax
+
+    from zookeeper_tpu.serving.decode import engine as engine_module
+
+    module, params, state, _ = lm
+    compile_ = jax.stages.Lowered.compile
+    seen = []
+
+    def recording(self, compiler_options=None):
+        seen.append(compiler_options)
+        return compile_(self)
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", recording)
+    make_engine(module, params, state).warmup()
+    assert seen and not any(seen)
+    seen.clear()
+    monkeypatch.setattr(engine_module, "_compiles_for_tpu", lambda: True)
+    engine = make_engine(module, params, state)
+    engine.warmup()
+    assert [o for o in seen if o] == [
+        engine_module._DECODE_STEP_TPU_OPTIONS
+    ]
+    assert len(seen) == engine.compile_count > 1
 
 
 # -- weight swap (engine level) -------------------------------------------

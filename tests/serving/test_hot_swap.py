@@ -9,6 +9,12 @@ import pytest
 from zookeeper_tpu.core import configure
 from zookeeper_tpu.serving import CheckpointWatcher, InferenceEngine, ServingMetrics
 
+from tests.serving.test_decode_engine import (
+    build_lm,
+    make_engine as make_paged_engine,
+    make_scheduler,
+)
+
 pytestmark = [pytest.mark.serving, pytest.mark.chaos]
 
 
@@ -356,3 +362,80 @@ def test_dead_watcher_is_observable(tmp_path):
     assert metrics.totals["watcher_stopped"] == 1
     assert watch.current_step == 1  # frozen, and marked as such
     watch.stop()
+
+
+# -- the decode engine: a checkpoint swapped in is held as bind holds one --
+
+
+def decode_lm(seed, d_model=32):
+    """``(module, params, state)``: bfloat16 compute over float32
+    parameters, the case in which the engine holds what it was not given."""
+    return build_lm(
+        d_model=d_model, max_seq_len=32, seed=seed, compute_dtype="bfloat16"
+    )[:3]
+
+
+def make_decode_engine(module, params, state):
+    # make_paged_engine turns the prefix cache off: a direct swap_weights does
+    # not drop it (the scheduler's staged swap does)
+    engine = make_paged_engine(
+        module, params, state, slots=1, seq_buckets=(8,), kv_capacity=32
+    )
+    engine.warmup()
+    return engine
+
+
+def generate(engine, prompt):
+    return make_scheduler(engine, max_new_tokens=8).generate(prompt)
+
+
+def test_decode_swap_of_a_float32_checkpoint_is_held_cast():
+    """The trainer's float32 checkpoint passes ``check_swap`` against
+    what was BOUND (float32), though the engine holds its matmul
+    kernels in bfloat16; it is placed as ``bind`` places one, and serves
+    the tokens a fresh ``bind`` of it serves, with no compile."""
+    import jax
+    import jax.numpy as jnp
+
+    module, p1, state = decode_lm(seed=0)
+    _, p2, _ = decode_lm(seed=1)
+    engine = make_decode_engine(module, p1, state)
+    warm = engine.compile_count
+    prompt = np.arange(1, 7, dtype=np.int32)
+    before = generate(engine, prompt)
+    engine.check_swap(p2, state)
+    engine.swap_weights(p2, state)
+    after = generate(engine, prompt)
+    assert engine.compile_count == warm
+    cold = make_decode_engine(module, p2, state)
+    for held, fresh in zip(
+        jax.tree.leaves(engine._variables), jax.tree.leaves(cold._variables)
+    ):
+        assert held.dtype == fresh.dtype
+        np.testing.assert_array_equal(np.asarray(held), np.asarray(fresh))
+    kernel = engine._variables["params"]["block0"]["up"]["kernel"]
+    assert kernel.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(after, generate(cold, prompt))
+    assert not np.array_equal(before, after)  # the swap really took
+
+
+@pytest.mark.parametrize("differs", ["shape", "dtype", "structure"])
+def test_decode_swap_is_checked_against_the_bound_tree(differs):
+    """A candidate is held to the tree ``bind`` was given, not to the
+    tree the engine holds: one in the HELD types (bfloat16 kernels) is a
+    dtype mismatch like any other."""
+    module, params, state = decode_lm(seed=0)
+    engine = make_decode_engine(module, params, state)
+    if differs == "shape":
+        _, candidate, _ = decode_lm(seed=0, d_model=64)
+        match = "shape/dtype mismatch"
+    elif differs == "dtype":
+        candidate = engine._variables["params"]
+        match = r"bfloat16 where the engine serves \(32, 96\)/float32"
+    else:
+        candidate = {k: v for k, v in params.items() if k != "pos"}
+        match = "does not match the bound"
+    with pytest.raises(ValueError, match=match):
+        engine.check_swap(candidate, state)
+    with pytest.raises(ValueError, match=match):
+        engine.swap_weights(candidate, state)

@@ -115,6 +115,84 @@ def test_model_step_holds_no_pool_sized_copy(shaped, module, method, width):
     assert whole_leaf_copies(compiled, cache) == 0
 
 
+@pytest.fixture(scope="module")
+def lowered_decode_step(shaped, module):
+    """``lowered(variables)``: the decode step through the pool kernel,
+    lowered for the one described chip at the cell's shapes."""
+
+    def step(variables, cache, tokens, lengths, table):
+        logits, new_cache = module.apply(
+            variables, tokens, lengths, cache, table,
+            method="decode_step_paged",
+            attention_override=partial(
+                ops.pool_paged_decode_attention, interpret=False
+            ),
+        )
+        return new_cache, jnp.argmax(logits, axis=-1)
+
+    def lowered(variables):
+        return jax.jit(step, donate_argnums=1).lower(
+            *shaped(
+                (variables, pool(), ints(SLOTS), ints(SLOTS),
+                 ints(SLOTS, MAX_PAGES))
+            )
+        )
+
+    return lowered
+
+
+@pytest.fixture(scope="module")
+def bound_and_held(module):
+    """The variables' avals as ``bind`` is given them (float32) and as
+    the engine holds them (``serving_variables``)."""
+    bound = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    )
+    return bound, jax.eval_shape(module.serving_variables, bound)
+
+
+def test_decode_step_reads_no_float32_kernel(lowered_decode_step, bound_and_held):
+    """The decode step compiled for the avals the engine HOLDS (matmul
+    kernels in bfloat16) has no float32 array of a kernel's shape
+    anywhere, so no operand of one and no convert from one; compiled for
+    the tree as bound it has them in its entry layout (4 bytes an
+    element across HBM on every call: 3.6 of the cell's 6.2 ms a step
+    before PR 30). The tables and the norms' scales stay float32 on both
+    sides."""
+    d = HEADS * HEAD_DIM
+    kernels = [
+        f"f32[{rows},{cols}]"
+        for rows, cols in ((d, 3 * d), (d, d), (d, 4 * d), (4 * d, d))
+    ]
+    as_bound, as_held = (
+        lowered_decode_step(variables).compile().as_text()
+        for variables in bound_and_held
+    )
+    for kernel in kernels:
+        assert kernel in as_bound
+        assert kernel not in as_held
+        assert kernel.replace("f32", "bf16") in as_held
+    for table in (f"f32[50257,{d}]", f"f32[1024,{d}]"):
+        assert table in as_bound and table in as_held
+
+
+def test_decode_step_options_are_the_compilers(lowered_decode_step, bound_and_held):
+    """What the engine compiles its decode step with on a TPU
+    (``_DECODE_STEP_TPU_OPTIONS``) is an option the chip's compiler
+    knows, and it does what the engine wants of it: over the held
+    (bfloat16) kernels the step has sliced weight prefetches without
+    it and none with it."""
+    from zookeeper_tpu.serving.decode.engine import _DECODE_STEP_TPU_OPTIONS
+
+    lowered = lowered_decode_step(bound_and_held[1])
+    assert "slice-start" in lowered.compile().as_text()
+    with_options = lowered.compile(
+        compiler_options=_DECODE_STEP_TPU_OPTIONS
+    ).as_text()
+    assert "slice-start" not in with_options
+    assert "tpu_custom_call" in with_options
+
+
 @pytest.mark.parametrize("quant", ["none", "int8"])
 def test_prefill_write_holds_no_pool_sized_copy(shaped, quant):
     cache = pool(quant)

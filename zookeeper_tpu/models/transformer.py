@@ -33,6 +33,8 @@ from typing import Any, Sequence, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax.errors import ScopeParamShapeError
+from flax.linen.dtypes import promote_dtype
 
 from zookeeper_tpu.core import Field, component
 from zookeeper_tpu.models.base import Model
@@ -116,6 +118,45 @@ def _pool_scales(layer):
     return layer.get("k_scale"), layer.get("v_scale")
 
 
+def _param(module, name, init_fn, shape, dtype):
+    """``module.param(name, init_fn, shape, dtype)``, cheap where the
+    parameter is already there. On every apply flax evaluates
+    ``init_fn`` abstractly only to compare the shape it gives with the
+    shape held (``Scope.param``: 1-3 ms a parameter a trace, which over
+    the 147 parameters and eight programs of a 24-layer server is a
+    quarter of its lowering time). The shape is an argument here, so the
+    same check is made on it as it stands."""
+    if not module.has_variable("params", name):
+        return module.param(name, init_fn, shape, dtype)
+    value = module.get_variable("params", name)
+    if jnp.shape(value) != tuple(shape):
+        raise ScopeParamShapeError(
+            name, module.scope.path_text, jnp.shape(value), tuple(shape)
+        )
+    return value
+
+
+class _Dense(nn.Module):
+    """``nn.Dense`` without a bias, op for op (its ``kernel`` under the
+    same name, cast to ``dtype`` on use, the same ``dot_general``), its
+    parameter read through :func:`_param`."""
+
+    features: int
+    dtype: Any
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = _param(
+            self, "kernel", nn.initializers.lecun_normal(),
+            (x.shape[-1], self.features), self.param_dtype,
+        )
+        x, kernel = promote_dtype(x, kernel, dtype=self.dtype)
+        return jax.lax.dot_general(
+            x, kernel, (((x.ndim - 1,), (0,)), ((), ()))
+        )
+
+
 class RMSNorm(nn.Module):
     """Root-mean-square layernorm (no mean subtraction, no bias): the
     cheaper norm that long-context transformer stacks standardized on;
@@ -128,8 +169,9 @@ class RMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x):
         x32 = x.astype(jnp.float32)
-        scale = self.param(
-            "scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype
+        scale = _param(
+            self, "scale", nn.initializers.ones, (x.shape[-1],),
+            self.param_dtype,
         )
         y = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
@@ -247,8 +289,7 @@ class _Block(nn.Module):
     def setup(self):
         d = self.d_model
         dense = partial(
-            nn.Dense, use_bias=False, dtype=self.dtype,
-            param_dtype=self.param_dtype,
+            _Dense, dtype=self.dtype, param_dtype=self.param_dtype
         )
         norm = partial(RMSNorm, dtype=self.dtype, param_dtype=self.param_dtype)
         self.ln1 = norm(name="RMSNorm_0")
@@ -272,7 +313,7 @@ class _Block(nn.Module):
             ):
                 setattr(
                     self, name,
-                    self.param(name, kernel, shape, self.param_dtype),
+                    _param(self, name, kernel, shape, self.param_dtype),
                 )
         else:
             self.wup = dense(self.mlp_ratio * d, name="up")
@@ -443,6 +484,15 @@ class _Block(nn.Module):
         return self._mlp(self._out(x, o)), layer
 
 
+#: What :meth:`TransformerLMModule.serving_leaf` names, below
+#: ``params/block<i>``: the dense layers' kernels and the experts'
+#: side-by-side ones.
+_BLOCK_MATMUL_LEAVES = frozenset(
+    [(dense, "kernel") for dense in ("qkv", "proj", "up", "down")]
+    + [(name,) for name in ("experts_gate", "experts_up", "experts_down")]
+)
+
+
 def _auto_pin_activations(attention, pin_activations):
     """Whether the residual-stream pins apply. ``None`` (the default)
     auto-selects: pinned for the within-chip tiers (incl. the bare
@@ -515,7 +565,8 @@ class TransformerLMModule(nn.Module):
     param_dtype: Any = jnp.float32
 
     def setup(self):
-        self.embed = self.param(
+        self.embed = _param(
+            self,
             "embed",
             nn.initializers.normal(0.02),
             (self.vocab_size, self.d_model),
@@ -523,14 +574,16 @@ class TransformerLMModule(nn.Module):
         )
         rope = self.positions == "rope"
         if not rope:
-            self.pos = self.param(
+            self.pos = _param(
+                self,
                 "pos",
                 nn.initializers.normal(0.02),
                 (self.max_seq_len, self.d_model),
                 self.param_dtype,
             )
         if not self.tie_embeddings:
-            self.head = self.param(
+            self.head = _param(
+                self,
                 "head",
                 nn.initializers.lecun_normal(),
                 (self.d_model, self.vocab_size),
@@ -611,6 +664,46 @@ class TransformerLMModule(nn.Module):
             x.astype(jnp.float32),
             self.embed.astype(jnp.float32),
         )
+
+    def serving_leaf(self, path, leaf):
+        """The rule of :meth:`serving_variables` for one leaf at its
+        ``jax.tree_util`` key path: cast to the compute dtype exactly the
+        leaves every traced method reads as ``leaf.astype(self.dtype)``
+        into a matmul and in no other type — the ``kernel`` of a block's
+        dense layers (``qkv``, ``proj``, ``up``, ``down``:
+        ``promote_dtype`` in :class:`_Dense`), a block's ``experts_gate``
+        / ``experts_up`` / ``experts_down`` (``ops/moe.py``:
+        ``rhs.astype(lhs.dtype)``) and an untied ``head``
+        (:meth:`_logits`). Everything read in float32
+        stays as it is: every norm's ``scale``, ``embed`` and ``pos``
+        (gathered and added before the cast; the tied head multiplies
+        ``embed`` in float32) and the ``router``. A leaf already in the
+        compute dtype is returned as the same array, and so is one the
+        compute dtype would widen (the program's convert reads fewer
+        bytes than a held copy would)."""
+        names = tuple(str(getattr(k, "key", k)) for k in path)
+        matmul_only = names == ("params", "head") or (
+            len(names) > 2
+            and names[0] == "params"
+            and names[1].startswith("block")
+            and names[2:] in _BLOCK_MATMUL_LEAVES
+        )
+        dtype = jnp.dtype(self.dtype)
+        if not matmul_only or dtype.itemsize >= leaf.dtype.itemsize:
+            return leaf
+        return leaf.astype(dtype)
+
+    def serving_variables(self, variables):
+        """The tree the serving methods (``prefill``,
+        ``decode_step_paged``, ``decode_verify_paged``) should be given
+        for ``variables``: each matmul kernel held once in the type the
+        programs multiply in, so that no compiled program converts it
+        again on every call (:meth:`serving_leaf` has the rule). The
+        values every matmul sees are the same roundings either way, so
+        every output is bit for bit what ``variables`` gives. Where the
+        parameters already are the compute dtype this is the tree it
+        was given."""
+        return jax.tree_util.tree_map_with_path(self.serving_leaf, variables)
 
     def _backbone(self, tokens, training: bool, collect_kv: bool):
         if tokens.ndim != 2:

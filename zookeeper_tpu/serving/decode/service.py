@@ -323,8 +323,9 @@ class LMServingConfig(Experiment):
 
     def _teardown_service(self, *, suppress: bool = False) -> None:
         """The ONE teardown sequence (endpoint port, device probe,
-        scheduler worker) shared by every exit path — the
-        ``run_teardown_steps`` contract ``ServingConfig`` uses."""
+        scheduler worker, the engine's device state) shared by every
+        exit path — the ``run_teardown_steps`` contract
+        ``ServingConfig`` uses."""
         from zookeeper_tpu.serving.service import run_teardown_steps
 
         steps = []
@@ -338,6 +339,9 @@ class LMServingConfig(Experiment):
             steps.append(probe.stop)
         steps.append(self._stop_flight_recorder)
         steps.append(self.scheduler.close)
+        # Last: the weights and the page pool leave the device with the
+        # service, not with the last reference to a stream.
+        steps.append(self.engine.release)
         run_teardown_steps(steps, suppress=suppress)
 
     def finish_report(
