@@ -134,3 +134,102 @@ def test_expert_grouped_matmul_compiles(shaped, rows, tm, k, n):
     assert f"bf16[{k},{64 * n}]" not in "".join(
         line for line in text.splitlines() if " copy(" in line
     )
+
+
+# -- the kernels of the ``falcon_h1_34b_4l`` cell (PR 31) ---------------------
+# 20 query heads over 4 key/value heads of 128 (a group of 5), 128 slots of
+# 2,048 tokens; a state-space mixer of 32 heads of 128 with a state of 256
+# in 2 groups, chunks of 128. In this file because one process loads the
+# TPU's compiler (the guide's section 2).
+
+F_SLOTS, F_HEADS, F_MAX_PAGES = 128, 20, 128
+SSM_HEADS, SSM_P, SSM_N, SSM_GROUPS, SSM_CHUNK = 32, 128, 256, 2, 128
+
+
+def test_group_of_five_pool_kernel_compiles(shaped):
+    def attend(q, k, v, table, lengths):
+        return ops.pool_paged_decode_attention(
+            q, k, v, table, lengths, kv_heads=KV_HEADS, interpret=False
+        )
+
+    assert ops.decode_attention_supported(KV_HEADS, HEAD_DIM)
+    pool = shaped((F_SLOTS * F_MAX_PAGES, 1, PAGE, KV_HEADS * HEAD_DIM), jnp.bfloat16)
+    compiled = jax.jit(attend).lower(
+        shaped((F_SLOTS, 1, F_HEADS, HEAD_DIM), jnp.bfloat16), pool, pool,
+        shaped((F_SLOTS, F_MAX_PAGES), np.int32), shaped((F_SLOTS,), np.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("s", [128, 1024])
+def test_group_of_five_flash_forward_compiles(shaped, s):
+    def attend(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, interpret=False)
+
+    compiled = jax.jit(attend).lower(
+        shaped((1, s, F_HEADS, HEAD_DIM), jnp.bfloat16),
+        shaped((1, s, KV_HEADS, HEAD_DIM), jnp.bfloat16),
+        shaped((1, s, KV_HEADS, HEAD_DIM), jnp.bfloat16),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssm_decode_update_is_one_fusion_in_place_that_the_reader_finds(shaped):
+    """128 slots' float32 state through the one-token update, left to
+    XLA: ONE fusion reads and writes the state, the donated state aliased
+    to the output with no copy of it on the way, and the start of that
+    fusion's HLO text (what the device trace keeps of an op:
+    ``zkbench/tracereduce.py:short_name``) holds the state's shape, which
+    is what ``ssm_decode_roofline`` finds it by."""
+    import json
+    import os
+
+    from zookeeper_tpu.observability.hlo import count_copies_of_size
+    from zookeeper_tpu.ops import ssm
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmarks", "layer_metrics", "ssm_decode_roofline.json")) as f:
+        (needle,) = json.load(f)["params"]["match"]
+    state = (F_SLOTS, SSM_HEADS, SSM_P, SSM_N)
+    assert needle == "f32[%d,%d,%d,%d]" % state
+    compiled = jax.jit(ssm.ssm_decode_update, donate_argnums=0).lower(
+        shaped(state, jnp.float32),
+        shaped((F_SLOTS, SSM_HEADS, SSM_P), jnp.bfloat16),
+        shaped((F_SLOTS, SSM_HEADS), jnp.float32),
+        shaped((SSM_HEADS,), jnp.float32),
+        shaped((F_SLOTS, SSM_GROUPS, SSM_N), jnp.bfloat16),
+        shaped((F_SLOTS, SSM_GROUPS, SSM_N), jnp.bfloat16),
+    ).compile()
+    text = compiled.as_text()
+    touching = [
+        line.split(" = ", 1)[1] for line in text.splitlines()
+        if " = " in line and needle in line and "parameter(" not in line
+        and line.lstrip().startswith(("%", "ROOT %"))
+        and " fused_computation" not in line
+    ]
+    entry = [t for t in touching if " fusion(" in t and "calls=" in t]
+    assert len(entry) == 1 and needle in entry[0][:240], touching
+    assert compiled.memory_analysis().alias_size_in_bytes == 4 * int(np.prod(state))
+    assert count_copies_of_size(text, {int(np.prod(state))}) == 0
+
+
+@pytest.mark.parametrize("s", [128, 1024])
+def test_ssm_chunk_scan_compiles_under_its_own_name(shaped, s):
+    from zookeeper_tpu.ops import ssm
+
+    compiled = jax.jit(
+        lambda x, dt, A, B, C: ssm.ssm_chunk_scan(
+            x, dt, A, B, C, chunk=SSM_CHUNK, interpret=False
+        )
+    ).lower(
+        shaped((1, s, SSM_HEADS, SSM_P), jnp.bfloat16),
+        shaped((1, s, SSM_HEADS), jnp.float32),
+        shaped((SSM_HEADS,), jnp.float32),
+        shaped((1, s, SSM_GROUPS, SSM_N), jnp.bfloat16),
+        shaped((1, s, SSM_GROUPS, SSM_N), jnp.bfloat16),
+    ).compile()
+    calls = [
+        line for line in compiled.as_text().splitlines()
+        if "tpu_custom_call" in line and " custom-call(" in line
+    ]
+    assert len(calls) == 1 and calls[0].strip().startswith("%_ssm_chunk_scan")

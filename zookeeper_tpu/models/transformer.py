@@ -27,12 +27,14 @@ Design (TPU-first, standard pre-norm decoder):
   / ``TrainingExperiment`` need no LM-specific fork.
 """
 
+import dataclasses
 from functools import partial
-from typing import Any, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax.errors import ScopeParamShapeError
 from flax.linen.dtypes import promote_dtype
 
@@ -45,7 +47,80 @@ from zookeeper_tpu.ops import (
     pool_verify_attention,
 )
 from zookeeper_tpu.ops.moe import sparse_moe
+from zookeeper_tpu.ops.ssm import (
+    causal_conv,
+    ssm_chunk_scan,
+    ssm_decode_update,
+)
 from zookeeper_tpu.parallel.sharding import constrain_batch_sharded
+
+
+#: Taps of a state-space mixer's causal convolution: 4 in every Mamba-2
+#: configuration the repo runs (a field once one needs another).
+SSM_CONV_TAPS = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMSpec:
+    """A Mamba-2 state-space mixer's sizes and its own multipliers, as
+    :class:`TransformerLM` hands them to every block: ``heads`` heads of
+    ``head_dim`` with a state of ``state`` a channel, ``B`` and ``C``
+    shared by the heads of each of ``groups`` groups, prefilled in
+    chunks of ``chunk`` tokens; ``in_multiplier`` on the mixer's input,
+    ``out_multiplier`` on its output, ``multipliers`` on the five
+    segments of its projection (gate, x, B, C, dt; empty: all 1)."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    chunk: int = 128
+    in_multiplier: float = 1.0
+    out_multiplier: float = 1.0
+    multipliers: Tuple[float, ...] = ()
+
+    @property
+    def inner(self) -> int:
+        """The mixer's inner width: ``heads x head_dim``."""
+        return self.heads * self.head_dim
+
+    @property
+    def bc(self) -> int:
+        """The width of ``B`` (or ``C``): ``groups x state``."""
+        return self.groups * self.state
+
+    @property
+    def segments(self) -> Tuple[int, ...]:
+        """Widths of the projection's segments: gate, x, B, C, dt."""
+        return (self.inner, self.inner, self.bc, self.bc, self.heads)
+
+    def slot_state(self, dtype) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """What the mixer keeps a sequence between tokens, ``{name:
+        (shape, dtype)}`` in the order :meth:`_Block._ssm` returns it:
+        the recurrence's float32 state and the convolution's last input
+        rows in the compute ``dtype``."""
+        return {
+            "ssm": ((self.heads, self.head_dim, self.state), jnp.float32),
+            "conv": ((SSM_CONV_TAPS - 1, self.inner + 2 * self.bc), dtype),
+        }
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """The scalar multipliers of a maximal-update parametrisation outside
+    the state-space mixer: on the embedding, the logits, attention's
+    output, the keys (before the rotation) and the gated MLP (its gate,
+    its output; empty: 1). 1.0 traces no multiply."""
+
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    mlp: Tuple[float, ...] = ()
+
+
+def _scaled(x, multiplier: float):
+    return x if multiplier == 1.0 else x * multiplier
 
 
 def _resolve_attention(attention):
@@ -179,6 +254,30 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
+class _GatedGroupNorm(nn.Module):
+    """The state-space mixer's output norm: ``RMSNorm(y * silu(z))`` with
+    the statistics taken over each of ``groups`` equal runs of channels
+    (float32), one gain a channel."""
+
+    groups: int
+    dtype: Any
+    eps: float = 1e-6
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, z):
+        y = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+        scale = _param(
+            self, "scale", nn.initializers.ones, (y.shape[-1],),
+            self.param_dtype,
+        )
+        grouped = y.reshape(*y.shape[:-1], self.groups, -1)
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, axis=-1, keepdims=True) + self.eps
+        )
+        return (grouped.reshape(y.shape) * scale).astype(self.dtype)
+
+
 def rope_inv_freq(head_dim: int, theta: float, yarn: Tuple = ()):
     """``(inv_freq [head_dim // 2] float32, attention_factor)`` of rotary
     positions over the whole head: ``inv_i = theta ** (-2 i /
@@ -255,10 +354,23 @@ class _Block(nn.Module):
     that is not ``d_model / num_heads``), ``rope_theta`` (rotary
     positions on q and k, YaRN-scaled where ``rope_yarn`` is given),
     ``window`` (a sliding-window layer: causal and at most ``window``
-    keys back), ``mlp="moe"`` (sparse SwiGLU experts, ``ops/moe.py``)
-    and ``param_dtype``. The three traced methods share ONE
-    projection-and-positions helper (:meth:`_qkv`) and one attention
-    keyword set (:meth:`_attention_kwargs`).
+    keys back), ``mlp="moe"`` (sparse SwiGLU experts, ``ops/moe.py``) or
+    ``"swiglu"`` (one dense gated MLP of ``mlp_dim``), ``ssm`` (a
+    Mamba-2 state-space mixer beside attention, on the same normed
+    input, :meth:`_ssm`; ``ops/ssm.py``), ``multipliers`` (1.0: no
+    multiply is traced) and ``param_dtype``. The three traced methods
+    share ONE projection-and-positions helper (:meth:`_qkv`) and one
+    attention keyword set (:meth:`_attention_kwargs`).
+
+    A block with the state-space mixer carries a second kind of state
+    beside its K/V rows (:meth:`SSMSpec.slot_state`): the recurrence's
+    ``ssm [b, heads, head_dim, state]`` float32 and the convolution's
+    last input rows ``conv [b, SSM_CONV_TAPS - 1, channels]``.
+    ``__call__`` returns them at each sequence's own length,
+    ``decode_paged`` reads and writes them beside the pool's leaves (a
+    fixed block a slot, not rows a token); ``decode_verify_paged`` is
+    refused, since advancing ``lengths`` by fewer rows than were
+    written rolls K/V back and cannot roll a recurrence back.
     """
 
     d_model: int
@@ -277,6 +389,10 @@ class _Block(nn.Module):
     experts_per_token: int = 0
     expert_dim: int = 0
     param_dtype: Any = jnp.float32
+    mlp_dim: int = 0  # mlp="swiglu": the gated MLP's width
+    norm_eps: float = 1e-6
+    ssm: Optional[SSMSpec] = None  # None: no state-space mixer
+    multipliers: Multipliers = Multipliers()
 
     @property
     def kv_heads(self) -> int:
@@ -291,7 +407,10 @@ class _Block(nn.Module):
         dense = partial(
             _Dense, dtype=self.dtype, param_dtype=self.param_dtype
         )
-        norm = partial(RMSNorm, dtype=self.dtype, param_dtype=self.param_dtype)
+        norm = partial(
+            RMSNorm, dtype=self.dtype, param_dtype=self.param_dtype,
+            eps=self.norm_eps,
+        )
         self.ln1 = norm(name="RMSNorm_0")
         # One fused projection: query heads, then key heads, then value
         # heads (three equal thirds in the GPT-2 shape).
@@ -316,21 +435,49 @@ class _Block(nn.Module):
                     _param(self, name, kernel, shape, self.param_dtype),
                 )
         else:
-            self.wup = dense(self.mlp_ratio * d, name="up")
+            width = self.mlp_ratio * d
+            if self.mlp == "swiglu":
+                width = self.mlp_dim
+                self.wgate = dense(width, name="gate")
+            self.wup = dense(width, name="up")
             self.wdown = dense(d, name="down")
+        if self.ssm is not None:
+            ssm = self.ssm
+            channels = ssm.inner + 2 * ssm.bc
+            # One projection: gate z, x, B, C, dt, in that order.
+            self.wssm_in = dense(sum(ssm.segments), name="ssm_in")
+            self.wssm_out = dense(d, name="ssm_out")
+            self.ssm_norm = _GatedGroupNorm(
+                groups=ssm.groups, dtype=self.dtype, eps=self.norm_eps,
+                param_dtype=self.param_dtype, name="ssm_norm",
+            )
+            for name, init, shape in (
+                ("ssm_conv_kernel", nn.initializers.lecun_normal(),
+                 (SSM_CONV_TAPS, channels)),
+                ("ssm_conv_bias", nn.initializers.zeros, (channels,)),
+                ("dt_bias", nn.initializers.zeros, (ssm.heads,)),
+                ("A_log", nn.initializers.zeros, (ssm.heads,)),
+                ("D", nn.initializers.ones, (ssm.heads,)),
+            ):
+                setattr(
+                    self, name,
+                    _param(self, name, init, shape, self.param_dtype),
+                )
 
-    def _qkv(self, x, positions):
+    def _qkv(self, normed, positions):
         """The projection and the positions, once for every traced
-        method: ``x [b, s, d]`` -> ``q [b, s, heads, head_dim]``, ``k``
-        and ``v [b, s, kv_heads, head_dim]``, q and k rotated by
-        ``positions [b, s]`` where the block has rotary positions."""
-        b, s, _ = x.shape
+        method: ``normed [b, s, d]`` (the block's input after its first
+        norm) -> ``q [b, s, heads, head_dim]``, ``k`` and ``v [b, s,
+        kv_heads, head_dim]``, q and k rotated by ``positions [b, s]``
+        where the block has rotary positions."""
+        b, s, _ = normed.shape
         h, hkv, hd = self.num_heads, self.kv_heads, self.head_size
-        qkv = self.wqkv(self.ln1(x))
+        qkv = self.wqkv(normed)
         q, k, v = jnp.split(qkv, [h * hd, (h + hkv) * hd], axis=-1)
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, hkv, hd)
         v = v.reshape(b, s, hkv, hd)
+        k = _scaled(k, self.multipliers.key)
         if self.rope_theta:
             inv_freq, factor = rope_inv_freq(
                 hd, self.rope_theta, self.rope_yarn
@@ -350,9 +497,65 @@ class _Block(nn.Module):
             kwargs["kv_heads"] = self.kv_heads
         return kwargs
 
-    def _out(self, x, o):
+    def _ssm(self, h, state=None, lengths=None):
+        """The state-space mixer on the normed ``h [b, s, d]``
+        (``ops/ssm.py`` holds the mathematics). ``state`` None: whole
+        sequences from their start, by the chunked scan, rows at or past
+        ``lengths [b]`` (None: none) being padding that neither the
+        recurrence nor the convolution's carry sees. ``state = (ssm,
+        conv)``: one token a sequence (``s == 1``) from that state.
+        Returns ``(out [b, s, d], (ssm, conv))``, the state after each
+        sequence's last real token."""
+        b, s, _ = h.shape
+        ssm = self.ssm
+        inner, bc = ssm.inner, ssm.bc
+        proj = self.wssm_in(_scaled(h, ssm.in_multiplier))
+        if ssm.multipliers:
+            proj = proj * jnp.asarray(
+                np.repeat(
+                    np.asarray(ssm.multipliers, np.float32), ssm.segments
+                ),
+                proj.dtype,
+            )
+        z, xbc, dt = jnp.split(proj, [inner, 2 * inner + 2 * bc], axis=-1)
+        xbc, conv = causal_conv(
+            xbc, self.ssm_conv_kernel, self.ssm_conv_bias,
+            carry=None if state is None else state[1], lengths=lengths,
+        )
+        xbc = nn.silu(xbc)
+        xs, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        xs = xs.reshape(b, s, ssm.heads, ssm.head_dim)
+        B = B.reshape(b, s, ssm.groups, ssm.state)
+        C = C.reshape(b, s, ssm.groups, ssm.state)
+        dt = nn.softplus(
+            dt.astype(jnp.float32) + self.dt_bias.astype(jnp.float32)
+        )
+        A = -jnp.exp(self.A_log.astype(jnp.float32))
+        if state is None:
+            if lengths is not None:
+                real = jnp.arange(s)[None, :] < lengths[:, None]
+                dt = jnp.where(real[:, :, None], dt, 0.0)
+            y, carried = ssm_chunk_scan(
+                xs, dt, A, B, C, chunk=min(ssm.chunk, s)
+            )
+        else:
+            y, carried = ssm_decode_update(
+                state[0], xs[:, 0], dt[:, 0], A, B[:, 0], C[:, 0]
+            )
+            y = y[:, None]
+        y = y + self.D.astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
+        out = self.wssm_out(self.ssm_norm(y.reshape(b, s, inner), z))
+        return _scaled(out, ssm.out_multiplier), (carried, conv)
+
+    def _out(self, x, o, mixed=None):
+        """The residual stream after the mixers: attention's heads ``o``
+        through the output projection, plus the state-space mixer's
+        ``mixed`` where the block has one."""
         b, s = o.shape[:2]
-        return x + self.wproj(o.reshape(b, s, -1))
+        x = x + _scaled(
+            self.wproj(o.reshape(b, s, -1)), self.multipliers.attention_out
+        )
+        return x if mixed is None else x + mixed
 
     def _mlp(self, x):
         h = self.ln2(x)
@@ -372,6 +575,10 @@ class _Block(nn.Module):
                 init_fn=lambda: jnp.zeros_like(load),
                 reduce_fn=lambda a, b: a + b,
             )
+        elif self.mlp == "swiglu":
+            on_gate, on_out = self.multipliers.mlp or (1.0, 1.0)
+            gate = _scaled(self.wgate(h), on_gate)
+            h = _scaled(self.wdown(self.wup(h) * nn.silu(gate)), on_out)
         else:
             h = self.wup(h)
             h = nn.gelu(h)
@@ -388,15 +595,28 @@ class _Block(nn.Module):
             out = constrain_batch_sharded(out)
         return out
 
-    def __call__(self, x, training: bool, return_kv: bool = False):
+    def __call__(
+        self, x, training: bool, return_kv: bool = False, lengths=None
+    ):
+        """``return_kv``: also the layer's state to seed a decode from,
+        ``(k, v)`` head tensors, and for a block with the state-space
+        mixer ``(k, v, ssm, conv)`` with the mixer's state at each
+        sequence's own length (``lengths [b]``; None: the whole
+        ``s``)."""
         b, s, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-        q, kh, vh = self._qkv(x, positions)
+        h = self.ln1(x)
+        q, kh, vh = self._qkv(h, positions)
         attn = _resolve_attention(self.attention)
         o = attn(q, kh, vh, causal=True, **self._attention_kwargs())
-        out = self._mlp(self._out(x, o))
+        state = (kh, vh)
+        mixed = None
+        if self.ssm is not None:
+            mixed, slot_state = self._ssm(h, lengths=lengths)
+            state += slot_state
+        out = self._mlp(self._out(x, o, mixed))
         if return_kv:
-            return out, (kh, vh)
+            return out, state
         return out
 
     def decode_paged(
@@ -420,7 +640,8 @@ class _Block(nn.Module):
         literally the same submodules."""
         page_table = layer_page_table(page_table, bool(self.window))
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
-        q, k, v = self._qkv(x, lengths[:, None])
+        h = self.ln1(x)
+        q, k, v = self._qkv(h, lengths[:, None])
         row = jnp.clip(lengths // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row[:, None], axis=1)[:, 0]
         page = jnp.where(
@@ -439,7 +660,18 @@ class _Block(nn.Module):
             k_scale=k_scale, v_scale=v_scale,
             **self._attention_kwargs(pool=True),
         )
-        return self._mlp(self._out(x, o)), layer
+        mixed = None
+        if self.ssm is not None:
+            # Every slot's block of state, advanced in place: a dead
+            # slot's too, which the next admission overwrites.
+            names = tuple(self.ssm.slot_state(self.dtype))
+            mixed, carried = self._ssm(
+                h, state=tuple(layer[name] for name in names)
+            )
+            layer = dict(layer)
+            for name, leaf in zip(names, carried):
+                layer[name] = leaf.astype(layer[name].dtype)
+        return self._mlp(self._out(x, o, mixed)), layer
 
     def decode_verify_paged(
         self, x, layer, page_table, lengths, valid=None,
@@ -461,11 +693,18 @@ class _Block(nn.Module):
         the pages exist). Rollback-by-length: the caller commits only
         the accepted prefix by advancing ``lengths`` that far; rejected
         rows stay masked garbage."""
+        if self.ssm is not None:
+            raise NotImplementedError(
+                "decode_verify_paged is not implemented for a block with "
+                "a state-space mixer: the caller commits a prefix of the "
+                "window by advancing `lengths`, which rolls K/V rows back "
+                "and cannot roll a recurrence's state back."
+            )
         page_table = layer_page_table(page_table, bool(self.window))
         w = x.shape[1]
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
         pos = lengths[:, None] + jnp.arange(w)[None, :]
-        q, k, v = self._qkv(x, pos)
+        q, k, v = self._qkv(self.ln1(x), pos)
         row = jnp.clip(pos // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row, axis=1)
         dead = (page < 0) | (pos >= page_table.shape[1] * ps)
@@ -488,7 +727,10 @@ class _Block(nn.Module):
 #: ``params/block<i>``: the dense layers' kernels and the experts'
 #: side-by-side ones.
 _BLOCK_MATMUL_LEAVES = frozenset(
-    [(dense, "kernel") for dense in ("qkv", "proj", "up", "down")]
+    [
+        (dense, "kernel")
+        for dense in ("qkv", "proj", "up", "down", "gate", "ssm_in", "ssm_out")
+    ]
     + [(name,) for name in ("experts_gate", "experts_up", "experts_down")]
 )
 
@@ -533,6 +775,13 @@ class TransformerLMModule(nn.Module):
     construction — same submodules, same einsum/precision discipline —
     which is what the decode-parity certification pins
     (docs/DESIGN.md §15).
+
+    A model with ``ssm`` (a state-space mixer beside attention in every
+    block) threads a second kind of state through them
+    (docs/DESIGN.md §27): ``prefill`` also returns each layer's
+    recurrent state at each sequence's own length, ``decode_step_paged``
+    reads and writes it as two more leaves of each cache layer (a fixed
+    block a slot), and ``decode_verify_paged`` is refused.
     """
 
     vocab_size: int
@@ -563,6 +812,13 @@ class TransformerLMModule(nn.Module):
     expert_dim: int = 0
     tie_embeddings: bool = True
     param_dtype: Any = jnp.float32
+    mlp_dim: int = 0
+    norm_eps: float = 1e-6
+    #: A Mamba-2 state-space mixer beside attention in every block
+    #: (``_Block._ssm``); None: none.
+    ssm: Optional[SSMSpec] = None
+    #: Scalar multipliers; 1.0 (and empty tuples) trace no multiply.
+    multipliers: Multipliers = Multipliers()
 
     def setup(self):
         self.embed = _param(
@@ -608,12 +864,17 @@ class TransformerLMModule(nn.Module):
                 experts_per_token=self.experts_per_token,
                 expert_dim=self.expert_dim,
                 param_dtype=self.param_dtype,
+                mlp_dim=self.mlp_dim,
+                norm_eps=self.norm_eps,
+                ssm=self.ssm,
+                multipliers=self.multipliers,
                 name=f"block{i}",
             )
             for i, windowed in enumerate(self.window_layers)
         ]
         self.final_norm = RMSNorm(
-            dtype=self.dtype, param_dtype=self.param_dtype, name="RMSNorm_0"
+            dtype=self.dtype, param_dtype=self.param_dtype, eps=self.norm_eps,
+            name="RMSNorm_0",
         )
 
     @property
@@ -637,6 +898,7 @@ class TransformerLMModule(nn.Module):
         act inside the blocks). ``positions`` None: a whole sequence
         from 0, the table's leading slice."""
         x = self.embed[tokens]
+        x = _scaled(x, self.multipliers.embedding)
         if self.positions != "rope":
             if positions is None:
                 x = x + self.pos[None, : tokens.shape[1]]
@@ -654,10 +916,11 @@ class TransformerLMModule(nn.Module):
         if not self.tie_embeddings:
             # A head of its own, multiplied as it is held (no float32
             # copy of a table that may be half a gigabyte), float32 out.
-            return jnp.einsum(
+            logits = jnp.einsum(
                 "bsd,dv->bsv", x, self.head.astype(x.dtype),
                 preferred_element_type=jnp.float32,
             )
+            return _scaled(logits, self.multipliers.lm_head)
         # Weight-tied LM head: logits in fp32 (the loss reduction dtype).
         return jnp.einsum(
             "bsd,vd->bsv",
@@ -670,14 +933,16 @@ class TransformerLMModule(nn.Module):
         ``jax.tree_util`` key path: cast to the compute dtype exactly the
         leaves every traced method reads as ``leaf.astype(self.dtype)``
         into a matmul and in no other type — the ``kernel`` of a block's
-        dense layers (``qkv``, ``proj``, ``up``, ``down``:
-        ``promote_dtype`` in :class:`_Dense`), a block's ``experts_gate``
+        dense layers (``qkv``, ``proj``, ``up``, ``down``, ``gate``,
+        ``ssm_in``, ``ssm_out``: ``promote_dtype`` in :class:`_Dense`), a
+        block's ``experts_gate``
         / ``experts_up`` / ``experts_down`` (``ops/moe.py``:
         ``rhs.astype(lhs.dtype)``) and an untied ``head``
         (:meth:`_logits`). Everything read in float32
         stays as it is: every norm's ``scale``, ``embed`` and ``pos``
         (gathered and added before the cast; the tied head multiplies
-        ``embed`` in float32) and the ``router``. A leaf already in the
+        ``embed`` in float32), the ``router`` and the state-space mixer's
+        convolution and per-head vectors. A leaf already in the
         compute dtype is returned as the same array, and so is one the
         compute dtype would widen (the program's convert reads fewer
         bytes than a held copy would)."""
@@ -693,6 +958,15 @@ class TransformerLMModule(nn.Module):
             return leaf
         return leaf.astype(dtype)
 
+    def slot_state_spec(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """What every layer keeps a sequence beside its K/V rows, as a
+        fixed block (not rows a token): ``{name: (shape, dtype)}`` in the
+        order ``prefill`` returns the leaves after ``k`` and ``v``, which
+        are also the names ``decode_step_paged`` reads and writes in a
+        cache layer. Empty for a model without a recurrent mixer. The
+        cache manager is generic over this."""
+        return {} if self.ssm is None else self.ssm.slot_state(self.dtype)
+
     def serving_variables(self, variables):
         """The tree the serving methods (``prefill``,
         ``decode_step_paged``, ``decode_verify_paged``) should be given
@@ -705,7 +979,9 @@ class TransformerLMModule(nn.Module):
         was given."""
         return jax.tree_util.tree_map_with_path(self.serving_leaf, variables)
 
-    def _backbone(self, tokens, training: bool, collect_kv: bool):
+    def _backbone(
+        self, tokens, training: bool, collect_kv: bool, lengths=None
+    ):
         if tokens.ndim != 2:
             raise ValueError(
                 f"TransformerLM expects [batch, seq] int tokens, got "
@@ -723,7 +999,9 @@ class TransformerLMModule(nn.Module):
         kv = []
         for block in self.blocks:
             if collect_kv:
-                x, layer_kv = block(x, training, return_kv=True)
+                x, layer_kv = block(
+                    x, training, return_kv=True, lengths=lengths
+                )
                 kv.append(layer_kv)
             else:
                 x = block(x, training)
@@ -742,9 +1020,16 @@ class TransformerLMModule(nn.Module):
         position (right padding cannot influence it — causal) and
         ``kv`` is a per-layer tuple of ``(k, v) [b, s, heads,
         head_dim]`` head tensors for the caller to scatter into its KV
-        cache. Numerically the same program as ``__call__`` — the
-        first emitted token is the full-context oracle's."""
-        x, kv = self._backbone(tokens, False, collect_kv=True)
+        cache; a model with the state-space mixer returns ``(k, v, ssm,
+        conv)`` a layer, the mixer's state after each sequence's LAST
+        REAL token (the leaves of :meth:`slot_state_spec`, in its order:
+        right padding does not advance the recurrence), for the caller
+        to write at the sequence's slot. Numerically the same program as ``__call__`` —
+        the first emitted token is the full-context oracle's."""
+        x, kv = self._backbone(
+            tokens, False, collect_kv=True,
+            lengths=lengths if self.ssm is not None else None,
+        )
         # The head reads the one row that is asked for: every row's
         # logits would be [s, vocab] float32 a sequence.
         idx = jnp.clip(lengths - 1, 0, tokens.shape[1] - 1)
@@ -935,6 +1220,37 @@ class TransformerLM(Model):
     #: The type the parameters are held in ("bfloat16" for a model
     #: published and served that way: half the bytes, read as they are).
     param_dtype: str = Field("float32")
+    #: Width of the dense gated MLP (``mlp="swiglu"``: ``down(up(h) *
+    #: silu(gate(h)))``).
+    mlp_dim: int = Field(0)
+    #: Epsilon of every RMSNorm.
+    norm_eps: float = Field(1e-6)
+    #: A Mamba-2 state-space mixer beside attention in every block, on
+    #: the same normed input, its output added to the stream with
+    #: attention's: ``ssm_heads`` heads of ``ssm_head_dim`` with a state
+    #: of ``ssm_state`` a channel, ``B`` and ``C`` shared by the heads of
+    #: each of ``ssm_groups`` groups, a causal convolution of
+    #: ``SSM_CONV_TAPS`` taps, prefilled in chunks of ``ssm_chunk`` tokens.
+    #: 0 heads: no mixer.
+    ssm_heads: int = Field(0)
+    ssm_head_dim: int = Field(0)
+    ssm_state: int = Field(0)
+    ssm_groups: int = Field(1)
+    ssm_chunk: int = Field(128)
+    #: Scalar multipliers (maximal-update parametrisation): on the
+    #: embedding, the logits, attention's output, the state-space
+    #: mixer's input and output, the keys (before the rotation), the five
+    #: segments of the state-space projection (gate, x, B, C, dt) and the
+    #: gated MLP (its gate, its output). 1.0, and an empty sequence,
+    #: trace nothing.
+    embedding_multiplier: float = Field(1.0)
+    lm_head_multiplier: float = Field(1.0)
+    attention_out_multiplier: float = Field(1.0)
+    key_multiplier: float = Field(1.0)
+    ssm_in_multiplier: float = Field(1.0)
+    ssm_out_multiplier: float = Field(1.0)
+    ssm_multipliers: Sequence[float] = Field(())
+    mlp_multipliers: Sequence[float] = Field(())
 
     def set_attention_override(self, fn) -> None:
         """The partitioner injection seam (``Partitioner.prepare_model``):
@@ -1002,8 +1318,38 @@ class TransformerLM(Model):
         ) if period else ()
         if "window" in layer_types and self.window < 1:
             raise ValueError("window layers need window >= 1.")
-        if self.mlp not in ("gelu", "moe"):
-            raise ValueError(f"mlp={self.mlp!r}: expected 'gelu' or 'moe'.")
+        if self.mlp not in ("gelu", "moe", "swiglu"):
+            raise ValueError(
+                f"mlp={self.mlp!r}: expected 'gelu', 'moe' or 'swiglu'."
+            )
+        if self.mlp == "swiglu" and self.mlp_dim < 1:
+            raise ValueError("mlp='swiglu' needs mlp_dim >= 1.")
+        if self.lm_head_multiplier != 1.0 and self.tie_embeddings:
+            raise ValueError(
+                "lm_head_multiplier needs a head of its own "
+                "(tie_embeddings=false)."
+            )
+        if self.ssm_heads:
+            if min(
+                self.ssm_head_dim, self.ssm_state, self.ssm_groups,
+                self.ssm_chunk,
+            ) < 1 or self.ssm_heads % self.ssm_groups:
+                raise ValueError(
+                    f"ssm_heads={self.ssm_heads} needs ssm_head_dim, "
+                    "ssm_state, ssm_chunk >= 1 and ssm_groups "
+                    f"({self.ssm_groups}) dividing the heads."
+                )
+            if "window" in layer_types:
+                raise ValueError(
+                    "a state-space mixer beside window layers is not "
+                    "implemented."
+                )
+        for name, want in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
+            if len(getattr(self, name)) not in (0, want):
+                raise ValueError(
+                    f"{name}={getattr(self, name)!r}: expected {want} "
+                    "numbers or none."
+                )
         if self.mlp == "moe" and not (
             1 <= self.experts_per_token <= self.num_experts
             and self.expert_dim >= 1
@@ -1058,6 +1404,25 @@ class TransformerLM(Model):
             expert_dim=int(self.expert_dim),
             tie_embeddings=bool(self.tie_embeddings),
             param_dtype=jnp.dtype(self.param_dtype),
+            mlp_dim=int(self.mlp_dim),
+            norm_eps=float(self.norm_eps),
+            ssm=SSMSpec(
+                heads=int(self.ssm_heads),
+                head_dim=int(self.ssm_head_dim),
+                state=int(self.ssm_state),
+                groups=int(self.ssm_groups),
+                chunk=int(self.ssm_chunk),
+                in_multiplier=float(self.ssm_in_multiplier),
+                out_multiplier=float(self.ssm_out_multiplier),
+                multipliers=tuple(float(m) for m in self.ssm_multipliers),
+            ) if self.ssm_heads else None,
+            multipliers=Multipliers(
+                embedding=float(self.embedding_multiplier),
+                lm_head=float(self.lm_head_multiplier),
+                attention_out=float(self.attention_out_multiplier),
+                key=float(self.key_multiplier),
+                mlp=tuple(float(m) for m in self.mlp_multipliers),
+            ),
         )
 
     def initialize(

@@ -60,6 +60,7 @@ from zookeeper_tpu.serving.decode.pages import (
     PagePool,
     allocate_page_pool,
     page_pool_bytes,
+    slot_state_bytes,
 )
 
 logger = logging.getLogger(__name__)
@@ -316,6 +317,29 @@ DecodeScheduler`.
                 "group's pages of the chunk before it, which admission "
                 "does not keep. Set engine.prefill_chunk_tokens=0."
             )
+        # A second kind of per-sequence state (docs/DESIGN.md §27): a
+        # model with a recurrent mixer keeps a fixed block a slot beside
+        # its K/V rows. What shares pages, splits a prompt, rolls rows
+        # back or moves pages has no such block to go with them.
+        spec = getattr(module, "slot_state_spec", None)
+        slot_leaves = dict(spec()) if spec is not None else {}
+        if slot_leaves and bool(self.prefix_cache):
+            raise ValueError(
+                "prefix_cache=true is not implemented for a model with "
+                "recurrent (state-space) state: a shared page has no "
+                "state to go with it (a hit would need a snapshot of the "
+                "state at the shared prefix's end; ROADMAP.md, Reach). "
+                "Set engine.prefix_cache=false."
+            )
+        if slot_leaves and int(self.prefill_chunk_tokens) > 0:
+            raise ValueError(
+                "prefill_chunk_tokens > 0 is not implemented for a model "
+                "with recurrent (state-space) state: a chunk, like the "
+                "warm-prefix extend, would have to start from the state "
+                "the chunk before it carried, and the extend program "
+                "carries none (ROADMAP.md, Reach). Set "
+                "engine.prefill_chunk_tokens=0."
+            )
         # A sequence's live window pages: the window's own, one more
         # for where the band starts inside a page, one for the row the
         # next dispatch writes before the iteration's release.
@@ -346,7 +370,14 @@ DecodeScheduler`.
 
             partitioner = SingleDevicePartitioner()
         partitioner.setup()
+        if slot_leaves and partitioner.mesh is not None:
+            raise ValueError(
+                "a model with recurrent (state-space) state is served on "
+                "one device: its slot state has no sharding rule under a "
+                "mesh yet (ROADMAP.md, Reach)."
+            )
         object.__setattr__(self, "_module", module)
+        object.__setattr__(self, "_slot_leaves", slot_leaves)
         object.__setattr__(self, "_partitioner", partitioner)
         object.__setattr__(self, "_seq_buckets", seq_buckets)
         object.__setattr__(self, "_prefill_buckets", prefill_buckets)
@@ -373,6 +404,7 @@ DecodeScheduler`.
                 prefix_cache=bool(self.prefix_cache),
                 window=window,
                 window_pages=self._window_pages,
+                slot_state=bool(slot_leaves),
             ),
         )
 
@@ -461,7 +493,21 @@ DecodeScheduler`.
             window_layers=window_layers,
             window_pages=self._window_pages,
         )
-        object.__setattr__(self, "_cache_nbytes", nbytes)
+        state_bytes = slot_state_bytes(
+            int(module.num_layers), int(self.slots), slot_leaves
+        )
+        object.__setattr__(
+            self, "_cache_nbytes", nbytes + sum(state_bytes.values())
+        )
+        if slot_leaves and _trace.enabled():
+            _trace.event(
+                "ssm_state_placed",
+                attrs={
+                    "layers": int(module.num_layers),
+                    "slots": int(self.slots),
+                    **{f"bytes_{n}": b for n, b in state_bytes.items()},
+                },
+            )
         object.__setattr__(self, "_compiled_cache", {})
         object.__setattr__(self, "_compile_count", 0)
         object.__setattr__(self, "_warmed", False)
@@ -537,14 +583,16 @@ DecodeScheduler`.
         )
 
     def _publish_bind_gauges(self) -> None:
-        """Bind-time decode gauge: the provisioned KV HBM
-        (``zk_decode_kv_bytes``)."""
+        """Bind-time decode gauge: the provisioned cache HBM
+        (``zk_decode_kv_bytes``: the page pool, and a recurrent model's
+        state a slot with it)."""
         from zookeeper_tpu.observability.registry import default_registry
 
         default_registry().gauge(
             "zk_decode_kv_bytes",
             help="HBM provisioned for the decode KV page pool (k+v and "
-            "scales, all layers)",
+            "scales, all layers) and, for a model with recurrent state, "
+            "its block a slot",
         ).set(float(self._cache_nbytes))
 
     def decode_mbu_for(self, seconds: float, program: str = "decode_step") -> float:
@@ -678,6 +726,8 @@ DecodeScheduler`.
             head_shards=self._head_shards,
             window_layers=self._window_layers,
             window_pages=self._window_pages,
+            slots=int(self.slots),
+            slot_leaves=self._slot_leaves,
         )
 
     def _place_cache(self, cache):
@@ -748,6 +798,8 @@ DecodeScheduler`.
 
     @property
     def kv_cache_nbytes(self) -> int:
+        """Bytes of the whole cache tree: the page pool and, for a model
+        with recurrent state, its block a slot."""
         self._require_bound()
         return self._cache_nbytes
 
@@ -1133,8 +1185,11 @@ PagePool`."""
             self._note_dispatch_compile(f"prefill/b{pb}s{sb}")
         ps = int(self.page_size)
         window_layers = self._window_layers
+        slot_leaves = tuple(self._slot_leaves)
 
-        def prefill_fn(variables, cache, tokens, lengths, slot_rows):
+        def prefill_fn(
+            variables, cache, tokens, lengths, slot_rows, slot_ids=None
+        ):
             from zookeeper_tpu.models.transformer import (
                 _pool_write_rows,
                 layer_page_table,
@@ -1160,14 +1215,23 @@ PagePool`."""
                 return jnp.where(dead, num_pages, pages)
 
             new_cache = []
-            for layer, (k, v), windowed in zip(cache, kv, window_layers):
+            for layer, (k, v, *state), windowed in zip(
+                cache, kv, window_layers
+            ):
                 table = layer_page_table(slot_rows, windowed)
-                new_cache.append(
-                    _pool_write_rows(
-                        layer, {"k": k, "v": v},
-                        targets(table, layer["k"].shape[0]), offs,
-                    )
+                layer = _pool_write_rows(
+                    layer, {"k": k, "v": v},
+                    targets(table, layer["k"].shape[0]), offs,
                 )
+                # A recurrent mixer's block a slot, overwritten whole at
+                # the admitted slots (nothing of the last tenant stays);
+                # a partial group's padding rows carry the id `slots`
+                # and write nowhere.
+                for name, rows in zip(slot_leaves, state):
+                    layer[name] = layer[name].at[slot_ids].set(
+                        rows.astype(layer[name].dtype), mode="drop"
+                    )
+                new_cache.append(layer)
             first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
             return tuple(new_cache), (
                 first if load is None else (first, load)
@@ -1180,6 +1244,8 @@ PagePool`."""
             jax.ShapeDtypeStruct((pb,), np.int32),
             self._table_like(pb),
         )
+        if slot_leaves:
+            example += (jax.ShapeDtypeStruct((pb,), np.int32),)
         compiled = self._aot(
             f"prefill/b{pb}s{sb}", prefill_fn, example, donate_cache_at=1
         )
@@ -1197,6 +1263,13 @@ PagePool`."""
         import jax.numpy as jnp
 
         self._require_bound()
+        if self._slot_leaves:
+            raise NotImplementedError(
+                "a speculative draft or verify is not implemented for a "
+                "model with recurrent (state-space) state: rejected rows "
+                "are rolled back by not advancing `lengths`, and that "
+                "cannot undo a recurrence (ROADMAP.md, Reach)."
+            )
         if width < 1:
             raise ValueError(f"verify width={width} must be >= 1.")
         if width > self._capacity:
@@ -1310,7 +1383,8 @@ PagePool`."""
             for layer in cache:
                 out.append(
                     {
-                        name: buf.at[dst].set(buf[src])
+                        name: buf if name in self._slot_leaves
+                        else buf.at[dst].set(buf[src])
                         for name, buf in layer.items()
                     }
                 )
@@ -1430,6 +1504,12 @@ PageTransfer` moves between mesh slices. READ-ONLY: the source pool
             raise NotImplementedError(
                 "page transfer moves one layer group's pages; a model "
                 "with window layers has two (ROADMAP.md, Reach)."
+            )
+        if self._slot_leaves:
+            raise NotImplementedError(
+                "page transfer moves pages; a model with recurrent "
+                "(state-space) state keeps a block a slot that is in "
+                "none of them (ROADMAP.md, Reach)."
             )
         return self._pool.pages_for(max(self._seq_buckets))
 
@@ -1582,7 +1662,13 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             lengths[i] = lens[i]
         # The slots' page-table rows: padding rows stay all -1 (every
         # write drops via the OOB page sentinel).
-        rows = self._pool.operand(slot_ids, pb)
+        operands = (tokens, lengths, self._pool.operand(slot_ids, pb))
+        if self._slot_leaves:
+            ids = np.full((pb,), int(self.slots), np.int32)  # OOB: dropped
+            ids[:n] = [int(s) for s in slot_ids]
+            operands += (ids,)
+            if _trace.enabled():
+                _trace.event("ssm_state_reset", attrs={"slots": n})
         compiled = self._prefill_compiled(pb, sb, during_dispatch=True)
         with _trace.span(
             "prefill_dispatch",
@@ -1594,7 +1680,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         ):
             try:
                 new_cache, first = compiled(
-                    self._variables, self._cache, tokens, lengths, rows
+                    self._variables, self._cache, *operands
                 )
             except BaseException:
                 # Donation already consumed the old buffers: restore a
@@ -1778,6 +1864,16 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             )
         compiled = self._decode_compiled(during_dispatch=True)
         self._note_kv_blocks(lengths)
+        if self._slot_leaves and _trace.enabled():
+            # The step reads and writes every slot's block of state;
+            # the slots that hold pages are the ones that decode.
+            _trace.event(
+                "decode_ssm_slots",
+                attrs={
+                    "slots_advanced": int(self.slots),
+                    "slots_live": int(np.count_nonzero(self._pool.counts)),
+                },
+            )
         with _trace.span(
             "decode_dispatch",
             attrs=(

@@ -46,6 +46,20 @@ page a cold prefill would have produced — which is why a weight
 hot-swap must invalidate the cache (exactly once), and why cached
 pages never outlive a swap.
 
+A second kind of per-sequence state rides in the same tree (docs/
+DESIGN.md §27): a model with a recurrent mixer keeps, a layer, a FIXED
+BLOCK A SLOT beside the rows a token: whatever leaves the model names
+(``slot_leaves``, ``{name: (shape a slot, dtype)}``; for a state-space
+mixer the recurrence's float32 state and the convolution's last input
+rows), each ``[slots, *shape]``. Nothing here knows what they hold.
+Those leaves are indexed by slot, not through the page table: no page is
+allocated for them, a prefill overwrites the whole block at the slots it
+admits, a decode step advances every slot's block in place, and nothing
+of them can be shared, copied a page at a time or rolled back by
+``lengths``, which is why the prefix cache, chunked prefill, speculation
+and the page handoff are refused for such a model
+(``DecodeEngine.bind``).
+
 Everything here is HOST state. The device half (the pool tree itself)
 is allocated by :func:`allocate_page_pool` and owned/donated by the
 ``DecodeEngine``.
@@ -63,8 +77,8 @@ __all__ = [
     "RadixPrefixCache",
     "allocate_page_pool",
     "page_pool_bytes",
+    "slot_state_bytes",
 ]
-
 
 def allocate_page_pool(
     num_layers: int,
@@ -77,6 +91,8 @@ def allocate_page_pool(
     head_shards: int = 1,
     window_layers: Sequence[bool] = (),
     window_pages: int = 0,
+    slots: int = 0,
+    slot_leaves: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
 ) -> Tuple[dict, ...]:
     """Zero-initialized page-pool pytree: a per-layer tuple of
     ``{"k", "v"}`` pools ``[num_pages, head_shards, page_size,
@@ -93,7 +109,11 @@ def allocate_page_pool(
     Layer groups: the layers marked in ``window_layers`` (sliding-window
     layers, which keep only the last ``window`` tokens of a sequence)
     get pools of ``window_pages`` pages, indexed by their own table
-    (:class:`PagePool`); every other layer gets ``num_pages``."""
+    (:class:`PagePool`); every other layer gets ``num_pages``.
+
+    ``slot_leaves`` (``{name: (shape a slot, dtype)}``, the model's own
+    names): every layer also gets ``[slots, *shape]`` zeros under each
+    name, the fixed block a slot of a recurrent mixer."""
     import jax.numpy as jnp
 
     from zookeeper_tpu.ops import kv_row_width
@@ -122,8 +142,23 @@ def allocate_page_pool(
             scales = shape[:3] + (num_heads // head_shards,)
             layer["k_scale"] = jnp.ones(scales, jnp.float32)
             layer["v_scale"] = jnp.ones(scales, jnp.float32)
+        for name, (per_slot, leaf_dtype) in (slot_leaves or {}).items():
+            layer[name] = jnp.zeros((slots,) + tuple(per_slot), leaf_dtype)
         layers.append(layer)
     return tuple(layers)
+
+
+def slot_state_bytes(
+    num_layers: int,
+    slots: int,
+    slot_leaves: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]],
+) -> Dict[str, int]:
+    """Bytes of each slot leaf over all layers and slots (``{name:
+    bytes}``; empty for a model without them)."""
+    return {
+        name: num_layers * slots * int(np.prod(shape)) * np.dtype(dtype).itemsize
+        for name, (shape, dtype) in (slot_leaves or {}).items()
+    }
 
 
 def page_pool_bytes(
@@ -450,7 +485,18 @@ class PagePool:
         prefix_cache: bool = True,
         window: int = 0,
         window_pages: int = 0,
+        slot_state: bool = False,
     ) -> None:
+        if slot_state and prefix_cache:
+            raise ValueError(
+                "the prefix cache shares pages, and a model with "
+                "recurrent state keeps a block a slot that no page "
+                "holds: a shared prefix has no state to go with it; "
+                "build the pool with prefix_cache=False."
+            )
+        #: The pool's tree also holds a fixed block a slot: its pages
+        #: alone are not a sequence.
+        self.slot_state = bool(slot_state)
         if window and prefix_cache:
             raise ValueError(
                 "the prefix cache does not reach a window group's pages "
@@ -637,6 +683,12 @@ class PagePool:
             raise NotImplementedError(
                 "page handoff into a pool with a window group is not "
                 "implemented (the transfer moves one group's pages)."
+            )
+        if self.slot_state:
+            raise NotImplementedError(
+                "page handoff into a pool with recurrent state a slot is "
+                "not implemented (the transfer moves pages, and the "
+                "slot's state block is in none of them)."
             )
         if self.counts[slot]:
             raise AssertionError(

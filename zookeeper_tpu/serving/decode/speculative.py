@@ -117,6 +117,17 @@ class SpeculativeDecoding:
                 "be silently meaningless. Build the draft at the "
                 "teacher's vocabulary."
             )
+        for role, module in (
+            ("teacher", engine._module), ("draft", draft_module)
+        ):
+            if getattr(module, "slot_state_spec", dict)():
+                raise ValueError(
+                    f"speculative decoding with a {role} that has "
+                    "recurrent (state-space) state is not implemented: a "
+                    "rejected draft token is rolled back by not advancing "
+                    "`lengths`, and that cannot undo a recurrence "
+                    "(ROADMAP.md, Reach)."
+                )
         if any(getattr(draft_module, "window_layers", ())):
             raise ValueError(
                 "a draft model with window layers is not implemented: a "
