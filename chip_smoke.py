@@ -16,7 +16,10 @@ every Pallas kernel against its reference:
    512 / 8 heads of 64 / vocab 1024 / bf16 with the paged KV layout and
    ``decode_attention=auto``; requests through ``DecodeScheduler.submit``
    / ``drain``; no warmed program's optimised HLO may copy a whole leaf
-   of the page pool (``DecodeEngine.pool_sized_copies``); the same
+   of the page pool (``DecodeEngine.pool_sized_copies``) or the whole
+   token table (``table_sized_copies``; at this width its rows are whole
+   lane tiles, ``tools/probe_table_copies.py`` asks it of a ragged one);
+   the same
    requests served by the kernel flavor and by the reference flavor must
    give identical tokens (compared in float32 at the highest matmul
    precision).
@@ -268,6 +271,9 @@ def serve(flavor, prompts, new_tokens, overrides):
             # Per warmed program, the instructions that re-lay-out a
             # whole leaf of the donated page pool (none may).
             "pool_sized_copies": engine.pool_sized_copies(),
+            # The same of the token table (its rows are whole lane
+            # tiles at this width: nothing to re-lay either way).
+            "table_sized_copies": engine.table_sized_copies(),
             # What the compiler's cost analysis says of a step that
             # holds a Pallas call (a fact for the benchmark to come).
             "decode_step_cost_flops": engine._ledger_records[
@@ -327,6 +333,11 @@ def server_phase(
             expect != "pallas" or not any(facts["pool_sized_copies"].values()),
             "programs that copy a whole leaf of the page pool: "
             f"{facts['pool_sized_copies']}",
+        )
+        check(
+            not any(facts["table_sized_copies"].values()),
+            "programs that copy the whole token table: "
+            f"{facts['table_sized_copies']}",
         )
         for out in tokens:
             check(out.shape == (new_tokens,), f"{out.shape} tokens answered")

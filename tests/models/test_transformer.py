@@ -100,6 +100,151 @@ def test_apply_checks_parameter_shapes_without_tracing_initializers(
             module.apply({"params": wrong}, tokens)
 
 
+UNTIED = {"tie_embeddings": False}
+
+
+@pytest.mark.parametrize("extra", [{}, UNTIED], ids=["tied", "untied"])
+@pytest.mark.parametrize("width", [96, 160])
+def test_held_tables_with_padded_rows_give_the_bound_trees_logits(
+    width, extra
+):
+    """A serving tree holds the tables ``_embed`` gathers from with rows
+    of whole 128-lane tiles (96 -> 128, 160 -> 256; zeros that are
+    sliced off before anything reads them) and, where the head is tied,
+    the table as bound a second time as ``tied_head``: every method's
+    logits are bit for bit the bound tree's. ``init`` never makes the
+    second home, and training never sees either."""
+    from zookeeper_tpu.serving.decode import allocate_page_pool
+
+    _, module, params, _ = make_model(
+        {"attention": "dense", "d_model": width, "num_heads": 4, **extra}
+    )
+    tied = not extra
+    assert "tied_head" not in params
+    assert params["embed"].shape == (61, width)
+    rows = module.table_row_width
+    assert rows == -(-width // 128) * 128
+    held = module.serving_variables({"params": params})["params"]
+    assert held["embed"].shape == (61, rows)
+    assert held["pos"].shape == (64, rows)
+    assert ("tied_head" in held) == tied
+    if tied:
+        assert held["tied_head"] is params["embed"]
+    for name in ("embed", "pos"):
+        np.testing.assert_array_equal(
+            np.asarray(held[name][:, :width]), np.asarray(params[name])
+        )
+        assert not np.asarray(held[name][:, width:]).any()
+
+    tokens = lm_batch()["input"][:2, :16]
+    lengths = jnp.asarray([16, 11], jnp.int32)
+    cache = allocate_page_pool(
+        module.num_layers, 16, 4, module.kv_heads, module.head_dim,
+        jnp.float32,
+    )
+    table = jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
+
+    @jax.jit
+    def run(p):
+        v = {"params": p}
+        whole = module.apply(v, tokens)
+        first, _ = module.apply(v, tokens, lengths, method="prefill")
+        wide, filled = module.apply(
+            v, tokens[:, :8], jnp.zeros(2, jnp.int32), cache, table,
+            method="decode_verify_paged",
+        )
+        one, _ = module.apply(
+            v, tokens[:, 8], jnp.full(2, 8, jnp.int32), filled, table,
+            method="decode_step_paged",
+        )
+        return whole, first, wide, one
+
+    for a, b in zip(run(params), run(held)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("extra", [{}, UNTIED], ids=["tied", "untied"])
+def test_tables_of_whole_tiles_are_held_as_bound(extra):
+    """At a width of whole tiles the serving tree's tables ARE the bound
+    arrays, no second home is named, and ``_embed``'s slice of the first
+    ``d_model`` columns traces to nothing: the forward lowers to the
+    text it lowered to before the slice was there (a bare gather, an
+    add, a convert)."""
+    _, module, params, _ = make_model(
+        {"attention": "dense", "d_model": 128, "num_heads": 4, **extra}
+    )
+    given = {"params": params}
+    assert module.serving_tree(given) is given
+    held = module.serving_variables(given)
+    assert jax.tree.structure(held) == jax.tree.structure(given)
+    assert held["params"]["embed"] is params["embed"]
+    assert held["params"]["pos"] is params["pos"]
+    tokens = lm_batch()["input"]
+
+    def embed(p, positions=None):
+        return module.apply(
+            {"params": p}, tokens, positions, method="_embed"
+        )
+
+    def plain(p, positions=None):
+        x = p["embed"][tokens]
+        if positions is None:
+            x = x + p["pos"][None, : tokens.shape[1]]
+        else:
+            x = x + p["pos"][jnp.clip(positions, 0, 63)]
+        return x.astype(module.dtype)
+
+    def ops_of(fn, *args):
+        text = jax.jit(fn).lower(params, *args).as_text()
+        return [
+            line.split("=", 1)[1].split()[0]
+            for line in text.splitlines() if " = stablehlo." in line
+        ]
+
+    positions = jnp.zeros(tokens.shape, jnp.int32)
+    assert ops_of(embed) == ops_of(plain)
+    assert ops_of(embed, positions) == ops_of(plain, positions)
+
+
+def test_param_refuses_a_table_of_any_third_shape():
+    """``embed`` and ``pos`` are accepted as bound or with rows of whole
+    tiles and in no other shape; a padded ``embed`` only where the head
+    has a home of its own (``tied_head``, itself held to the bound
+    shape, or an untied ``head``); flax's own error either way."""
+    from flax.errors import ScopeParamShapeError
+
+    tokens = lm_batch()["input"]
+
+    def padded(table, columns):
+        return jnp.pad(table, ((0, 0), (0, columns - table.shape[1])))
+
+    for extra in ({}, UNTIED):
+        _, module, params, _ = make_model(
+            {"attention": "dense", "d_model": 96, "num_heads": 4, **extra}
+        )
+        held = module.serving_variables({"params": params})["params"]
+        module.apply({"params": held}, tokens)  # the held tree is fine
+        for name in ("embed", "pos"):
+            for columns in (97, 256):
+                wrong = {**held, name: padded(params[name], columns)}
+                with pytest.raises(ScopeParamShapeError):
+                    module.apply({"params": wrong}, tokens)
+            taller = {**held, name: jnp.pad(held[name], ((0, 1), (0, 0)))}
+            with pytest.raises(ScopeParamShapeError):
+                module.apply({"params": taller}, tokens)
+        if not extra:
+            no_home = {k: v for k, v in held.items() if k != "tied_head"}
+            with pytest.raises(ScopeParamShapeError):
+                module.apply({"params": no_home}, tokens)
+            wrong_home = {**held, "tied_head": held["embed"]}
+            with pytest.raises(ScopeParamShapeError):
+                module.apply({"params": wrong_home}, tokens)
+            # beside a bound table the second home is not needed, but
+            # is the same table if it is there
+            module.apply({"params": {**params, "tied_head": params["embed"]}}, tokens)
+
+
 def test_flash_and_dense_attention_agree():
     """The model-level parity check: identical params, the two
     attention tiers produce the same logits (flash is exact; fp32 on

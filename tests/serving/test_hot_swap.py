@@ -391,9 +391,10 @@ def generate(engine, prompt):
 
 def test_decode_swap_of_a_float32_checkpoint_is_held_cast():
     """The trainer's float32 checkpoint passes ``check_swap`` against
-    what was BOUND (float32), though the engine holds its matmul
-    kernels in bfloat16; it is placed as ``bind`` places one, and serves
-    the tokens a fresh ``bind`` of it serves, with no compile."""
+    what was BOUND (float32 kernels, tables of ``d_model`` 32 columns),
+    though the engine holds its matmul kernels in bfloat16 and its
+    tables with rows of 128 lanes; it is placed as ``bind`` places one,
+    and serves the tokens a fresh ``bind`` of it serves, with no compile."""
     import jax
     import jax.numpy as jnp
 
@@ -408,30 +409,52 @@ def test_decode_swap_of_a_float32_checkpoint_is_held_cast():
     after = generate(engine, prompt)
     assert engine.compile_count == warm
     cold = make_decode_engine(module, p2, state)
+    assert jax.tree.structure(engine._variables) == jax.tree.structure(
+        cold._variables
+    )
     for held, fresh in zip(
         jax.tree.leaves(engine._variables), jax.tree.leaves(cold._variables)
     ):
-        assert held.dtype == fresh.dtype
+        assert (held.dtype, held.shape) == (fresh.dtype, fresh.shape)
         np.testing.assert_array_equal(np.asarray(held), np.asarray(fresh))
-    kernel = engine._variables["params"]["block0"]["up"]["kernel"]
-    assert kernel.dtype == jnp.bfloat16
+    held = engine._variables["params"]
+    assert held["block0"]["up"]["kernel"].dtype == jnp.bfloat16
+    assert p2["embed"].shape == (53, 32) and p2["pos"].shape == (32, 32)
+    assert held["embed"].shape == (53, 128) and held["pos"].shape == (32, 128)
+    np.testing.assert_array_equal(
+        np.asarray(held["tied_head"]), np.asarray(p2["embed"])
+    )
     np.testing.assert_array_equal(after, generate(cold, prompt))
     assert not np.array_equal(before, after)  # the swap really took
 
 
-@pytest.mark.parametrize("differs", ["shape", "dtype", "structure"])
+@pytest.mark.parametrize(
+    "differs", ["shape", "dtype", "structure", "padded", "held"]
+)
 def test_decode_swap_is_checked_against_the_bound_tree(differs):
     """A candidate is held to the tree ``bind`` was given, not to the
     tree the engine holds: one in the HELD types (bfloat16 kernels) is a
-    dtype mismatch like any other."""
+    dtype mismatch like any other, one with the HELD tables' padded rows
+    a shape mismatch like any other, and the held tree itself (a tied
+    head's table twice) another structure."""
     module, params, state = decode_lm(seed=0)
     engine = make_decode_engine(module, params, state)
+    held = engine._variables["params"]
     if differs == "shape":
         _, candidate, _ = decode_lm(seed=0, d_model=64)
         match = "shape/dtype mismatch"
     elif differs == "dtype":
-        candidate = engine._variables["params"]
+        candidate = {k: v for k, v in held.items() if k != "tied_head"}
         match = r"bfloat16 where the engine serves \(32, 96\)/float32"
+    elif differs == "padded":
+        candidate = {**params, "embed": held["embed"], "pos": held["pos"]}
+        match = (
+            r"shape/dtype mismatch — \(53, 128\)/float32 where the engine "
+            r"serves \(53, 32\)/float32; \(32, 128\)/float32 where"
+        )
+    elif differs == "held":
+        candidate = held
+        match = "does not match the bound"
     else:
         candidate = {k: v for k, v in params.items() if k != "pos"}
         match = "does not match the bound"

@@ -157,8 +157,9 @@ def test_decode_step_reads_no_float32_kernel(lowered_decode_step, bound_and_held
     anywhere, so no operand of one and no convert from one; compiled for
     the tree as bound it has them in its entry layout (4 bytes an
     element across HBM on every call: 3.6 of the cell's 6.2 ms a step
-    before PR 30). The tables and the norms' scales stay float32 on both
-    sides."""
+    before PR 30). The tables stay float32 on both sides: as bound with
+    rows of 1600, as held with rows of 13 whole tiles for the gather
+    beside the bound token table for the tied head."""
     d = HEADS * HEAD_DIM
     kernels = [
         f"f32[{rows},{cols}]"
@@ -172,8 +173,89 @@ def test_decode_step_reads_no_float32_kernel(lowered_decode_step, bound_and_held
         assert kernel in as_bound
         assert kernel not in as_held
         assert kernel.replace("f32", "bf16") in as_held
+    rows = ops.kv_row_width(1, d)
+    assert (d, rows) == (1600, 1664)
     for table in (f"f32[50257,{d}]", f"f32[1024,{d}]"):
-        assert table in as_bound and table in as_held
+        assert table in as_bound
+    for table in (f"f32[50257,{rows}]", f"f32[1024,{rows}]"):
+        assert table in as_held and table not in as_bound
+    assert f"f32[50257,{d}]" in as_held  # the tied head's home
+    assert f"f32[1024,{d}]" not in as_held
+
+
+@pytest.fixture(scope="module")
+def compiled_text(shaped, module, lowered_decode_step, bound_and_held):
+    """``compiled_text(program, tree)``: the optimised HLO of the decode
+    step, a 1,024-token cold prefill or a 128-token extend at the
+    cell's widths, over the variables ``"bound"`` or ``"held"``."""
+    from functools import lru_cache
+
+    def prefill(variables, tokens, lengths):
+        logits, kv = module.apply(variables, tokens, lengths, method="prefill")
+        return jnp.argmax(logits, axis=-1), kv
+
+    def extend(variables, cache, tokens, lengths, table, valid):
+        logits, new_cache = module.apply(
+            variables, tokens, lengths, cache, table, valid=valid,
+            method="decode_verify_paged",
+        )
+        return new_cache, jnp.argmax(logits[:, -1], axis=-1)
+
+    @lru_cache(maxsize=None)
+    def text(program, tree):
+        variables = bound_and_held[("bound", "held").index(tree)]
+        if program == "decode_step":
+            lowered = lowered_decode_step(variables)
+        elif program == "prefill":
+            lowered = jax.jit(prefill).lower(
+                *shaped((variables, ints(1, 1024), ints(1)))
+            )
+        else:
+            lowered = jax.jit(extend, donate_argnums=1).lower(
+                *shaped(
+                    (variables, pool(), ints(1, 128), ints(1),
+                     ints(1, MAX_PAGES), ints(1))
+                )
+            )
+        return lowered.compile().as_text()
+
+    return text
+
+
+#: Elements of the token table and of the position table, as bound and
+#: with rows of whole tiles.
+TOKEN_TABLE = (50257 * 1600, 50257 * 1664)
+POSITION_TABLE = (1024 * 1600, 1024 * 1664)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill", "extend"])
+def test_no_program_re_lays_the_held_token_table(compiled_text, program):
+    """Over the tree the engine HOLDS (tables with rows of 13 whole
+    tiles) no program copies or transposes an array as large as the
+    token table; the decode step none as large as the position table
+    either (in a 1,024-token prefill an activation has that size)."""
+    text = compiled_text(program, "held")
+    assert "f32[50257,1664]{1,0:T(8,128)}" in text  # rows contiguous
+    assert count_copies_of_size(text, TOKEN_TABLE) == 0
+    if program == "decode_step":
+        assert count_copies_of_size(text, TOKEN_TABLE + POSITION_TABLE) == 0
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_bound_token_table_is_re_laid_on_every_call(compiled_text, program):
+    """The witness that the count sees the fault: over the tree AS
+    BOUND the chip's compiler gives ``f32[50257,1600]`` the layout that
+    wastes fewest tile bytes, ``{0,1}`` (1600 is 12.5 tiles), in which
+    a row is not contiguous, and copies all 322 MB row-major before the
+    gather, in every program, on every call (1 ms of the cell's 3.9 ms
+    decode step before the engine held the table padded); the decode
+    step copies the position table the same way."""
+    text = compiled_text(program, "bound")
+    assert "f32[50257,1600]{0,1:T(8,128)}" in text
+    tokens = count_copies_of_size(text, TOKEN_TABLE)
+    assert tokens >= 1
+    if program == "decode_step":
+        assert count_copies_of_size(text, TOKEN_TABLE + POSITION_TABLE) > tokens
 
 
 def test_decode_step_options_are_the_compilers(lowered_decode_step, bound_and_held):

@@ -43,6 +43,7 @@ from zookeeper_tpu.models.base import Model
 from zookeeper_tpu.ops import (
     attention_reference,
     flash_attention,
+    kv_row_width,
     pool_decode_attention,
     pool_verify_attention,
 )
@@ -193,18 +194,21 @@ def _pool_scales(layer):
     return layer.get("k_scale"), layer.get("v_scale")
 
 
-def _param(module, name, init_fn, shape, dtype):
+def _param(module, name, init_fn, shape, dtype, held_shape=None):
     """``module.param(name, init_fn, shape, dtype)``, cheap where the
     parameter is already there. On every apply flax evaluates
     ``init_fn`` abstractly only to compare the shape it gives with the
     shape held (``Scope.param``: 1-3 ms a parameter a trace, which over
     the 147 parameters and eight programs of a 24-layer server is a
     quarter of its lowering time). The shape is an argument here, so the
-    same check is made on it as it stands."""
+    same check is made on it as it stands. ``held_shape`` is the one
+    other shape a serving tree may hold the parameter in
+    (:meth:`TransformerLMModule.serving_leaf`'s tables); any third is
+    refused like any parameter's."""
     if not module.has_variable("params", name):
         return module.param(name, init_fn, shape, dtype)
     value = module.get_variable("params", name)
-    if jnp.shape(value) != tuple(shape):
+    if jnp.shape(value) not in (tuple(shape), held_shape):
         raise ScopeParamShapeError(
             name, module.scope.path_text, jnp.shape(value), tuple(shape)
         )
@@ -821,13 +825,33 @@ class TransformerLMModule(nn.Module):
     multipliers: Multipliers = Multipliers()
 
     def setup(self):
+        # A serving tree may hold the tables :meth:`_embed` gathers from
+        # with rows of whole lane tiles (:meth:`serving_leaf`): ``embed``
+        # only where the head has a table of its own, which for a tied
+        # head is the table as bound under ``tied_head`` (``init`` never
+        # makes it).
+        own_head = not self.tie_embeddings or self.has_variable(
+            "params", "tied_head"
+        )
         self.embed = _param(
             self,
             "embed",
             nn.initializers.normal(0.02),
             (self.vocab_size, self.d_model),
             self.param_dtype,
+            held_shape=(self.vocab_size, self.table_row_width)
+            if own_head
+            else None,
         )
+        if self.tie_embeddings:
+            self.tied_head = (
+                _param(
+                    self, "tied_head", None,
+                    (self.vocab_size, self.d_model), self.param_dtype,
+                )
+                if own_head
+                else self.embed
+            )
         rope = self.positions == "rope"
         if not rope:
             self.pos = _param(
@@ -836,6 +860,7 @@ class TransformerLMModule(nn.Module):
                 nn.initializers.normal(0.02),
                 (self.max_seq_len, self.d_model),
                 self.param_dtype,
+                held_shape=(self.max_seq_len, self.table_row_width),
             )
         if not self.tie_embeddings:
             self.head = _param(
@@ -886,6 +911,13 @@ class TransformerLMModule(nn.Module):
         return self.num_kv_heads or self.num_heads
 
     @property
+    def table_row_width(self) -> int:
+        """``d_model`` rounded up to whole 128-lane tiles: the row a
+        serving tree holds the gathered tables in (GPT-2 XL's 1600 ->
+        1664), the rule of the page pool's rows."""
+        return kv_row_width(1, self.d_model)
+
+    @property
     def window_layers(self) -> Tuple[bool, ...]:
         """Per layer, whether it is a sliding-window layer."""
         if not self.layer_types:
@@ -896,16 +928,19 @@ class TransformerLMModule(nn.Module):
         """The residual stream's start: the token table's rows, plus
         the position table's where the model has one (rotary positions
         act inside the blocks). ``positions`` None: a whole sequence
-        from 0, the table's leading slice."""
-        x = self.embed[tokens]
+        from 0, the table's leading slice. A table held with padded
+        rows (:meth:`serving_leaf`) gives its first ``d_model`` columns;
+        of one held as bound that slice is the rows themselves."""
+        d = self.d_model
+        x = self.embed[tokens][..., :d]
         x = _scaled(x, self.multipliers.embedding)
         if self.positions != "rope":
             if positions is None:
-                x = x + self.pos[None, : tokens.shape[1]]
+                x = x + self.pos[None, : tokens.shape[1], :d]
             else:
                 x = x + self.pos[
                     jnp.clip(positions, 0, self.max_seq_len - 1)
-                ]
+                ][..., :d]
         return x.astype(self.dtype)
 
     def _pin(self) -> bool:
@@ -925,12 +960,15 @@ class TransformerLMModule(nn.Module):
         return jnp.einsum(
             "bsd,vd->bsv",
             x.astype(jnp.float32),
-            self.embed.astype(jnp.float32),
+            self.tied_head.astype(jnp.float32),
         )
 
     def serving_leaf(self, path, leaf):
         """The rule of :meth:`serving_variables` for one leaf at its
-        ``jax.tree_util`` key path: cast to the compute dtype exactly the
+        ``jax.tree_util`` key path. Two things are held otherwise than
+        bound, each for what the chip does with it on every call.
+
+        Cast to the compute dtype: exactly the
         leaves every traced method reads as ``leaf.astype(self.dtype)``
         into a matmul and in no other type — the ``kernel`` of a block's
         dense layers (``qkv``, ``proj``, ``up``, ``down``, ``gate``,
@@ -938,15 +976,28 @@ class TransformerLMModule(nn.Module):
         block's ``experts_gate``
         / ``experts_up`` / ``experts_down`` (``ops/moe.py``:
         ``rhs.astype(lhs.dtype)``) and an untied ``head``
-        (:meth:`_logits`). Everything read in float32
-        stays as it is: every norm's ``scale``, ``embed`` and ``pos``
-        (gathered and added before the cast; the tied head multiplies
-        ``embed`` in float32), the ``router`` and the state-space mixer's
-        convolution and per-head vectors. A leaf already in the
+        (:meth:`_logits`). A leaf already in the
         compute dtype is returned as the same array, and so is one the
         compute dtype would widen (the program's convert reads fewer
-        bytes than a held copy would)."""
+        bytes than a held copy would).
+
+        Rows padded with zeros to whole 128-lane tiles
+        (:attr:`table_row_width`): ``embed`` and ``pos``, the tables
+        :meth:`_embed` indexes by row. The TPU holds a 2-D array in
+        the layout that wastes fewest tile bytes, which for rows of 12.5
+        tiles (GPT-2 XL's 1600) is the one whose rows are not contiguous,
+        and every program that gathers rows then re-lays the whole table
+        out first (docs/DESIGN.md §15). A width of whole tiles is the
+        same array.
+
+        Everything else read in float32 stays as it is: every norm's
+        ``scale``, the ``router``, the state-space mixer's convolution
+        and per-head vectors, and ``tied_head``
+        (:meth:`serving_tree`)."""
         names = tuple(str(getattr(k, "key", k)) for k in path)
+        if names in (("params", "embed"), ("params", "pos")):
+            pad = self.table_row_width - leaf.shape[-1]
+            return jnp.pad(leaf, ((0, 0), (0, pad))) if pad else leaf
         matmul_only = names == ("params", "head") or (
             len(names) > 2
             and names[0] == "params"
@@ -957,6 +1008,20 @@ class TransformerLMModule(nn.Module):
         if not matmul_only or dtype.itemsize >= leaf.dtype.itemsize:
             return leaf
         return leaf.astype(dtype)
+
+    def serving_tree(self, variables):
+        """The tree :meth:`serving_leaf` is mapped over: ``variables``,
+        and where a tied head's table has rows that :meth:`serving_leaf`
+        pads, the same array a second time as ``params/tied_head``. The
+        head multiplies the table as bound (the layout it arrives in is
+        the one its matmul reads in place), the gather reads the padded
+        one: two uses, two homes."""
+        if not self.tie_embeddings or self.d_model == self.table_row_width:
+            return variables
+        params = variables["params"]
+        return {
+            **variables, "params": {**params, "tied_head": params["embed"]}
+        }
 
     def slot_state_spec(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
         """What every layer keeps a sequence beside its K/V rows, as a
@@ -971,13 +1036,16 @@ class TransformerLMModule(nn.Module):
         """The tree the serving methods (``prefill``,
         ``decode_step_paged``, ``decode_verify_paged``) should be given
         for ``variables``: each matmul kernel held once in the type the
-        programs multiply in, so that no compiled program converts it
+        programs multiply in and each gathered table with rows of whole
+        lane tiles, so that no compiled program converts or re-lays one
         again on every call (:meth:`serving_leaf` has the rule). The
-        values every matmul sees are the same roundings either way, so
-        every output is bit for bit what ``variables`` gives. Where the
-        parameters already are the compute dtype this is the tree it
-        was given."""
-        return jax.tree_util.tree_map_with_path(self.serving_leaf, variables)
+        values every matmul and every sum sees are the same either way,
+        so every output is bit for bit what ``variables`` gives. Where
+        the parameters already are the compute dtype and ``d_model`` is
+        whole tiles this is the tree it was given."""
+        return jax.tree_util.tree_map_with_path(
+            self.serving_leaf, self.serving_tree(variables)
+        )
 
     def _backbone(
         self, tokens, training: bool, collect_kv: bool, lengths=None

@@ -668,15 +668,20 @@ DecodeScheduler`.
         as the module's serving methods want it
         (``TransformerLMModule.serving_leaf``: a matmul kernel the
         programs would cast on every call is cast here, once, on the
-        device). A leaf at a time, so the device never holds a second
-        whole tree in the given type; the caller's arrays are not
-        donated. While tracing, one ``decode_variables_placed`` event
-        says how far that engaged: ``leaves_cast``, ``bytes_bound``
-        (the tree as ``bind`` was given it) and ``bytes_held``."""
+        device, and a table they gather rows from gets rows of whole
+        lane tiles; ``serving_tree`` names the bound table a second
+        time where a tied head still multiplies it). A leaf at a time,
+        so the device never holds a second whole tree in the given
+        type; the caller's arrays are not donated. While tracing, one
+        ``decode_variables_placed`` event says how far that engaged:
+        ``leaves_cast``, ``leaves_padded``, ``bytes_bound`` (the tree
+        as ``bind`` was given it) and ``bytes_held``."""
         import jax
 
-        serving_leaf = getattr(
-            self._module, "serving_leaf", lambda path, leaf: leaf
+        module = self._module
+        serving_leaf = getattr(module, "serving_leaf", lambda path, leaf: leaf)
+        variables = getattr(module, "serving_tree", lambda tree: tree)(
+            variables
         )
 
         def place(path, leaf, sharding=None):
@@ -685,19 +690,39 @@ DecodeScheduler`.
         sharding = self._partitioner.variables_sharding(variables)
         trees = (variables,) if sharding is None else (variables, sharding)
         held = jax.tree_util.tree_map_with_path(place, *trees)
+        if sharding is not None:
+            # Where the programs take the HELD tree (``_aot``): a
+            # partitioner that reads shapes may want a padded table
+            # elsewhere than the bound one; a no-op for every other leaf.
+            held = jax.tree.map(
+                jax.device_put, held,
+                self._partitioner.variables_sharding(held),
+            )
         if _trace.enabled():
-            bound = jax.tree.leaves(self._bound_avals)
-            kept = jax.tree.leaves(held)
+            # A bound leaf is held under its own path; ``tied_head`` is
+            # held only.
+            bound, kept = (
+                {
+                    jax.tree_util.keystr(path): leaf
+                    for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+                }
+                for tree in (self._bound_avals, held)
+            )
             _trace.event(
                 "decode_variables_placed",
                 attrs={
                     "leaves_cast": sum(
-                        b.dtype != k.dtype for b, k in zip(bound, kept)
+                        b.dtype != kept[name].dtype
+                        for name, b in bound.items()
+                    ),
+                    "leaves_padded": sum(
+                        b.shape != kept[name].shape
+                        for name, b in bound.items()
                     ),
                     "bytes_bound": sum(
-                        b.size * b.dtype.itemsize for b in bound
+                        b.size * b.dtype.itemsize for b in bound.values()
                     ),
-                    "bytes_held": sum(int(k.nbytes) for k in kept),
+                    "bytes_held": sum(int(k.nbytes) for k in kept.values()),
                 },
             )
         return held
@@ -1602,12 +1627,25 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         object.__setattr__(self, "_warmed", True)
         return len(self._compiled_cache)
 
-    def pool_sized_copies(self) -> Dict[str, int]:
+    def _copies_of_sizes(self, sizes) -> Dict[str, int]:
         """For every program compiled so far (``decode_step``,
         ``prefill/8/1024``, ``extend/1/128``, ``copy_page``, ...), how
         many instructions of its optimised HLO copy or transpose an
-        array as large as a leaf of the KV cache
-        (``observability.hlo.count_copies_of_size``). The cache is
+        array of one of ``sizes`` elements
+        (``observability.hlo.count_copies_of_size``)."""
+        from zookeeper_tpu.observability.hlo import count_copies_of_size
+
+        self._require_bound()
+        return {
+            "/".join(str(part) for part in key[:-1]): count_copies_of_size(
+                compiled.as_text(), sizes
+            )
+            for key, compiled in self._compiled_cache.items()
+        }
+
+    def pool_sized_copies(self) -> Dict[str, int]:
+        """A program's instructions that copy an array as large as a
+        leaf of the KV cache. The cache is
         donated through every dispatch to be updated in place; a
         program that holds such an instruction re-lays-out a whole leaf
         on every call instead, which is what made a decode step cost
@@ -1615,19 +1653,28 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         ``chip_smoke.py`` holds every count at zero on the chip."""
         import jax
 
-        from zookeeper_tpu.observability.hlo import count_copies_of_size
+        return self._copies_of_sizes(
+            {
+                int(np.prod(np.shape(leaf)))
+                for leaf in jax.tree.leaves(self._cache)
+            }
+        )
 
-        self._require_bound()
-        sizes = {
-            int(np.prod(np.shape(leaf)))
-            for leaf in jax.tree.leaves(self._cache)
-        }
-        return {
-            "/".join(str(part) for part in key[:-1]): count_copies_of_size(
-                compiled.as_text(), sizes
-            )
-            for key, compiled in self._compiled_cache.items()
-        }
+    def table_sized_copies(self) -> Dict[str, int]:
+        """The same reading over the token table's sizes, as bound and
+        as held: a program that copies that many elements re-lays the
+        whole table out before it gathers a few rows of it, which the
+        chip does to a table whose rows are not whole 128-lane tiles
+        (a millisecond of ``gpt2_xl_24l``'s 3.9 ms decode step before
+        the engine held such rows padded, docs/DESIGN.md §15). Not the
+        position table's: a prefill's activations share its size."""
+        return self._copies_of_sizes(
+            {
+                int(np.prod(np.shape(tree["params"]["embed"])))
+                for tree in (self._bound_avals, self._variables)
+                if "embed" in tree["params"]
+            }
+        )
 
     # -- dispatch --------------------------------------------------------
 
