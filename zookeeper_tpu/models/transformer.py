@@ -47,6 +47,7 @@ from zookeeper_tpu.ops import (
     pool_decode_attention,
     pool_verify_attention,
 )
+from zookeeper_tpu.ops.kda import kda_chunk_scan, kda_decode_update
 from zookeeper_tpu.ops.moe import sparse_moe
 from zookeeper_tpu.ops.ssm import (
     causal_conv,
@@ -103,6 +104,40 @@ class SSMSpec:
         return {
             "ssm": ((self.heads, self.head_dim, self.state), jnp.float32),
             "conv": ((SSM_CONV_TAPS - 1, self.inner + 2 * self.bc), dtype),
+        }
+
+
+@dataclasses.dataclass(frozen=True)
+class KDASpec:
+    """A gated delta-rule linear-attention mixer's sizes (Kimi Delta
+    Attention; ``ops/kda.py``), as :class:`TransformerLM` hands them to
+    the blocks of its ``"kda"`` layers: ``heads`` heads whose keys and
+    values are both ``head_dim`` wide, a causal convolution of
+    ``conv_taps`` taps on q, k and v, the decay's and the output gate's
+    low-rank projections of ``gate_rank``, prefilled in chunks of
+    ``chunk`` tokens; ``neg_eigval``: ``beta = 2 sigmoid(.)`` (the
+    transition may have negative eigenvalues), else ``sigmoid(.)``."""
+
+    heads: int
+    head_dim: int
+    conv_taps: int = 4
+    gate_rank: int = 128
+    chunk: int = 64
+    neg_eigval: bool = True
+
+    @property
+    def inner(self) -> int:
+        """The mixer's inner width: ``heads x head_dim``."""
+        return self.heads * self.head_dim
+
+    def slot_state(self, dtype) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """What the mixer keeps a sequence between tokens, ``{name:
+        (shape, dtype)}`` in the order :meth:`_Block._kda` returns it:
+        the rule's float32 state a head and the convolution's last input
+        rows (q, k and v side by side) in the compute ``dtype``."""
+        return {
+            "kda": ((self.heads, self.head_dim, self.head_dim), jnp.float32),
+            "kda_conv": ((self.conv_taps - 1, 3 * self.inner), dtype),
         }
 
 
@@ -361,7 +396,14 @@ class _Block(nn.Module):
     keys back), ``mlp="moe"`` (sparse SwiGLU experts, ``ops/moe.py``) or
     ``"swiglu"`` (one dense gated MLP of ``mlp_dim``), ``ssm`` (a
     Mamba-2 state-space mixer beside attention, on the same normed
-    input, :meth:`_ssm`; ``ops/ssm.py``), ``multipliers`` (1.0: no
+    input, :meth:`_ssm`; ``ops/ssm.py``), ``kda`` (the block's ONLY
+    mixer is gated delta-rule linear attention, :meth:`_kda`;
+    ``ops/kda.py``: no q/k/v/proj are built and the layer keeps no K/V
+    rows), ``attention_gate`` (a sigmoid gate a channel on attention's
+    heads before the output projection), ``held_experts`` (the chip's
+    share of the routed experts, ``ops/moe.py``) and
+    ``shared_expert_dim`` (a SwiGLU expert every token takes beside the
+    routed ones), ``multipliers`` (1.0: no
     multiply is traced) and ``param_dtype``. The three traced methods
     share ONE projection-and-positions helper (:meth:`_qkv`) and one
     attention keyword set (:meth:`_attention_kwargs`).
@@ -397,6 +439,10 @@ class _Block(nn.Module):
     norm_eps: float = 1e-6
     ssm: Optional[SSMSpec] = None  # None: no state-space mixer
     multipliers: Multipliers = Multipliers()
+    kda: Optional[KDASpec] = None  # a spec: the block's only mixer
+    attention_gate: bool = False
+    held_experts: Tuple[int, ...] = ()  # (first, count); empty: all
+    shared_expert_dim: int = 0  # 0: no shared expert
 
     @property
     def kv_heads(self) -> int:
@@ -416,28 +462,64 @@ class _Block(nn.Module):
             eps=self.norm_eps,
         )
         self.ln1 = norm(name="RMSNorm_0")
-        # One fused projection: query heads, then key heads, then value
-        # heads (three equal thirds in the GPT-2 shape).
-        self.wqkv = dense(
-            (self.num_heads + 2 * self.kv_heads) * self.head_size, name="qkv"
-        )
-        self.wproj = dense(d, name="proj")
+        if self.kda is None:
+            # One fused projection: query heads, then key heads, then
+            # value heads (three equal thirds in the GPT-2 shape).
+            self.wqkv = dense(
+                (self.num_heads + 2 * self.kv_heads) * self.head_size,
+                name="qkv",
+            )
+            self.wproj = dense(d, name="proj")
+            if self.attention_gate:
+                self.wattn_gate = dense(
+                    self.num_heads * self.head_size, name="attn_gate"
+                )
+        else:
+            kda = self.kda
+            # One projection: q, k and v, each `inner` wide, in that order.
+            self.wkda_qkv = dense(3 * kda.inner, name="kda_qkv")
+            self.wkda_f1 = dense(kda.gate_rank, name="kda_f1")
+            self.wkda_f2 = dense(kda.inner, name="kda_f2")
+            self.wkda_g1 = dense(kda.gate_rank, name="kda_g1")
+            self.wkda_g2 = dense(kda.inner, name="kda_g2")
+            self.wkda_beta = dense(kda.heads, name="kda_beta")
+            self.wkda_out = dense(d, name="kda_out")
+            self.kda_norm = norm(name="kda_norm")
+            for name, init, shape in (
+                ("kda_conv_kernel", nn.initializers.lecun_normal(),
+                 (kda.conv_taps, 3 * kda.inner)),
+                ("kda_dt_bias", nn.initializers.zeros, (kda.inner,)),
+                ("kda_A_log", nn.initializers.zeros, (kda.heads,)),
+            ):
+                setattr(
+                    self, name,
+                    _param(self, name, init, shape, self.param_dtype),
+                )
         self.ln2 = norm(name="RMSNorm_1")
         if self.mlp == "moe":
-            e, f = self.num_experts, self.expert_dim
+            f = self.expert_dim
+            held = self.held_experts[1] if self.held_experts else self.num_experts
             # The experts' matrices lie side by side, expert e the column
             # block e (ops/moe.py): each leaf a plain [fan_in, out] kernel.
+            # The router scores every expert; the three expert leaves hold
+            # the chip's share of them.
             kernel = nn.initializers.lecun_normal()
             for name, shape in (
-                ("router", (d, e)),
-                ("experts_gate", (d, e * f)),
-                ("experts_up", (d, e * f)),
-                ("experts_down", (f, e * d)),
+                ("router", (d, self.num_experts)),
+                ("experts_gate", (d, held * f)),
+                ("experts_up", (d, held * f)),
+                ("experts_down", (f, held * d)),
             ):
                 setattr(
                     self, name,
                     _param(self, name, kernel, shape, self.param_dtype),
                 )
+            if self.shared_expert_dim:
+                self.wshared_gate = dense(
+                    self.shared_expert_dim, name="shared_gate"
+                )
+                self.wshared_up = dense(self.shared_expert_dim, name="shared_up")
+                self.wshared_down = dense(d, name="shared_down")
         else:
             width = self.mlp_ratio * d
             if self.mlp == "swiglu":
@@ -551,27 +633,86 @@ class _Block(nn.Module):
         out = self.wssm_out(self.ssm_norm(y.reshape(b, s, inner), z))
         return _scaled(out, ssm.out_multiplier), (carried, conv)
 
-    def _out(self, x, o, mixed=None):
-        """The residual stream after the mixers: attention's heads ``o``
-        through the output projection, plus the state-space mixer's
-        ``mixed`` where the block has one."""
-        b, s = o.shape[:2]
-        x = x + _scaled(
-            self.wproj(o.reshape(b, s, -1)), self.multipliers.attention_out
+    def _kda(self, h, state=None, lengths=None):
+        """The gated delta-rule mixer on the normed ``h [b, s, d]``
+        (``ops/kda.py`` holds the mathematics), with :meth:`_ssm`'s
+        contract: ``state`` None runs whole sequences from their start by
+        the chunked form, rows at or past ``lengths [b]`` being padding
+        that neither the rule nor the convolution's carry sees; ``state
+        = (kda, kda_conv)`` runs one token a sequence from that state.
+        Returns ``(out [b, s, d], (kda, kda_conv))``."""
+        b, s, _ = h.shape
+        kda = self.kda
+        heads, hd = kda.heads, kda.head_dim
+        f32 = jnp.float32
+        qkv, conv = causal_conv(
+            self.wkda_qkv(h), self.kda_conv_kernel, None,
+            carry=None if state is None else state[1], lengths=lengths,
         )
+        q, k, v = (
+            x.reshape(b, s, heads, hd)
+            for x in jnp.split(nn.silu(qkv), 3, axis=-1)
+        )
+
+        def unit(x):
+            x = x.astype(f32)
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6
+            )
+
+        q = (unit(q) * hd ** -0.5).astype(self.dtype)
+        k = unit(k).astype(self.dtype)
+        # the log decay, a channel of the key: <= 0, float32
+        g = -jnp.exp(self.kda_A_log.astype(f32))[:, None] * nn.softplus(
+            self.wkda_f2(self.wkda_f1(h)).astype(f32)
+            + self.kda_dt_bias.astype(f32)
+        ).reshape(b, s, heads, hd)
+        beta = nn.sigmoid(self.wkda_beta(h).astype(f32))
+        if kda.neg_eigval:
+            beta = 2.0 * beta
+        if state is None:
+            o, carried = kda_chunk_scan(
+                q, k, v, g, beta, chunk=kda.chunk, lengths=lengths
+            )
+        else:
+            o, carried = kda_decode_update(
+                state[0], q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+            )
+            o = o[:, None]
+        gate = nn.sigmoid(self.wkda_g2(self.wkda_g1(h)).astype(f32))
+        o = self.kda_norm(o).astype(f32).reshape(b, s, -1) * gate
+        return self.wkda_out(o.astype(self.dtype)), (carried, conv)
+
+    def _out(self, x, o, mixed=None, h=None):
+        """The residual stream after the mixers: attention's heads ``o``
+        (times a sigmoid gate a channel read from the normed ``h``, where
+        the block has one) through the output projection, plus the
+        state-space mixer's ``mixed`` where the block has one."""
+        b, s = o.shape[:2]
+        o = o.reshape(b, s, -1)
+        if self.attention_gate:
+            gate = nn.sigmoid(self.wattn_gate(h).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+        x = x + _scaled(self.wproj(o), self.multipliers.attention_out)
         return x if mixed is None else x + mixed
 
     def _mlp(self, x):
         h = self.ln2(x)
         if self.mlp == "moe":
             b, s, d = h.shape
-            h, load = sparse_moe(
+            routed, load = sparse_moe(
                 h.reshape(b * s, d),
                 self.router,
                 self.experts_gate, self.experts_up, self.experts_down,
                 k=self.experts_per_token,
+                held=tuple(self.held_experts) or None,
             )
-            h = h.reshape(b, s, d)
+            routed = routed.reshape(b, s, d)
+            if self.shared_expert_dim:
+                routed = routed + self.wshared_down(
+                    self.wshared_up(h) * nn.silu(self.wshared_gate(h))
+                )
+            h = routed
             # Rows each expert took, for whoever asks (``mutable=
             # ["moe_load"]``: the decode engine while tracing).
             self.sow(
@@ -603,13 +744,17 @@ class _Block(nn.Module):
         self, x, training: bool, return_kv: bool = False, lengths=None
     ):
         """``return_kv``: also the layer's state to seed a decode from,
-        ``(k, v)`` head tensors, and for a block with the state-space
-        mixer ``(k, v, ssm, conv)`` with the mixer's state at each
-        sequence's own length (``lengths [b]``; None: the whole
-        ``s``)."""
+        ``(k, v)`` head tensors, for a block with the state-space
+        mixer ``(k, v, ssm, conv)`` and for a ``kda`` block ``(kda,
+        kda_conv)`` alone, a mixer's state at each sequence's own length
+        (``lengths [b]``; None: the whole ``s``)."""
         b, s, _ = x.shape
-        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         h = self.ln1(x)
+        if self.kda is not None:
+            mixed, state = self._kda(h, lengths=lengths)
+            out = self._mlp(x + mixed)
+            return (out, state) if return_kv else out
+        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         q, kh, vh = self._qkv(h, positions)
         attn = _resolve_attention(self.attention)
         o = attn(q, kh, vh, causal=True, **self._attention_kwargs())
@@ -618,7 +763,7 @@ class _Block(nn.Module):
         if self.ssm is not None:
             mixed, slot_state = self._ssm(h, lengths=lengths)
             state += slot_state
-        out = self._mlp(self._out(x, o, mixed))
+        out = self._mlp(self._out(x, o, mixed, h))
         if return_kv:
             return out, state
         return out
@@ -642,9 +787,21 @@ class _Block(nn.Module):
         only ever taken by slots whose output is discarded. Same
         projections and norms as ``__call__``: the weights are
         literally the same submodules."""
+        h = self.ln1(x)
+        if self.kda is not None:
+            # No K/V rows: the layer's whole state is its block a slot,
+            # advanced in place for every slot (a dead slot's too).
+            names = tuple(self.kda.slot_state(self.dtype))
+            mixed, carried = self._kda(
+                h, state=tuple(layer[name] for name in names)
+            )
+            layer = {
+                name: leaf.astype(layer[name].dtype)
+                for name, leaf in zip(names, carried)
+            }
+            return self._mlp(x + mixed), layer
         page_table = layer_page_table(page_table, bool(self.window))
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
-        h = self.ln1(x)
         q, k, v = self._qkv(h, lengths[:, None])
         row = jnp.clip(lengths // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row[:, None], axis=1)[:, 0]
@@ -675,7 +832,7 @@ class _Block(nn.Module):
             layer = dict(layer)
             for name, leaf in zip(names, carried):
                 layer[name] = leaf.astype(layer[name].dtype)
-        return self._mlp(self._out(x, o, mixed)), layer
+        return self._mlp(self._out(x, o, mixed, h)), layer
 
     def decode_verify_paged(
         self, x, layer, page_table, lengths, valid=None,
@@ -697,10 +854,10 @@ class _Block(nn.Module):
         the pages exist). Rollback-by-length: the caller commits only
         the accepted prefix by advancing ``lengths`` that far; rejected
         rows stay masked garbage."""
-        if self.ssm is not None:
+        if self.ssm is not None or self.kda is not None:
             raise NotImplementedError(
                 "decode_verify_paged is not implemented for a block with "
-                "a state-space mixer: the caller commits a prefix of the "
+                "a recurrent mixer: the caller commits a prefix of the "
                 "window by advancing `lengths`, which rolls K/V rows back "
                 "and cannot roll a recurrence's state back."
             )
@@ -708,7 +865,8 @@ class _Block(nn.Module):
         w = x.shape[1]
         num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
         pos = lengths[:, None] + jnp.arange(w)[None, :]
-        q, k, v = self._qkv(self.ln1(x), pos)
+        h = self.ln1(x)
+        q, k, v = self._qkv(h, pos)
         row = jnp.clip(pos // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row, axis=1)
         dead = (page < 0) | (pos >= page_table.shape[1] * ps)
@@ -724,7 +882,7 @@ class _Block(nn.Module):
             k_scale=k_scale, v_scale=v_scale,
             **self._attention_kwargs(pool=True),
         )
-        return self._mlp(self._out(x, o)), layer
+        return self._mlp(self._out(x, o, h=h)), layer
 
 
 #: What :meth:`TransformerLMModule.serving_leaf` names, below
@@ -733,7 +891,12 @@ class _Block(nn.Module):
 _BLOCK_MATMUL_LEAVES = frozenset(
     [
         (dense, "kernel")
-        for dense in ("qkv", "proj", "up", "down", "gate", "ssm_in", "ssm_out")
+        for dense in (
+            "qkv", "proj", "up", "down", "gate", "ssm_in", "ssm_out",
+            "attn_gate", "shared_gate", "shared_up", "shared_down",
+            "kda_qkv", "kda_f1", "kda_f2", "kda_g1", "kda_g2", "kda_beta",
+            "kda_out",
+        )
     ]
     + [(name,) for name in ("experts_gate", "experts_up", "experts_down")]
 )
@@ -785,7 +948,11 @@ class TransformerLMModule(nn.Module):
     (docs/DESIGN.md §27): ``prefill`` also returns each layer's
     recurrent state at each sequence's own length, ``decode_step_paged``
     reads and writes it as two more leaves of each cache layer (a fixed
-    block a slot), and ``decode_verify_paged`` is refused.
+    block a slot), and ``decode_verify_paged`` is refused. What a layer
+    keeps follows its kind (docs/DESIGN.md §28): a ``"kda"`` layer of
+    ``layer_types`` has linear attention as its only mixer, so its cache
+    layer is the block a slot alone and it has no K/V rows
+    (:attr:`attention_layers`, :meth:`slot_state_spec`).
     """
 
     vocab_size: int
@@ -802,12 +969,13 @@ class TransformerLMModule(nn.Module):
     # every default is the GPT-2 shape):
     num_kv_heads: int = 0
     head_size: int = 0  # 0: d_model // num_heads
-    positions: str = "learned"  # or "rope": no position table
+    positions: str = "learned"  # "rope", "none": no position table
     rope_theta: float = 10000.0
     rope_yarn: Tuple = ()  # (factor, original_len, beta_fast, beta_slow)
-    #: "full" or "window" a layer; empty: every layer full. Window
-    #: layers attend ``window`` keys back with plain rotary positions,
-    #: full layers everything with the YaRN-scaled table.
+    #: "full", "window" or "kda" a layer; empty: every layer full.
+    #: Window layers attend ``window`` keys back with plain rotary
+    #: positions, full layers everything with the YaRN-scaled table, a
+    #: "kda" layer has linear attention (``kda``) in attention's place.
     layer_types: Tuple = ()
     window: int = 0
     mlp: str = "gelu"  # or "moe"
@@ -823,6 +991,13 @@ class TransformerLMModule(nn.Module):
     ssm: Optional[SSMSpec] = None
     #: Scalar multipliers; 1.0 (and empty tuples) trace no multiply.
     multipliers: Multipliers = Multipliers()
+    #: The "kda" layers' mixer; the attention layers' output gate; the
+    #: chip's share ``(first, count)`` of the routed experts; the width
+    #: of a shared expert beside them (``_Block``'s fields).
+    kda: Optional[KDASpec] = None
+    attention_gate: bool = False
+    held_experts: Tuple[int, ...] = ()
+    shared_expert_dim: int = 0
 
     def setup(self):
         # A serving tree may hold the tables :meth:`_embed` gathers from
@@ -853,7 +1028,7 @@ class TransformerLMModule(nn.Module):
                 else self.embed
             )
         rope = self.positions == "rope"
-        if not rope:
+        if self.positions == "learned":
             self.pos = _param(
                 self,
                 "pos",
@@ -893,9 +1068,15 @@ class TransformerLMModule(nn.Module):
                 norm_eps=self.norm_eps,
                 ssm=self.ssm,
                 multipliers=self.multipliers,
+                kda=None if attends else self.kda,
+                attention_gate=self.attention_gate,
+                held_experts=self.held_experts,
+                shared_expert_dim=self.shared_expert_dim,
                 name=f"block{i}",
             )
-            for i, windowed in enumerate(self.window_layers)
+            for i, (windowed, attends) in enumerate(
+                zip(self.window_layers, self.attention_layers)
+            )
         ]
         self.final_norm = RMSNorm(
             dtype=self.dtype, param_dtype=self.param_dtype, eps=self.norm_eps,
@@ -924,6 +1105,14 @@ class TransformerLMModule(nn.Module):
             return (False,) * self.num_layers
         return tuple(t == "window" for t in self.layer_types)
 
+    @property
+    def attention_layers(self) -> Tuple[bool, ...]:
+        """Per layer, whether it has softmax attention and so K/V rows a
+        token; a "kda" layer has neither."""
+        if not self.layer_types:
+            return (True,) * self.num_layers
+        return tuple(t != "kda" for t in self.layer_types)
+
     def _embed(self, tokens, positions=None):
         """The residual stream's start: the token table's rows, plus
         the position table's where the model has one (rotary positions
@@ -934,7 +1123,7 @@ class TransformerLMModule(nn.Module):
         d = self.d_model
         x = self.embed[tokens][..., :d]
         x = _scaled(x, self.multipliers.embedding)
-        if self.positions != "rope":
+        if self.positions == "learned":
             if positions is None:
                 x = x + self.pos[None, : tokens.shape[1], :d]
             else:
@@ -1023,14 +1212,21 @@ class TransformerLMModule(nn.Module):
             **variables, "params": {**params, "tied_head": params["embed"]}
         }
 
-    def slot_state_spec(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
-        """What every layer keeps a sequence beside its K/V rows, as a
-        fixed block (not rows a token): ``{name: (shape, dtype)}`` in the
-        order ``prefill`` returns the leaves after ``k`` and ``v``, which
-        are also the names ``decode_step_paged`` reads and writes in a
-        cache layer. Empty for a model without a recurrent mixer. The
-        cache manager is generic over this."""
-        return {} if self.ssm is None else self.ssm.slot_state(self.dtype)
+    def slot_state_spec(
+        self,
+    ) -> Tuple[Dict[str, Tuple[Tuple[int, ...], Any]], ...]:
+        """What each layer keeps a sequence as a fixed block (not rows a
+        token), a layer: ``{name: (shape, dtype)}`` in the order
+        ``prefill`` returns the leaves (after ``k`` and ``v`` where the
+        layer has them, :attr:`attention_layers`), which are also the
+        names ``decode_step_paged`` reads and writes in a cache layer.
+        ``{}`` for a layer without a recurrent mixer. The cache manager
+        is generic over this."""
+        beside = {} if self.ssm is None else self.ssm.slot_state(self.dtype)
+        return tuple(
+            beside if attends else self.kda.slot_state(self.dtype)
+            for attends in self.attention_layers
+        )
 
     def serving_variables(self, variables):
         """The tree the serving methods (``prefill``,
@@ -1089,14 +1285,15 @@ class TransformerLMModule(nn.Module):
         ``kv`` is a per-layer tuple of ``(k, v) [b, s, heads,
         head_dim]`` head tensors for the caller to scatter into its KV
         cache; a model with the state-space mixer returns ``(k, v, ssm,
-        conv)`` a layer, the mixer's state after each sequence's LAST
+        conv)`` a layer and a "kda" layer ``(kda, kda_conv)`` alone, the
+        mixer's state after each sequence's LAST
         REAL token (the leaves of :meth:`slot_state_spec`, in its order:
         right padding does not advance the recurrence), for the caller
         to write at the sequence's slot. Numerically the same program as ``__call__`` —
         the first emitted token is the full-context oracle's."""
         x, kv = self._backbone(
             tokens, False, collect_kv=True,
-            lengths=lengths if self.ssm is not None else None,
+            lengths=lengths if any(self.slot_state_spec()) else None,
         )
         # The head reads the one row that is asked for: every row's
         # logits would be [s, vocab] float32 a sequence.
@@ -1261,8 +1458,10 @@ class TransformerLM(Model):
     #: Size of one head. -1: ``d_model / num_heads``; set it where the
     #: heads' total is not the model's width (32 x 128 on 2304).
     head_dim: int = Field(-1)
-    #: "learned" (a position table added to the embedding) or "rope"
-    #: (rotary positions on q and k over the whole head, rotate-half).
+    #: "learned" (a position table added to the embedding), "rope"
+    #: (rotary positions on q and k over the whole head, rotate-half) or
+    #: "none" (no table and no rotation: the order comes from the causal
+    #: mask and from recurrent layers).
     positions: str = Field("learned")
     rope_theta: float = Field(10000.0)
     #: YaRN scaling of the FULL layers' rotary table; 1.0 is plain RoPE.
@@ -1271,8 +1470,10 @@ class TransformerLM(Model):
     yarn_original_len: int = Field(8192)
     yarn_beta_fast: float = Field(32.0)
     yarn_beta_slow: float = Field(1.0)
-    #: One of "full" / "window" a layer, repeated cyclically to
+    #: One of "full" / "window" / "kda" a layer, repeated cyclically to
     #: ``num_layers`` (so a period is enough); empty: every layer full.
+    #: A "kda" layer has gated delta-rule linear attention (``kda_*``)
+    #: as its only mixer and keeps no K/V rows.
     layer_types: Sequence[str] = Field(())
     #: Keys a window layer attends, the query's own included.
     window: int = Field(0)
@@ -1283,6 +1484,28 @@ class TransformerLM(Model):
     num_experts: int = Field(0)
     experts_per_token: int = Field(0)
     expert_dim: int = Field(0)
+    #: The chip's share of the routed experts, ``(first, count)``: the
+    #: router scores all ``num_experts``, the expert leaves hold those
+    #: ``count`` and the layer's result is the part they give
+    #: (``ops/moe.py``). Empty: all of them.
+    held_experts: Sequence[int] = Field(())
+    #: Width of a shared SwiGLU expert every token takes beside the
+    #: routed ones (``mlp="moe"``); 0: none.
+    shared_expert_dim: int = Field(0)
+    #: A sigmoid gate a channel on attention's heads before the output
+    #: projection, read from the block's normed input.
+    attention_gate: bool = Field(False)
+    #: The "kda" layers' mixer (``ops/kda.py``): ``kda_heads`` heads of
+    #: ``kda_head_dim`` keys and values, a causal convolution of
+    #: ``kda_conv_taps`` taps on q, k and v, low-rank decay and output
+    #: gates of ``kda_gate_rank``, prefilled in chunks of ``kda_chunk``;
+    #: ``kda_neg_eigval``: beta in (0, 2), else (0, 1).
+    kda_heads: int = Field(0)
+    kda_head_dim: int = Field(0)
+    kda_conv_taps: int = Field(4)
+    kda_gate_rank: int = Field(128)
+    kda_chunk: int = Field(64)
+    kda_neg_eigval: bool = Field(True)
     #: False: a head of its own beside the embedding.
     tie_embeddings: bool = Field(True)
     #: The type the parameters are held in ("bfloat16" for a model
@@ -1369,23 +1592,51 @@ class TransformerLM(Model):
                 f"head_dim={head_dim}: the query heads must be a multiple "
                 "of the key/value heads."
             )
-        if self.positions not in ("learned", "rope"):
+        if self.positions not in ("learned", "rope", "none"):
             raise ValueError(
-                f"positions={self.positions!r}: expected 'learned' or "
-                "'rope'."
+                f"positions={self.positions!r}: expected 'learned', "
+                "'rope' or 'none'."
             )
         if self.positions == "rope" and head_dim % 2:
             raise ValueError(f"rope needs an even head_dim, got {head_dim}.")
         period = tuple(str(t) for t in self.layer_types)
-        if any(t not in ("full", "window") for t in period):
+        if any(t not in ("full", "window", "kda") for t in period):
             raise ValueError(
-                f"layer_types={period!r}: each is 'full' or 'window'."
+                f"layer_types={period!r}: each is 'full', 'window' or "
+                "'kda'."
             )
         layer_types = tuple(
             period[i % len(period)] for i in range(self.num_layers)
         ) if period else ()
         if "window" in layer_types and self.window < 1:
             raise ValueError("window layers need window >= 1.")
+        if "kda" in layer_types and (
+            min(
+                self.kda_heads, self.kda_head_dim, self.kda_gate_rank,
+                self.kda_chunk,
+            ) < 1
+            or self.kda_conv_taps < 2
+        ):
+            raise ValueError(
+                "kda layers need kda_heads, kda_head_dim, kda_gate_rank, "
+                "kda_chunk >= 1 and kda_conv_taps >= 2."
+            )
+        if "kda" in layer_types and self.ssm_heads:
+            raise ValueError(
+                "a state-space mixer beside kda layers is not implemented."
+            )
+        held = tuple(int(n) for n in self.held_experts)
+        if held and not (
+            self.mlp == "moe" and len(held) == 2
+            and 0 <= held[0] and 1 <= held[1]
+            and held[0] + held[1] <= self.num_experts
+        ):
+            raise ValueError(
+                f"held_experts={held!r}: expected (first, count) inside "
+                f"mlp='moe''s num_experts ({self.num_experts})."
+            )
+        if self.shared_expert_dim and self.mlp != "moe":
+            raise ValueError("shared_expert_dim needs mlp='moe'.")
         if self.mlp not in ("gelu", "moe", "swiglu"):
             raise ValueError(
                 f"mlp={self.mlp!r}: expected 'gelu', 'moe' or 'swiglu'."
@@ -1491,6 +1742,17 @@ class TransformerLM(Model):
                 key=float(self.key_multiplier),
                 mlp=tuple(float(m) for m in self.mlp_multipliers),
             ),
+            kda=KDASpec(
+                heads=int(self.kda_heads),
+                head_dim=int(self.kda_head_dim),
+                conv_taps=int(self.kda_conv_taps),
+                gate_rank=int(self.kda_gate_rank),
+                chunk=int(self.kda_chunk),
+                neg_eigval=bool(self.kda_neg_eigval),
+            ) if "kda" in layer_types else None,
+            attention_gate=bool(self.attention_gate),
+            held_experts=held,
+            shared_expert_dim=int(self.shared_expert_dim),
         )
 
     def initialize(

@@ -43,7 +43,7 @@ __all__ = ["causal_conv", "ssm_chunk_scan", "ssm_decode_update"]
 def causal_conv(x, kernel, bias, carry=None, lengths=None):
     """Causal depthwise convolution over the sequence: ``x [b, s, c]``,
     ``kernel [k, c]`` (tap ``k - 1`` meets the current row), ``bias
-    [c]``; ``y_t = bias + sum_j kernel[j] x_{t - (k - 1) + j}``, the rows
+    [c]`` (None: none); ``y_t = bias + sum_j kernel[j] x_{t - (k - 1) + j}``, the rows
     before position 0 taken from ``carry [b, k - 1, c]`` (zeros where
     None: a sequence's start). Returns ``(y [b, s, c]`` in ``x``'s type,
     float32 sums, ``rows [b, k - 1, c])``: the last ``k - 1`` inputs of
@@ -55,7 +55,7 @@ def causal_conv(x, kernel, bias, carry=None, lengths=None):
     if carry is None:
         carry = jnp.zeros((b, taps - 1, c), x.dtype)
     padded = jnp.concatenate([carry.astype(x.dtype), x], axis=1)
-    y = bias.astype(jnp.float32)
+    y = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(taps):
         y = y + kernel[j].astype(jnp.float32) * padded[:, j : j + s].astype(
             jnp.float32

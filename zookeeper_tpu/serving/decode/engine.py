@@ -321,9 +321,23 @@ DecodeScheduler`.
         # model with a recurrent mixer keeps a fixed block a slot beside
         # its K/V rows. What shares pages, splits a prompt, rolls rows
         # back or moves pages has no such block to go with them.
+        # What a layer keeps follows its kind (§28): K/V rows where it
+        # has attention, the block where it has a recurrent mixer.
         spec = getattr(module, "slot_state_spec", None)
-        slot_leaves = dict(spec()) if spec is not None else {}
-        if slot_leaves and bool(self.prefix_cache):
+        slot_leaves = (
+            tuple(dict(d) for d in spec()) if spec
+            else ({},) * int(module.num_layers)
+        )
+        slot_state = any(slot_leaves)
+        attention_layers = tuple(getattr(module, "attention_layers", ())) or (
+            (True,) * int(module.num_layers)
+        )
+        if not any(attention_layers):
+            raise ValueError(
+                "a model with no attention layer has no K/V rows to page: "
+                "the decode engine's slots are sized by them."
+            )
+        if slot_state and bool(self.prefix_cache):
             raise ValueError(
                 "prefix_cache=true is not implemented for a model with "
                 "recurrent (state-space) state: a shared page has no "
@@ -331,7 +345,7 @@ DecodeScheduler`.
                 "state at the shared prefix's end; ROADMAP.md, Reach). "
                 "Set engine.prefix_cache=false."
             )
-        if slot_leaves and int(self.prefill_chunk_tokens) > 0:
+        if slot_state and int(self.prefill_chunk_tokens) > 0:
             raise ValueError(
                 "prefill_chunk_tokens > 0 is not implemented for a model "
                 "with recurrent (state-space) state: a chunk, like the "
@@ -370,7 +384,7 @@ DecodeScheduler`.
 
             partitioner = SingleDevicePartitioner()
         partitioner.setup()
-        if slot_leaves and partitioner.mesh is not None:
+        if slot_state and partitioner.mesh is not None:
             raise ValueError(
                 "a model with recurrent (state-space) state is served on "
                 "one device: its slot state has no sharding rule under a "
@@ -378,6 +392,14 @@ DecodeScheduler`.
             )
         object.__setattr__(self, "_module", module)
         object.__setattr__(self, "_slot_leaves", slot_leaves)
+        object.__setattr__(self, "_attention_layers", attention_layers)
+        # The recurrent mixers the model has, by their first leaf's name
+        # ("ssm", "kda"): what the per-dispatch counter events are named
+        # after. Empty for a model that keeps no block a slot.
+        object.__setattr__(
+            self, "_slot_kinds",
+            tuple(sorted({next(iter(d)) for d in slot_leaves if d})),
+        )
         object.__setattr__(self, "_partitioner", partitioner)
         object.__setattr__(self, "_seq_buckets", seq_buckets)
         object.__setattr__(self, "_prefill_buckets", prefill_buckets)
@@ -404,7 +426,7 @@ DecodeScheduler`.
                 prefix_cache=bool(self.prefix_cache),
                 window=window,
                 window_pages=self._window_pages,
-                slot_state=bool(slot_leaves),
+                slot_state=slot_state,
             ),
         )
 
@@ -492,22 +514,23 @@ DecodeScheduler`.
             head_shards=head_shards,
             window_layers=window_layers,
             window_pages=self._window_pages,
+            attention_layers=attention_layers,
         )
-        state_bytes = slot_state_bytes(
-            int(module.num_layers), int(self.slots), slot_leaves
-        )
+        state_bytes = slot_state_bytes(int(self.slots), slot_leaves)
         object.__setattr__(
             self, "_cache_nbytes", nbytes + sum(state_bytes.values())
         )
-        if slot_leaves and _trace.enabled():
-            _trace.event(
-                "ssm_state_placed",
-                attrs={
-                    "layers": int(module.num_layers),
-                    "slots": int(self.slots),
-                    **{f"bytes_{n}": b for n, b in state_bytes.items()},
-                },
-            )
+        if _trace.enabled():
+            for kind in self._slot_kinds:
+                mine = [d for d in slot_leaves if kind in d]
+                _trace.event(
+                    f"{kind}_state_placed",
+                    attrs={
+                        "layers": len(mine),
+                        "slots": int(self.slots),
+                        **{f"bytes_{n}": state_bytes[n] for n in mine[0]},
+                    },
+                )
         object.__setattr__(self, "_compiled_cache", {})
         object.__setattr__(self, "_compile_count", 0)
         object.__setattr__(self, "_warmed", False)
@@ -753,6 +776,7 @@ DecodeScheduler`.
             window_pages=self._window_pages,
             slots=int(self.slots),
             slot_leaves=self._slot_leaves,
+            attention_layers=self._attention_layers,
         )
 
     def _place_cache(self, cache):
@@ -1092,12 +1116,15 @@ PagePool`."""
         ])
         return out, load
 
-    def _note_moe_load(self, out, program: str):
+    def _note_moe_load(self, out, program: str, rows: int):
         """A dispatch's token output, which for a model with experts is
         ``(tokens, load)``: returns the tokens and, while tracing,
         records one ``moe_tokens_per_expert`` event (counts
         ``[layers][experts]`` as the device summed them; the array came
-        back with the dispatch's own readback)."""
+        back with the dispatch's own readback). For a model that holds a
+        share of its experts the counts are the held experts', and one
+        ``moe_held_choices`` event says what share of the dispatch's
+        ``rows`` x top-k x layers routed choices they were."""
         if not isinstance(out, tuple):
             return out
         out, load = out
@@ -1105,13 +1132,23 @@ PagePool`."""
             return out
         import jax
 
+        counts = np.asarray(jax.device_get(load))
         _trace.event(
             "moe_tokens_per_expert",
-            attrs={
-                "program": program,
-                "counts": np.asarray(jax.device_get(load)).tolist(),
-            },
+            attrs={"program": program, "counts": counts.tolist()},
         )
+        if getattr(self._module, "held_experts", ()):
+            _trace.event(
+                "moe_held_choices",
+                attrs={
+                    "program": program,
+                    "choices_held": int(counts.sum()),
+                    "choices_routed": rows * counts.shape[0]
+                    * int(self._module.experts_per_token),
+                    "tokens_per_expert_max": int(counts.max()),
+                    "tokens_per_expert_mean": float(counts.mean()),
+                },
+            )
         return out
 
     def _note_kv_blocks(self, lengths: np.ndarray) -> None:
@@ -1131,8 +1168,13 @@ PagePool`."""
         from zookeeper_tpu import ops
 
         totals = np.zeros(3, np.int64)
-        for windowed in sorted(set(self._window_layers)):
-            pool = self._cache[self._window_layers.index(windowed)]["k"]
+        groups = {
+            windowed: i
+            for i, windowed in reversed(list(enumerate(self._window_layers)))
+            if self._attention_layers[i]
+        }
+        for windowed, i in sorted(groups.items()):
+            pool = self._cache[i]["k"]
             page_size, width = (int(n) for n in pool.shape[2:])
             window = int(self._module.window) if windowed else None
             totals += ops.pool_decode_work(
@@ -1210,7 +1252,8 @@ PagePool`."""
             self._note_dispatch_compile(f"prefill/b{pb}s{sb}")
         ps = int(self.page_size)
         window_layers = self._window_layers
-        slot_leaves = tuple(self._slot_leaves)
+        attention_layers = self._attention_layers
+        slot_leaves = self._slot_leaves
 
         def prefill_fn(
             variables, cache, tokens, lengths, slot_rows, slot_ids=None
@@ -1240,19 +1283,22 @@ PagePool`."""
                 return jnp.where(dead, num_pages, pages)
 
             new_cache = []
-            for layer, (k, v, *state), windowed in zip(
-                cache, kv, window_layers
+            for layer, state, windowed, attends, names in zip(
+                cache, kv, window_layers, attention_layers, slot_leaves
             ):
-                table = layer_page_table(slot_rows, windowed)
-                layer = _pool_write_rows(
-                    layer, {"k": k, "v": v},
-                    targets(table, layer["k"].shape[0]), offs,
-                )
+                layer = dict(layer)
+                if attends:
+                    k, v, *state = state
+                    table = layer_page_table(slot_rows, windowed)
+                    layer = _pool_write_rows(
+                        layer, {"k": k, "v": v},
+                        targets(table, layer["k"].shape[0]), offs,
+                    )
                 # A recurrent mixer's block a slot, overwritten whole at
                 # the admitted slots (nothing of the last tenant stays);
                 # a partial group's padding rows carry the id `slots`
                 # and write nowhere.
-                for name, rows in zip(slot_leaves, state):
+                for name, rows in zip(names, state):
                     layer[name] = layer[name].at[slot_ids].set(
                         rows.astype(layer[name].dtype), mode="drop"
                     )
@@ -1269,7 +1315,7 @@ PagePool`."""
             jax.ShapeDtypeStruct((pb,), np.int32),
             self._table_like(pb),
         )
-        if slot_leaves:
+        if self._slot_kinds:
             example += (jax.ShapeDtypeStruct((pb,), np.int32),)
         compiled = self._aot(
             f"prefill/b{pb}s{sb}", prefill_fn, example, donate_cache_at=1
@@ -1288,7 +1334,7 @@ PagePool`."""
         import jax.numpy as jnp
 
         self._require_bound()
-        if self._slot_leaves:
+        if self._slot_kinds:
             raise NotImplementedError(
                 "a speculative draft or verify is not implemented for a "
                 "model with recurrent (state-space) state: rejected rows "
@@ -1403,12 +1449,14 @@ PagePool`."""
         if during_dispatch and self._warmed:
             self._note_dispatch_compile("copy_page")
 
+        slot_leaves = self._slot_leaves
+
         def copy_fn(cache, src, dst):
             out = []
-            for layer in cache:
+            for layer, names in zip(cache, slot_leaves):
                 out.append(
                     {
-                        name: buf if name in self._slot_leaves
+                        name: buf if name in names
                         else buf.at[dst].set(buf[src])
                         for name, buf in layer.items()
                     }
@@ -1530,7 +1578,7 @@ PageTransfer` moves between mesh slices. READ-ONLY: the source pool
                 "page transfer moves one layer group's pages; a model "
                 "with window layers has two (ROADMAP.md, Reach)."
             )
-        if self._slot_leaves:
+        if self._slot_kinds:
             raise NotImplementedError(
                 "page transfer moves pages; a model with recurrent "
                 "(state-space) state keeps a block a slot that is in "
@@ -1710,12 +1758,13 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         # The slots' page-table rows: padding rows stay all -1 (every
         # write drops via the OOB page sentinel).
         operands = (tokens, lengths, self._pool.operand(slot_ids, pb))
-        if self._slot_leaves:
+        if self._slot_kinds:
             ids = np.full((pb,), int(self.slots), np.int32)  # OOB: dropped
             ids[:n] = [int(s) for s in slot_ids]
             operands += (ids,)
             if _trace.enabled():
-                _trace.event("ssm_state_reset", attrs={"slots": n})
+                for kind in self._slot_kinds:
+                    _trace.event(f"{kind}_state_reset", attrs={"slots": n})
         compiled = self._prefill_compiled(pb, sb, during_dispatch=True)
         with _trace.span(
             "prefill_dispatch",
@@ -1736,7 +1785,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 self._reset_cache()
                 raise
             object.__setattr__(self, "_cache", new_cache)
-            first = self._note_moe_load(first, "prefill")
+            first = self._note_moe_load(first, "prefill", pb * sb)
             first = np.asarray(jax.device_get(first))
         return first[:n].astype(np.int32)
 
@@ -1911,16 +1960,19 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             )
         compiled = self._decode_compiled(during_dispatch=True)
         self._note_kv_blocks(lengths)
-        if self._slot_leaves and _trace.enabled():
+        if _trace.enabled():
             # The step reads and writes every slot's block of state;
             # the slots that hold pages are the ones that decode.
-            _trace.event(
-                "decode_ssm_slots",
-                attrs={
-                    "slots_advanced": int(self.slots),
-                    "slots_live": int(np.count_nonzero(self._pool.counts)),
-                },
-            )
+            for kind in self._slot_kinds:
+                _trace.event(
+                    f"decode_{kind}_slots",
+                    attrs={
+                        "slots_advanced": int(self.slots),
+                        "slots_live": int(
+                            np.count_nonzero(self._pool.counts)
+                        ),
+                    },
+                )
         with _trace.span(
             "decode_dispatch",
             attrs=(
@@ -1937,7 +1989,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 self._reset_cache()  # donation consumed the buffers
                 raise
             object.__setattr__(self, "_cache", new_cache)
-            nxt = self._note_moe_load(nxt, "decode_step")
+            nxt = self._note_moe_load(nxt, "decode_step", int(self.slots))
             nxt = np.asarray(jax.device_get(nxt))
             # Readback-bounded wall time — the only honest dispatch
             # clock (the compiled call returns un-synced arrays).

@@ -60,6 +60,13 @@ of them can be shared, copied a page at a time or rolled back by
 and the page handoff are refused for such a model
 (``DecodeEngine.bind``).
 
+What a layer's entry of the tree holds follows the layer's kind
+(docs/DESIGN.md §28): K/V pages only where the layer has attention
+(``attention_layers``), the block a slot only where it has a recurrent
+mixer (``slot_leaves``, a layer). A layer whose only mixer is linear
+attention has the block and no rows; the page table, the allocator and
+every page count are the attention layers' alone.
+
 Everything here is HOST state. The device half (the pool tree itself)
 is allocated by :func:`allocate_page_pool` and owned/donated by the
 ``DecodeEngine``.
@@ -92,7 +99,8 @@ def allocate_page_pool(
     window_layers: Sequence[bool] = (),
     window_pages: int = 0,
     slots: int = 0,
-    slot_leaves: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
+    slot_leaves: Sequence[Dict[str, Tuple[Tuple[int, ...], Any]]] = (),
+    attention_layers: Sequence[bool] = (),
 ) -> Tuple[dict, ...]:
     """Zero-initialized page-pool pytree: a per-layer tuple of
     ``{"k", "v"}`` pools ``[num_pages, head_shards, page_size,
@@ -111,9 +119,11 @@ def allocate_page_pool(
     get pools of ``window_pages`` pages, indexed by their own table
     (:class:`PagePool`); every other layer gets ``num_pages``.
 
-    ``slot_leaves`` (``{name: (shape a slot, dtype)}``, the model's own
-    names): every layer also gets ``[slots, *shape]`` zeros under each
-    name, the fixed block a slot of a recurrent mixer."""
+    ``slot_leaves`` (a layer, ``{name: (shape a slot, dtype)}``, the
+    model's own names; empty: none anywhere): the layer also gets
+    ``[slots, *shape]`` zeros under each name, the fixed block a slot of
+    a recurrent mixer. ``attention_layers`` (a layer; empty: all): a
+    layer without attention gets no ``k`` / ``v`` pages at all."""
     import jax.numpy as jnp
 
     from zookeeper_tpu.ops import kv_row_width
@@ -128,37 +138,41 @@ def allocate_page_pool(
         raise ValueError(f"quant={quant!r}: expected 'none' or 'int8'.")
     width = kv_row_width(num_heads, head_dim, head_shards)
     row_dtype = jnp.int8 if quant == "int8" else dtype
+    attends = _attends(num_layers, attention_layers)
     layers = []
-    for windowed in window_layers:
-        pages = window_pages if windowed else num_pages
-        shape = (pages, head_shards, page_size, width)
-        layer = {
-            "k": jnp.zeros(shape, row_dtype),
-            "v": jnp.zeros(shape, row_dtype),
-        }
-        if quant == "int8":
-            # Scale 1.0 everywhere: a zeroed int8 page dequantizes to
-            # exact zeros, matching the fp pool's initial state.
-            scales = shape[:3] + (num_heads // head_shards,)
-            layer["k_scale"] = jnp.ones(scales, jnp.float32)
-            layer["v_scale"] = jnp.ones(scales, jnp.float32)
-        for name, (per_slot, leaf_dtype) in (slot_leaves or {}).items():
+    for i, windowed in enumerate(window_layers):
+        layer = {}
+        if attends[i]:
+            pages = window_pages if windowed else num_pages
+            shape = (pages, head_shards, page_size, width)
+            layer["k"] = jnp.zeros(shape, row_dtype)
+            layer["v"] = jnp.zeros(shape, row_dtype)
+            if quant == "int8":
+                # Scale 1.0 everywhere: a zeroed int8 page dequantizes to
+                # exact zeros, matching the fp pool's initial state.
+                scales = shape[:3] + (num_heads // head_shards,)
+                layer["k_scale"] = jnp.ones(scales, jnp.float32)
+                layer["v_scale"] = jnp.ones(scales, jnp.float32)
+        leaves = slot_leaves[i] if slot_leaves else {}
+        for name, (per_slot, leaf_dtype) in leaves.items():
             layer[name] = jnp.zeros((slots,) + tuple(per_slot), leaf_dtype)
         layers.append(layer)
     return tuple(layers)
 
 
 def slot_state_bytes(
-    num_layers: int,
     slots: int,
-    slot_leaves: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]],
+    slot_leaves: Sequence[Dict[str, Tuple[Tuple[int, ...], Any]]],
 ) -> Dict[str, int]:
-    """Bytes of each slot leaf over all layers and slots (``{name:
-    bytes}``; empty for a model without them)."""
-    return {
-        name: num_layers * slots * int(np.prod(shape)) * np.dtype(dtype).itemsize
-        for name, (shape, dtype) in (slot_leaves or {}).items()
-    }
+    """Bytes of each slot leaf over all slots and the layers that have
+    it (``{name: bytes}``; empty for a model without them)."""
+    out: Dict[str, int] = {}
+    for leaves in slot_leaves:
+        for name, (shape, dtype) in leaves.items():
+            out[name] = out.get(name, 0) + (
+                slots * int(np.prod(shape)) * np.dtype(dtype).itemsize
+            )
+    return out
 
 
 def page_pool_bytes(
@@ -172,21 +186,38 @@ def page_pool_bytes(
     head_shards: int = 1,
     window_layers: Sequence[bool] = (),
     window_pages: int = 0,
+    attention_layers: Sequence[bool] = (),
 ) -> int:
     """Total HBM the pool occupies (k + v rows at their padded width,
-    all layers, plus the scale arrays when quantized) — the §20
-    capacity-planning number. Layer groups as in
-    :func:`allocate_page_pool`."""
+    all layers that have attention, plus the scale arrays when
+    quantized) — the §20 capacity-planning number. Layer groups and
+    ``attention_layers`` as in :func:`allocate_page_pool`."""
     from zookeeper_tpu.ops import kv_row_width
 
-    windowed = sum(_window_layers(num_layers, window_layers, window_pages))
-    pages = (num_layers - windowed) * num_pages + windowed * window_pages
+    attends = _attends(num_layers, attention_layers)
+    kinds = _window_layers(num_layers, window_layers, window_pages)
+    windowed = sum(w and a for w, a in zip(kinds, attends))
+    pages = (sum(attends) - windowed) * num_pages + windowed * window_pages
     rows = 2 * pages * page_size
     width = head_shards * kv_row_width(num_heads, head_dim, head_shards)
     total = rows * width * (1 if quant == "int8" else itemsize)
     if quant == "int8":
         total += rows * num_heads * 4  # float32 scale per (row, head)
     return total
+
+
+def _attends(
+    num_layers: int, attention_layers: Sequence[bool]
+) -> Tuple[bool, ...]:
+    attention_layers = tuple(bool(a) for a in attention_layers)
+    if not attention_layers:
+        return (True,) * num_layers
+    if len(attention_layers) != num_layers:
+        raise ValueError(
+            f"attention_layers has {len(attention_layers)} entries for "
+            f"{num_layers} layers."
+        )
+    return attention_layers
 
 
 def _window_layers(

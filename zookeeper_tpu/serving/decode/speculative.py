@@ -120,7 +120,7 @@ class SpeculativeDecoding:
         for role, module in (
             ("teacher", engine._module), ("draft", draft_module)
         ):
-            if getattr(module, "slot_state_spec", dict)():
+            if any(getattr(module, "slot_state_spec", tuple)()):
                 raise ValueError(
                     f"speculative decoding with a {role} that has "
                     "recurrent (state-space) state is not implemented: a "
