@@ -19,7 +19,10 @@ from zookeeper_tpu.models.transformer import (
     TransformerLMModule,
     _pool_write_rows,
 )
-from zookeeper_tpu.observability.hlo import count_copies_of_size
+from zookeeper_tpu.observability.hlo import (
+    count_copies_of_size,
+    count_row_scatters_of_size,
+)
 from zookeeper_tpu.serving.decode.pages import allocate_page_pool
 
 PAGES, PAGE_SIZE, HEADS, HEAD_DIM, SLOTS, MAX_PAGES = 3072, 16, 25, 64, 48, 64
@@ -295,6 +298,85 @@ def test_prefill_write_holds_no_pool_sized_copy(shaped, quant):
     # their scatter; the int8 rows are not.
     rows_only = [{"k": cache[0]["k"], "v": cache[0]["v"]}]
     assert whole_leaf_copies(compiled, rows_only) == 0
+
+
+@pytest.fixture(scope="module")
+def engine_programs(shaped):
+    """``text(program)``: the engine's own ``prefill_fn`` (a cold
+    1,024-token prefill) or ``extend_fn`` (a 128-token warm suffix), as
+    an engine bound at the cell's pool, widths and buckets (one layer, a
+    small vocabulary: neither is in the write) builds it, compiled for
+    the one described chip; and the sizes of its pool's leaves."""
+    from functools import lru_cache
+
+    from zookeeper_tpu.core import configure
+    from zookeeper_tpu.models.transformer import TransformerLM
+    from zookeeper_tpu.serving.decode import DecodeEngine
+
+    model = TransformerLM()
+    configure(
+        model,
+        {
+            "num_layers": 1, "d_model": HEADS * HEAD_DIM, "num_heads": HEADS,
+            "mlp_ratio": 4, "compute_dtype": "bfloat16", "attention": "flash",
+        },
+    )
+    module = model.build((1024,), 512)
+    params, _ = model.initialize(module, (1024,), seed=0)
+    engine = DecodeEngine()
+    configure(
+        engine,
+        {
+            "slots": SLOTS, "page_size": PAGE_SIZE, "kv_capacity": 1024,
+            "seq_buckets": (128, 512, 1024), "prefix_cache": True,
+            "decode_attention": "reference",
+        },
+        name="engine",
+    )
+    engine.bind(module, params, {})
+    leaves = {int(np.prod(x.shape)) for x in jax.tree.leaves(engine._cache)}
+    assert leaves == {PAGES * PAGE_SIZE * 1664}
+    built = {}
+
+    def keep(key, fn, example, **_):
+        built[key] = (fn, example)
+
+    object.__setattr__(engine, "_aot", keep)  # build, do not compile here
+
+    @lru_cache(maxsize=None)
+    def text(program):
+        if program == "prefill":
+            engine._prefill_compiled(1, 1024)
+        else:
+            engine._extend_compiled(1, 128)
+        fn, example = built.popitem()[1]
+        return jax.jit(fn, donate_argnums=1).lower(
+            *shaped(example)
+        ).compile().as_text()
+
+    return text, leaves
+
+
+def test_cold_prefill_program_writes_the_pool_by_page(engine_programs):
+    """``prefill_fn`` as the engine builds it at the cell's shapes: no
+    scatter over a pool leaf whose window is one row (until PR 34 it held
+    two, of 1,024 indices each: 7.5 of a prefill's 20 ms on the chip), a
+    scatter a leaf whose window is a page, and no copy of a leaf; the
+    extend program, whose window starts anywhere in a page, keeps the row
+    write."""
+    text, leaves = engine_programs
+    prefill = text("prefill")
+    assert count_row_scatters_of_size(prefill, leaves) == 0
+    by_page = [
+        line for line in prefill.splitlines()
+        if " scatter(" in line and "update_window_dims={1,2}" in line
+    ]
+    assert len(by_page) == 2
+    assert all(f"bf16[{PAGES},{PAGE_SIZE},1664]" in line for line in by_page)
+    assert count_copies_of_size(prefill, leaves) == 0
+    extend = text("extend")
+    assert count_row_scatters_of_size(extend, leaves) == 2
+    assert count_copies_of_size(extend, leaves) == 0
 
 
 def kernel_scoped_vmem_requests(compiled):

@@ -194,13 +194,63 @@ def _pool_write_rows(layer, rows, pages, offsets):
     then re-lays-out the whole pool — docs/DESIGN.md §20.) Quantizes
     inline when the layer carries scale arrays (int8 pools — see
     ``ops.quantizers.quantize_kv_rows``); the scales go through the
-    same write. Returns the updated layer dict."""
-    from zookeeper_tpu.ops import fold_kv_rows, quantize_kv_rows
+    same write. Returns the updated layer dict.
+
+    One scatter index a ROW: the write of the programs whose window
+    starts at ``lengths``, anywhere in a page (``decode_paged``: one
+    row a slot; ``decode_verify_paged``: the speculative verify, the
+    warm-prefix extend, a prefill chunk). A cold prefill starts at
+    position 0 and writes by page: :func:`_pool_write_pages`."""
 
     def write(buf, vals):
         return buf.at[pages, :, offsets].set(
             vals.astype(buf.dtype), mode="drop"
         )
+
+    return _pool_write(layer, rows, write)
+
+
+def _pool_write_pages(layer, rows, pages):
+    """Write ``rows [pb, sb, heads, head_dim]``, the K/V of positions
+    ``0 .. sb - 1`` of ``pb`` sequences, into a page-pool layer dict a
+    PAGE at a time: positions ``p * page_size .. (p + 1) * page_size -
+    1`` of sequence ``i`` land as page ``pages[i, p]`` whole (``pages
+    [pb, ceil(sb / page_size)]``; an entry ``== num_pages``, the OOB
+    sentinel, writes nowhere; ``sb`` is padded to whole pages here).
+    Folded and quantized as :func:`_pool_write_rows` does, the scales
+    through the same write, the donated pool updated in place; what
+    differs is the scatter's window, a page's ``[head_shards,
+    page_size, row_width]`` (whole tiles, contiguous) where a row's is
+    a strided sixteenth of each of them, and one index a page where
+    there was one a row (on the v5e a row at a time cost a third of a
+    1,024-token prefill's device time: docs/DESIGN.md §20).
+
+    The cold prefill's write (``DecodeEngine``'s ``prefill_fn``): only
+    a window that starts at position 0 holds whole pages. It leaves one
+    thing in the pool that the row write does not: the rows from a
+    sequence's length to the end of its last page hold the padding
+    positions' K/V (finite) and not the page's last tenant's. Every
+    reader masks by the sequence's length, and a decode step writes row
+    ``length`` before it reads it."""
+    ps = layer["k"].shape[2]
+    pb, sb = rows["k"].shape[:2]
+    pad = pages.shape[1] * ps - sb
+
+    def write(buf, vals):
+        # [pb, sb, head_shards, x] -> a page's block [head_shards, ps, x]
+        if pad:
+            vals = jnp.pad(vals, [(0, 0), (0, pad)] + [(0, 0)] * 2)
+        vals = vals.reshape(pb, -1, ps, *vals.shape[2:]).swapaxes(2, 3)
+        return buf.at[pages].set(vals.astype(buf.dtype), mode="drop")
+
+    return _pool_write(layer, rows, write)
+
+
+def _pool_write(layer, rows, write):
+    """``write(buf, vals)`` for the K and the V rows of ``rows`` in the
+    pool's stored form (``ops.fold_kv_rows``; int8 with the scales when
+    the layer carries them: ``ops.quantizers.quantize_kv_rows``)."""
+    from zookeeper_tpu.ops import fold_kv_rows, quantize_kv_rows
 
     out = dict(layer)
     for name, scale_name in (("k", "k_scale"), ("v", "v_scale")):

@@ -535,6 +535,10 @@ DecodeScheduler`.
         object.__setattr__(self, "_compile_count", 0)
         object.__setattr__(self, "_warmed", False)
         object.__setattr__(self, "_recompiles_detected", 0)
+        # Prompt rows whose K/V went into the pool a page at a time
+        # (`prefill`) and a row at a time (`prefill_warm`,
+        # `prefill_chunk`): `pool_status()["kv_page_write_share"]`.
+        object.__setattr__(self, "_kv_rows_written", {"pages": 0, "rows": 0})
         object.__setattr__(self, "_ledger_records", {})
         flavor, attn_fn = self._resolve_decode_attention()
         object.__setattr__(self, "_decode_attention_flavor", flavor)
@@ -919,8 +923,19 @@ PagePool`."""
         return self._pool.invalidate_prefix()
 
     def pool_status(self) -> dict:
-        """The ``/statusz`` ``kv_pool`` sub-section."""
-        return self._pool.status()
+        """The ``/statusz`` ``kv_pool`` sub-section: the allocator's
+        counts and ``kv_page_write_share``, the share of the prompt rows
+        written so far that a cold prefill wrote a page at a time (the
+        rest went a row at a time through the extend program: warm
+        prefixes, prefill chunks; docs/DESIGN.md §20). 0.0 before the
+        first prompt."""
+        written = self._kv_rows_written
+        return {
+            **self._pool.status(),
+            "kv_page_write_share": round(
+                written["pages"] / max(sum(written.values()), 1), 4
+            ),
+        }
 
     @property
     def compile_count(self) -> int:
@@ -1259,28 +1274,32 @@ PagePool`."""
             variables, cache, tokens, lengths, slot_rows, slot_ids=None
         ):
             from zookeeper_tpu.models.transformer import (
-                _pool_write_rows,
+                _pool_write_pages,
                 layer_page_table,
             )
 
             (last_logits, kv), load = self._apply(
                 variables, tokens, lengths, method="prefill"
             )
-            # Scatter each prompt row through its slot's page-table
-            # row: position j lands at (slot_rows[:, j // ps], j % ps).
-            # Rows past the true length, unallocated table entries, and
-            # a partial group's padding rows (all -1 rows) take the OOB
+            # A cold prefill starts at position 0, so its rows p * ps ..
+            # p * ps + ps - 1 are the page slot_rows[:, p] whole: each
+            # layer's K/V go into the pool a page at a time
+            # (docs/DESIGN.md §20). Pages wholly past the true length
+            # (the bucket's padding), unallocated table entries, and a
+            # partial group's padding rows (all -1 rows) take the OOB
             # page sentinel and write nowhere. A window layer's table
-            # holds the prompt's tail only: the rows before it drop the
+            # holds the prompt's tail only: the pages before it drop the
             # same way.
-            j = jnp.arange(sb)
-            row = jnp.clip(j // ps, 0, slot_rows.shape[-1] - 1)
-            offs = jnp.broadcast_to(j % ps, (pb, sb))
+            first_row = jnp.arange(-(-sb // ps)) * ps
+            targets = {}  # a layer group's, traced once for its layers
 
-            def targets(table, num_pages):
-                pages = table[:, row]  # [pb, sb]
-                dead = (j[None, :] >= lengths[:, None]) | (pages < 0)
-                return jnp.where(dead, num_pages, pages)
+            def pages_of(windowed, num_pages):
+                if windowed not in targets:
+                    table = layer_page_table(slot_rows, windowed)
+                    pages = table[:, : first_row.shape[0]]
+                    dead = (first_row >= lengths[:, None]) | (pages < 0)
+                    targets[windowed] = jnp.where(dead, num_pages, pages)
+                return targets[windowed]
 
             new_cache = []
             for layer, state, windowed, attends, names in zip(
@@ -1289,10 +1308,9 @@ PagePool`."""
                 layer = dict(layer)
                 if attends:
                     k, v, *state = state
-                    table = layer_page_table(slot_rows, windowed)
-                    layer = _pool_write_rows(
+                    layer = _pool_write_pages(
                         layer, {"k": k, "v": v},
-                        targets(table, layer["k"].shape[0]), offs,
+                        pages_of(windowed, layer["k"].shape[0]),
                     )
                 # A recurrent mixer's block a slot, overwritten whole at
                 # the admitted slots (nothing of the last tenant stays);
@@ -1766,10 +1784,12 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 for kind in self._slot_kinds:
                     _trace.event(f"{kind}_state_reset", attrs={"slots": n})
         compiled = self._prefill_compiled(pb, sb, during_dispatch=True)
+        self._kv_rows_written["pages"] += sum(lens)
         with _trace.span(
             "prefill_dispatch",
             attrs=(
-                {"requests": n, "bucket": pb, "seq_bucket": sb}
+                {"requests": n, "bucket": pb, "seq_bucket": sb,
+                 "kv_write": "pages"}
                 if _trace.enabled()
                 else None
             ),
@@ -1833,10 +1853,12 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             out_idx[i] = suf.shape[0] - 1
         rows = self._pool.operand(slot_ids, pb)
         compiled = self._extend_compiled(pb, w, during_dispatch=True)
+        self._kv_rows_written["rows"] += sum(suffixes)
         with _trace.span(
             "prefill_warm_dispatch",
             attrs=(
-                {"requests": n, "bucket": pb, "width": w}
+                {"requests": n, "bucket": pb, "width": w,
+                 "kv_write": "rows"}
                 if _trace.enabled()
                 else None
             ),
@@ -1901,11 +1923,12 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             out_idx[i] = lens[i] - 1
         rows = self._pool.operand(slot_ids, pb)
         compiled = self._extend_compiled(pb, w, during_dispatch=True)
+        self._kv_rows_written["rows"] += sum(lens)
         with _trace.span(
             "prefill_chunk_dispatch",
             attrs=(
                 {"lanes": n, "bucket": pb, "width": w,
-                 "tokens": int(sum(lens))}
+                 "tokens": int(sum(lens)), "kv_write": "rows"}
                 if _trace.enabled()
                 else None
             ),
