@@ -557,7 +557,9 @@ def test_leaves_of_one_iteration_share_its_step_and_end_in_one_event(leaf_run):
     for (thread, step), recs in sorted(groups.items()):
         ends = [r for r in recs if r["name"] == "sched_iteration_end"]
         assert len(ends) == 1
-        assert set(ends[0]["attrs"]) == {"admitted", "decoded", "chunks"}
+        assert set(ends[0]["attrs"]) == {
+            "admitted", "decoded", "chunks", "wall_ns", "cpu_ns",
+        }
         spans = [r for r in recs if r["phase"] == "X"]
         assert spans[0]["name"] == "sched_sweep"
         assert spans[-1]["name"] == "sched_bookkeeping"

@@ -1131,23 +1131,15 @@ PagePool`."""
         ])
         return out, load
 
-    def _note_moe_load(self, out, program: str, rows: int):
-        """A dispatch's token output, which for a model with experts is
-        ``(tokens, load)``: returns the tokens and, while tracing,
-        records one ``moe_tokens_per_expert`` event (counts
-        ``[layers][experts]`` as the device summed them; the array came
-        back with the dispatch's own readback). For a model that holds a
-        share of its experts the counts are the held experts', and one
-        ``moe_held_choices`` event says what share of the dispatch's
-        ``rows`` x top-k x layers routed choices they were."""
-        if not isinstance(out, tuple):
-            return out
-        out, load = out
-        if not _trace.enabled():
-            return out
-        import jax
-
-        counts = np.asarray(jax.device_get(load))
+    def _note_moe_load(self, counts, program: str, rows: int) -> None:
+        """The load a traced dispatch of a model with experts read back
+        beside its tokens (``counts [layers][experts]`` as the device
+        summed them, already on the host): one ``moe_tokens_per_expert``
+        event. For a model that holds a share of its experts the counts
+        are the held experts', and one ``moe_held_choices`` event says
+        what share of the dispatch's ``rows`` x top-k x layers routed
+        choices they were. Recorded after the dispatch span has closed,
+        so that building the lists is not in it."""
         _trace.event(
             "moe_tokens_per_expert",
             attrs={"program": program, "counts": counts.tolist()},
@@ -1164,7 +1156,6 @@ PagePool`."""
                     "tokens_per_expert_mean": float(counts.mean()),
                 },
             )
-        return out
 
     def _note_kv_blocks(self, lengths: np.ndarray) -> None:
         """While tracing, and where the decode step attends through the
@@ -1744,58 +1735,48 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
 
     # -- dispatch --------------------------------------------------------
 
-    def prefill(self, prompts: Sequence[np.ndarray], slot_ids: Sequence[int]):
-        """Admit a group: write each prompt's KV into its slot and emit
-        each sequence's FIRST token. ``prompts`` are 1-D int arrays (up
-        to the largest prefill bucket of them, each at most
-        ``max_prompt`` tokens); ``slot_ids`` the target slots (unique).
-        Returns the first tokens as a host ``[len(prompts)] int32``
-        array. The TTFT token: the scheduler stamps time-to-first-token
-        off this call's readback."""
+    @staticmethod
+    def _prepare_span(program: str):
+        """``dispatch_prepare``: the leaf from the top of a dispatch
+        method's host work (argument checks, bucket choice and padding,
+        the page-table operand, the program's lookup, and the
+        bookkeeping only a trace pays for) to its dispatch span's start.
+        It lies between the scheduler's leaves, enclosed by none."""
+        return _trace.span(
+            "dispatch_prepare",
+            attrs={"program": program} if _trace.enabled() else None,
+        )
+
+    def _dispatch(
+        self,
+        span: str,
+        attrs: Optional[Dict[str, Any]],
+        program: str,
+        compiled,
+        operands: tuple,
+        *,
+        rows: int = 0,
+        observe: bool = False,
+    ) -> np.ndarray:
+        """The one body of every cache-donating dispatch: inside the
+        span ``span``, the compiled call, the cache swap, one
+        ``dispatch_enqueued`` event (the span's inner boundary: before
+        it the host launches the step, after it the host waits for the
+        device and reads back), and ONE ``jax.device_get`` of the whole
+        output. A model with experts returns ``(tokens, load)``; the
+        load is read back with the tokens while tracing (else dropped
+        on the device) and becomes its events after the span has closed
+        (``rows``: the dispatch's rows, for :meth:`_note_moe_load`).
+        ``observe`` feeds the readback-bounded wall time — the only
+        honest dispatch clock, the compiled call returns un-synced
+        arrays — to the MBU gauge under ``program``. Returns the tokens
+        as a host array."""
         import jax
 
-        self._require_bound()
-        n = len(prompts)
-        if n == 0:
-            return np.zeros((0,), np.int32)
-        if n != len(set(int(s) for s in slot_ids)) or n != len(slot_ids):
-            raise ValueError(
-                f"slot_ids {list(slot_ids)!r} must be unique and match "
-                f"the {n} prompts."
-            )
-        lens = [int(np.shape(p)[0]) for p in prompts]
-        if min(lens) < 1:
-            raise ValueError("empty prompt is not servable.")
-        pb = self.prefill_bucket_for(n)
-        sb = self.seq_bucket_for(max(lens))
-        tokens = np.zeros((pb, sb), np.int32)
-        lengths = np.ones((pb,), np.int32)  # pad rows: len 1, dropped
-        for i, (p, _) in enumerate(zip(prompts, slot_ids)):
-            tokens[i, : lens[i]] = np.asarray(p, np.int32)
-            lengths[i] = lens[i]
-        # The slots' page-table rows: padding rows stay all -1 (every
-        # write drops via the OOB page sentinel).
-        operands = (tokens, lengths, self._pool.operand(slot_ids, pb))
-        if self._slot_kinds:
-            ids = np.full((pb,), int(self.slots), np.int32)  # OOB: dropped
-            ids[:n] = [int(s) for s in slot_ids]
-            operands += (ids,)
-            if _trace.enabled():
-                for kind in self._slot_kinds:
-                    _trace.event(f"{kind}_state_reset", attrs={"slots": n})
-        compiled = self._prefill_compiled(pb, sb, during_dispatch=True)
-        self._kv_rows_written["pages"] += sum(lens)
-        with _trace.span(
-            "prefill_dispatch",
-            attrs=(
-                {"requests": n, "bucket": pb, "seq_bucket": sb,
-                 "kv_write": "pages"}
-                if _trace.enabled()
-                else None
-            ),
-        ):
+        with _trace.span(span, attrs=attrs):
+            t0 = time.perf_counter() if observe else 0.0
             try:
-                new_cache, first = compiled(
+                new_cache, out = compiled(
                     self._variables, self._cache, *operands
                 )
             except BaseException:
@@ -1805,8 +1786,70 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 self._reset_cache()
                 raise
             object.__setattr__(self, "_cache", new_cache)
-            first = self._note_moe_load(first, "prefill", pb * sb)
-            first = np.asarray(jax.device_get(first))
+            if _trace.enabled():
+                _trace.event("dispatch_enqueued", attrs={"program": program})
+            elif isinstance(out, tuple):
+                out = out[0]
+            out = jax.device_get(out)
+            if observe:
+                self._observe_decode(time.perf_counter() - t0, program)
+        if isinstance(out, tuple):
+            out, load = out
+            self._note_moe_load(load, program, rows)
+        return np.asarray(out)
+
+    def prefill(self, prompts: Sequence[np.ndarray], slot_ids: Sequence[int]):
+        """Admit a group: write each prompt's KV into its slot and emit
+        each sequence's FIRST token. ``prompts`` are 1-D int arrays (up
+        to the largest prefill bucket of them, each at most
+        ``max_prompt`` tokens); ``slot_ids`` the target slots (unique).
+        Returns the first tokens as a host ``[len(prompts)] int32``
+        array. The TTFT token: the scheduler stamps time-to-first-token
+        off this call's readback."""
+        self._require_bound()
+        n = len(prompts)
+        if n == 0:
+            return np.zeros((0,), np.int32)
+        with self._prepare_span("prefill"):
+            if n != len(set(int(s) for s in slot_ids)) or n != len(slot_ids):
+                raise ValueError(
+                    f"slot_ids {list(slot_ids)!r} must be unique and "
+                    f"match the {n} prompts."
+                )
+            lens = [int(np.shape(p)[0]) for p in prompts]
+            if min(lens) < 1:
+                raise ValueError("empty prompt is not servable.")
+            pb = self.prefill_bucket_for(n)
+            sb = self.seq_bucket_for(max(lens))
+            tokens = np.zeros((pb, sb), np.int32)
+            lengths = np.ones((pb,), np.int32)  # pad rows: len 1, dropped
+            for i, (p, _) in enumerate(zip(prompts, slot_ids)):
+                tokens[i, : lens[i]] = np.asarray(p, np.int32)
+                lengths[i] = lens[i]
+            # The slots' page-table rows: padding rows stay all -1
+            # (every write drops via the OOB page sentinel).
+            operands = (tokens, lengths, self._pool.operand(slot_ids, pb))
+            if self._slot_kinds:
+                ids = np.full((pb,), int(self.slots), np.int32)  # OOB: dropped
+                ids[:n] = [int(s) for s in slot_ids]
+                operands += (ids,)
+                if _trace.enabled():
+                    for kind in self._slot_kinds:
+                        _trace.event(
+                            f"{kind}_state_reset", attrs={"slots": n}
+                        )
+            compiled = self._prefill_compiled(pb, sb, during_dispatch=True)
+            self._kv_rows_written["pages"] += sum(lens)
+        first = self._dispatch(
+            "prefill_dispatch",
+            (
+                {"requests": n, "bucket": pb, "seq_bucket": sb,
+                 "kv_write": "pages"}
+                if _trace.enabled()
+                else None
+            ),
+            "prefill", compiled, operands, rows=pb * sb,
+        )
         return first[:n].astype(np.int32)
 
     def prefill_warm(
@@ -1822,57 +1865,48 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         width bucket holding the longest suffix. Emits each request's
         first token exactly like :meth:`prefill`; the TTFT collapse for
         warm prefixes is this method's whole reason to exist."""
-        import jax
-
         self._require_bound()
         n = len(prompts)
         if n == 0:
             return np.zeros((0,), np.int32)
-        suffixes = [
-            int(np.shape(p)[0]) - int(sh)
-            for p, sh in zip(prompts, shared_lens)
-        ]
-        if min(suffixes) < 1:
-            raise ValueError(
-                "warm prefill needs >= 1 suffix token per prompt (the "
-                "prefix match is capped at len - 1 so the first "
-                "emission's logits exist)."
-            )
-        pb = self.prefill_bucket_for(n)
-        w = self.seq_bucket_for(max(suffixes))
-        tokens = np.zeros((pb, w), np.int32)
-        lengths = np.zeros((pb,), np.int32)
-        valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
-        out_idx = np.zeros((pb,), np.int32)
-        for i, (p, s, sh) in enumerate(zip(prompts, slot_ids, shared_lens)):
-            p = np.asarray(p, np.int32)
-            suf = p[int(sh):]
-            tokens[i, : suf.shape[0]] = suf
-            lengths[i] = int(sh)
-            valid[i] = suf.shape[0]
-            out_idx[i] = suf.shape[0] - 1
-        rows = self._pool.operand(slot_ids, pb)
-        compiled = self._extend_compiled(pb, w, during_dispatch=True)
-        self._kv_rows_written["rows"] += sum(suffixes)
-        with _trace.span(
+        with self._prepare_span("prefill_extend"):
+            suffixes = [
+                int(np.shape(p)[0]) - int(sh)
+                for p, sh in zip(prompts, shared_lens)
+            ]
+            if min(suffixes) < 1:
+                raise ValueError(
+                    "warm prefill needs >= 1 suffix token per prompt (the "
+                    "prefix match is capped at len - 1 so the first "
+                    "emission's logits exist)."
+                )
+            pb = self.prefill_bucket_for(n)
+            w = self.seq_bucket_for(max(suffixes))
+            tokens = np.zeros((pb, w), np.int32)
+            lengths = np.zeros((pb,), np.int32)
+            valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
+            out_idx = np.zeros((pb,), np.int32)
+            for i, (p, sh) in enumerate(zip(prompts, shared_lens)):
+                p = np.asarray(p, np.int32)
+                suf = p[int(sh):]
+                tokens[i, : suf.shape[0]] = suf
+                lengths[i] = int(sh)
+                valid[i] = suf.shape[0]
+                out_idx[i] = suf.shape[0] - 1
+            rows = self._pool.operand(slot_ids, pb)
+            compiled = self._extend_compiled(pb, w, during_dispatch=True)
+            self._kv_rows_written["rows"] += sum(suffixes)
+        first = self._dispatch(
             "prefill_warm_dispatch",
-            attrs=(
+            (
                 {"requests": n, "bucket": pb, "width": w,
                  "kv_write": "rows"}
                 if _trace.enabled()
                 else None
             ),
-        ):
-            try:
-                new_cache, first = compiled(
-                    self._variables, self._cache, tokens, lengths, rows,
-                    valid, out_idx,
-                )
-            except BaseException:
-                self._reset_cache()  # donation consumed the buffers
-                raise
-            object.__setattr__(self, "_cache", new_cache)
-            first = np.asarray(jax.device_get(first))
+            "prefill_extend", compiled,
+            (tokens, lengths, rows, valid, out_idx),
+        )
         return first[:n].astype(np.int32)
 
     def prefill_chunk(
@@ -1897,52 +1931,43 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         once with full causal context over the committed prefix. Rides
         the warmed ``prefill_extend`` (bucket, width) grid — zero new
         compiles for any chunk within the seq buckets."""
-        import jax
-
         self._require_bound()
         n = len(chunks)
         if n == 0:
             return np.zeros((0,), np.int32)
-        lens = [int(np.shape(c)[0]) for c in chunks]
-        if min(lens) < 1:
-            raise ValueError(
-                "prefill_chunk needs >= 1 token per lane (zero-token "
-                "chunks must be skipped by the planner)."
-            )
-        pb = self.prefill_bucket_for(n)
-        w = self.seq_bucket_for(max(lens))
-        tokens = np.zeros((pb, w), np.int32)
-        lengths = np.zeros((pb,), np.int32)
-        valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
-        out_idx = np.zeros((pb,), np.int32)
-        for i, (c, s, off) in enumerate(zip(chunks, slot_ids, offsets)):
-            c = np.asarray(c, np.int32)
-            tokens[i, : lens[i]] = c
-            lengths[i] = int(off)
-            valid[i] = lens[i]
-            out_idx[i] = lens[i] - 1
-        rows = self._pool.operand(slot_ids, pb)
-        compiled = self._extend_compiled(pb, w, during_dispatch=True)
-        self._kv_rows_written["rows"] += sum(lens)
-        with _trace.span(
+        with self._prepare_span("prefill_extend"):
+            lens = [int(np.shape(c)[0]) for c in chunks]
+            if min(lens) < 1:
+                raise ValueError(
+                    "prefill_chunk needs >= 1 token per lane (zero-token "
+                    "chunks must be skipped by the planner)."
+                )
+            pb = self.prefill_bucket_for(n)
+            w = self.seq_bucket_for(max(lens))
+            tokens = np.zeros((pb, w), np.int32)
+            lengths = np.zeros((pb,), np.int32)
+            valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
+            out_idx = np.zeros((pb,), np.int32)
+            for i, (c, off) in enumerate(zip(chunks, offsets)):
+                c = np.asarray(c, np.int32)
+                tokens[i, : lens[i]] = c
+                lengths[i] = int(off)
+                valid[i] = lens[i]
+                out_idx[i] = lens[i] - 1
+            rows = self._pool.operand(slot_ids, pb)
+            compiled = self._extend_compiled(pb, w, during_dispatch=True)
+            self._kv_rows_written["rows"] += sum(lens)
+        last = self._dispatch(
             "prefill_chunk_dispatch",
-            attrs=(
+            (
                 {"lanes": n, "bucket": pb, "width": w,
                  "tokens": int(sum(lens)), "kv_write": "rows"}
                 if _trace.enabled()
                 else None
             ),
-        ):
-            try:
-                new_cache, last = compiled(
-                    self._variables, self._cache, tokens, lengths, rows,
-                    valid, out_idx,
-                )
-            except BaseException:
-                self._reset_cache()  # donation consumed the buffers
-                raise
-            object.__setattr__(self, "_cache", new_cache)
-            last = np.asarray(jax.device_get(last))
+            "prefill_extend", compiled,
+            (tokens, lengths, rows, valid, out_idx),
+        )
         return last[:n].astype(np.int32)
 
     def copy_page(self, src: int, dst: int) -> None:
@@ -1969,54 +1994,39 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         int32`` array. Inactive slots ride along (fixed shape) — the
         scheduler ignores their output and never advances their
         lengths."""
-        import jax
-
         self._require_bound()
-        tokens = np.asarray(tokens, np.int32)
-        lengths = np.asarray(lengths, np.int32)
-        if tokens.shape != (int(self.slots),) or lengths.shape != (
-            int(self.slots),
-        ):
-            raise ValueError(
-                f"decode expects [slots]={self.slots} token and length "
-                f"arrays, got {tokens.shape} / {lengths.shape}."
-            )
-        compiled = self._decode_compiled(during_dispatch=True)
-        self._note_kv_blocks(lengths)
-        if _trace.enabled():
-            # The step reads and writes every slot's block of state;
-            # the slots that hold pages are the ones that decode.
-            for kind in self._slot_kinds:
-                _trace.event(
-                    f"decode_{kind}_slots",
-                    attrs={
-                        "slots_advanced": int(self.slots),
-                        "slots_live": int(
-                            np.count_nonzero(self._pool.counts)
-                        ),
-                    },
+        with self._prepare_span("decode_step"):
+            tokens = np.asarray(tokens, np.int32)
+            lengths = np.asarray(lengths, np.int32)
+            if tokens.shape != (int(self.slots),) or lengths.shape != (
+                int(self.slots),
+            ):
+                raise ValueError(
+                    f"decode expects [slots]={self.slots} token and length "
+                    f"arrays, got {tokens.shape} / {lengths.shape}."
                 )
-        with _trace.span(
+            compiled = self._decode_compiled(during_dispatch=True)
+            self._note_kv_blocks(lengths)
+            if _trace.enabled():
+                # The step reads and writes every slot's block of state;
+                # the slots that hold pages are the ones that decode.
+                for kind in self._slot_kinds:
+                    _trace.event(
+                        f"decode_{kind}_slots",
+                        attrs={
+                            "slots_advanced": int(self.slots),
+                            "slots_live": int(
+                                np.count_nonzero(self._pool.counts)
+                            ),
+                        },
+                    )
+            operands = (tokens, lengths, self._pool.operand())
+        nxt = self._dispatch(
             "decode_dispatch",
-            attrs=(
-                {"slots": int(self.slots)} if _trace.enabled() else None
-            ),
-        ):
-            t0 = time.perf_counter()
-            try:
-                new_cache, nxt = compiled(
-                    self._variables, self._cache, tokens, lengths,
-                    self._pool.operand(),
-                )
-            except BaseException:
-                self._reset_cache()  # donation consumed the buffers
-                raise
-            object.__setattr__(self, "_cache", new_cache)
-            nxt = self._note_moe_load(nxt, "decode_step", int(self.slots))
-            nxt = np.asarray(jax.device_get(nxt))
-            # Readback-bounded wall time — the only honest dispatch
-            # clock (the compiled call returns un-synced arrays).
-            self._observe_decode(time.perf_counter() - t0)
+            {"slots": int(self.slots)} if _trace.enabled() else None,
+            "decode_step", compiled, operands,
+            rows=int(self.slots), observe=True,
+        )
         return nxt.astype(np.int32)
 
     def verify(self, tokens: np.ndarray, lengths: np.ndarray) -> np.ndarray:
@@ -2031,46 +2041,33 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         rejected rows stay masked garbage. Active slots must satisfy
         ``lengths + w <= capacity`` (the scheduler's eligibility check)
         — inactive slots ride along clamped and ignored."""
-        import jax
-
         self._require_bound()
-        tokens = np.asarray(tokens, np.int32)
-        lengths = np.asarray(lengths, np.int32)
-        if (
-            tokens.ndim != 2
-            or tokens.shape[0] != int(self.slots)
-            or lengths.shape != (int(self.slots),)
-        ):
-            raise ValueError(
-                f"verify expects [slots={self.slots}, w] tokens and "
-                f"[slots] lengths, got {tokens.shape} / {lengths.shape}."
-            )
-        w = int(tokens.shape[1])
-        compiled = self._verify_compiled(w, during_dispatch=True)
-        with _trace.span(
+        with self._prepare_span("verify_step"):
+            tokens = np.asarray(tokens, np.int32)
+            lengths = np.asarray(lengths, np.int32)
+            if (
+                tokens.ndim != 2
+                or tokens.shape[0] != int(self.slots)
+                or lengths.shape != (int(self.slots),)
+            ):
+                raise ValueError(
+                    f"verify expects [slots={self.slots}, w] tokens and "
+                    f"[slots] lengths, got {tokens.shape} / {lengths.shape}."
+                )
+            w = int(tokens.shape[1])
+            compiled = self._verify_compiled(w, during_dispatch=True)
+            operands = (tokens, lengths, self._pool.operand())
+        # Under speculation THIS is the hot program, so it feeds the MBU
+        # roofline gauge like decode.
+        nxt = self._dispatch(
             "verify_dispatch",
-            attrs=(
+            (
                 {"slots": int(self.slots), "width": w}
                 if _trace.enabled()
                 else None
             ),
-        ):
-            t0 = time.perf_counter()
-            try:
-                new_cache, nxt = compiled(
-                    self._variables, self._cache, tokens, lengths,
-                    self._pool.operand(),
-                )
-            except BaseException:
-                self._reset_cache()  # donation consumed the buffers
-                raise
-            object.__setattr__(self, "_cache", new_cache)
-            nxt = np.asarray(jax.device_get(nxt))
-            # Readback-bounded: under speculation THIS is the hot
-            # program, so it feeds the MBU roofline gauge like decode.
-            self._observe_decode(
-                time.perf_counter() - t0, program=f"verify_step/w{w}"
-            )
+            f"verify_step/w{w}", compiled, operands, observe=True,
+        )
         return nxt.astype(np.int32)
 
     # -- hot swap --------------------------------------------------------

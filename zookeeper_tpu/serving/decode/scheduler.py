@@ -1574,7 +1574,17 @@ class DecodeScheduler:
             # spans that tile the time between the dispatch spans and
             # enclose none of them (the staged weight swap has a span
             # of its own inside the engine, so it stays outside the
-            # leaves); one ``sched_iteration_end`` event closes them.
+            # leaves); one ``sched_iteration_end`` event closes them,
+            # and while tracing carries the iteration's wall time and
+            # this thread's CPU time: what is left of the first after
+            # the second and the dispatches' readback waits is time the
+            # thread neither ran nor waited for the device (the GIL,
+            # ``_lock``, the machine's other threads).
+            started = (
+                (time.perf_counter_ns(), time.thread_time_ns())
+                if _trace.enabled()
+                else None
+            )
             iteration = self._iteration + 1
             object.__setattr__(self, "_iteration", iteration)
             _trace.set_current_step(iteration)
@@ -1610,14 +1620,16 @@ class DecodeScheduler:
                 with self._cv:
                     self._cv.notify_all()
             if _trace.enabled():
-                _trace.event(
-                    "sched_iteration_end",
-                    attrs={
-                        "admitted": admitted,
-                        "decoded": spent,
-                        "chunks": chunks,
-                    },
-                )
+                attrs = {
+                    "admitted": admitted,
+                    "decoded": spent,
+                    "chunks": chunks,
+                }
+                if started is not None:
+                    # CPU first: its interval lies inside the wall's.
+                    attrs["cpu_ns"] = time.thread_time_ns() - started[1]
+                    attrs["wall_ns"] = time.perf_counter_ns() - started[0]
+                _trace.event("sched_iteration_end", attrs=attrs)
                 _trace.set_current_step(None)
         return self._has_work()
 
@@ -1789,7 +1801,11 @@ class DecodeScheduler:
     def _worker_loop(self) -> None:
         while not self._stop.is_set():
             if not self._has_work() and not self.swap_pending:
-                with self._cv:
+                # No stream and no swap: the wait for work is a leaf of
+                # its own, outside any iteration (its ``step`` is None),
+                # so a trace can tell a device idle for want of traffic
+                # from one the host keeps waiting.
+                with _trace.span("worker_idle_wait"), self._cv:
                     self._cv.wait(0.05)
                 continue
             try:
