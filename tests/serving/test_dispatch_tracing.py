@@ -4,7 +4,14 @@
 leaf ends where the dispatch span starts; ``worker_idle_wait`` spans the
 worker's wait for work, outside any iteration; ``sched_iteration_end``
 carries the iteration's wall and thread CPU time; and with the tracer
-off a dispatch records nothing and reads no clock but the two it had."""
+off a dispatch records nothing and reads no clock but the two it had.
+
+Since PR 36 a decode step is launched unread: its ``decode_dispatch``
+span holds its own boundary event and then waits for the step BEFORE it,
+if nobody has read it (one span = launch one step, wait for the one
+before); a step nobody has waited for is read in a
+``decode_readback`` leaf; and ``sched_iteration_end`` also says what the
+pipeline did (``in_flight``, ``dropped``)."""
 
 import time
 
@@ -51,6 +58,9 @@ def tracer():
 
 
 def emptied(eng):
+    """No slot holds pages and no decode step is left unread."""
+    if eng._last_step is not None:
+        eng._last_step.result()
     for slot in range(SLOTS):
         eng.release_slot(slot)
     return eng
@@ -77,8 +87,8 @@ def staged(eng, kind):
     lengths = np.zeros((SLOTS,), np.int32)
     lengths[0] = prompt.shape[0]
     assert eng.ensure_rows(0, prompt.shape[0] + 2)
-    if kind == "decode":
-        return lambda: eng.decode(tokens, lengths)
+    if kind == "decode":  # launched, and read at once
+        return lambda: np.asarray(eng.decode(tokens, lengths))
     return lambda: eng.verify(np.zeros((SLOTS, 2), np.int32), lengths)
 
 
@@ -165,9 +175,49 @@ def test_with_the_tracer_off_a_dispatch_records_and_times_nothing_new(
     assert trace.get_tracer() is None
 
 
+def test_a_decode_span_launches_one_step_and_waits_for_the_one_before(engine, tracer):
+    """A step comes back unread; the next step's span reads it after its
+    own boundary event and leaves its own step unread; read on its own
+    (converted to the host array it becomes), a step waits in the
+    ``decode_readback`` leaf, once."""
+    staged(engine, "decode")
+    tokens = np.zeros((SLOTS,), np.int32)
+    lengths = np.zeros((SLOTS,), np.int32)
+    lengths[0] = 9
+    tracer.clear()
+    first = engine.decode(tokens, lengths)
+    assert first.tokens is None and first.seconds is None and not first.behind
+    lengths[0] += 1
+    # the second step's input is the first one's output, kept on the device
+    second = engine.decode(np.full((SLOTS,), -1, np.int32), lengths)
+    assert first.tokens is not None and first.seconds > 0
+    assert second.tokens is None and second.behind
+    assert first.result() is first.tokens  # no wait left, no leaf
+    records = tracer.snapshot()
+    assert not [r for r in records if r["name"] == "decode_readback"]
+    spans = check_dispatches(records, expect="decode_step")
+    assert [r["name"] for r in spans] == ["decode_dispatch"] * 2
+    got = np.asarray(second)
+    assert got.shape == (SLOTS,) and got.dtype == np.int32 and len(second) == SLOTS
+    assert second.seconds > 0  # from the first step's read to its own
+    second.result()
+    records = tracer.snapshot()
+    (leaf,) = [r for r in records if r["name"] == "decode_readback"]
+    assert leaf["phase"] == "X" and leaf["ts_ns"] >= spans[-1]["ts_ns"] + spans[-1]["dur_ns"]
+    assert overlapping_spans(records) == []
+    # the same two steps with the host supplying the token: same answer
+    lengths[0] = 9
+    again = engine.decode(tokens, lengths)
+    np.testing.assert_array_equal(again, first.tokens)
+    lengths[0] += 1
+    assert engine.decode(again, lengths)[0] == got[0]
+
+
 def test_a_model_with_experts_reads_its_load_back_inside_and_reports_it_after(tracer):
-    """The load rides the dispatch's one readback, and its events follow
-    the span: none lies inside a dispatch span any more."""
+    """The load rides its dispatch's one readback, and its events follow
+    the span that READ it: a prefill's its own span, a decode step's the
+    next step's span (which waits for it) or, for the last step, the
+    ``decode_readback`` leaf. None lies inside a span."""
     from tests.serving import test_solar_open2_serving as solar
 
     module, params = solar.tiny.build()
@@ -176,19 +226,27 @@ def test_a_model_with_experts_reads_its_load_back_inside_and_reports_it_after(tr
     sched.submit(np.arange(9, dtype=np.int32), max_new_tokens=4).result(timeout=600)
     records = tracer.snapshot()
     dispatches = check_dispatches(records)
-    assert {r["name"] for r in dispatches} == {"prefill_dispatch", "decode_dispatch"}
+    assert [r["name"] for r in dispatches] == ["prefill_dispatch"] + ["decode_dispatch"] * 3
+    (leaf,) = [r for r in records if r["name"] == "decode_readback"]
     loads = [r for r in records if r["name"] == "moe_tokens_per_expert"]
     held = [r for r in records if r["name"] == "moe_held_choices"]
     assert len(loads) == len(held) == len(dispatches) == 4
-    for span, load in zip(dispatches, loads):
-        end = span["ts_ns"] + span["dur_ns"]
-        assert load["ts_ns"] >= end
-        assert load["step"] == span["step"]
+    # who read each dispatch: the prefill itself; a decode step's
+    # successor; the leaf for the step nothing was launched behind
+    readers = [dispatches[0], dispatches[2], dispatches[3], leaf]
+    for span, reader, load in zip(dispatches, readers, loads):
+        assert load["ts_ns"] >= reader["ts_ns"] + reader["dur_ns"]
+        assert load["step"] == reader["step"]
         (event,) = [
             r for r in records
             if r["name"] == "dispatch_enqueued" and inside(r, span)
         ]
         assert load["attrs"]["program"] == event["attrs"]["program"]
+    for load in loads + held:
+        assert not any(
+            inside(load, r) for r in records
+            if r["phase"] == "X" and r["name"] != "sched_deliver"
+        )
     assert overlapping_spans(records) == []
 
 
@@ -254,7 +312,11 @@ def test_an_iteration_ends_with_its_wall_and_cpu_time(engine, tracer, synchronou
     for recs in groups.values():
         (end,) = [r for r in recs if r["name"] == "sched_iteration_end"]
         attrs = end["attrs"]
-        assert set(attrs) == {"admitted", "decoded", "chunks", "wall_ns", "cpu_ns"}
+        assert set(attrs) == {
+            "admitted", "decoded", "chunks", "wall_ns", "cpu_ns",
+            "in_flight", "dropped",
+        }
+        assert attrs["in_flight"] in (0, 1) and attrs["dropped"] == 0
         assert 0 <= attrs["cpu_ns"] <= attrs["wall_ns"]
         # the wall time is taken around the leaves: from before the
         # first to after the last
@@ -269,3 +331,8 @@ def test_an_iteration_ends_with_its_wall_and_cpu_time(engine, tracer, synchronou
         )
         assert waits <= attrs["wall_ns"]
     check_dispatches(records)
+    # one stream of five tokens: four steps, all but the first launched
+    # with the step before unread, the last read in a leaf of its own
+    ends = [r["attrs"] for r in records if r["name"] == "sched_iteration_end"]
+    assert sum(a["in_flight"] for a in ends) == 3
+    assert len([r for r in records if r["name"] == "decode_readback"]) == 1

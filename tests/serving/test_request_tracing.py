@@ -559,7 +559,10 @@ def test_leaves_of_one_iteration_share_its_step_and_end_in_one_event(leaf_run):
         assert len(ends) == 1
         assert set(ends[0]["attrs"]) == {
             "admitted", "decoded", "chunks", "wall_ns", "cpu_ns",
+            "in_flight", "dropped",
         }
+        assert ends[0]["attrs"]["in_flight"] in (0, 1)
+        assert ends[0]["attrs"]["dropped"] == 0  # every stream ends by budget
         spans = [r for r in recs if r["phase"] == "X"]
         assert spans[0]["name"] == "sched_sweep"
         assert spans[-1]["name"] == "sched_bookkeeping"

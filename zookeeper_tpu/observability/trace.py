@@ -42,13 +42,17 @@ A span's inner boundary is an ``i`` record, never a nested ``X``: where
 a reader wants the two halves of a leaf apart, the code records one
 event inside it (the decode engine's ``dispatch_enqueued`` inside every
 ``*_dispatch`` span: before it the host launches the step, after it the
-thread waits for the device and reads back). The leaf rule stands,
+thread waits for the device and reads back — its own output, or, in a
+``decode_dispatch`` span, the decode step BEFORE: that path keeps one
+step unread, and a step nothing is launched behind is read in the
+``decode_readback`` leaf). The leaf rule stands,
 whatever reads the span reads what it read, and an event enters no
 ``TraceAnnotation``. Beside it (docs/DESIGN.md §13): ``dispatch_prepare``,
 the leaf from the top of a dispatch's host work to the dispatch span's
 start, which also holds the bookkeeping only a trace pays for;
 ``worker_idle_wait``, the decode scheduler's wait for work, outside any
-iteration; and ``wall_ns`` / ``cpu_ns`` on ``sched_iteration_end``.
+iteration; and ``wall_ns`` / ``cpu_ns`` / ``in_flight`` / ``dropped`` on
+``sched_iteration_end``.
 
 One clock with the device trace: while the tracer is enabled every
 span also enters a ``jax.profiler.TraceAnnotation`` of the same name

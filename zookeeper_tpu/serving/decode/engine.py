@@ -66,7 +66,7 @@ from zookeeper_tpu.serving.decode.pages import (
 logger = logging.getLogger(__name__)
 
 
-__all__ = ["DecodeEngine"]
+__all__ = ["DecodeEngine", "DecodeStep"]
 
 #: What the decode step is compiled with for a TPU. XLA may fetch a
 #: weight into fast memory ahead of its matmul in several slices, each
@@ -86,6 +86,99 @@ def _compiles_for_tpu() -> bool:
     import jax
 
     return jax.devices()[0].platform == "tpu"
+
+
+class DecodeStep:
+    """One decode step's result, unread (docs/DESIGN.md §13): every
+    slot's next token as the device array the compiled call returned,
+    its copy to the host started at once — the transfer begins when the
+    step ends, not when a thread next wakes — and the engine's input
+    for the step after (``decode_fn`` keeps it where the host supplies
+    no token). It reads like the host ``[slots] int32`` array it
+    becomes (``np.asarray(step)``, ``step[slot]``), waiting only if
+    nobody has yet: the engine waits for a step inside the NEXT step's
+    dispatch span, and whoever else must see a quiet engine converts
+    it.
+
+    ``seconds`` is the step's readback-bounded wall time once read:
+    from its launch, or — launched behind a step still unread, so that
+    it starts on the device when that one ends — from that step's read
+    to its own. None where another program's readback came between
+    (the wall then times more than this step)."""
+
+    __slots__ = (
+        "_engine", "_out", "_rows", "_t0", "_readbacks", "_load",
+        "device_tokens", "behind", "tokens", "seconds",
+    )
+
+    def __init__(self, engine, out, rows: int, t0: float, behind: bool):
+        self._engine = engine
+        self._out = out
+        self._rows = rows
+        self._t0 = t0
+        #: Launched with the step before it still unread: the pipeline
+        #: was full, and this step starts when that one ends.
+        self.behind = behind
+        self._readbacks = engine._readbacks
+        self._load = None
+        #: The step's tokens on the host, ``[slots] int32``; None until read.
+        self.tokens: Optional[np.ndarray] = None
+        self.seconds: Optional[float] = None
+        leaves = out if isinstance(out, tuple) else (out,)
+        for leaf in leaves:
+            leaf.copy_to_host_async()
+        #: The tokens on the device: what the step after keeps where
+        #: the host supplies no token.
+        self.device_tokens = leaves[0]
+
+    def _read(self) -> None:
+        """Wait for the step and keep its tokens (and, while tracing, a
+        model with experts' load) on the host; feed the MBU gauge."""
+        if self.tokens is not None:
+            return
+        import jax
+
+        engine = self._engine
+        out, self._out = jax.device_get(self._out), None
+        now = time.perf_counter()
+        if isinstance(out, tuple):
+            out, self._load = out
+        self.tokens = np.asarray(out).astype(np.int32)
+        since = engine._step_read_at if self.behind else self._t0
+        if since is not None and engine._readbacks == self._readbacks:
+            self.seconds = now - since
+            engine._observe_decode(self.seconds, "decode_step")
+        object.__setattr__(engine, "_step_read_at", now)
+
+    def _note_load(self) -> None:
+        """The load's events, once, after the span that read it closed."""
+        load, self._load = self._load, None
+        if load is not None:
+            self._engine._note_moe_load(load, "decode_step", self._rows)
+
+    def result(self) -> np.ndarray:
+        """The step's tokens as a host ``[slots] int32`` array. A step
+        nobody has waited for yet is waited for here, in a leaf of its
+        own (``decode_readback``)."""
+        if self.tokens is None:
+            with _trace.span("decode_readback"):
+                self._read()
+        self._note_load()
+        return self.tokens
+
+    # Reads like the host array it becomes.
+
+    def __array__(self, dtype=None, copy=None):
+        out = self.result()
+        if dtype is not None:
+            out = out.astype(dtype, copy=False)
+        return out.copy() if copy else out
+
+    def __getitem__(self, index):
+        return self.result()[index]
+
+    def __len__(self) -> int:
+        return len(self.result())
 
 
 @component
@@ -540,6 +633,12 @@ DecodeScheduler`.
         # `prefill_chunk`): `pool_status()["kv_page_write_share"]`.
         object.__setattr__(self, "_kv_rows_written", {"pages": 0, "rows": 0})
         object.__setattr__(self, "_ledger_records", {})
+        # The unread decode step's bookkeeping (DecodeStep): the last
+        # step launched, other programs' readbacks so far, and when the
+        # last step was read.
+        object.__setattr__(self, "_last_step", None)
+        object.__setattr__(self, "_readbacks", 0)
+        object.__setattr__(self, "_step_read_at", None)
         flavor, attn_fn = self._resolve_decode_attention()
         object.__setattr__(self, "_decode_attention_flavor", flavor)
         object.__setattr__(self, "_decode_attention_fn", attn_fn)
@@ -810,6 +909,7 @@ DecodeScheduler`.
         object.__setattr__(
             self, "_cache", self._place_cache(self._allocate_cache())
         )
+        object.__setattr__(self, "_last_step", None)
         self._pool.reset()
 
     def release(self) -> None:
@@ -822,6 +922,7 @@ DecodeScheduler`.
         repeatedly."""
         object.__setattr__(self, "_variables", None)
         object.__setattr__(self, "_cache", None)
+        object.__setattr__(self, "_last_step", None)
 
     # -- geometry --------------------------------------------------------
 
@@ -1220,7 +1321,11 @@ PagePool`."""
         attn_override = self._decode_attention_fn
         n = int(self.slots)
 
-        def decode_fn(variables, cache, tokens, lengths, table):
+        def decode_fn(variables, cache, tokens, lengths, table, prev):
+            # A negative entry keeps the device's: the slot's input is
+            # the token the step before put out, which never visits the
+            # host (docs/DESIGN.md §13).
+            tokens = jnp.where(tokens < 0, prev, tokens)
             (logits, new_cache), load = self._apply(
                 variables, tokens, lengths, cache, table,
                 method="decode_step_paged",
@@ -1235,6 +1340,7 @@ PagePool`."""
             jax.ShapeDtypeStruct((n,), np.int32),
             jax.ShapeDtypeStruct((n,), np.int32),
             self._table_like(n),
+            jax.ShapeDtypeStruct((n,), np.int32),
         )
         compiled = self._aot(
             "decode_step", decode_fn, example, donate_cache_at=1,
@@ -1757,20 +1863,36 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         *,
         rows: int = 0,
         observe: bool = False,
-    ) -> np.ndarray:
+        unread: bool = False,
+    ):
         """The one body of every cache-donating dispatch: inside the
         span ``span``, the compiled call, the cache swap, one
         ``dispatch_enqueued`` event (the span's inner boundary: before
         it the host launches the step, after it the host waits for the
-        device and reads back), and ONE ``jax.device_get`` of the whole
-        output. A model with experts returns ``(tokens, load)``; the
-        load is read back with the tokens while tracing (else dropped
-        on the device) and becomes its events after the span has closed
-        (``rows``: the dispatch's rows, for :meth:`_note_moe_load`).
-        ``observe`` feeds the readback-bounded wall time — the only
-        honest dispatch clock, the compiled call returns un-synced
-        arrays — to the MBU gauge under ``program``. Returns the tokens
-        as a host array."""
+        device and reads back), and the wait.
+
+        What the wait is for depends on ``unread``. A prefill, an
+        extend, a chunk and a verify read their OWN output back, ONE
+        ``jax.device_get`` of the whole of it, and return the tokens as
+        a host array: the scheduler needs the first token, or the
+        verified window, before it can plan. The decode step
+        (``unread=True``) leaves its output on the device and returns a
+        :class:`DecodeStep`; what this span waits for after its event
+        is the step BEFORE, if nobody has read it yet: one span =
+        launch one step, wait for the one before (the device runs them
+        in the order they were enqueued, so the step just launched
+        computes while the host reads and delivers the last). So the
+        engine never holds more than ONE step unread.
+
+        A model with experts returns ``(tokens, load)``; the load is
+        read back with the tokens while tracing (else dropped on the
+        device) and becomes its events after the span that read it has
+        closed (``rows``: the dispatch's rows, for
+        :meth:`_note_moe_load`). ``observe`` feeds the readback-bounded
+        wall time — the only honest dispatch clock, the compiled call
+        returns un-synced arrays — to the MBU gauge under ``program``;
+        an unread step's clock is its handle's
+        (:attr:`DecodeStep.seconds`)."""
         import jax
 
         with _trace.span(span, attrs=attrs):
@@ -1790,9 +1912,26 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 _trace.event("dispatch_enqueued", attrs={"program": program})
             elif isinstance(out, tuple):
                 out = out[0]
-            out = jax.device_get(out)
-            if observe:
-                self._observe_decode(time.perf_counter() - t0, program)
+            if unread:
+                before = self._last_step
+                step = DecodeStep(
+                    self, out, rows, t0,
+                    behind=before is not None and before.tokens is None,
+                )
+                object.__setattr__(self, "_last_step", step)
+                if before is not None:
+                    before._read()
+            else:
+                out = jax.device_get(out)
+                # Another program's readback: no unread decode step's
+                # wall time is its own any more.
+                object.__setattr__(self, "_readbacks", self._readbacks + 1)
+                if observe:
+                    self._observe_decode(time.perf_counter() - t0, program)
+        if unread:
+            if before is not None:
+                before._note_load()
+            return step
         if isinstance(out, tuple):
             out, load = out
             self._note_moe_load(load, program, rows)
@@ -1987,17 +2126,40 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             raise
         object.__setattr__(self, "_cache", new_cache)
 
-    def decode(self, tokens: np.ndarray, lengths: np.ndarray) -> np.ndarray:
-        """One token for EVERY slot: feed the current input token per
-        slot (each sits at position ``lengths[slot]``), write its K/V,
-        and return the argmax next token per slot as a host ``[slots]
-        int32`` array. Inactive slots ride along (fixed shape) — the
-        scheduler ignores their output and never advances their
-        lengths."""
+    def decode(self, tokens: np.ndarray, lengths: np.ndarray) -> DecodeStep:
+        """Launch one token for EVERY slot and return the step UNREAD
+        (docs/DESIGN.md §13): feed the current input token per slot
+        (each sits at position ``lengths[slot]``), write its K/V, and
+        leave the argmax next token per slot on the device, its copy to
+        the host under way. The returned :class:`DecodeStep` reads like
+        the host ``[slots] int32`` array it becomes: a caller that must
+        see each step's tokens before it can choose the next (the
+        speculative draft's proposals, a test that drives the engine by
+        hand) converts or indexes it and so reads at once.
+
+        A NEGATIVE entry of ``tokens`` keeps the device's: that slot's
+        input is what the step launched before this one put out for it
+        (merged inside ``decode_fn``, so the token that feeds the next
+        step never visits the host); the host supplies a token only
+        where it knows better — a slot a prefill has just filled, the
+        first step of an engine. Inactive slots ride along (fixed
+        shape) — the scheduler ignores their output and never advances
+        their lengths.
+
+        If the step launched before this one is still unread, this
+        step's ``decode_dispatch`` span waits for it after the boundary
+        event, while the device runs the step just enqueued: launching
+        with the last step unread is the whole pipeline, and at most
+        ONE step is ever unread (``DecodeScheduler._decode`` reads and
+        delivers step N right after it has launched N+1)."""
         self._require_bound()
         with self._prepare_span("decode_step"):
-            tokens = np.asarray(tokens, np.int32)
-            lengths = np.asarray(lengths, np.int32)
+            # Copies, all three operands: the step is still to run when
+            # this returns, a host backend reads an operand where it
+            # lies, and the caller's arrays and the pool's table (the
+            # allocator's live state) change under it.
+            tokens = np.array(tokens, np.int32)
+            lengths = np.array(lengths, np.int32)
             if tokens.shape != (int(self.slots),) or lengths.shape != (
                 int(self.slots),
             ):
@@ -2005,6 +2167,9 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                     f"decode expects [slots]={self.slots} token and length "
                     f"arrays, got {tokens.shape} / {lengths.shape}."
                 )
+            table = self._pool.operand()
+            if table is self._pool.table:  # one layer group: no stack
+                table = table.copy()
             compiled = self._decode_compiled(during_dispatch=True)
             self._note_kv_blocks(lengths)
             if _trace.enabled():
@@ -2020,14 +2185,19 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                             ),
                         },
                     )
-            operands = (tokens, lengths, self._pool.operand())
-        nxt = self._dispatch(
+            # No step yet: nothing of the device's to keep.
+            prev = (
+                self._last_step.device_tokens
+                if self._last_step is not None
+                else np.zeros_like(tokens)
+            )
+            operands = (tokens, lengths, table, prev)
+        return self._dispatch(
             "decode_dispatch",
             {"slots": int(self.slots)} if _trace.enabled() else None,
             "decode_step", compiled, operands,
-            rows=int(self.slots), observe=True,
+            rows=int(self.slots), observe=True, unread=True,
         )
-        return nxt.astype(np.int32)
 
     def verify(self, tokens: np.ndarray, lengths: np.ndarray) -> np.ndarray:
         """``w`` tokens for EVERY slot in one dispatch (docs/DESIGN.md

@@ -67,6 +67,11 @@ _COUNTER_NAMES = (
     "prefills_total",
     # Decode dispatches (each serves every active slot one token).
     "decode_steps_total",
+    # The decode pipeline (docs/DESIGN.md §13): steps launched with the
+    # step before them unread, and tokens decoded for a stream that had
+    # ended by then (an EOS is seen one step late).
+    "steps_in_flight_total",
+    "tokens_dropped_total",
     # PR 4 admission-control family.
     "rejected_total",
     "deadline_expired_total",
@@ -293,12 +298,28 @@ class DecodeMetrics:
         obs["counters"]["prefills_total"].inc()
         obs["counters"]["requests_total"].inc(int(requests))
 
-    def record_decode_step(self, step_ms: float, tokens: int) -> None:
-        """One decode dispatch delivered ``tokens`` stream tokens."""
+    def record_decode_step(
+        self,
+        step_ms: Optional[float],
+        tokens: int,
+        *,
+        in_flight: bool = False,
+        dropped: int = 0,
+    ) -> None:
+        """One decode dispatch delivered ``tokens`` stream tokens.
+        ``step_ms`` is None where no wall time is the step's alone
+        (another program's readback came between its launch and its
+        read): the step counts, the series takes no sample.
+        ``in_flight``: it was launched with the step before it unread;
+        ``dropped``: tokens it decoded for a stream that had ended
+        (docs/DESIGN.md §13)."""
         obs = self._obs()
-        self._observe("token_ms", step_ms)
+        if step_ms is not None:
+            self._observe("token_ms", step_ms)
         obs["counters"]["decode_steps_total"].inc()
         obs["counters"]["tokens_total"].inc(int(tokens))
+        obs["counters"]["steps_in_flight_total"].inc(int(in_flight))
+        obs["counters"]["tokens_dropped_total"].inc(int(dropped))
 
     def record_first_tokens(self, n: int) -> None:
         """Prefill-emitted tokens count toward the stream total too."""

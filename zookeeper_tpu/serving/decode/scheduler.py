@@ -14,10 +14,16 @@ not the request. The ``DecodeScheduler`` owns that loop:
    slot array, always.
 2. **Decode**: one ``decode_step`` dispatch advances EVERY active slot
    one token; tokens stream into each request's
-   :class:`DecodeStream` as they are read back.
+   :class:`DecodeStream` as they are read back. The plain path keeps
+   ONE step unread (docs/DESIGN.md §13): an iteration launches step
+   N+1, then reads and delivers step N, and the tokens that feed N+1
+   stay on the device — the host's work between two steps runs while
+   the device computes.
 3. **Finish**: EOS, per-request ``max_new_tokens``, the engine's
    KV/positional capacity, or a deadline ends a stream and frees its
-   slot for the next admit round.
+   slot for the next admit round. An end the host can count is known
+   before the step is planned; an EOS is seen one step late (one token
+   decoded and dropped).
 
 Admission control is the PR 4 machinery re-expressed for streams:
 ``shed_above`` sheds with :class:`RejectedError` before enqueueing,
@@ -67,7 +73,7 @@ import logging
 import threading
 import time
 from collections import deque
-from typing import Any, List, Optional
+from typing import Any, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -270,6 +276,29 @@ class DecodeStream:
             self._scheduler._advance(self)
 
 
+class _UnreadStep(NamedTuple):
+    """The decode step the scheduler has launched and not yet read:
+    the engine's handle and the host's view the step was launched
+    from, which its delivery is checked against."""
+
+    #: What ``engine.decode`` returned: the step's tokens, read when
+    #: converted (a :class:`~zookeeper_tpu.serving.decode.engine.\
+    #: DecodeStep`).
+    step: Any
+    #: Launched with the step before it unread (the pipeline was full).
+    behind: bool
+    #: ``_slot_stream`` as it was: a slot whose stream has changed
+    #: since decoded a token nobody owns.
+    snapshot: list
+    #: The slots that decoded.
+    active: List[int]
+    #: Cached rows per slot BEFORE the step (its ``lengths`` operand).
+    lengths: np.ndarray
+    #: The draft's rows and catch-up counts (speculation bound only).
+    dlengths: Optional[np.ndarray]
+    counts: Optional[np.ndarray]
+
+
 @component
 class DecodeScheduler:
     """Continuous-batching scheduler over a
@@ -399,6 +428,12 @@ class DecodeScheduler:
         # iteration shares (the scheduler's leaves and the engine's
         # dispatch spans, through the tracer's thread-local step).
         object.__setattr__(self, "_iteration", 0)
+        # The one decode step launched and not yet read (None: the
+        # pipeline is empty), and the running counts of what the
+        # pipeline did: steps launched with the step before unread,
+        # tokens decoded for a slot whose stream had gone.
+        object.__setattr__(self, "_unread", None)
+        object.__setattr__(self, "_pipeline", {"in_flight": 0, "dropped": 0})
         return self
 
     def _require_bound(self) -> None:
@@ -719,6 +754,9 @@ class DecodeScheduler:
             return
         if any(s is not None for s in self._slot_stream):
             return  # in-flight sequences keep their weight version
+        # A step an EOS left behind may still be unread: nothing of it
+        # is owned, but the engine swaps at rest.
+        self._resolve_unread()
         params, model_state, step = pending
         self._engine.swap_weights(params, model_state)
         # Paged layout: cached prefix pages hold K/V computed under the
@@ -783,9 +821,13 @@ class DecodeScheduler:
     # -- the scheduling loop ---------------------------------------------
 
     def _has_work(self) -> bool:
+        """Streams queued or in slots, or a decode step launched and
+        not yet read (a caller that stops driving leaves it so)."""
         with self._lock:
-            return bool(self._queue) or any(
-                s is not None for s in self._slot_stream
+            return (
+                self._unread is not None
+                or bool(self._queue)
+                or any(s is not None for s in self._slot_stream)
             )
 
     def _free_slot(self, slot: int) -> None:
@@ -822,9 +864,16 @@ class DecodeScheduler:
                 [streams[i].prompt for i in live], [slots[i] for i in live]
             )
 
-    def _finish_or_continue(self, slot: int, token: int) -> None:
+    def _finish_or_continue(
+        self, slot: int, token: int, rows: Optional[int] = None
+    ) -> None:
         """Deliver ``token`` to the slot's stream and retire the slot
-        when the stream is complete. Caller holds the lock."""
+        when the stream is complete. ``rows``: the slot's cached rows
+        once the dispatch that emitted ``token`` has run; the slot's
+        current length unless a later step has advanced it already.
+        Caller holds the lock."""
+        if rows is None:
+            rows = int(self._slot_lengths[slot])
         stream = self._slot_stream[slot]
         now = time.perf_counter()
         last = float(self._slot_last_emit[slot])
@@ -841,7 +890,7 @@ class DecodeScheduler:
             reason = "eos"
         elif len(stream._tokens) >= stream._max_new:
             reason = "length"
-        elif self._slot_lengths[slot] + 1 >= self._engine.token_limit:
+        elif rows + 1 >= self._engine.token_limit:
             # The sequence now totals token_limit tokens (cached
             # lengths + the token just delivered): feeding the delivered
             # token back would write past the KV capacity or the
@@ -1102,28 +1151,69 @@ class DecodeScheduler:
                     self._metrics.record_first_tokens(delivered)
 
     def _decode(self) -> int:
-        """One decode dispatch over the whole slot array; deliver each
-        active slot's token. Returns the iteration's decode token
-        SPEND for the chunked-prefill budget (one per decoded slot;
-        a speculative window counts k + 1 — docs/DESIGN.md §25).
-        Mid-prefill slots (in ``_chunk_state``) are excluded from the
-        active set: their streams own pages but must not emit tokens,
-        and the batched dispatch's garbage write at their cursor row
-        is overwritten by the chunk that commits that position later
-        the same iteration. Caller holds ``_step_lock``; the dispatch
-        runs outside ``_lock`` over a snapshot of the slot arrays — a
-        slot whose stream was failed mid-dispatch (``close()``, crash)
-        skips delivery (its cache row write is masked garbage at
-        ``j >= length`` for the next occupant, per the refill
-        invariant).
+        """Launch one decode step over the whole slot array, then read
+        and deliver the step BEFORE it: the plain path keeps exactly
+        one step unread (docs/DESIGN.md §13), so delivery, the sweeps,
+        admission planning and the next plan run while the device
+        computes, and the step after is queued before this one ends.
 
-        With speculation bound, the two-model window schedule
-        (:meth:`_decode_spec`) runs instead — unless any active slot is
-        within one window of its token limit, in which case THIS
-        iteration falls back to the plain path (a clamped multi-token
-        append would land on live rows; the plain path's
-        truncate-at-exactly-token_limit contract takes over, and the
-        slot finishes within a few iterations)."""
+        The order of one call, with step N unread on entry:
+
+        1. Plan N+1 from the host's state with N's effects applied
+           ahead of its readback: each slot that decoded in N is one
+           row longer already (``_slot_lengths`` advances at LAUNCH),
+           and its input token is N's output, which stays on the device
+           (``_slot_tokens`` -1: ``decode_fn`` keeps the device's). The
+           host supplies a token only for a slot a prefill has just
+           filled or when nothing was launched since the last read.
+        2. Enqueue N+1 (``engine.decode``, which returns it unread);
+           its ``decode_dispatch`` span waits for N after the boundary
+           event.
+        3. Deliver N (``sched_deliver``, ``_finish_or_continue``).
+
+        A stream's end the host can count (its budget, ``token_limit``)
+        is known when N+1 is planned: the slot sits N+1 out, no step is
+        wasted (:meth:`_ending_unread`). An end only the token tells —
+        EOS; also a deadline, ``close()``, a crash — is seen ONE STEP
+        LATE: the slot decoded once more in N+1, that token is dropped
+        when N+1 is read (counted: ``status()["decode_pipeline"]``,
+        ``dropped`` on ``sched_iteration_end``), and its K/V row or
+        state update lands in pages or a state block the slot owned at
+        launch. A freed slot's pages and state are safe in the next
+        occupant's hands because the device runs dispatches in the
+        order they were enqueued: the occupant's prefill comes after
+        the late step, and overwrites or masks (``j >= length``) what
+        it wrote.
+
+        With nothing to launch (every active stream completes in N, or
+        none is left) N is read in a ``decode_readback`` leaf of its
+        own and the pipeline is empty again. Who else reads the unread
+        step, because they must see the engine quiet:
+        :meth:`_maybe_apply_swap` and :meth:`close`
+        (:meth:`_resolve_unread`); ``drain()`` and a synchronous
+        caller's ``result()`` by driving until ``_has_work()`` is
+        false, which an unread step keeps true; :meth:`_on_crash`
+        discards it with the streams it fails.
+
+        Returns the iteration's decode token SPEND for the
+        chunked-prefill budget (one per slot launched; a speculative
+        window counts k + 1 — docs/DESIGN.md §25). Mid-prefill slots
+        (in ``_chunk_state``) are excluded from the active set: their
+        streams own pages but must not emit tokens, and the batched
+        dispatch's garbage write at their cursor row is overwritten by
+        the chunk that commits that position later. Caller holds
+        ``_step_lock``; the dispatch runs outside ``_lock`` over a
+        snapshot of the slot arrays.
+
+        With speculation bound the host must see every token to choose
+        the next window, so nothing stays unread: the two-model window
+        schedule (:meth:`_decode_spec`) runs instead — unless any
+        active slot is within one window of its token limit, in which
+        case THIS iteration falls back to the plain step, read at once
+        (a clamped multi-token append would land on live rows; the
+        plain path's truncate-at-exactly-token_limit contract takes
+        over, and the slot finishes within a few iterations). Which of
+        the two it is follows from what is bound, not from an option."""
         spec = getattr(self, "_speculative", None)
         if spec is not None:
             with _trace.span("sched_decode_plan"), self._lock:
@@ -1150,55 +1240,136 @@ class DecodeScheduler:
             if eligible:
                 return self._decode_spec(spec)
         engine = self._engine
+        unread = self._unread
         with _trace.span("sched_decode_plan"), self._lock:
-            self._ensure_active_rows(1)
+            # The unread step's effects, ahead of its readback: its
+            # slots are one row longer already (advanced when it was
+            # launched), and a stream it completes by the host's own
+            # count sits this step out.
+            ending = self._ending_unread(unread)
+            self._ensure_active_rows(1, ending)
             snapshot = list(self._slot_stream)
             active = [
                 i for i, s in enumerate(snapshot)
-                if s is not None and i not in self._chunk_state
+                if s is not None
+                and i not in self._chunk_state
+                and i not in ending
             ]
-            if not active:
-                return 0
-            tokens = self._slot_tokens.astype(np.int32)
-            lengths = self._slot_lengths.astype(np.int32)
             counts = None
+            if active:
+                tokens = self._slot_tokens.astype(np.int32)
+                lengths = self._slot_lengths.astype(np.int32)
+                if spec is not None:
+                    dlengths = self._slot_draft_state()
+                    ctokens, counts = self._draft_catchup_window(
+                        active, tokens
+                    )
+                for slot in active:
+                    # From here on the slot's input token is this
+                    # step's output, on the device (-1: keep it).
+                    self._slot_lengths[slot] += 1
+                    self._slot_tokens[slot] = -1
+        if not active and unread is None:
+            return 0
+        launched = None
+        if active:
+            # Inside its span the engine waits for ``unread``, the step
+            # before, which computes no longer than this launch took.
+            launched = _UnreadStep(
+                engine.decode(tokens, lengths), unread is not None,
+                snapshot, active, lengths,
+                dlengths if spec is not None else None, counts,
+            )
+            self._pipeline["in_flight"] += launched.behind
             if spec is not None:
-                dlengths = self._slot_draft_state()
-                ctokens, counts = self._draft_catchup_window(active, tokens)
-        t0 = time.perf_counter()
-        nxt = engine.decode(tokens, lengths)
-        if spec is not None:
-            # Keep the DRAFT cache in sync through plain iterations
-            # (the near-capacity fallback): the draft consumes the same
-            # token(s) via its width-2 catch-up append, so the
-            # gap-is-at-most-one invariant the speculative window
-            # relies on holds across any mix of plain and speculative
-            # iterations. At draft length == capacity-1 the width-2
-            # window's second row lies past the table and is dropped;
-            # that slot's stream is at token_limit - 1 and finishes
-            # THIS iteration.
-            spec.draft_engine.verify(ctokens, dlengths)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        with _trace.span("sched_deliver"), self._lock:
-            delivered = 0
-            for slot in active:
-                if self._slot_stream[slot] is not snapshot[slot]:
-                    continue  # failed by close()/crash mid-dispatch
-                self._slot_lengths[slot] += 1
-                if counts is not None:
-                    self._draft_lengths[slot] = int(
-                        dlengths[slot]
-                    ) + int(counts[slot])
-                    self._draft_pending[slot] = []
-                token = int(nxt[slot])
-                self._slot_tokens[slot] = token
-                self._finish_or_continue(slot, token)
-                delivered += 1
-            if self._metrics is not None:
-                self._metrics.record_decode_step(dt_ms, delivered)
+                # Keep the DRAFT cache in sync through plain iterations
+                # (the near-capacity fallback): the draft consumes the
+                # same token(s) via its width-2 catch-up append, so the
+                # gap-is-at-most-one invariant the speculative window
+                # relies on holds across any mix of plain and
+                # speculative iterations. At draft length ==
+                # capacity-1 the width-2 window's second row lies past
+                # the table and is dropped; that slot's stream is at
+                # token_limit - 1 and finishes THIS iteration.
+                spec.draft_engine.verify(ctokens, dlengths)
+                # The host must see these tokens to choose the next
+                # window: with speculation bound nothing stays unread.
+                unread, launched = launched, None
+        object.__setattr__(self, "_unread", launched)
+        if unread is not None:
+            self._deliver_step(unread)
         return len(active)
 
-    def _ensure_active_rows(self, extra: int) -> None:
+    def _ending_unread(self, unread) -> set:
+        """The slots whose stream the UNREAD step's token completes by
+        the host's own count — its budget (``max_new``) or
+        ``token_limit`` — known before the token is: such a slot does
+        not decode in the step planned now, so no step is wasted on it.
+        An end only the token tells (EOS) is seen one step late
+        (:meth:`_decode`). Caller holds ``_lock``."""
+        if unread is None:
+            return set()
+        limit = self._engine.token_limit
+        return {
+            slot for slot in unread.active
+            if self._slot_stream[slot] is unread.snapshot[slot]
+            and (
+                len(unread.snapshot[slot]._tokens) + 1
+                >= unread.snapshot[slot]._max_new
+                # _slot_lengths already counts the unread step's row
+                or self._slot_lengths[slot] + 1 >= limit
+            )
+        }
+
+    def _deliver_step(self, unread) -> None:
+        """Read ``unread`` (converting it: instant where the next step's
+        dispatch span has waited for it, else in a ``decode_readback``
+        leaf of its own) and deliver each slot's token. A slot whose stream ended
+        or failed after the step was launched — an EOS the step before
+        revealed, a deadline, ``close()``, a crash — decoded a token
+        nobody owns: it is dropped, and counted. Where no step has been
+        launched since (``_unread`` is None), the tokens are also the
+        slots' host-known inputs again. Caller holds ``_step_lock``."""
+        nxt = np.asarray(unread.step)
+        latest = self._unread is None
+        with _trace.span("sched_deliver"), self._lock:
+            delivered = dropped = 0
+            for slot in unread.active:
+                if self._slot_stream[slot] is not unread.snapshot[slot]:
+                    dropped += 1
+                    continue
+                if unread.counts is not None:
+                    self._draft_lengths[slot] = int(
+                        unread.dlengths[slot]
+                    ) + int(unread.counts[slot])
+                    self._draft_pending[slot] = []
+                token = int(nxt[slot])
+                if latest:
+                    self._slot_tokens[slot] = token
+                self._finish_or_continue(
+                    slot, token, rows=int(unread.lengths[slot]) + 1
+                )
+                delivered += 1
+            self._pipeline["dropped"] += dropped
+            if self._metrics is not None:
+                # (an engine that hands back plain host tokens times nothing)
+                seconds = getattr(unread.step, "seconds", None)
+                self._metrics.record_decode_step(
+                    None if seconds is None else seconds * 1e3, delivered,
+                    in_flight=unread.behind, dropped=dropped,
+                )
+
+    def _resolve_unread(self) -> None:
+        """Bring the engine to rest for whoever must see it quiet (a
+        staged swap, ``close()``): read the unread step, if any, and
+        deliver what of it still has an owner. Caller holds
+        ``_step_lock`` or has stopped the loop."""
+        unread = self._unread
+        if unread is not None:
+            object.__setattr__(self, "_unread", None)
+            self._deliver_step(unread)
+
+    def _ensure_active_rows(self, extra: int, skip=()) -> None:
         """Pre-dispatch page guarantee: every active slot must hold
         pages covering ``length + extra`` rows before the next decode (``extra=1``) or verify
         window (``extra=w``) writes them. A slot the pool cannot grow
@@ -1207,11 +1378,13 @@ class DecodeScheduler:
         resubmit lands once other streams release pages). Caller holds
         ``_lock``."""
         for slot, stream in enumerate(self._slot_stream):
-            if stream is None or slot in self._chunk_state:
+            if stream is None or slot in self._chunk_state or slot in skip:
                 # Mid-prefill slots already hold pages for the FULL
                 # prompt (admit_slot allocates them up front); the
                 # batched dispatch's garbage writes past their cursor
-                # land in those pages or drop via the OOB sentinel.
+                # land in those pages or drop via the OOB sentinel. So
+                # do those of ``skip``, the slots the unread step
+                # completes: they write no further row of their own.
                 continue
             if self._engine.ensure_rows(
                 slot, int(self._slot_lengths[slot]) + int(extra)
@@ -1559,6 +1732,18 @@ class DecodeScheduler:
         """One scheduler iteration: swap boundary, deadline sweeps,
         admit (prefill), decode. Returns whether work remains.
 
+        The decode phase launches this iteration's step and THEN reads
+        and delivers the step the last iteration launched
+        (:meth:`_decode`), so on entry one step may be unread and
+        computing: the sweeps, admission planning and the plan run
+        under it, a prefill admitted here is enqueued behind it, and
+        whatever frees a slot meanwhile (a deadline, ``close()``)
+        leaves that step one token nobody owns, dropped when it is
+        read. The swap boundary reads the unread step before the
+        weights change. While tracing the closing event says what the
+        pipeline did: ``in_flight`` (1: the step was enqueued with the
+        one before unread) and ``dropped``.
+
         ``_step_lock`` serializes iterations (sync mode admits
         multi-threaded callers); ``_lock`` guards only the bookkeeping
         phases and is RELEASED across the device dispatches inside
@@ -1587,6 +1772,7 @@ class DecodeScheduler:
             )
             iteration = self._iteration + 1
             object.__setattr__(self, "_iteration", iteration)
+            pipeline = dict(self._pipeline)
             _trace.set_current_step(iteration)
             with self._lock:
                 plan = faults.active()
@@ -1626,6 +1812,12 @@ class DecodeScheduler:
                     "chunks": chunks,
                 }
                 if started is not None:
+                    # What the pipeline did: 1 when this iteration's
+                    # step was enqueued with the one before unread (0:
+                    # the pipeline was empty, or speculation keeps it
+                    # so), and the tokens decoded for nobody.
+                    for key, before in pipeline.items():
+                        attrs[key] = self._pipeline[key] - before
                     # CPU first: its interval lies inside the wall's.
                     attrs["cpu_ns"] = time.thread_time_ns() - started[1]
                     attrs["wall_ns"] = time.perf_counter_ns() - started[0]
@@ -1648,6 +1840,9 @@ class DecodeScheduler:
     def _on_crash(self, error: BaseException) -> None:
         _trace.set_current_step(None)  # the iteration never closed
         with self._lock:
+            # The unread step dies with its streams: never read, its
+            # tokens have no owner left.
+            object.__setattr__(self, "_unread", None)
             streams = [s for s in self._slot_stream if s is not None]
             streams += list(self._queue)
             self._queue.clear()
@@ -1833,6 +2028,16 @@ class DecodeScheduler:
                 self._cv.notify_all()
             worker.join(timeout=5)
             object.__setattr__(self, "_worker", None)
+        try:
+            # The loop has stopped: what the unread step decoded still
+            # reaches its streams, and the engine is left at rest.
+            self._resolve_unread()
+        except Exception:
+            # A failed device read: the streams fail below.
+            logger.warning(
+                "close(): the unread decode step could not be read",
+                exc_info=True,
+            )
         err = RuntimeError("DecodeScheduler closed with streams pending.")
         with self._lock:
             for stream in list(self._queue):
@@ -1890,6 +2095,15 @@ class DecodeScheduler:
                 "compiles": engine.compile_count,
                 "recompiles_detected": engine.recompiles_detected,
                 "swap_pending": self.swap_pending,
+                # The decode pipeline (docs/DESIGN.md §13): whether a
+                # step is unread now, steps launched with the one
+                # before unread, tokens decoded for a stream that had
+                # ended (an EOS is seen one step late).
+                "decode_pipeline": {
+                    "unread": self._unread is not None,
+                    "steps_in_flight": self._pipeline["in_flight"],
+                    "tokens_dropped": self._pipeline["dropped"],
+                },
                 # Speculative schedule vitals (docs/DESIGN.md §18): k,
                 # live acceptance, draft compile discipline.
                 "speculative": (

@@ -535,12 +535,11 @@ class DisaggScheduler(DecodeScheduler):
     # -- loop hooks -------------------------------------------------------
 
     def _has_work(self) -> bool:
+        if super()._has_work():
+            return True
         with self._lock:
-            return (
-                bool(self._queue)
-                or bool(self._parked)
-                or any(s is not None for s in self._lane_stream)
-                or any(s is not None for s in self._slot_stream)
+            return bool(self._parked) or any(
+                s is not None for s in self._lane_stream
             )
 
     def _expire_active(self) -> None:
