@@ -4,7 +4,11 @@ key/value heads of 128, bfloat16, 64 slots, pages of 16, a window of
 1,024): the chip's own compiler must accept the grouped pool kernel with
 and without a window, the banded, grouped flash forward, and the experts'
 grouped matmul at a decode step's and a prefill's rows. No time comes from
-here."""
+here. The kernels of the ``deepseek_v2_lite_6l`` cell at its widths
+too: the latent decode kernel (128 slots, pages of 64 rows of 640 lanes),
+the prefill/extend kernel (heads of 192 against values of 128) and the
+experts' grouped matmul at its prefill's rows, with the tiles
+``ops.moe.grouped_matmul`` picks for them."""
 
 import jax
 import jax.numpy as jnp
@@ -335,3 +339,53 @@ def test_held_expert_grouped_matmul_compiles(shaped, rows, tm, k, n):
     assert f"bf16[{k},{40 * n}]" not in "".join(
         line for line in text.splitlines() if " copy(" in line
     )
+
+
+def test_latent_decode_kernel_compiles(shaped):
+    from zookeeper_tpu.ops.mla import _mla_paged_decode_call, mla_decode_block_pages
+
+    per_item = mla_decode_block_pages(64, 640, 2, 288)
+
+    def attend(q, pool, table, lengths):
+        return _mla_paged_decode_call(
+            q, pool, table, lengths, scale=0.1147, value_dim=512,
+            interpret=False, per_item=per_item,
+        )
+
+    jax.jit(attend).lower(
+        shaped((128, 16, 576), jnp.bfloat16), shaped((4608, 1, 64, 640), jnp.bfloat16),
+        shaped((128, 288), jnp.int32), shaped((128,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("w,n", [(1024, 18432), (17408, 17408)], ids=["extend", "prefill"])
+def test_latent_window_kernel_compiles(shaped, w, n):
+    from zookeeper_tpu.ops.mla import _mla_extend_call
+
+    def attend(q, k, v, offsets):
+        return _mla_extend_call(
+            q, k, v, offsets, scale=0.1147, block_q=256, block_k=512,
+            interpret=False,
+        )
+
+    jax.jit(attend).lower(
+        shaped((1, w, 16, 192), jnp.bfloat16), shaped((1, n, 16, 192), jnp.bfloat16),
+        shaped((1, n, 16, 128), jnp.bfloat16), shaped((1,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "k,n,out", [(2048, 1408, jnp.float32), (1408, 2048, jnp.bfloat16)],
+    ids=["gate", "down"],
+)
+def test_deepseek_expert_matmul_compiles_at_its_prefill_rows(shaped, k, n, out):
+    from zookeeper_tpu.ops.moe import _gmm, _gmm_tiles
+
+    rows = 17408 * 6
+    tm, tn = _gmm_tiles(rows, k, n, 2, out)
+    jax.jit(
+        lambda lhs, rhs, sizes: _gmm(lhs, rhs, sizes, out_dtype=out, tm=tm, tn=tn)
+    ).lower(
+        shaped((rows, k), jnp.bfloat16), shaped((k, 64 * n), jnp.bfloat16),
+        shaped((64,), jnp.int32),
+    ).compile()

@@ -48,6 +48,12 @@ from zookeeper_tpu.ops import (
     pool_verify_attention,
 )
 from zookeeper_tpu.ops.kda import kda_chunk_scan, kda_decode_update
+from zookeeper_tpu.ops.mla import (
+    mla_attention_reference,
+    mla_flash_attention,
+    mla_gathered_rows,
+    mla_pool_decode_attention,
+)
 from zookeeper_tpu.ops.moe import sparse_moe
 from zookeeper_tpu.ops.ssm import (
     causal_conv,
@@ -142,6 +148,34 @@ class KDASpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention's sizes (DeepSeek-V2 without a query
+    low-rank; ``ops/mla.py``), as :class:`TransformerLM` hands them to
+    every block: a token's keys and values come from one ``kv_rank``-wide
+    latent (normed) through ``mla_kv_b``, each head's key is ``nope_dim``
+    of it beside a ``rope_dim``-wide rotary key that the heads share, its
+    value ``v_dim`` wide; ``scale`` is the softmax's, YaRN's factor
+    included (:func:`yarn_score_factor`). The cache keeps ``[c, k_pe]``,
+    :attr:`row` values a token a layer, and no K/V a head."""
+
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    scale: float
+
+    @property
+    def qk_dim(self) -> int:
+        """A head's query/key width: ``nope_dim + rope_dim``."""
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def row(self) -> int:
+        """A token's cached latent row: ``kv_rank + rope_dim``."""
+        return self.kv_rank + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class Multipliers:
     """The scalar multipliers of a maximal-update parametrisation outside
     the state-space mixer: on the embedding, the logits, attention's
@@ -232,8 +266,9 @@ def _pool_write_pages(layer, rows, pages):
     positions' K/V (finite) and not the page's last tenant's. Every
     reader masks by the sequence's length, and a decode step writes row
     ``length`` before it reads it."""
-    ps = layer["k"].shape[2]
-    pb, sb = rows["k"].shape[:2]
+    first = next(iter(rows))
+    ps = layer[first].shape[2]
+    pb, sb = rows[first].shape[:2]
     pad = pages.shape[1] * ps - sb
 
     def write(buf, vals):
@@ -247,16 +282,17 @@ def _pool_write_pages(layer, rows, pages):
 
 
 def _pool_write(layer, rows, write):
-    """``write(buf, vals)`` for the K and the V rows of ``rows`` in the
-    pool's stored form (``ops.fold_kv_rows``; int8 with the scales when
-    the layer carries them: ``ops.quantizers.quantize_kv_rows``)."""
+    """``write(buf, vals)`` for each leaf of ``rows`` (``k`` and ``v``, or
+    a latent layer's ``c``) in the pool's stored form
+    (``ops.fold_kv_rows``; int8 with the scales when the layer carries
+    them: ``ops.quantizers.quantize_kv_rows``)."""
     from zookeeper_tpu.ops import fold_kv_rows, quantize_kv_rows
 
     out = dict(layer)
-    for name, scale_name in (("k", "k_scale"), ("v", "v_scale")):
+    for name, vals in rows.items():
+        scale_name = f"{name}_scale"
         buf = layer[name]
         shards, width = buf.shape[1], buf.shape[3]
-        vals = rows[name]
         if scale_name in layer:
             vals, s = quantize_kv_rows(vals)
             out[scale_name] = write(
@@ -367,6 +403,13 @@ class _GatedGroupNorm(nn.Module):
         return (grouped.reshape(y.shape) * scale).astype(self.dtype)
 
 
+def _yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1 mscale ln(factor) + 1``."""
+    import math
+
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def rope_inv_freq(head_dim: int, theta: float, yarn: Tuple = ()):
     """``(inv_freq [head_dim // 2] float32, attention_factor)`` of rotary
     positions over the whole head: ``inv_i = theta ** (-2 i /
@@ -377,8 +420,16 @@ def rope_inv_freq(head_dim: int, theta: float, yarn: Tuple = ()):
     length, ``low = floor(d(beta_fast))`` and ``high = ceil(d(beta_slow))``
     (clipped to the head), dimensions below ``low`` keep ``inv_i``,
     those above ``high`` take ``inv_i / factor``, and a linear ramp
-    blends between; cos and sin are scaled by ``0.1 ln(factor) + 1``.
-    The table is static: it does not depend on the sequence's length."""
+    blends between. The table is static: it does not depend on the
+    sequence's length.
+
+    ``attention_factor`` is what cos and sin are scaled by, and which of
+    YaRN's two conventions applies is said by the tuple, never assumed:
+    four numbers (no ``mscale`` in the config, mellum2's) give
+    ``0.1 ln(factor) + 1``; six, ``yarn + (mscale, mscale_all_dim)``
+    (DeepSeek's), give ``m(mscale) / m(mscale_all_dim)`` with ``m(s) =
+    0.1 s ln(factor) + 1`` (1.0 where the two are equal), and the score
+    then takes ``m(mscale_all_dim) ** 2`` instead (:func:`yarn_score_factor`)."""
     import math
 
     import numpy as np
@@ -387,7 +438,7 @@ def rope_inv_freq(head_dim: int, theta: float, yarn: Tuple = ()):
     inv = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / head_dim)
     if not yarn:
         return jnp.asarray(inv, jnp.float32), 1.0
-    factor, original_len, beta_fast, beta_slow = yarn
+    factor, original_len, beta_fast, beta_slow = yarn[:4]
 
     def turns_dim(turns):
         return (
@@ -404,7 +455,22 @@ def rope_inv_freq(head_dim: int, theta: float, yarn: Tuple = ()):
         1.0,
     )
     inv = inv * (1.0 - ramp) + (inv / factor) * ramp
-    return jnp.asarray(inv, jnp.float32), 0.1 * math.log(factor) + 1.0
+    if len(yarn) == 6:
+        on_rotation = _yarn_mscale(factor, yarn[4]) / _yarn_mscale(
+            factor, yarn[5]
+        )
+    else:
+        on_rotation = _yarn_mscale(factor)
+    return jnp.asarray(inv, jnp.float32), on_rotation
+
+
+def yarn_score_factor(yarn: Tuple = ()) -> float:
+    """What DeepSeek's YaRN puts on the softmax scale: ``m(mscale_all_dim)
+    ** 2`` for a six-number ``yarn`` (:func:`rope_inv_freq`), else 1.0
+    (the other convention scales cos and sin instead)."""
+    if len(yarn) != 6:
+        return 1.0
+    return _yarn_mscale(yarn[0], yarn[5]) ** 2
 
 
 def apply_rope(xs, positions, inv_freq, attention_factor: float = 1.0):
@@ -453,7 +519,10 @@ class _Block(nn.Module):
     heads before the output projection), ``held_experts`` (the chip's
     share of the routed experts, ``ops/moe.py``) and
     ``shared_expert_dim`` (a SwiGLU expert every token takes beside the
-    routed ones), ``multipliers`` (1.0: no
+    routed ones), ``mla`` (latent attention in place of q/k/v heads, one
+    latent row a token: :meth:`_mla_expanded` for prefill and extend,
+    :meth:`_mla_absorbed` for decode; ``ops/mla.py``, docs/DESIGN.md
+    §29), ``renormalize_experts``, ``multipliers`` (1.0: no
     multiply is traced) and ``param_dtype``. The three traced methods
     share ONE projection-and-positions helper (:meth:`_qkv`) and one
     attention keyword set (:meth:`_attention_kwargs`).
@@ -493,6 +562,8 @@ class _Block(nn.Module):
     attention_gate: bool = False
     held_experts: Tuple[int, ...] = ()  # (first, count); empty: all
     shared_expert_dim: int = 0  # 0: no shared expert
+    mla: Optional[MLASpec] = None  # a spec: latent attention, one row a token
+    renormalize_experts: bool = True  # False: the top-k softmax weights as they are
 
     @property
     def kv_heads(self) -> int:
@@ -512,7 +583,22 @@ class _Block(nn.Module):
             eps=self.norm_eps,
         )
         self.ln1 = norm(name="RMSNorm_0")
-        if self.kda is None:
+        if self.mla is not None:
+            mla = self.mla
+            # The query's heads (nope then rope a head), the latent with
+            # the shared rotary key, the latent's norm, and the latent's
+            # expansion to each head's key (nope) then value: a raw
+            # kernel, since decode multiplies it in absorbed form.
+            self.wmla_q = dense(self.num_heads * mla.qk_dim, name="mla_q")
+            self.wmla_kv_a = dense(mla.row, name="mla_kv_a")
+            self.mla_kv_norm = norm(name="mla_kv_norm")
+            self.mla_kv_b = _param(
+                self, "mla_kv_b", nn.initializers.lecun_normal(),
+                (mla.kv_rank, self.num_heads * (mla.nope_dim + mla.v_dim)),
+                self.param_dtype,
+            )
+            self.wproj = dense(d, name="proj")
+        elif self.kda is None:
             # One fused projection: query heads, then key heads, then
             # value heads (three equal thirds in the GPT-2 shape).
             self.wqkv = dense(
@@ -733,6 +819,87 @@ class _Block(nn.Module):
         o = self.kda_norm(o).astype(f32).reshape(b, s, -1) * gate
         return self.wkda_out(o.astype(self.dtype)), (carried, conv)
 
+    def _mla_rotate(self, x, positions):
+        """The rotary part of a latent layer's queries or keys, ``x [b, s,
+        heads, rope_dim]``, rotated by ``positions [b, s]``."""
+        inv_freq, factor = rope_inv_freq(
+            self.mla.rope_dim, self.rope_theta, self.rope_yarn
+        )
+        return apply_rope((x,), positions, inv_freq, factor)[0]
+
+    def _mla_latent(self, h, positions):
+        """A latent layer's cached rows for the normed ``h [b, s, d]`` at
+        ``positions [b, s]``: ``[RMSNorm(c), RoPE(k_pe)]``, ``[b, s,
+        row]`` in the compute dtype."""
+        mla = self.mla
+        c, k_pe = jnp.split(self.wmla_kv_a(h), [mla.kv_rank], axis=-1)
+        k_pe = self._mla_rotate(k_pe[:, :, None, :], positions)[:, :, 0]
+        return jnp.concatenate([self.mla_kv_norm(c), k_pe], axis=-1)
+
+    def _mla_query(self, h, positions):
+        """``(q_nope [b, s, heads, nope_dim], q_pe [b, s, heads,
+        rope_dim])``, the rotary part rotated."""
+        b, s, _ = h.shape
+        mla = self.mla
+        q = self.wmla_q(h).reshape(b, s, self.num_heads, mla.qk_dim)
+        q_nope, q_pe = jnp.split(q, [mla.nope_dim], axis=-1)
+        return q_nope, self._mla_rotate(q_pe, positions)
+
+    def _mla_kv_b(self):
+        """The latent's expansion ``[kv_rank, heads, nope_dim + v_dim]``
+        in the compute dtype: ``[..., :nope_dim]`` is ``W_uk``, the rest
+        ``W_uv``."""
+        mla = self.mla
+        return self.mla_kv_b.astype(self.dtype).reshape(
+            mla.kv_rank, self.num_heads, mla.nope_dim + mla.v_dim
+        )
+
+    def _mla_expanded(self, h, positions, rows, offsets):
+        """Latent attention in the EXPANDED form (prefill, extend;
+        ``ops/mla.py``): the queries of ``h [b, w, d]`` at ``positions``
+        against every head's keys and values expanded from the cached
+        ``rows [b, n, row]``, query ``j`` attending rows ``0 ..
+        offsets + j``. Returns ``[b, w, heads, v_dim]``."""
+        mla = self.mla
+        q_nope, q_pe = self._mla_query(h, positions)
+        c, k_pe = jnp.split(rows, [mla.kv_rank], axis=-1)
+        kv = jnp.einsum("bnr,rhe->bnhe", c, self._mla_kv_b())
+        k_nope, v = jnp.split(kv, [mla.nope_dim], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe[:, :, None, :], (
+                *k_nope.shape[:3], mla.rope_dim
+            ))], axis=-1,
+        )
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        attend = (
+            mla_attention_reference if self.attention == "dense"
+            else mla_flash_attention
+        )
+        return attend(q, k, v, offsets, scale=mla.scale)
+
+    def _mla_absorbed(self, h, positions, pool, page_table, lengths, attn):
+        """One decode token a sequence in the ABSORBED form: the query
+        multiplied into the latent (``q_nope W_uk^T``, beside ``q_pe``),
+        ``attn`` over the pool's rows (``ops/mla.py``), and the latent
+        average out through ``W_uv``. Returns ``[b, 1, heads, v_dim]``."""
+        mla = self.mla
+        q_nope, q_pe = self._mla_query(h, positions)
+        w = self._mla_kv_b()
+        q_lat = jnp.einsum(
+            "bhn,rhn->bhr", q_nope[:, 0], w[..., : mla.nope_dim],
+            preferred_element_type=jnp.float32,
+        )
+        q = jnp.concatenate([q_lat.astype(self.dtype), q_pe[:, 0]], axis=-1)
+        o_lat = attn(
+            q, pool, page_table, lengths, scale=mla.scale,
+            value_dim=mla.kv_rank,
+        )
+        o = jnp.einsum(
+            "bhr,rhv->bhv", o_lat.astype(self.dtype), w[..., mla.nope_dim :],
+            preferred_element_type=jnp.float32,
+        )
+        return o.astype(self.dtype)[:, None]
+
     def _out(self, x, o, mixed=None, h=None):
         """The residual stream after the mixers: attention's heads ``o``
         (times a sigmoid gate a channel read from the normed ``h``, where
@@ -756,6 +923,7 @@ class _Block(nn.Module):
                 self.experts_gate, self.experts_up, self.experts_down,
                 k=self.experts_per_token,
                 held=tuple(self.held_experts) or None,
+                renormalize=self.renormalize_experts,
             )
             routed = routed.reshape(b, s, d)
             if self.shared_expert_dim:
@@ -805,6 +973,14 @@ class _Block(nn.Module):
             out = self._mlp(x + mixed)
             return (out, state) if return_kv else out
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        if self.mla is not None:
+            # The layer's state is its latent rows, one "head" a token.
+            rows = self._mla_latent(h, positions)
+            o = self._mla_expanded(
+                h, positions, rows, jnp.zeros((b,), jnp.int32)
+            )
+            out = self._mlp(self._out(x, o, h=h))
+            return (out, (rows[:, :, None, :],)) if return_kv else out
         q, kh, vh = self._qkv(h, positions)
         attn = _resolve_attention(self.attention)
         o = attn(q, kh, vh, causal=True, **self._attention_kwargs())
@@ -851,8 +1027,8 @@ class _Block(nn.Module):
             }
             return self._mlp(x + mixed), layer
         page_table = layer_page_table(page_table, bool(self.window))
-        num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
-        q, k, v = self._qkv(h, lengths[:, None])
+        pool = layer["c" if self.mla is not None else "k"]
+        num_pages, ps = pool.shape[0], pool.shape[2]
         row = jnp.clip(lengths // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row[:, None], axis=1)[:, 0]
         page = jnp.where(
@@ -861,6 +1037,15 @@ class _Block(nn.Module):
             page,
         )
         off = lengths % ps
+        if self.mla is not None:
+            rows = self._mla_latent(h, lengths[:, None])
+            layer = _pool_write_rows(layer, {"c": rows}, page, off)
+            o = self._mla_absorbed(
+                h, lengths[:, None], layer["c"], page_table, lengths,
+                attention_override or mla_pool_decode_attention,
+            )
+            return self._mlp(self._out(x, o, h=h)), layer
+        q, k, v = self._qkv(h, lengths[:, None])
         layer = _pool_write_rows(
             layer, {"k": k[:, 0], "v": v[:, 0]}, page, off
         )
@@ -913,10 +1098,10 @@ class _Block(nn.Module):
             )
         page_table = layer_page_table(page_table, bool(self.window))
         w = x.shape[1]
-        num_pages, ps = layer["k"].shape[0], layer["k"].shape[2]
+        pool = layer["c" if self.mla is not None else "k"]
+        num_pages, ps = pool.shape[0], pool.shape[2]
         pos = lengths[:, None] + jnp.arange(w)[None, :]
         h = self.ln1(x)
-        q, k, v = self._qkv(h, pos)
         row = jnp.clip(pos // ps, 0, page_table.shape[1] - 1)
         page = jnp.take_along_axis(page_table, row, axis=1)
         dead = (page < 0) | (pos >= page_table.shape[1] * ps)
@@ -924,6 +1109,17 @@ class _Block(nn.Module):
             dead = dead | (jnp.arange(w)[None, :] >= valid[:, None])
         page = jnp.where(dead, num_pages, page)
         off = pos % ps
+        if self.mla is not None:
+            # The window's rows go in first; every query then attends the
+            # slot's rows through the table, expanded (ops/mla.py).
+            layer = _pool_write_rows(
+                layer, {"c": self._mla_latent(h, pos)[:, :, None, :]},
+                page, off,
+            )
+            rows = mla_gathered_rows(layer["c"], page_table, self.mla.row)
+            o = self._mla_expanded(h, pos, rows, lengths)
+            return self._mlp(self._out(x, o, h=h)), layer
+        q, k, v = self._qkv(h, pos)
         layer = _pool_write_rows(layer, {"k": k, "v": v}, page, off)
         k_scale, v_scale = _pool_scales(layer)
         attn = attention_override or pool_verify_attention
@@ -945,10 +1141,13 @@ _BLOCK_MATMUL_LEAVES = frozenset(
             "qkv", "proj", "up", "down", "gate", "ssm_in", "ssm_out",
             "attn_gate", "shared_gate", "shared_up", "shared_down",
             "kda_qkv", "kda_f1", "kda_f2", "kda_g1", "kda_g2", "kda_beta",
-            "kda_out",
+            "kda_out", "mla_q", "mla_kv_a",
         )
     ]
-    + [(name,) for name in ("experts_gate", "experts_up", "experts_down")]
+    + [
+        (name,)
+        for name in ("experts_gate", "experts_up", "experts_down", "mla_kv_b")
+    ]
 )
 
 
@@ -1048,6 +1247,13 @@ class TransformerLMModule(nn.Module):
     attention_gate: bool = False
     held_experts: Tuple[int, ...] = ()
     shared_expert_dim: int = 0
+    #: Latent attention in every layer in place of q/k/v heads (a spec);
+    #: the leading layers whose MLP is the dense ``mlp_dim`` SwiGLU in a
+    #: model of experts; whether the top-k router weights are
+    #: renormalised.
+    mla: Optional[MLASpec] = None
+    dense_layers: int = 0
+    moe_renormalize: bool = True
 
     def setup(self):
         # A serving tree may hold the tables :meth:`_embed` gathers from
@@ -1109,7 +1315,7 @@ class TransformerLMModule(nn.Module):
                 rope_theta=self.rope_theta if rope else 0.0,
                 rope_yarn=() if windowed else self.rope_yarn,
                 window=self.window if windowed else 0,
-                mlp=self.mlp,
+                mlp="swiglu" if i < self.dense_layers else self.mlp,
                 num_experts=self.num_experts,
                 experts_per_token=self.experts_per_token,
                 expert_dim=self.expert_dim,
@@ -1122,6 +1328,8 @@ class TransformerLMModule(nn.Module):
                 attention_gate=self.attention_gate,
                 held_experts=self.held_experts,
                 shared_expert_dim=self.shared_expert_dim,
+                mla=self.mla,
+                renormalize_experts=self.moe_renormalize,
                 name=f"block{i}",
             )
             for i, (windowed, attends) in enumerate(
@@ -1592,6 +1800,27 @@ class TransformerLM(Model):
     ssm_out_multiplier: float = Field(1.0)
     ssm_multipliers: Sequence[float] = Field(())
     mlp_multipliers: Sequence[float] = Field(())
+    #: Multi-head latent attention in every layer (DeepSeek-V2, no query
+    #: low-rank; ``MLASpec``): a ``mla_kv_rank``-wide latent a token,
+    #: heads of ``mla_nope_dim`` + ``mla_rope_dim`` query/key and
+    #: ``mla_v_dim`` value, the rotary key shared by the heads. 0: none.
+    mla_kv_rank: int = Field(0)
+    mla_nope_dim: int = Field(0)
+    mla_rope_dim: int = Field(0)
+    mla_v_dim: int = Field(0)
+    #: DeepSeek's YaRN pair ``(mscale, mscale_all_dim)``: given (both >
+    #: 0), cos and sin take ``m(mscale) / m(mscale_all_dim)`` and the
+    #: softmax scale ``m(mscale_all_dim) ** 2``; 0 (a config without
+    #: them), cos and sin take ``0.1 ln(yarn_factor) + 1``
+    #: (``rope_inv_freq``).
+    yarn_mscale: float = Field(0.0)
+    yarn_mscale_all_dim: float = Field(0.0)
+    #: The leading layers of a model of experts whose MLP is the dense
+    #: SwiGLU of ``mlp_dim`` (a config's ``first_k_dense_replace``).
+    dense_layers: int = Field(0)
+    #: Whether the top-k router weights are renormalised to sum 1 (a
+    #: config's ``norm_topk_prob``); False uses the softmax's own.
+    moe_renormalize: bool = Field(True)
 
     def set_attention_override(self, fn) -> None:
         """The partitioner injection seam (``Partitioner.prepare_model``):
@@ -1693,6 +1922,31 @@ class TransformerLM(Model):
             )
         if self.mlp == "swiglu" and self.mlp_dim < 1:
             raise ValueError("mlp='swiglu' needs mlp_dim >= 1.")
+        if self.dense_layers and not (
+            self.mlp == "moe" and self.mlp_dim >= 1
+            and 0 < self.dense_layers < self.num_layers
+        ):
+            raise ValueError(
+                f"dense_layers={self.dense_layers} needs mlp='moe', mlp_dim "
+                f">= 1 and fewer than num_layers ({self.num_layers})."
+            )
+        mla = None
+        if self.mla_kv_rank:
+            if min(self.mla_nope_dim, self.mla_rope_dim, self.mla_v_dim) < 1 or (
+                self.mla_rope_dim % 2
+            ):
+                raise ValueError(
+                    "latent attention needs mla_nope_dim, mla_v_dim >= 1 and "
+                    "an even mla_rope_dim >= 2."
+                )
+            if self.positions != "rope" or layer_types or self.ssm_heads or (
+                attention not in ("flash", "dense")
+            ):
+                raise ValueError(
+                    "latent attention (mla_kv_rank > 0) is implemented with "
+                    "positions='rope', every layer full (no layer_types), no "
+                    "state-space mixer and attention 'flash' or 'dense'."
+                )
         if self.lm_head_multiplier != 1.0 and self.tie_embeddings:
             raise ValueError(
                 "lm_head_multiplier needs a head of its own "
@@ -1734,6 +1988,23 @@ class TransformerLM(Model):
             rope_yarn = (
                 float(self.yarn_factor), int(self.yarn_original_len),
                 float(self.yarn_beta_fast), float(self.yarn_beta_slow),
+            )
+            if (self.yarn_mscale > 0) != (self.yarn_mscale_all_dim > 0):
+                raise ValueError(
+                    "yarn_mscale and yarn_mscale_all_dim are given together."
+                )
+            if self.yarn_mscale > 0:
+                rope_yarn += (
+                    float(self.yarn_mscale), float(self.yarn_mscale_all_dim)
+                )
+        if self.mla_kv_rank:
+            qk = self.mla_nope_dim + self.mla_rope_dim
+            mla = MLASpec(
+                kv_rank=int(self.mla_kv_rank),
+                nope_dim=int(self.mla_nope_dim),
+                rope_dim=int(self.mla_rope_dim),
+                v_dim=int(self.mla_v_dim),
+                scale=qk ** -0.5 * yarn_score_factor(rope_yarn),
             )
         (seq_len,) = input_shape
         if self.max_seq_len == -1:
@@ -1803,6 +2074,9 @@ class TransformerLM(Model):
             attention_gate=bool(self.attention_gate),
             held_experts=held,
             shared_expert_dim=int(self.shared_expert_dim),
+            mla=mla,
+            dense_layers=int(self.dense_layers),
+            moe_renormalize=bool(self.moe_renormalize),
         )
 
     def initialize(

@@ -67,21 +67,18 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype):
             lhs, stacked, group_sizes, preferred_element_type=out_dtype
         )
     m = lhs.shape[0]
-    # 256 rows a tile once an expert sees hundreds (a prefill), 128
-    # below; 512 rows against a whole block overflow VMEM.
-    tm = 256 if m >= 4096 and m % 256 == 0 else 128
+    tm, tn = _gmm_tiles(m, k, n, rhs.dtype.itemsize, out_dtype)
     pad = -m % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    out = _gmm(
-        lhs, rhs, group_sizes, out_dtype=out_dtype, tm=tm,
-        tn=_column_tile(k, n, rhs.dtype.itemsize),
-    )
+    out = _gmm(lhs, rhs, group_sizes, out_dtype=out_dtype, tm=tm, tn=tn)
     return out[:m] if pad else out
 
 
 #: The largest block of one expert's matrix a grid step takes whole.
 _BLOCK_BYTES = 6 * 2**20
+#: The largest block a 256-row tile with a float32 output meets.
+_WIDE_TILE_BLOCK_BYTES = 5 * 2**20
 
 
 def _column_tile(k: int, n: int, itemsize: int) -> int:
@@ -93,6 +90,20 @@ def _column_tile(k: int, n: int, itemsize: int) -> int:
         if n % (parts * 128) == 0 and k * (n // parts) * itemsize <= _BLOCK_BYTES
     ]
     return fits[0] if fits else n
+
+
+def _gmm_tiles(m: int, k: int, n: int, itemsize: int, out_dtype):
+    """``(tm, tn)`` of :func:`_gmm` for ``m`` rows against ``[k, n]``
+    blocks: 256 rows a tile once an expert sees hundreds (a prefill), 128
+    below; 512 rows against a whole block overflow VMEM, and so do 256
+    float32 rows against a block past ``_WIDE_TILE_BLOCK_BYTES`` (the v5e
+    compiler: DeepSeek-V2's 2048 x 1408 gate, 5.5 MiB, wants 59.6 of 58.75
+    MiB of scoped VMEM; PERF.md, PR 37)."""
+    tn = _column_tile(k, n, itemsize)
+    wide = k * tn * itemsize <= _WIDE_TILE_BLOCK_BYTES or (
+        jnp.dtype(out_dtype).itemsize < 4
+    )
+    return (256 if m >= 4096 and m % 256 == 0 and wide else 128), tn
 
 
 @partial(jax.jit, static_argnames=("out_dtype", "tm", "tn", "interpret"))
@@ -177,12 +188,13 @@ def _gmm(lhs, rhs, group_sizes, *, out_dtype, tm, tn=None, interpret=False):
 
 
 def route_top_k(
-    x: jax.Array, router: jax.Array, k: int
+    x: jax.Array, router: jax.Array, k: int, renormalize: bool = True
 ) -> Tuple[jax.Array, jax.Array]:
     """``(weights [t, k] float32, experts [t, k] int32)`` for tokens
     ``x [t, d]`` under ``router [d, experts]``: softmax over every
     expert in float32 at the highest matmul precision, the ``k``
-    largest, renormalised to sum 1."""
+    largest, renormalised to sum 1 (``renormalize``, a config's
+    ``norm_topk_prob``) or used as they are."""
     logits = jnp.dot(
         x.astype(jnp.float32),
         router.astype(jnp.float32),
@@ -190,7 +202,8 @@ def route_top_k(
     )
     probs = jax.nn.softmax(logits, axis=-1)
     weights, experts = jax.lax.top_k(probs, k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, experts.astype(jnp.int32)
 
 
@@ -203,6 +216,7 @@ def sparse_moe(
     *,
     k: int,
     held: Optional[Tuple[int, int]] = None,
+    renormalize: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """``y [t, d]`` and ``tokens_per_expert [experts] int32`` for
     ``x [t, d]``: ``y = sum_e w_e down_e(silu(gate_e x) * up_e x)`` over
@@ -213,10 +227,11 @@ def sparse_moe(
     column blocks of experts ``first .. first + count`` only, the sum
     runs over a token's routed experts among those, and the counts are
     theirs (``[count]``). The pairs whose expert is not held sort behind
-    the last group, where the grouped matmul computes nothing."""
+    the last group, where the grouped matmul computes nothing.
+    ``renormalize``: :func:`route_top_k`'s."""
     t, d = x.shape
     num_experts = router.shape[1]
-    weights, experts = route_top_k(x, router, k)
+    weights, experts = route_top_k(x, router, k, renormalize)
     mine = None
     if held is not None:
         first, num_experts = held

@@ -269,6 +269,14 @@ DecodeScheduler`.
     #: not exceed the largest seq bucket (chunks ride the warmed
     #: ``prefill_extend`` width grid — zero new compiles).
     prefill_chunk_tokens: int = Field(0)
+    #: Widths of the warm-prefix extend program (ascending; empty: the
+    #: seq buckets). Narrower than the seq buckets where prompts are long
+    #: and their unshared tails short (a 16,384-token shared prefix, a
+    #: body of 64-1,024): the cold prefill keeps its long bucket and the
+    #: extend grid compiles no program as wide as it. A warm hit whose
+    #: tail exceeds the widest is admitted cold (``PagePool.max_suffix``);
+    #: chunks ride this grid too.
+    extend_buckets: Sequence[int] = Field(())
 
     # -- binding ---------------------------------------------------------
 
@@ -378,14 +386,25 @@ DecodeScheduler`.
                 "expected 0 (monolithic prefill) or a positive chunk "
                 "size in tokens."
             )
-        if int(self.prefill_chunk_tokens) > max(seq_buckets):
+        extend_buckets = tuple(int(w) for w in self.extend_buckets) or (
+            seq_buckets
+        )
+        if any(w < 1 for w in extend_buckets) or list(extend_buckets) != (
+            sorted(set(extend_buckets))
+        ) or max(extend_buckets) > max(seq_buckets):
+            raise ValueError(
+                f"extend_buckets={self.extend_buckets!r} must be a "
+                "strictly-ascending tuple of positive widths, none past "
+                "the largest seq bucket."
+            )
+        if int(self.prefill_chunk_tokens) > max(extend_buckets):
             raise ValueError(
                 f"prefill_chunk_tokens={self.prefill_chunk_tokens} "
-                f"exceeds the largest seq bucket {max(seq_buckets)}"
-                ": chunks ride the warmed prefill_extend width "
-                "grid, so a chunk wider than every bucket would "
-                "compile on the dispatch path; shrink the chunk or "
-                "widen seq_buckets."
+                f"exceeds the largest seq bucket (or extend bucket) "
+                f"{max(extend_buckets)}: chunks ride the warmed "
+                "prefill_extend width grid, so a chunk wider than every "
+                "bucket would compile on the dispatch path; shrink the "
+                "chunk or widen seq_buckets (or extend_buckets)."
             )
         max_pages = capacity // int(self.page_size)
         # Layer groups (docs/DESIGN.md §20): a model with sliding-window
@@ -483,7 +502,25 @@ DecodeScheduler`.
                 "one device: its slot state has no sharding rule under a "
                 "mesh yet (ROADMAP.md, Reach)."
             )
+        # Latent attention (docs/DESIGN.md §29): one latent row a token a
+        # layer in a leaf ``c`` of the pool, in place of K and V a head.
+        mla = getattr(module, "mla", None)
+        latent = int(mla.row) if mla is not None else 0
+        if latent and str(self.kv_quant) != "none":
+            raise ValueError(
+                "kv_quant='int8' is not implemented for latent attention "
+                "(MLA): its scales are a head's, and a latent row has no "
+                "heads. Set engine.kv_quant='none'."
+            )
+        if latent and partitioner.mesh is not None:
+            raise ValueError(
+                "latent attention (MLA) is served on one device: its "
+                "latent pool has no sharding rule under a mesh (the heads "
+                "it would shard over share one row; ROADMAP.md, Reach)."
+            )
         object.__setattr__(self, "_module", module)
+        object.__setattr__(self, "_latent", latent)
+        object.__setattr__(self, "_extend_buckets", extend_buckets)
         object.__setattr__(self, "_slot_leaves", slot_leaves)
         object.__setattr__(self, "_attention_layers", attention_layers)
         # The recurrent mixers the model has, by their first leaf's name
@@ -520,6 +557,7 @@ DecodeScheduler`.
                 window=window,
                 window_pages=self._window_pages,
                 slot_state=slot_state,
+                max_suffix=max(extend_buckets),
             ),
         )
 
@@ -608,6 +646,7 @@ DecodeScheduler`.
             window_layers=window_layers,
             window_pages=self._window_pages,
             attention_layers=attention_layers,
+            latent=latent,
         )
         state_bytes = slot_state_bytes(int(self.slots), slot_leaves)
         object.__setattr__(
@@ -668,6 +707,13 @@ DecodeScheduler`.
             choice = (
                 "pallas" if jax.default_backend() == "tpu" else "reference"
             )
+        if self._latent:
+            # Latent attention's own kernel (the absorbed form, ops/mla.py).
+            from zookeeper_tpu.ops import mla
+
+            if choice == "reference":
+                return "reference", mla.mla_pool_decode_attention
+            return "pallas_mla", mla.mla_paged_decode_attention
         if choice == "reference":
             return "reference", ops.pool_decode_attention
         head_dim = self._head_dim()
@@ -712,6 +758,7 @@ DecodeScheduler`.
         """Bind-time decode gauge: the provisioned cache HBM
         (``zk_decode_kv_bytes``: the page pool, and a recurrent model's
         state a slot with it)."""
+        from zookeeper_tpu import ops
         from zookeeper_tpu.observability.registry import default_registry
 
         default_registry().gauge(
@@ -720,6 +767,16 @@ DecodeScheduler`.
             "scales, all layers) and, for a model with recurrent state, "
             "its block a slot",
         ).set(float(self._cache_nbytes))
+        if self._latent:
+            default_registry().gauge(
+                "zk_decode_latent_row_bytes",
+                help="bytes one token's latent row takes in the pool, a "
+                "layer (latent attention: kv_rank + rope_dim values "
+                "padded to whole 128-lane registers)",
+            ).set(float(
+                ops.kv_row_width(1, self._latent)
+                * np.dtype(self._module.dtype).itemsize
+            ))
 
     def decode_mbu_for(self, seconds: float, program: str = "decode_step") -> float:
         """MBU of a decode-path program (default ``decode_step``; the
@@ -880,6 +937,7 @@ DecodeScheduler`.
             slots=int(self.slots),
             slot_leaves=self._slot_leaves,
             attention_layers=self._attention_layers,
+            latent=self._latent,
         )
 
     def _place_cache(self, cache):
@@ -1060,6 +1118,15 @@ PagePool`."""
             f"{max(self._seq_buckets)}; widen seq_buckets."
         )
 
+    def extend_bucket_for(self, width: int) -> int:
+        for w in self._extend_buckets:
+            if w >= width:
+                return w
+        raise ValueError(
+            f"a window of {width} tokens exceeds the largest extend bucket "
+            f"{max(self._extend_buckets)}."
+        )
+
     def prefill_bucket_for(self, n: int) -> int:
         for b in self._prefill_buckets:
             if b >= n:
@@ -1226,9 +1293,11 @@ PagePool`."""
         if not getattr(module, "num_experts", 0):
             return module.apply(*args, **kwargs), None
         out, sown = module.apply(*args, mutable=["moe_load"], **kwargs)
+        # (the leading dense layers of such a model sow nothing)
         load = jnp.stack([
             sown["moe_load"][f"block{i}"]["tokens_per_expert"]
             for i in range(int(module.num_layers))
+            if f"block{i}" in sown["moe_load"]
         ])
         return out, load
 
@@ -1303,6 +1372,49 @@ PagePool`."""
                 )
             ),
         )
+
+    def _note_mla_rows(self, lengths: np.ndarray) -> None:
+        """While tracing, for a model with latent attention: one
+        ``decode_mla_rows`` event a decode dispatch, ``rows_read`` (the
+        latent rows the step attends: each slot that holds pages its
+        ``length + 1``, times the layers) and ``rows_shared`` (those of
+        them on pages that more than one slot attends in the step: a
+        shared prefix, read again for every slot on it, which a kernel
+        could read once for all). Not the page's refcount: the prefix
+        cache holds a reference on every cached page, a slot's own body
+        among them."""
+        if not (_trace.enabled() and self._latent):
+            return
+        pool = self._pool
+        live = pool.counts > 0
+        n = np.where(live, np.asarray(lengths, np.int64) + 1, 0)
+        starts = np.arange(pool.max_pages_per_slot) * pool.page_size
+        on_page = np.clip(n[:, None] - starts[None, :], 0, pool.page_size)
+        table = np.where(on_page > 0, pool.table, -1)
+        holders = np.bincount(table[table >= 0], minlength=pool.num_pages)
+        shared = (table >= 0) & (holders[np.clip(table, 0, None)] > 1)
+        layers = int(sum(self._attention_layers))
+        _trace.event(
+            "decode_mla_rows",
+            attrs={
+                "rows_read": int(n.sum()) * layers,
+                "rows_shared": int((on_page * shared).sum()) * layers,
+            },
+        )
+
+    def _note_extend_rows(self, prefix, new) -> None:
+        """While tracing, for a model with latent attention: one
+        ``extend_mla_rows`` event an extend dispatch (warm-prefix or
+        chunk), ``rows_prefix`` (the cached rows its windows attend) and
+        ``rows_new`` (the rows it writes), summed over its lanes."""
+        if _trace.enabled() and self._latent:
+            _trace.event(
+                "extend_mla_rows",
+                attrs={
+                    "rows_prefix": int(np.sum(prefix)),
+                    "rows_new": int(np.sum(new)),
+                },
+            )
 
     def _decode_compiled(self, *, during_dispatch: bool = False):
         import jax
@@ -1399,15 +1511,17 @@ PagePool`."""
                 return targets[windowed]
 
             new_cache = []
+            paged = ("c",) if self._latent else ("k", "v")
             for layer, state, windowed, attends, names in zip(
                 cache, kv, window_layers, attention_layers, slot_leaves
             ):
                 layer = dict(layer)
                 if attends:
-                    k, v, *state = state
+                    rows = dict(zip(paged, state))
+                    state = state[len(paged):]
                     layer = _pool_write_pages(
-                        layer, {"k": k, "v": v},
-                        pages_of(windowed, layer["k"].shape[0]),
+                        layer, rows,
+                        pages_of(windowed, layer[paged[0]].shape[0]),
                     )
                 # A recurrent mixer's block a slot, overwritten whole at
                 # the admitted slots (nothing of the last tenant stays);
@@ -1449,6 +1563,12 @@ PagePool`."""
         import jax.numpy as jnp
 
         self._require_bound()
+        if self._latent:
+            raise NotImplementedError(
+                "speculative decoding is not implemented for latent "
+                "attention (MLA): its verify would expand every slot's "
+                "latent rows a step (docs/DESIGN.md §29; ROADMAP.md, Reach)."
+            )
         if self._slot_kinds:
             raise NotImplementedError(
                 "a speculative draft or verify is not implemented for a "
@@ -1699,6 +1819,12 @@ PageTransfer` moves between mesh slices. READ-ONLY: the source pool
                 "(state-space) state keeps a block a slot that is in "
                 "none of them (ROADMAP.md, Reach)."
             )
+        if self._latent:
+            raise NotImplementedError(
+                "the page handoff is not implemented for latent attention "
+                "(MLA): the transfer programs move k/v pages, and a latent "
+                "pool holds one row a token (ROADMAP.md, Reach)."
+            )
         return self._pool.pages_for(max(self._seq_buckets))
 
     def warmup_transfer(self) -> None:
@@ -1785,8 +1911,8 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
         # full grid warmed.
         if self.prefix_cache or int(self.prefill_chunk_tokens) > 0:
             for pb in self._prefill_buckets:
-                for sb in self._seq_buckets:
-                    self._extend_compiled(pb, sb)
+                for w in self._extend_buckets:
+                    self._extend_compiled(pb, w)
         object.__setattr__(self, "_warmed", True)
         return len(self._compiled_cache)
 
@@ -2020,7 +2146,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                     "emission's logits exist)."
                 )
             pb = self.prefill_bucket_for(n)
-            w = self.seq_bucket_for(max(suffixes))
+            w = self.extend_bucket_for(max(suffixes))
             tokens = np.zeros((pb, w), np.int32)
             lengths = np.zeros((pb,), np.int32)
             valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
@@ -2035,6 +2161,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             rows = self._pool.operand(slot_ids, pb)
             compiled = self._extend_compiled(pb, w, during_dispatch=True)
             self._kv_rows_written["rows"] += sum(suffixes)
+            self._note_extend_rows(lengths[:n], suffixes)
         first = self._dispatch(
             "prefill_warm_dispatch",
             (
@@ -2082,7 +2209,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                     "chunks must be skipped by the planner)."
                 )
             pb = self.prefill_bucket_for(n)
-            w = self.seq_bucket_for(max(lens))
+            w = self.extend_bucket_for(max(lens))
             tokens = np.zeros((pb, w), np.int32)
             lengths = np.zeros((pb,), np.int32)
             valid = np.zeros((pb,), np.int32)  # pad rows: 0 valid, dropped
@@ -2096,6 +2223,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
             rows = self._pool.operand(slot_ids, pb)
             compiled = self._extend_compiled(pb, w, during_dispatch=True)
             self._kv_rows_written["rows"] += sum(lens)
+            self._note_extend_rows(lengths[:n], lens)
         last = self._dispatch(
             "prefill_chunk_dispatch",
             (
@@ -2172,6 +2300,7 @@ PagePool.adopt_slot`). ``block`` must already be placed on this
                 table = table.copy()
             compiled = self._decode_compiled(during_dispatch=True)
             self._note_kv_blocks(lengths)
+            self._note_mla_rows(lengths)
             if _trace.enabled():
                 # The step reads and writes every slot's block of state;
                 # the slots that hold pages are the ones that decode.

@@ -101,6 +101,7 @@ def allocate_page_pool(
     slots: int = 0,
     slot_leaves: Sequence[Dict[str, Tuple[Tuple[int, ...], Any]]] = (),
     attention_layers: Sequence[bool] = (),
+    latent: int = 0,
 ) -> Tuple[dict, ...]:
     """Zero-initialized page-pool pytree: a per-layer tuple of
     ``{"k", "v"}`` pools ``[num_pages, head_shards, page_size,
@@ -123,7 +124,13 @@ def allocate_page_pool(
     model's own names; empty: none anywhere): the layer also gets
     ``[slots, *shape]`` zeros under each name, the fixed block a slot of
     a recurrent mixer. ``attention_layers`` (a layer; empty: all): a
-    layer without attention gets no ``k`` / ``v`` pages at all."""
+    layer without attention gets no ``k`` / ``v`` pages at all.
+
+    ``latent`` > 0 (latent attention, docs/DESIGN.md §29): an attention
+    layer keeps ONE leaf ``c`` ``[num_pages, 1, page_size, width]`` in
+    place of ``k`` and ``v``, a token's ``latent`` values padded to whole
+    128-lane registers (576 -> 640); ``num_heads`` / ``head_dim`` do not
+    enter, and ``quant`` must be ``"none"``."""
     import jax.numpy as jnp
 
     from zookeeper_tpu.ops import kv_row_width
@@ -136,13 +143,18 @@ def allocate_page_pool(
         )
     if quant not in ("none", "int8"):
         raise ValueError(f"quant={quant!r}: expected 'none' or 'int8'.")
+    if latent and (quant != "none" or head_shards != 1):
+        raise ValueError("a latent pool is unquantized and unsharded.")
     width = kv_row_width(num_heads, head_dim, head_shards)
     row_dtype = jnp.int8 if quant == "int8" else dtype
     attends = _attends(num_layers, attention_layers)
     layers = []
     for i, windowed in enumerate(window_layers):
         layer = {}
-        if attends[i]:
+        if attends[i] and latent:
+            shape = (num_pages, 1, page_size, kv_row_width(1, latent))
+            layer["c"] = jnp.zeros(shape, dtype)
+        elif attends[i]:
             pages = window_pages if windowed else num_pages
             shape = (pages, head_shards, page_size, width)
             layer["k"] = jnp.zeros(shape, row_dtype)
@@ -187,14 +199,20 @@ def page_pool_bytes(
     window_layers: Sequence[bool] = (),
     window_pages: int = 0,
     attention_layers: Sequence[bool] = (),
+    latent: int = 0,
 ) -> int:
     """Total HBM the pool occupies (k + v rows at their padded width,
     all layers that have attention, plus the scale arrays when
-    quantized) — the §20 capacity-planning number. Layer groups and
-    ``attention_layers`` as in :func:`allocate_page_pool`."""
+    quantized; a latent pool's one padded row a token) — the §20
+    capacity-planning number. Layer groups, ``attention_layers`` and
+    ``latent`` as in :func:`allocate_page_pool`."""
     from zookeeper_tpu.ops import kv_row_width
 
     attends = _attends(num_layers, attention_layers)
+    if latent:
+        return sum(attends) * num_pages * page_size * kv_row_width(
+            1, latent
+        ) * itemsize
     kinds = _window_layers(num_layers, window_layers, window_pages)
     windowed = sum(w and a for w, a in zip(kinds, attends))
     pages = (sum(attends) - windowed) * num_pages + windowed * window_pages
@@ -517,6 +535,7 @@ class PagePool:
         window: int = 0,
         window_pages: int = 0,
         slot_state: bool = False,
+        max_suffix: int = 0,
     ) -> None:
         if slot_state and prefix_cache:
             raise ValueError(
@@ -543,6 +562,10 @@ class PagePool:
             )
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
+        #: The longest unshared tail a warm admission may have (the
+        #: widest extend program; 0: no bound): a hit that leaves more is
+        #: admitted cold, its pages its own.
+        self.max_suffix = int(max_suffix)
         self.slots = int(slots)
         self.max_pages_per_slot = int(max_pages_per_slot)
         #: The runtime page-table operand: -1 = unallocated (dispatches
@@ -674,6 +697,8 @@ class PagePool:
             # recomputed so the warm dispatch produces the first
             # emission's logits (and the accounting stays honest).
             t = min(t, length - 1)
+            if self.max_suffix and length - t > self.max_suffix:
+                t = 0
             shared_tokens = t
             shared_pages = pages[: self.pages_for(t)]
         n_full_shared = shared_tokens // self.page_size
