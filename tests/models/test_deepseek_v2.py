@@ -169,7 +169,7 @@ def test_prefill_then_decode_through_the_latent_pool(built, flavor):
     np.testing.assert_allclose(logits[0], want[prompt - 1], atol=1e-4, rtol=1e-4)
     attn = (
         mla.mla_pool_decode_attention if flavor == "reference"
-        else mla.mla_paged_decode_attention
+        else mla.mla_cascade_decode_attention
     )
     for pos in range(prompt, 46):
         step = jnp.asarray([tokens[pos], 0], jnp.int32)
@@ -235,34 +235,116 @@ def test_absorbed_decode_is_the_expanded_attention():
     np.testing.assert_allclose(absorbed, expanded, atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize(
-    "dtype,block_pages",
-    [(jnp.float32, None), (jnp.float32, 3), (jnp.bfloat16, 3)],
-    ids=["f32-one-block", "f32-blocks-of-3", "bf16-blocks-of-3"],
-)
-def test_the_paged_decode_kernel_is_its_oracle(dtype, block_pages):
-    """The Pallas kernel (interpret mode) over scattered pages, a slot
-    with no rows but its first, one on a page boundary and one over
-    several blocks (of 3 pages: work items that span a slot): float32
-    1e-5; bfloat16 operands 2e-2 (the probabilities meet the values in
-    bfloat16)."""
+#: The decode kernel's cases over a pool of 130 pages of 8 rows: ``(dtype,
+#: block_pages, slots, members)``, each slot ``(prefix, length)`` — the
+#: physical pages its table starts with (a prefix is a list of pages, the
+#: same for every slot that names it), then pages of its own — and how
+#: many slots the cascade reads shared pages for.
+_A, _B = [3, 17, 29, 41, 53, 65], [7, 19, 31, 43, 55]
+KERNEL_CASES = {
+    # no sharing: a slot with no rows but its first, one on a page
+    # boundary, one over several blocks (of 3 pages: items across a slot)
+    "f32-one-block": (jnp.float32, None, [([], 0), ([], 63), ([], 311)], 0),
+    "f32-blocks-of-3": (jnp.float32, 3, [([], 0), ([], 63), ([], 311)], 0),
+    "bf16-blocks-of-3": (jnp.bfloat16, 3, [([], 0), ([], 63), ([], 311)], 0),
+    # one run of 6 pages, two whole blocks of 3, shared by every slot; the
+    # first slot's own rows start on the page after it
+    "shared-by-all": (jnp.float32, 3, [(_A, 48), (_A, 100), (_A, 311)], 3),
+    # a run of 5 pages ends inside its second block of 4
+    "run-ends-inside-a-block": (jnp.float32, 4, [(_B, 60), (_B, 45), (_B, 200)], 3),
+    # two groups on different prefixes, and a slot on neither
+    "two-groups": (
+        jnp.float32, 3, [(_A, 60), (_B, 70), (_A, 90), (_B, 45), ([], 30)], 4,
+    ),
+    # a member that matches the run's first 2 pages only
+    "a-member-leaves-early": (
+        jnp.float32, 3, [(_A, 100), (_A, 80), (_A[:2], 120)], 3,
+    ),
+    "bf16-two-groups": (
+        jnp.bfloat16, 3, [(_A, 60), (_B, 70), (_A, 90), (_B, 45), ([], 30)], 4,
+    ),
+    # 48 slots make the break-even 3 (float32): a group of two stays on
+    # the per-slot path
+    "two-below-the-break-even": (
+        jnp.float32, None, [(_A, 60), (_A, 70)] + [([], 5)] * 46, 0,
+    ),
+}
+
+
+def _kernel_case(slots, max_pages=40, page=8, pages=130):
+    """``(table, lengths)``: each slot's prefix pages, then pages of its
+    own drawn from the pages no prefix uses, -1 past its length."""
     rng = np.random.default_rng(1)
-    slots, heads, row, value_dim, page, max_pages, pages = 3, 16, 72, 64, 8, 40, 130
-    pool = jnp.asarray(rng.normal(size=(pages, 1, page, 128)), dtype)
-    table = jnp.asarray(
-        rng.permutation(pages)[: slots * max_pages].reshape(slots, max_pages), jnp.int32
+    free = [p for p in rng.permutation(pages) if p not in _A + _B]
+    table = np.full((len(slots), max_pages), -1, np.int32)
+    for i, (prefix, length) in enumerate(slots):
+        need = length // page + 1
+        mine = [free.pop() for _ in range(max(0, need - len(prefix)))]
+        table[i, :need] = (list(prefix) + mine)[:need]
+    return table, np.asarray([n for _, n in slots], np.int32)
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_paged_decode_kernel_is_its_oracle(case):
+    """The cascade kernel (interpret mode) over scattered pages, without
+    sharing and with runs shared by a group, against its oracle: float32
+    1e-5; bfloat16 operands 2e-2 (the probabilities meet the values in
+    bfloat16). The grouping engages for exactly the case's members."""
+    dtype, block_pages, slots, members = KERNEL_CASES[case]
+    rng = np.random.default_rng(1)
+    heads, row, value_dim, page = 16, 72, 64, 8
+    pool = jnp.asarray(rng.normal(size=(130, 1, page, 128)), dtype)
+    table, lengths = _kernel_case(slots)
+    q = jnp.asarray(rng.normal(size=(len(slots), heads, row)) * 0.5, dtype)
+    need = mla.mla_cascade_need(
+        len(slots), heads, 128, value_dim, jnp.dtype(dtype).itemsize
     )
-    lengths = jnp.asarray([0, 63, 311], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(slots, heads, row)) * 0.5, dtype)
+    assert need == (3 if len(slots) == 48 else 2)
+    _, shared, _ = mla.mla_shared_runs(table, lengths, page_size=page, need=need)
+    assert int((shared > 0).sum()) == members
+    table, lengths = jnp.asarray(table), jnp.asarray(lengths)
     want = mla.mla_pool_decode_attention(
         q, pool, table, lengths, scale=0.3, value_dim=value_dim
     )
-    got = mla.mla_paged_decode_attention(
+    got = mla.mla_cascade_decode_attention(
         q, pool, table, lengths, scale=0.3, value_dim=value_dim,
         interpret=True, block_pages=block_pages,
     )
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
+def test_the_cascade_groups_a_hand_written_table(xp):
+    """Pages of 4. Slots 0-2 start on page 10: slot 1 matches slot 0 for
+    3 pages, slot 2 for 2 (then its own); slots 3-4 start on page 40 and
+    match for the 2 whole pages slot 3 holds. Slot 5 holds no whole page,
+    slot 6 none at all. At a break-even of 2 the first group's run is 3
+    pages (2 members hold page 2) and the second's 2; at 3, 2 and none."""
+    table = xp.asarray([
+        [10, 11, 12, 13, 14], [10, 11, 12, 20, 21], [10, 11, 30, 31, -1],
+        [40, 41, 42, -1, -1], [40, 41, 42, 43, -1], [50, -1, -1, -1, -1],
+        [-1, -1, -1, -1, -1],
+    ], np.int32)
+    lengths = xp.asarray([19, 18, 13, 9, 11, 2, 0], np.int32)
+    leader, shared, run = mla.mla_shared_runs(table, lengths, page_size=4, need=2)
+    assert isinstance(shared, type(lengths))
+    np.testing.assert_array_equal(shared, [3, 3, 2, 2, 2, 0, 0])
+    np.testing.assert_array_equal(run, [3, 0, 0, 2, 0, 0, 0])
+    np.testing.assert_array_equal(np.asarray(leader)[:5], [0, 0, 0, 3, 3])
+    _, shared, run = mla.mla_shared_runs(table, lengths, page_size=4, need=3)
+    np.testing.assert_array_equal(shared, [2, 2, 2, 0, 0, 0, 0])
+    np.testing.assert_array_equal(run, [2, 0, 0, 0, 0, 0, 0])
+
+
+def test_the_cascade_break_even_at_deepseek_v2_lite():
+    """128 slots of 16 heads, rows of 640 lanes, values of 512, bfloat16:
+    2 x 2,048 rows x 1,152 operations a row against 640 x 2 bytes at the
+    v5e's 240.5 operations a byte: 15.3, so 16 members; the rows of fewer
+    slots need fewer, never below 2."""
+    assert mla.mla_cascade_need(128, 16, 640, 512, 2) == 16
+    assert mla.mla_cascade_need(3, 4, 128, 32, 4) == 2
+    assert mla.mla_head_rows(4, 4) == 8 and mla.mla_head_rows(4, 2) == 16
 
 
 @pytest.mark.parametrize("offsets", [(0, 0), (0, 25), (37, 3)])

@@ -19,6 +19,7 @@ import deepseek_v2_tiny as tiny  # noqa: E402
 
 from zookeeper_tpu.core import configure  # noqa: E402
 from zookeeper_tpu.observability import trace  # noqa: E402
+from zookeeper_tpu.ops import mla  # noqa: E402
 from zookeeper_tpu.serving.decode import DecodeEngine, DecodeScheduler  # noqa: E402
 
 pytestmark = pytest.mark.serving
@@ -141,7 +142,8 @@ def test_a_hit_past_the_widest_extend_is_admitted_cold(built):
 
 def test_the_counters_say_what_the_steps_read(built):
     """While tracing: ``decode_mla_rows`` a decode dispatch (rows read
-    over every layer; those on pages another live slot reads too) and
+    over every layer; those on pages another live slot reads too; those
+    the cascade reads through a shared item, and the rows it moves) and
     ``extend_mla_rows`` an extend, and the bind gauge of a latent row's
     bytes (40 values padded to 128 lanes, float32)."""
     from zookeeper_tpu.observability.registry import default_registry
@@ -158,6 +160,8 @@ def test_the_counters_say_what_the_steps_read(built):
         ]
         for stream in streams:
             stream.result(timeout=600)
+        # then one alone, on a prompt of its own
+        sched.generate(np.arange(100, 112, dtype=np.int32), max_new_tokens=3)
         records = tracer.snapshot()
     finally:
         trace.disable()
@@ -169,6 +173,22 @@ def test_the_counters_say_what_the_steps_read(built):
     # (24 rows) in 3 layers, and no other page is read twice
     assert {a["rows_shared"] for a in reads} <= {0, 2 * 24 * 3}
     assert 2 * 24 * 3 in {a["rows_shared"] for a in reads}
+    assert all(
+        0 <= a["rows_cascaded"] <= a["rows_shared"] <= a["rows_read"] for a in reads
+    )
+    # three slots make two members enough: where both hold the prefix the
+    # kernel reads its three pages once through a shared item, not twice;
+    # a step with one live slot reads every row of its own
+    assert mla.mla_cascade_need(SLOTS, 4, 128, 32, 4) == 2
+    for a in reads:
+        assert a["rows_cascaded"] == a["rows_shared"]
+        assert a["rows_fetched"] == a["rows_read"] - a["rows_shared"] // 2
+    both = [a for a in reads if a["rows_shared"]]
+    assert both and all(a["rows_fetched"] < a["rows_read"] for a in both)
+    alone = [a for a in reads if a["rows_shared"] == 0]
+    assert alone and all(
+        a["rows_fetched"] == a["rows_read"] and a["rows_cascaded"] == 0 for a in alone
+    )
     gauge = default_registry().gauge("zk_decode_latent_row_bytes", help="")
     assert gauge.value == 128 * 4
 

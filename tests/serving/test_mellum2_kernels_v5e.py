@@ -5,9 +5,9 @@ key/value heads of 128, bfloat16, 64 slots, pages of 16, a window of
 and without a window, the banded, grouped flash forward, and the experts'
 grouped matmul at a decode step's and a prefill's rows. No time comes from
 here. The kernels of the ``deepseek_v2_lite_6l`` cell at its widths
-too: the latent decode kernel (128 slots, pages of 64 rows of 640 lanes),
-the prefill/extend kernel (heads of 192 against values of 128) and the
-experts' grouped matmul at its prefill's rows, with the tiles
+too: the latent cascade decode kernel (128 slots, pages of 64 rows of 640
+lanes), the prefill/extend kernel (heads of 192 against values of 128) and
+the experts' grouped matmul at its prefill's rows, with the tiles
 ``ops.moe.grouped_matmul`` picks for them."""
 
 import jax
@@ -342,20 +342,22 @@ def test_held_expert_grouped_matmul_compiles(shaped, rows, tm, k, n):
 
 
 def test_latent_decode_kernel_compiles(shaped):
-    from zookeeper_tpu.ops.mla import _mla_paged_decode_call, mla_decode_block_pages
-
-    per_item = mla_decode_block_pages(64, 640, 2, 288)
+    """The cascade decode kernel: every slot's queries, maxima, sums and
+    latent averages resident in VMEM beside the two blocks of 25 pages."""
+    from zookeeper_tpu.ops.mla import mla_cascade_decode_attention
 
     def attend(q, pool, table, lengths):
-        return _mla_paged_decode_call(
+        return mla_cascade_decode_attention(
             q, pool, table, lengths, scale=0.1147, value_dim=512,
-            interpret=False, per_item=per_item,
+            interpret=False,
         )
 
-    jax.jit(attend).lower(
+    compiled = jax.jit(attend).lower(
         shaped((128, 16, 576), jnp.bfloat16), shaped((4608, 1, 64, 640), jnp.bfloat16),
         shaped((128, 288), jnp.int32), shaped((128,), jnp.int32),
     ).compile()
+    text = compiled.as_text()
+    assert "_mla_cascade_decode_call" in text and "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("w,n", [(1024, 18432), (17408, 17408)], ids=["extend", "prefill"])

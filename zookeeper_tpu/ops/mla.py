@@ -8,15 +8,17 @@ width]`` (``width`` the row rounded up to whole 128-lane registers, the
 rule of every pool row: ``ops.kv_row_width(1, row)``). Head ``h``'s key is
 ``[c W_uk_h, k_pe]`` and its value ``c W_uv_h``.
 
-- Decode, the ABSORBED form (:func:`mla_paged_decode_attention`, a Pallas
-  kernel, and :func:`mla_pool_decode_attention`, its ``jax.numpy``
+- Decode, the ABSORBED form (:func:`mla_cascade_decode_attention`, a
+  Pallas kernel, and :func:`mla_pool_decode_attention`, its ``jax.numpy``
   oracle): the query is absorbed into the latent, ``q_h = [q_nope_h
   W_uk_h^T, q_pe_h]`` (``row`` wide), so every head scores the latent rows
   themselves (``s = scale q_h . row``) and reads back their first
   ``kv_rank`` values (``o_lat_h = sum softmax(s) c``); the caller
   multiplies ``o_lat_h W_uv_h``. The kernel reads each page once for all
   heads: 16 query heads over one shared latent "head" are one matmul a
-  block.
+  block. And it reads a page that many slots hold at the same logical
+  position (a shared prefix) once for all of them, a cascade
+  (:func:`mla_shared_runs`; docs/DESIGN.md §30).
 - Prefill and extend, the EXPANDED form (:func:`mla_flash_attention`, a
   Pallas flash forward, and :func:`mla_attention_reference`): keys of
   ``nope + rope`` and values of ``v_dim`` a head, expanded from the latent
@@ -34,20 +36,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from zookeeper_tpu.ops.attention import (
-    _MASK_VALUE,
-    _flash_precision,
-    _pool_work_items,
+from zookeeper_tpu.observability.peaks import (
+    TPU_DATASHEET_BF16_TFLOPS,
+    TPU_DATASHEET_HBM_GBPS,
 )
+from zookeeper_tpu.ops.attention import _MASK_VALUE, _flash_precision
 from zookeeper_tpu.ops.blocks import _round_up, vmem_limit_bytes
-
-#: Bytes of latent rows one work item of the decode kernel fetches into a
-#: buffer: a work item costs a grid step and a wait whatever it holds, and
-#: a slot holds 16,000 rows and more (1,600 rows of 640 lanes in two
-#: buffers are 4 MB of VMEM).
-_MLA_BLOCK_BYTES = 2 * 2**20
 
 
 def mla_gathered_rows(pool, page_table, row: int):
@@ -64,7 +61,7 @@ def mla_pool_decode_attention(
     q, pool, page_table, lengths, *, scale: float, value_dim: int
 ):
     """The absorbed decode step over a latent page pool, in ``jax.numpy``
-    (the oracle of :func:`mla_paged_decode_attention`, same contract):
+    (the oracle of :func:`mla_cascade_decode_attention`, same contract):
     ``q [slots, heads, row]`` the absorbed queries, ``pool [num_pages, 1,
     page_size, width]``, ``page_table [slots, max_pages]``, ``lengths
     [slots]`` the rows already cached (rows ``0..lengths`` are attended:
@@ -85,31 +82,158 @@ def mla_pool_decode_attention(
     )
 
 
-def mla_decode_block_pages(page_size: int, width: int, itemsize: int, max_pages: int) -> int:
-    """Pages one work item of :func:`mla_paged_decode_attention` fetches:
-    as many as ``_MLA_BLOCK_BYTES`` hold, at least one, at most a slot's
-    table."""
-    return max(1, min(max_pages, _MLA_BLOCK_BYTES // (page_size * width * itemsize)))
+#: Bytes of latent rows one work item of the decode kernel fetches into a
+#: buffer: a work item costs a grid step and a wait whatever it holds, and
+#: a slot holds 16,000 rows and more (1,600 rows of 640 lanes in two
+#: buffers are 4 MB of VMEM).
+_MLA_BLOCK_BYTES = 2 * 2**20
+
+#: Query rows (slots x heads) a shared work item scores at a time, so that
+#: its scores stay a few MB of VMEM (on a v5e at DeepSeek-V2-Lite's shapes,
+#: 128, 256 and 512 rows took 0.833, 0.802 and 0.799 ms a layer).
+_CASCADE_CHUNK_ROWS = 256
+
+#: bf16 MXU operations a v5e does in the time its HBM moves one byte (197
+#: TFLOP/s over 819 GB/s, the datasheet's): the exchange rate of the
+#: cascade's break-even (:func:`mla_cascade_need`).
+_OPS_PER_HBM_BYTE = (
+    TPU_DATASHEET_BF16_TFLOPS["v5e"] * 1e12 / (TPU_DATASHEET_HBM_GBPS["v5e"] * 1e9)
+)
 
 
-def mla_paged_decode_attention(
+def mla_head_rows(heads: int, itemsize: int) -> int:
+    """Query rows a slot takes in the decode kernel: its heads rounded up
+    to whole sublane tiles of the pool's dtype (8 rows of float32, 16 of
+    bfloat16), so that a slot's rows start on a tile."""
+    return _round_up(heads, 32 // itemsize)
+
+
+def mla_cascade_need(
+    slots: int, heads: int, width: int, value_dim: int, itemsize: int
+) -> int:
+    """Slots that must hold a page at the same logical position before the
+    decode kernel reads it once for all of them: the cascade's break-even.
+
+    A member that reads the page alone moves ``page_size x width x
+    itemsize`` bytes at HBM's rate. A shared item moves them once and
+    scores them against every slot's query rows, ``2 x slots x rows x
+    page_size x (width + value_dim)`` operations at the MXU's (the most it
+    costs: it skips a block of slots that holds no member). The shared
+    read pays once ``need`` separate reads cost more: never fewer than 2;
+    16 of 128 slots at DeepSeek-V2-Lite's widths (16 heads, rows of 640,
+    values of 512, bfloat16)."""
+    rows = slots * mla_head_rows(heads, itemsize)
+    ratio = 2.0 * rows * (width + value_dim) / (width * itemsize * _OPS_PER_HBM_BYTE)
+    return max(2, int(ratio) + 1)
+
+
+def mla_shared_runs(page_table, lengths, *, page_size: int, need: int):
+    """The cascade's grouping rule, in numpy or ``jax.numpy`` as its
+    operands are (the decode kernel reads its pages by it, and the
+    engine's ``decode_mla_rows`` counter counts by it). ``page_table
+    [slots, max_pages]`` (-1: unallocated), ``lengths [slots]`` the rows
+    already cached (a slot attends ``lengths + 1``).
+
+    A slot HOLDS its logical page ``j`` when the page is allocated and
+    whole: a partial last page is never shared (copy-on-write gives each
+    writer its own). Slots that hold the same physical page as their
+    logical page 0 form a group, led by the lowest of them; a member's
+    MATCH is the run of leading pages it holds where its leader holds the
+    same physical page. The group's RUN is the leading pages that at least
+    ``need`` of its members match (:func:`mla_cascade_need`), and a member
+    reads its first ``min(match, run)`` pages through the group's shared
+    items, the rest alone.
+
+    Returns ``(leader, shared, run)``, each ``[slots]`` int32: the slot
+    whose table a slot's shared pages are read through, the leading pages
+    it reads there (0: none, and ``leader`` then means nothing), and for a
+    leader its group's run (0 for the others)."""
+    xp = np if isinstance(page_table, np.ndarray) else jnp
+    slots, max_pages = page_table.shape
+    whole = (xp.clip(lengths, 0, max_pages * page_size - 1) + 1) // page_size
+    j = xp.arange(max_pages)
+    held = (page_table >= 0) & (j[None, :] < whole[:, None])
+    first = xp.where(held[:, 0], page_table[:, 0], -1)
+    same = (first[:, None] == first[None, :]) & (first >= 0)[:, None]
+    leader = xp.argmax(same, axis=1)
+    matched = held & held[leader] & (page_table == page_table[leader])
+    match = xp.cumprod(matched.astype(xp.int32), axis=1).sum(axis=1)
+    # members[g, j]: the slots of the group led by g that match page j
+    groups = (leader[None, :] == xp.arange(slots)[:, None]).astype(xp.float32)
+    members = groups @ (match[:, None] > j[None, :]).astype(xp.float32)
+    run = (members >= need).sum(axis=1)
+    shared = xp.minimum(match, run[leader])
+    return leader.astype(xp.int32), shared.astype(xp.int32), run.astype(xp.int32)
+
+
+def _cascade_work_items(lens, leader, shared, run, page_size, per_item, steps):
+    """The cascade kernel's grid, on the device: first each group's SHARED
+    items (its leader, a block of ``per_item`` pages of its run), then
+    each slot's OWN items (a block of its pages from its first unshared
+    one through the new token's). ``(item_slot, item_first, item_live,
+    item_shared, total)``: whose table an item reads, its first logical
+    page, the pages it fetches, its kind, and how many items there are
+    (``steps``: the most blocks a slot's table holds, which sizes the
+    arrays: at most half the slots lead a run)."""
+    slots = lens.shape[0]
+    last = lens // page_size
+    counts = jnp.concatenate(
+        [-(-run // per_item), (last - shared) // per_item + 1]
+    )
+    ends = jnp.cumsum(counts)
+    item = jnp.arange((slots + slots // 2) * steps, dtype=jnp.int32)
+    owner = jnp.minimum(jnp.searchsorted(ends, item, side="right"), 2 * slots - 1)
+    is_shared = owner < slots
+    slot = jnp.where(is_shared, owner, owner - slots).astype(jnp.int32)
+    first = jnp.where(is_shared, 0, shared[slot]) + (
+        item - (ends - counts)[owner]
+    ) * per_item
+    live = jnp.minimum(
+        jnp.where(is_shared, run[slot], last[slot] + 1) - first, per_item
+    )
+    return (
+        slot, first.astype(jnp.int32), live.astype(jnp.int32),
+        is_shared.astype(jnp.int32), ends[-1:].astype(jnp.int32),
+    )
+
+
+def _cascade_chunk_slots(slots: int, head_rows: int) -> int:
+    """Slots a shared item scores at a time: the most that divides
+    ``slots`` and keeps their rows within ``_CASCADE_CHUNK_ROWS``."""
+    most = max(1, _CASCADE_CHUNK_ROWS // head_rows)
+    return max(d for d in range(1, min(slots, most) + 1) if slots % d == 0)
+
+
+def mla_cascade_decode_attention(
     q, pool, page_table, lengths, *, scale: float, value_dim: int,
     interpret: Optional[bool] = None, block_pages: Optional[int] = None,
 ):
     """Pallas TPU decode attention in absorbed form over a latent page
-    pool, the contract of :func:`mla_pool_decode_attention`.
+    pool, the contract of :func:`mla_pool_decode_attention`, reading a
+    shared prefix once a step for all the slots that hold it.
 
-    The pool stays whole in HBM and the kernel fetches its own pages, as
-    ``ops.pool_paged_decode_attention`` does: the grid is the LIVE (slot,
-    block of ``N`` consecutive logical pages) work items of the step
-    (``N`` by :func:`mla_decode_block_pages`, or ``block_pages``), one async copy a live page
-    from wherever the slot's table put it into a ``[2, N * page_size,
-    width]`` VMEM block, double-buffered across work items. A block is
-    then TWO matmuls for all heads: scores ``[heads, width] x [width,
-    keys]`` over the whole row (the query's padding lanes are zeros) and
-    ``[heads, keys] x [keys, value_dim]`` over the latent's values, with
-    the online softmax a row of heads (float32). Rows past a slot's length
-    are masked, whatever the block holds there."""
+    The pool stays whole in HBM and the kernel fetches its own pages, one
+    async copy a page into a ``[2, N * page_size, width]`` VMEM block,
+    double-buffered across work items (``N`` pages: as many as
+    ``_MLA_BLOCK_BYTES`` hold, or ``block_pages``). The grid is the step's
+    work items (:func:`mla_shared_runs` groups the slots):
+
+    - a SHARED item is a block of a group's run, fetched once and scored
+      against the query rows of every slot, ``_CASCADE_CHUNK_ROWS`` at a
+      time (a block of slots with no member is skipped), a row's keys
+      masked to its slot's shared pages (none for a slot off the group);
+    - an OWN item is a block of one slot's pages past its shared ones,
+      scored against its heads' rows, masked to its length.
+
+    Each is two matmuls, scores ``[rows, width] x [width, keys]`` over the
+    whole row (the query's padding lanes are zeros) and ``[rows, keys] x
+    [keys, value_dim]`` over the latent's values, with the online softmax
+    a row (float32) into a running max, sum and latent average of every
+    (slot, head) that stay in VMEM for the whole grid: the parts of a row
+    merge exactly, whatever their order, and the average is divided by the
+    sum once, after the last item. With no run a group shares past the
+    break-even (:func:`mla_cascade_need`), the grid is the slots' own
+    items alone: every slot reads every page of its own."""
     b, h, row = q.shape
     if pool.ndim != 4 or pool.shape[1] != 1 or pool.shape[3] < row:
         raise ValueError(
@@ -122,25 +246,29 @@ def mla_paged_decode_attention(
         )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    _, _, ps, width = pool.shape
+    itemsize = pool.dtype.itemsize
     if block_pages is None:
-        block_pages = mla_decode_block_pages(
-            pool.shape[2], pool.shape[3], pool.dtype.itemsize,
-            page_table.shape[1],
+        block_pages = max(
+            1, min(page_table.shape[1], _MLA_BLOCK_BYTES // (ps * width * itemsize))
         )
-    return _mla_paged_decode_call(
+    return _mla_cascade_decode_call(
         q, pool, page_table, lengths, scale=float(scale),
         value_dim=int(value_dim), interpret=bool(interpret),
         per_item=int(block_pages),
+        need=mla_cascade_need(b, h, width, value_dim, itemsize),
     )
 
 
 @partial(
-    jax.jit, static_argnames=("scale", "value_dim", "interpret", "per_item")
+    jax.jit,
+    static_argnames=("scale", "value_dim", "interpret", "per_item", "need"),
 )
-def _mla_paged_decode_call(
-    q, pool, page_table, lengths, *, scale, value_dim, interpret, per_item
+def _mla_cascade_decode_call(
+    q, pool, page_table, lengths, *, scale, value_dim, interpret, per_item,
+    need,
 ):
-    """The kernel behind :func:`mla_paged_decode_attention`, jitted so a
+    """The kernel behind :func:`mla_cascade_decode_attention`, jitted so a
     program traces and lowers it once, not once a layer."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -148,121 +276,148 @@ def _mla_paged_decode_call(
     b, h, row = q.shape
     num_pages, _, ps, width = pool.shape
     nm = page_table.shape[1]
-    qs = jnp.pad(q, ((0, 0), (0, 0), (0, width - row))).astype(pool.dtype)
+    hp = mla_head_rows(h, pool.dtype.itemsize)
+    rows = b * hp
+    qs = jnp.pad(q, ((0, 0), (0, hp - h), (0, width - row)))
+    qs = qs.astype(pool.dtype).reshape(rows, width)
     lens = jnp.clip(lengths.astype(jnp.int32), 0, nm * ps - 1)
+    leader, shared, run = mla_shared_runs(
+        page_table.astype(jnp.int32), lens, page_size=ps, need=need
+    )
     table = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
     keys = per_item * ps
     steps = -(-nm // per_item)
-    item_slot, item_step, total = _pool_work_items(
-        lens, ps, None, per_item, steps
-    )
+    items = _cascade_work_items(lens, leader, shared, run, ps, per_item, steps)
+    spc = _cascade_chunk_slots(b, hp)
+    chunk, chunks = spc * hp, b // spc
+    # a row's slot's group and shared rows; and, a leader by a block of
+    # slots, whether the block holds a member of its group
+    member = (leader[None, :] == jnp.arange(b)[:, None]) & (shared > 0)[None, :]
+    busy = member.reshape(b, chunks, spc).any(axis=2).astype(jnp.int32).reshape(-1)
+    row_leader = jnp.repeat(leader, hp)[:, None]
+    row_limit = jnp.repeat(shared * ps, hp)[:, None]
     precision = _flash_precision(pool.dtype)
     lanes = 128
 
-    def kernel(lens_ref, table_ref, slot_ref, step_ref, n_ref, q_ref,
-               pool_hbm, o_ref, m_ref, l_ref, acc_ref, buf, sem):
+    def kernel(lens_ref, table_ref, busy_ref, slot_ref, first_ref, live_ref,
+               kind_ref, n_ref, q_ref, leader_ref, limit_ref, pool_hbm,
+               o_ref, m_ref, l_ref, buf, sem):
         it = pl.program_id(0)
         into = lax.rem(it, 2)
+        s, first = slot_ref[it], first_ref[it]
 
-        def item(i):
-            # item i's slot, its block's first logical page, live pages
-            s = slot_ref[i]
-            first = step_ref[i] * per_item
-            return s, first, jnp.minimum(lens_ref[s] // ps - first + 1, per_item)
-
-        def copy(s, first, j, slot):
-            return pltpu.make_async_copy(
-                pool_hbm.at[table_ref[s, first + j], 0],
-                buf.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
-                sem.at[slot],
-            )
-
-        def each_page(block, slot, act):
-            s, first, live = block
-
+        def each_page(i, slot, act):
+            # item i's live pages, from wherever its table put them
             def page(j, carry):
-                act(copy(s, first, j, slot))
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[table_ref[slot_ref[i], first_ref[i] + j], 0],
+                    buf.at[slot, pl.ds(pl.multiple_of(j * ps, ps), ps)],
+                    sem.at[slot],
+                ))
                 return carry
 
-            lax.fori_loop(0, live, page, 0)
-
-        mine = s, first, live = item(it)
+            lax.fori_loop(0, live_ref[i], page, 0)
 
         @pl.when(it == 0)
         def _prime():
             # rows no copy lands on are masked, and 0 x garbage must stay 0
             buf[...] = jnp.zeros_like(buf)
-            each_page(mine, into, lambda c: c.start())
+            m_ref[...] = jnp.full_like(m_ref, _MASK_VALUE)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            o_ref[...] = jnp.zeros_like(o_ref)
+            each_page(it, into, lambda c: c.start())
 
         @pl.when(it + 1 < n_ref[0])
         def _prefetch():
-            each_page(item(it + 1), 1 - into, lambda c: c.start())
+            each_page(it + 1, 1 - into, lambda c: c.start())
 
-        each_page(mine, into, lambda c: c.wait())
+        each_page(it, into, lambda c: c.wait())
 
-        @pl.when(step_ref[it] == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, _MASK_VALUE)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def attend(r0, n, limit):
+            # rows r0 .. r0 + n against the block's keys below ``limit``;
+            # a row whose keys are all masked keeps its state, or (before
+            # its first key) gathers a sum its first key's rescale zeroes
+            kv = buf[into]  # [keys, width]
+            sc = lax.dot_general(
+                q_ref[pl.ds(r0, n), :], kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision,
+            ) * scale  # [n, keys]
+            ki = first * ps + lax.broadcasted_iota(jnp.int32, (n, keys), 1)
+            sc = jnp.where(ki < limit, sc, _MASK_VALUE)
+            m = m_ref[pl.ds(r0, n), :1]
+            m_new = jnp.maximum(m, sc.max(axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m - m_new)
+            m_ref[pl.ds(r0, n), :] = jnp.broadcast_to(m_new, (n, lanes))
+            l_ref[pl.ds(r0, n), :] = l_ref[pl.ds(r0, n), :] * corr + (
+                jnp.broadcast_to(p.sum(axis=1, keepdims=True), (n, lanes))
+            )
+            o_ref[pl.ds(r0, n), :] = o_ref[pl.ds(r0, n), :] * corr + (
+                lax.dot_general(
+                    p.astype(kv.dtype), kv[:, :value_dim],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=precision,
+                )
+            )
 
-        kv = buf[into]  # [keys, width]
-        sc = lax.dot_general(
-            q_ref[0], kv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        ) * scale  # [heads, keys]
-        ki = first * ps + lax.broadcasted_iota(jnp.int32, (h, keys), 1)
-        sc = jnp.where(ki <= lens_ref[s], sc, _MASK_VALUE)
-        m = m_ref[:, :1]
-        m_new = jnp.maximum(m, sc.max(axis=1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m - m_new)
-        m_ref[...] = jnp.broadcast_to(m_new, (h, lanes))
-        l_ref[...] = l_ref[...] * corr + jnp.broadcast_to(
-            p.sum(axis=1, keepdims=True), (h, lanes)
-        )
-        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-            p.astype(kv.dtype), kv[:, :value_dim], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        )
+        @pl.when(kind_ref[it] == 0)
+        def _own():
+            attend(pl.multiple_of(s * hp, hp), hp, lens_ref[s] + 1)
 
-        @pl.when(step_ref[it] == lens_ref[s] // ps // per_item)
+        @pl.when(kind_ref[it] == 1)
+        def _shared():
+            def block(c, carry):
+                @pl.when(busy_ref[s * chunks + c] > 0)
+                def _attend():
+                    # a row's keys: its slot's shared rows if the item's
+                    # group is its own, else none
+                    r0 = pl.multiple_of(c * chunk, chunk)
+                    limit = jnp.where(
+                        leader_ref[pl.ds(r0, chunk), :] == s,
+                        limit_ref[pl.ds(r0, chunk), :], 0,
+                    )
+                    attend(r0, chunk, limit)
+
+                return carry
+
+            lax.fori_loop(0, chunks, block, 0)
+
+        @pl.when(it == n_ref[0] - 1)
         def _finalize():
-            o_ref[0] = acc_ref[...] / l_ref[:, :1]
+            o_ref[...] = o_ref[...] / l_ref[:, :1]
 
-    def by_slot(it, lens_ref, table_ref, slot_ref, step_ref, n_ref):
-        return (slot_ref[it], 0, 0)
-
+    vmem = pltpu.MemorySpace.VMEM
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(total[0],),
-        in_specs=[
-            pl.BlockSpec((1, h, width), by_slot),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, h, value_dim), by_slot),
+        num_scalar_prefetch=8,
+        grid=(items[-1][0],),
+        in_specs=[pl.BlockSpec(memory_space=vmem)] * 3
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=vmem),
         scratch_shapes=[
-            pltpu.VMEM((h, lanes), jnp.float32),
-            pltpu.VMEM((h, lanes), jnp.float32),
-            pltpu.VMEM((h, value_dim), jnp.float32),
+            pltpu.VMEM((rows, lanes), jnp.float32),
+            pltpu.VMEM((rows, lanes), jnp.float32),
             pltpu.VMEM((2, keys, width), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     estimate = (
-        2 * keys * width * pool.dtype.itemsize  # the two blocks
-        + 3 * h * keys * 4  # scores, p and their mask
-        + 2 * h * width * 4 + h * value_dim * 4  # q, out, accumulator
+        rows * width * pool.dtype.itemsize  # the queries
+        + rows * value_dim * 4 + 4 * rows * lanes * 4  # average, max, sum,
+        # and a row's group and limit
+        + 2 * keys * width * pool.dtype.itemsize  # the two blocks
+        + 4 * chunk * keys * 4  # a chunk's scores, p and their mask
+        + 2 * chunk * value_dim * 4  # its product and rescaled average
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, value_dim), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rows, value_dim), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes(estimate)
         ),
         interpret=interpret,
-    )(lens, table, item_slot, item_step, total, qs, pool)
+    )(lens, table, busy, *items, qs, row_leader, row_limit, pool)
+    return out.reshape(b, hp, value_dim)[:, :h]
 
 
 def mla_attention_reference(q, k, v, offsets, *, scale: float):

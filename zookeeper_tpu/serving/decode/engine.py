@@ -713,7 +713,7 @@ DecodeScheduler`.
 
             if choice == "reference":
                 return "reference", mla.mla_pool_decode_attention
-            return "pallas_mla", mla.mla_paged_decode_attention
+            return "pallas_mla", mla.mla_cascade_decode_attention
         if choice == "reference":
             return "reference", ops.pool_decode_attention
         head_dim = self._head_dim()
@@ -1382,10 +1382,17 @@ PagePool`."""
         shared prefix, read again for every slot on it, which a kernel
         could read once for all). Not the page's refcount: the prefix
         cache holds a reference on every cached page, a slot's own body
-        among them."""
+        among them. And by the decode kernel's own grouping rule
+        (``ops.mla.mla_shared_runs``): ``rows_cascaded`` (the rows it
+        reads through a group's shared items, once for each member) and
+        ``rows_fetched`` (the rows it moves from HBM: each group's run
+        once, each slot's rows past it)."""
         if not (_trace.enabled() and self._latent):
             return
-        pool = self._pool
+        from zookeeper_tpu import ops
+        from zookeeper_tpu.ops import mla
+
+        pool, module = self._pool, self._module
         live = pool.counts > 0
         n = np.where(live, np.asarray(lengths, np.int64) + 1, 0)
         starts = np.arange(pool.max_pages_per_slot) * pool.page_size
@@ -1393,12 +1400,25 @@ PagePool`."""
         table = np.where(on_page > 0, pool.table, -1)
         holders = np.bincount(table[table >= 0], minlength=pool.num_pages)
         shared = (table >= 0) & (holders[np.clip(table, 0, None)] > 1)
+        _, cascaded, run = mla.mla_shared_runs(
+            table, np.maximum(n - 1, 0), page_size=pool.page_size,
+            need=mla.mla_cascade_need(
+                int(self.slots), int(module.num_heads),
+                ops.kv_row_width(1, self._latent), int(module.mla.kv_rank),
+                np.dtype(module.dtype).itemsize,
+            ),
+        )
+        cascaded = cascaded.astype(np.int64) * pool.page_size
         layers = int(sum(self._attention_layers))
         _trace.event(
             "decode_mla_rows",
             attrs={
                 "rows_read": int(n.sum()) * layers,
                 "rows_shared": int((on_page * shared).sum()) * layers,
+                "rows_cascaded": int(cascaded.sum()) * layers,
+                "rows_fetched": int(
+                    run.sum() * pool.page_size + (n - cascaded).sum()
+                ) * layers,
             },
         )
 
