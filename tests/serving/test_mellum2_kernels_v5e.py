@@ -8,7 +8,11 @@ here. The kernels of the ``deepseek_v2_lite_6l`` cell at its widths
 too: the latent cascade decode kernel (128 slots, pages of 64 rows of 640
 lanes), the prefill/extend kernel (heads of 192 against values of 128) and
 the experts' grouped matmul at its prefill's rows, with the tiles
-``ops.moe.grouped_matmul`` picks for them."""
+``ops.moe.grouped_matmul`` picks for them. And the ``longcat_flash_ep32_4l``
+cell's: the cascade decode kernel at 64 heads (every slot's 64 query rows
+resident), the prefill kernel at 64 heads and a 4,096-token prompt, and
+the 16 held experts' 6144 x 2048 blocks in column tiles at a decode
+step's and a prefill's rows."""
 
 import jax
 import jax.numpy as jnp
@@ -390,4 +394,57 @@ def test_deepseek_expert_matmul_compiles_at_its_prefill_rows(shaped, k, n, out):
     ).lower(
         shaped((rows, k), jnp.bfloat16), shaped((k, 64 * n), jnp.bfloat16),
         shaped((64,), jnp.int32),
+    ).compile()
+
+
+def test_latent_decode_kernel_compiles_at_64_heads(shaped):
+    """LongCat-Flash: 128 slots of 64 heads, pages of 64 rows, 96 a slot."""
+    from zookeeper_tpu.ops.mla import mla_cascade_decode_attention
+
+    def attend(q, pool, table, lengths):
+        return mla_cascade_decode_attention(
+            q, pool, table, lengths, scale=192 ** -0.5, value_dim=512,
+            interpret=False,
+        )
+
+    compiled = jax.jit(attend).lower(
+        shaped((128, 64, 576), jnp.bfloat16), shaped((4608, 1, 64, 640), jnp.bfloat16),
+        shaped((128, 96), jnp.int32), shaped((128,), jnp.int32),
+    ).compile()
+    assert "_mla_cascade_decode_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("w", [768, 4096])
+def test_latent_prefill_kernel_compiles_at_64_heads(shaped, w):
+    from zookeeper_tpu.ops.mla import _mla_extend_call
+
+    def attend(q, k, v, offsets):
+        return _mla_extend_call(
+            q, k, v, offsets, scale=192 ** -0.5, block_q=256, block_k=512,
+            interpret=False,
+        )
+
+    jax.jit(attend).lower(
+        shaped((1, w, 64, 192), jnp.bfloat16), shaped((1, w, 64, 192), jnp.bfloat16),
+        shaped((1, w, 64, 128), jnp.bfloat16), shaped((1,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("rows", [128 * 12, 4096 * 12], ids=["decode", "prefill"])
+@pytest.mark.parametrize(
+    "k,n,out", [(6144, 2048, jnp.float32), (2048, 6144, jnp.bfloat16)],
+    ids=["gate", "down"],
+)
+def test_longcat_held_expert_matmul_compiles(shaped, rows, k, n, out):
+    """24 MiB blocks: 4 column tiles of 512 (gate, up) and of 1,536
+    (down)."""
+    from zookeeper_tpu.ops.moe import _gmm, _gmm_tiles
+
+    tm, tn = _gmm_tiles(rows, k, n, 2, out)
+    assert n // tn == 4
+    jax.jit(
+        lambda lhs, rhs, sizes: _gmm(lhs, rhs, sizes, out_dtype=out, tm=tm, tn=tn)
+    ).lower(
+        shaped((rows, k), jnp.bfloat16), shaped((k, 16 * n), jnp.bfloat16),
+        shaped((16,), jnp.int32),
     ).compile()

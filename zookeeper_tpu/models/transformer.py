@@ -149,20 +149,27 @@ class KDASpec:
 
 @dataclasses.dataclass(frozen=True)
 class MLASpec:
-    """Multi-head latent attention's sizes (DeepSeek-V2 without a query
-    low-rank; ``ops/mla.py``), as :class:`TransformerLM` hands them to
-    every block: a token's keys and values come from one ``kv_rank``-wide
-    latent (normed) through ``mla_kv_b``, each head's key is ``nope_dim``
-    of it beside a ``rope_dim``-wide rotary key that the heads share, its
-    value ``v_dim`` wide; ``scale`` is the softmax's, YaRN's factor
-    included (:func:`yarn_score_factor`). The cache keeps ``[c, k_pe]``,
-    :attr:`row` values a token a layer, and no K/V a head."""
+    """Multi-head latent attention's sizes (DeepSeek-V2, LongCat-Flash;
+    ``ops/mla.py``), as :class:`TransformerLM` hands them to every block:
+    a token's keys and values come from one ``kv_rank``-wide latent
+    (normed, then times ``kv_scale``) through ``mla_kv_b``, each head's
+    key is ``nope_dim`` of it beside a ``rope_dim``-wide rotary key that
+    the heads share, its value ``v_dim`` wide; ``scale`` is the
+    softmax's, YaRN's factor included (:func:`yarn_score_factor`). The
+    query is one projection (``q_rank`` 0, DeepSeek-V2-Lite) or a
+    low-rank pair with a norm between (``mla_q_a``, ``mla_q_norm``,
+    ``mla_q_b``), the whole query then times ``q_scale``. 1.0 traces no
+    multiply. The cache keeps ``[c, k_pe]``, :attr:`row` values a token a
+    layer, and no K/V a head."""
 
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
     scale: float
+    q_rank: int = 0
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @property
     def qk_dim(self) -> int:
@@ -522,8 +529,14 @@ class _Block(nn.Module):
     routed ones), ``mla`` (latent attention in place of q/k/v heads, one
     latent row a token: :meth:`_mla_expanded` for prefill and extend,
     :meth:`_mla_absorbed` for decode; ``ops/mla.py``, docs/DESIGN.md
-    §29), ``renormalize_experts``, ``multipliers`` (1.0: no
-    multiply is traced) and ``param_dtype``. The three traced methods
+    §29), ``renormalize_experts``, the router's ``router_bias`` (a
+    correction bias that picks and does not weigh), ``routed_scale`` and
+    ``zero_experts`` (identity experts past the real ones), ``moe_branch``
+    and ``moe_join`` (LongCat-Flash's shortcut MoE: the block that
+    branches computes the experts on its MLP's normed input beside its
+    dense MLP and hands their sum on beside the stream, the block that
+    joins adds it at its end; docs/DESIGN.md §31), ``multipliers`` (1.0:
+    no multiply is traced) and ``param_dtype``. The three traced methods
     share ONE projection-and-positions helper (:meth:`_qkv`) and one
     attention keyword set (:meth:`_attention_kwargs`).
 
@@ -564,6 +577,11 @@ class _Block(nn.Module):
     shared_expert_dim: int = 0  # 0: no shared expert
     mla: Optional[MLASpec] = None  # a spec: latent attention, one row a token
     renormalize_experts: bool = True  # False: the top-k softmax weights as they are
+    router_bias: bool = False  # a correction bias in the experts' choice
+    routed_scale: float = 1.0  # on the routed weights
+    zero_experts: int = 0  # identity experts past num_experts
+    moe_branch: bool = False  # the experts beside the dense MLP, carried on
+    moe_join: bool = False  # the carried experts' sum added at the end
 
     @property
     def kv_heads(self) -> int:
@@ -589,7 +607,14 @@ class _Block(nn.Module):
             # the shared rotary key, the latent's norm, and the latent's
             # expansion to each head's key (nope) then value: a raw
             # kernel, since decode multiplies it in absorbed form.
-            self.wmla_q = dense(self.num_heads * mla.qk_dim, name="mla_q")
+            if mla.q_rank:
+                self.wmla_q_a = dense(mla.q_rank, name="mla_q_a")
+                self.mla_q_norm = norm(name="mla_q_norm")
+                self.wmla_q_b = dense(
+                    self.num_heads * mla.qk_dim, name="mla_q_b"
+                )
+            else:
+                self.wmla_q = dense(self.num_heads * mla.qk_dim, name="mla_q")
             self.wmla_kv_a = dense(mla.row, name="mla_kv_a")
             self.mla_kv_norm = norm(name="mla_kv_norm")
             self.mla_kv_b = _param(
@@ -632,16 +657,22 @@ class _Block(nn.Module):
                     _param(self, name, init, shape, self.param_dtype),
                 )
         self.ln2 = norm(name="RMSNorm_1")
-        if self.mlp == "moe":
+        if self.mlp == "moe" or self.moe_branch:
             f = self.expert_dim
             held = self.held_experts[1] if self.held_experts else self.num_experts
             # The experts' matrices lie side by side, expert e the column
             # block e (ops/moe.py): each leaf a plain [fan_in, out] kernel.
-            # The router scores every expert; the three expert leaves hold
-            # the chip's share of them.
+            # The router scores every expert, the identity ones last; the
+            # three expert leaves hold the chip's share of the real ones.
             kernel = nn.initializers.lecun_normal()
+            width = self.num_experts + self.zero_experts
+            if self.router_bias:
+                self.router_bias_ = _param(
+                    self, "router_bias", nn.initializers.zeros, (width,),
+                    self.param_dtype,
+                )
             for name, shape in (
-                ("router", (d, self.num_experts)),
+                ("router", (d, width)),
                 ("experts_gate", (d, held * f)),
                 ("experts_up", (d, held * f)),
                 ("experts_down", (f, held * d)),
@@ -656,7 +687,7 @@ class _Block(nn.Module):
                 )
                 self.wshared_up = dense(self.shared_expert_dim, name="shared_up")
                 self.wshared_down = dense(d, name="shared_down")
-        else:
+        if self.mlp != "moe":
             width = self.mlp_ratio * d
             if self.mlp == "swiglu":
                 width = self.mlp_dim
@@ -829,19 +860,24 @@ class _Block(nn.Module):
 
     def _mla_latent(self, h, positions):
         """A latent layer's cached rows for the normed ``h [b, s, d]`` at
-        ``positions [b, s]``: ``[RMSNorm(c), RoPE(k_pe)]``, ``[b, s,
-        row]`` in the compute dtype."""
+        ``positions [b, s]``: ``[kv_scale RMSNorm(c), RoPE(k_pe)]``,
+        ``[b, s, row]`` in the compute dtype."""
         mla = self.mla
         c, k_pe = jnp.split(self.wmla_kv_a(h), [mla.kv_rank], axis=-1)
         k_pe = self._mla_rotate(k_pe[:, :, None, :], positions)[:, :, 0]
-        return jnp.concatenate([self.mla_kv_norm(c), k_pe], axis=-1)
+        c = _scaled(self.mla_kv_norm(c), mla.kv_scale)
+        return jnp.concatenate([c, k_pe], axis=-1)
 
     def _mla_query(self, h, positions):
         """``(q_nope [b, s, heads, nope_dim], q_pe [b, s, heads,
         rope_dim])``, the rotary part rotated."""
         b, s, _ = h.shape
         mla = self.mla
-        q = self.wmla_q(h).reshape(b, s, self.num_heads, mla.qk_dim)
+        if mla.q_rank:
+            q = self.wmla_q_b(self.mla_q_norm(self.wmla_q_a(h)))
+        else:
+            q = self.wmla_q(h)
+        q = _scaled(q, mla.q_scale).reshape(b, s, self.num_heads, mla.qk_dim)
         q_nope, q_pe = jnp.split(q, [mla.nope_dim], axis=-1)
         return q_nope, self._mla_rotate(q_pe, positions)
 
@@ -913,31 +949,58 @@ class _Block(nn.Module):
         x = x + _scaled(self.wproj(o), self.multipliers.attention_out)
         return x if mixed is None else x + mixed
 
-    def _mlp(self, x):
-        h = self.ln2(x)
-        if self.mlp == "moe":
-            b, s, d = h.shape
-            routed, load = sparse_moe(
-                h.reshape(b * s, d),
-                self.router,
-                self.experts_gate, self.experts_up, self.experts_down,
-                k=self.experts_per_token,
-                held=tuple(self.held_experts) or None,
-                renormalize=self.renormalize_experts,
+    def _experts(self, h):
+        """The experts' sum for the normed ``h [b, s, d]``: the routed
+        ones (the chip's share; the identity ones), and a shared one
+        where the block has it."""
+        b, s, d = h.shape
+        routed, load, *zero = sparse_moe(
+            h.reshape(b * s, d),
+            self.router,
+            self.experts_gate, self.experts_up, self.experts_down,
+            k=self.experts_per_token,
+            held=tuple(self.held_experts) or None,
+            renormalize=self.renormalize_experts,
+            bias=self.router_bias_ if self.router_bias else None,
+            scale=self.routed_scale,
+            zero_experts=self.zero_experts,
+        )
+        routed = routed.reshape(b, s, d)
+        if self.shared_expert_dim:
+            routed = routed + self.wshared_down(
+                self.wshared_up(h) * nn.silu(self.wshared_gate(h))
             )
-            routed = routed.reshape(b, s, d)
-            if self.shared_expert_dim:
-                routed = routed + self.wshared_down(
-                    self.wshared_up(h) * nn.silu(self.wshared_gate(h))
-                )
-            h = routed
-            # Rows each expert took, for whoever asks (``mutable=
-            # ["moe_load"]``: the decode engine while tracing).
+        # Rows each expert took, and the identity choices where the
+        # router has them, for whoever asks (``mutable=["moe_load"]``:
+        # the decode engine while tracing).
+        for name, count in zip(("tokens_per_expert", "zero_choices"), [load] + zero):
             self.sow(
-                "moe_load", "tokens_per_expert", load,
-                init_fn=lambda: jnp.zeros_like(load),
+                "moe_load", name, count,
+                init_fn=partial(jnp.zeros_like, count),
                 reduce_fn=lambda a, b: a + b,
             )
+        return routed
+
+    def _split_carry(self, x):
+        """``(stream, carried)`` of what the block is handed. A joining
+        block (``moe_join``) is handed ``(stream, m)`` by the branching
+        block before it (``moe_branch``), ``m`` the experts' sum it
+        carries; every other block the stream alone. That pair is the
+        only place the stream is not an array."""
+        if self.moe_join:
+            stream, carried = x
+            return stream, carried
+        return x, None
+
+    def _mlp(self, x, carried=None):
+        """The MLP half: ``x`` plus the MLP of ``RMSNorm_1(x)``. A
+        branching block returns ``(out, m)``, ``m`` the experts' sum on
+        the same normed input; a joining block adds the ``carried`` one
+        (:meth:`_split_carry`)."""
+        h = self.ln2(x)
+        branch = self._experts(h) if self.moe_branch else None
+        if self.mlp == "moe":
+            h = self._experts(h)
         elif self.mlp == "swiglu":
             on_gate, on_out = self.multipliers.mlp or (1.0, 1.0)
             gate = _scaled(self.wgate(h), on_gate)
@@ -954,9 +1017,11 @@ class _Block(nn.Module):
         # against (parallel/sharding.py). No-op outside a mesh scope;
         # see ``_auto_pin_activations`` for when the pin is skipped.
         out = x + h
+        if self.moe_join:
+            out = out + carried
         if self.pin_activations:
             out = constrain_batch_sharded(out)
-        return out
+        return out if branch is None else (out, branch)
 
     def __call__(
         self, x, training: bool, return_kv: bool = False, lengths=None
@@ -966,11 +1031,12 @@ class _Block(nn.Module):
         mixer ``(k, v, ssm, conv)`` and for a ``kda`` block ``(kda,
         kda_conv)`` alone, a mixer's state at each sequence's own length
         (``lengths [b]``; None: the whole ``s``)."""
+        x, shortcut = self._split_carry(x)
         b, s, _ = x.shape
         h = self.ln1(x)
         if self.kda is not None:
             mixed, state = self._kda(h, lengths=lengths)
-            out = self._mlp(x + mixed)
+            out = self._mlp(x + mixed, shortcut)
             return (out, state) if return_kv else out
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         if self.mla is not None:
@@ -979,7 +1045,7 @@ class _Block(nn.Module):
             o = self._mla_expanded(
                 h, positions, rows, jnp.zeros((b,), jnp.int32)
             )
-            out = self._mlp(self._out(x, o, h=h))
+            out = self._mlp(self._out(x, o, h=h), shortcut)
             return (out, (rows[:, :, None, :],)) if return_kv else out
         q, kh, vh = self._qkv(h, positions)
         attn = _resolve_attention(self.attention)
@@ -989,7 +1055,7 @@ class _Block(nn.Module):
         if self.ssm is not None:
             mixed, slot_state = self._ssm(h, lengths=lengths)
             state += slot_state
-        out = self._mlp(self._out(x, o, mixed, h))
+        out = self._mlp(self._out(x, o, mixed, h), shortcut)
         if return_kv:
             return out, state
         return out
@@ -1013,6 +1079,7 @@ class _Block(nn.Module):
         only ever taken by slots whose output is discarded. Same
         projections and norms as ``__call__``: the weights are
         literally the same submodules."""
+        x, shortcut = self._split_carry(x)
         h = self.ln1(x)
         if self.kda is not None:
             # No K/V rows: the layer's whole state is its block a slot,
@@ -1025,7 +1092,7 @@ class _Block(nn.Module):
                 name: leaf.astype(layer[name].dtype)
                 for name, leaf in zip(names, carried)
             }
-            return self._mlp(x + mixed), layer
+            return self._mlp(x + mixed, shortcut), layer
         page_table = layer_page_table(page_table, bool(self.window))
         pool = layer["c" if self.mla is not None else "k"]
         num_pages, ps = pool.shape[0], pool.shape[2]
@@ -1044,7 +1111,7 @@ class _Block(nn.Module):
                 h, lengths[:, None], layer["c"], page_table, lengths,
                 attention_override or mla_pool_decode_attention,
             )
-            return self._mlp(self._out(x, o, h=h)), layer
+            return self._mlp(self._out(x, o, h=h), shortcut), layer
         q, k, v = self._qkv(h, lengths[:, None])
         layer = _pool_write_rows(
             layer, {"k": k[:, 0], "v": v[:, 0]}, page, off
@@ -1067,7 +1134,7 @@ class _Block(nn.Module):
             layer = dict(layer)
             for name, leaf in zip(names, carried):
                 layer[name] = leaf.astype(layer[name].dtype)
-        return self._mlp(self._out(x, o, mixed, h)), layer
+        return self._mlp(self._out(x, o, mixed, h), shortcut), layer
 
     def decode_verify_paged(
         self, x, layer, page_table, lengths, valid=None,
@@ -1097,6 +1164,7 @@ class _Block(nn.Module):
                 "and cannot roll a recurrence's state back."
             )
         page_table = layer_page_table(page_table, bool(self.window))
+        x, shortcut = self._split_carry(x)
         w = x.shape[1]
         pool = layer["c" if self.mla is not None else "k"]
         num_pages, ps = pool.shape[0], pool.shape[2]
@@ -1118,7 +1186,7 @@ class _Block(nn.Module):
             )
             rows = mla_gathered_rows(layer["c"], page_table, self.mla.row)
             o = self._mla_expanded(h, pos, rows, lengths)
-            return self._mlp(self._out(x, o, h=h)), layer
+            return self._mlp(self._out(x, o, h=h), shortcut), layer
         q, k, v = self._qkv(h, pos)
         layer = _pool_write_rows(layer, {"k": k, "v": v}, page, off)
         k_scale, v_scale = _pool_scales(layer)
@@ -1128,7 +1196,7 @@ class _Block(nn.Module):
             k_scale=k_scale, v_scale=v_scale,
             **self._attention_kwargs(pool=True),
         )
-        return self._mlp(self._out(x, o, h=h)), layer
+        return self._mlp(self._out(x, o, h=h), shortcut), layer
 
 
 #: What :meth:`TransformerLMModule.serving_leaf` names, below
@@ -1141,7 +1209,7 @@ _BLOCK_MATMUL_LEAVES = frozenset(
             "qkv", "proj", "up", "down", "gate", "ssm_in", "ssm_out",
             "attn_gate", "shared_gate", "shared_up", "shared_down",
             "kda_qkv", "kda_f1", "kda_f2", "kda_g1", "kda_g2", "kda_beta",
-            "kda_out", "mla_q", "mla_kv_a",
+            "kda_out", "mla_q", "mla_kv_a", "mla_q_a", "mla_q_b",
         )
     ]
     + [
@@ -1254,6 +1322,16 @@ class TransformerLMModule(nn.Module):
     mla: Optional[MLASpec] = None
     dense_layers: int = 0
     moe_renormalize: bool = True
+    #: The router's correction bias, its scale and its identity experts
+    #: (``_Block``'s ``router_bias``, ``routed_scale``, ``zero_experts``);
+    #: layers in pairs whose first branches the experts off its MLP's
+    #: normed input and whose second joins them at its end (LongCat-Flash's
+    #: shortcut MoE: every block's MLP is then the dense ``mlp_dim``
+    #: SwiGLU).
+    moe_router_bias: bool = False
+    moe_scale: float = 1.0
+    moe_zero_experts: int = 0
+    moe_shortcut: bool = False
 
     def setup(self):
         # A serving tree may hold the tables :meth:`_embed` gathers from
@@ -1315,7 +1393,10 @@ class TransformerLMModule(nn.Module):
                 rope_theta=self.rope_theta if rope else 0.0,
                 rope_yarn=() if windowed else self.rope_yarn,
                 window=self.window if windowed else 0,
-                mlp="swiglu" if i < self.dense_layers else self.mlp,
+                mlp=(
+                    "swiglu" if i < self.dense_layers or self.moe_shortcut
+                    else self.mlp
+                ),
                 num_experts=self.num_experts,
                 experts_per_token=self.experts_per_token,
                 expert_dim=self.expert_dim,
@@ -1330,6 +1411,11 @@ class TransformerLMModule(nn.Module):
                 shared_expert_dim=self.shared_expert_dim,
                 mla=self.mla,
                 renormalize_experts=self.moe_renormalize,
+                router_bias=self.moe_router_bias,
+                routed_scale=self.moe_scale,
+                zero_experts=self.moe_zero_experts,
+                moe_branch=self.moe_shortcut and i % 2 == 0,
+                moe_join=self.moe_shortcut and i % 2 == 1,
                 name=f"block{i}",
             )
             for i, (windowed, attends) in enumerate(
@@ -1800,8 +1886,8 @@ class TransformerLM(Model):
     ssm_out_multiplier: float = Field(1.0)
     ssm_multipliers: Sequence[float] = Field(())
     mlp_multipliers: Sequence[float] = Field(())
-    #: Multi-head latent attention in every layer (DeepSeek-V2, no query
-    #: low-rank; ``MLASpec``): a ``mla_kv_rank``-wide latent a token,
+    #: Multi-head latent attention in every layer (DeepSeek-V2,
+    #: LongCat-Flash; ``MLASpec``): a ``mla_kv_rank``-wide latent a token,
     #: heads of ``mla_nope_dim`` + ``mla_rope_dim`` query/key and
     #: ``mla_v_dim`` value, the rotary key shared by the heads. 0: none.
     mla_kv_rank: int = Field(0)
@@ -1821,6 +1907,27 @@ class TransformerLM(Model):
     #: Whether the top-k router weights are renormalised to sum 1 (a
     #: config's ``norm_topk_prob``); False uses the softmax's own.
     moe_renormalize: bool = Field(True)
+    #: The query's low rank (a config's ``q_lora_rank``; 0: one
+    #: projection), with an RMSNorm between its two halves.
+    mla_q_rank: int = Field(0)
+    #: LongCat-Flash's ``mla_scale_q_lora`` and ``mla_scale_kv_lora``:
+    #: the whole query times ``sqrt(d_model / mla_q_rank)`` and the normed
+    #: latent times ``sqrt(d_model / mla_kv_rank)``.
+    mla_lora_scales: bool = Field(False)
+    #: A correction bias a router column that joins the scores in the
+    #: choice of the top-k and not in their weights (DeepSeek-V3's and
+    #: LongCat-Flash's ``e_score_correction_bias``).
+    moe_router_bias: bool = Field(False)
+    #: What the routed weights are multiplied by (``routed_scaling_factor``).
+    moe_scale: float = Field(1.0)
+    #: Identity ("zero-computation") experts past ``num_experts``: router
+    #: columns whose expert's output is its input (``ops/moe.py``).
+    moe_zero_experts: int = Field(0)
+    #: LongCat-Flash's double layer: layers in pairs, each with its dense
+    #: SwiGLU of ``mlp_dim``; the first of a pair also routes its MLP's
+    #: normed input through the experts and the second adds their sum at
+    #: its end. ``num_layers`` counts the sub-layers (twice the model's).
+    moe_shortcut: bool = Field(False)
 
     def set_attention_override(self, fn) -> None:
         """The partitioner injection seam (``Partitioner.prepare_model``):
@@ -1947,6 +2054,25 @@ class TransformerLM(Model):
                     "positions='rope', every layer full (no layer_types), no "
                     "state-space mixer and attention 'flash' or 'dense'."
                 )
+        if self.mla_q_rank and not self.mla_kv_rank:
+            raise ValueError("mla_q_rank needs latent attention (mla_kv_rank).")
+        if self.mla_lora_scales and not self.mla_q_rank:
+            raise ValueError("mla_lora_scales needs mla_q_rank.")
+        if (
+            self.moe_router_bias or self.moe_zero_experts or self.moe_shortcut
+            or self.moe_scale != 1.0
+        ) and self.mlp != "moe":
+            raise ValueError(
+                "moe_router_bias, moe_scale, moe_zero_experts and "
+                "moe_shortcut need mlp='moe'."
+            )
+        if self.moe_shortcut and (
+            self.num_layers % 2 or self.dense_layers or self.mlp_dim < 1
+        ):
+            raise ValueError(
+                "moe_shortcut pairs the layers: it needs an even num_layers "
+                f"({self.num_layers}), no dense_layers and mlp_dim >= 1."
+            )
         if self.lm_head_multiplier != 1.0 and self.tie_embeddings:
             raise ValueError(
                 "lm_head_multiplier needs a head of its own "
@@ -1999,12 +2125,16 @@ class TransformerLM(Model):
                 )
         if self.mla_kv_rank:
             qk = self.mla_nope_dim + self.mla_rope_dim
+            lora = self.mla_lora_scales
             mla = MLASpec(
                 kv_rank=int(self.mla_kv_rank),
                 nope_dim=int(self.mla_nope_dim),
                 rope_dim=int(self.mla_rope_dim),
                 v_dim=int(self.mla_v_dim),
                 scale=qk ** -0.5 * yarn_score_factor(rope_yarn),
+                q_rank=int(self.mla_q_rank),
+                q_scale=(self.d_model / self.mla_q_rank) ** 0.5 if lora else 1.0,
+                kv_scale=(self.d_model / self.mla_kv_rank) ** 0.5 if lora else 1.0,
             )
         (seq_len,) = input_shape
         if self.max_seq_len == -1:
@@ -2077,6 +2207,10 @@ class TransformerLM(Model):
             mla=mla,
             dense_layers=int(self.dense_layers),
             moe_renormalize=bool(self.moe_renormalize),
+            moe_router_bias=bool(self.moe_router_bias),
+            moe_scale=float(self.moe_scale),
+            moe_zero_experts=int(self.moe_zero_experts),
+            moe_shortcut=bool(self.moe_shortcut),
         )
 
     def initialize(

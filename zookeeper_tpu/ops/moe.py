@@ -5,7 +5,9 @@ and a prefill's thousands alike:
 
 1. route: ``softmax(x @ router)`` in float32 (a bfloat16 router flips
    near-ties between experts, and the served token with them), the ``k``
-   largest per token, their weights renormalised to sum 1;
+   largest per token (chosen by ``softmax + bias`` where the router has a
+   correction bias, which then weights nothing), their weights
+   renormalised to sum 1 or used as they are, times a routing scale;
 2. sort the ``tokens * k`` (token, expert) pairs by expert, so each
    expert's rows are contiguous;
 3. three grouped matmuls over the sorted rows (row block ``e`` meets
@@ -34,6 +36,14 @@ layer's output that those experts give: the parts of every share add up
 to the uncut layer. What the other chips hold, and the exchange that
 would sum the parts, are not here. A shared expert beside the routed
 ones is the block's (``models/transformer.py``), not this function's.
+
+Identity ("zero-computation") experts (``zero_experts``; LongCat-Flash,
+docs/DESIGN.md §31): the router's last ``zero_experts`` columns are experts
+whose output is their input. A choice of one adds ``w x`` to the token's
+sum: no row is sorted, grouped or multiplied for it, and every share
+computes it for its tokens, as it would a shared expert (so the parts of
+every share add up to the uncut layer with the identity part counted
+once). The held share ranges over the real experts only.
 """
 
 from functools import partial
@@ -188,22 +198,32 @@ def _gmm(lhs, rhs, group_sizes, *, out_dtype, tm, tn=None, interpret=False):
 
 
 def route_top_k(
-    x: jax.Array, router: jax.Array, k: int, renormalize: bool = True
+    x: jax.Array, router: jax.Array, k: int, renormalize: bool = True,
+    bias: Optional[jax.Array] = None, scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """``(weights [t, k] float32, experts [t, k] int32)`` for tokens
     ``x [t, d]`` under ``router [d, experts]``: softmax over every
     expert in float32 at the highest matmul precision, the ``k``
     largest, renormalised to sum 1 (``renormalize``, a config's
-    ``norm_topk_prob``) or used as they are."""
+    ``norm_topk_prob``) or used as they are, times ``scale`` (a config's
+    ``routed_scaling_factor``; 1.0 traces no multiply). ``bias
+    [experts]`` (a correction bias): the ``k`` are the largest of ``probs
+    + bias``, and their weights are still their ``probs``."""
     logits = jnp.dot(
         x.astype(jnp.float32),
         router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, k)
+    else:
+        _, experts = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -217,7 +237,10 @@ def sparse_moe(
     k: int,
     held: Optional[Tuple[int, int]] = None,
     renormalize: bool = True,
-) -> Tuple[jax.Array, jax.Array]:
+    bias: Optional[jax.Array] = None,
+    scale: float = 1.0,
+    zero_experts: int = 0,
+) -> Tuple[jax.Array, ...]:
     """``y [t, d]`` and ``tokens_per_expert [experts] int32`` for
     ``x [t, d]``: ``y = sum_e w_e down_e(silu(gate_e x) * up_e x)`` over
     each token's ``k`` routed experts (see the module docstring). The
@@ -228,14 +251,23 @@ def sparse_moe(
     runs over a token's routed experts among those, and the counts are
     theirs (``[count]``). The pairs whose expert is not held sort behind
     the last group, where the grouped matmul computes nothing.
-    ``renormalize``: :func:`route_top_k`'s."""
+    ``renormalize``, ``bias``, ``scale``: :func:`route_top_k`'s.
+
+    ``zero_experts``: the router's last ``zero_experts`` columns are
+    identity experts (the module docstring): a token's weight on them
+    times ``x`` joins its sum, the counts stay the real experts', and a
+    third value follows them: the identity choices, an int32 scalar."""
     t, d = x.shape
-    num_experts = router.shape[1]
-    weights, experts = route_top_k(x, router, k, renormalize)
+    num_experts = router.shape[1] - zero_experts
+    weights, experts = route_top_k(x, router, k, renormalize, bias, scale)
     mine = None
-    if held is not None:
-        first, num_experts = held
-        mine = (experts >= first) & (experts < first + num_experts)
+    if held is not None or zero_experts:
+        first, count = held if held is not None else (0, num_experts)
+        mine = (experts >= first) & (experts < first + count)
+        if zero_experts:
+            identity = experts >= num_experts
+            zero_weight = jnp.sum(jnp.where(identity, weights, 0.0), axis=1)
+        num_experts = count
         experts = jnp.where(mine, experts - first, num_experts)
         weights = jnp.where(mine, weights, 0.0)
 
@@ -269,4 +301,8 @@ def sparse_moe(
         # rows behind the last group are whatever the buffer held
         out = jnp.where(mine[..., None], out, 0)
     y = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)
+    if zero_experts:
+        y = y + zero_weight[:, None] * x.astype(jnp.float32)
+        chosen = jnp.sum(identity, dtype=jnp.int32)
+        return y.astype(x.dtype), group_sizes, chosen
     return y.astype(x.dtype), group_sizes

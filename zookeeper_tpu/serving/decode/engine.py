@@ -124,7 +124,9 @@ class DecodeStep:
         #: The step's tokens on the host, ``[slots] int32``; None until read.
         self.tokens: Optional[np.ndarray] = None
         self.seconds: Optional[float] = None
-        leaves = out if isinstance(out, tuple) else (out,)
+        import jax
+
+        leaves = jax.tree_util.tree_leaves(out)
         for leaf in leaves:
             leaf.copy_to_host_async()
         #: The tokens on the device: what the step after keeps where
@@ -1285,8 +1287,10 @@ PagePool`."""
 
     def _apply(self, *args, **kwargs):
         """``module.apply`` that also brings back what the model's
-        expert layers sowed (``moe_load``: rows per expert, a layer),
-        stacked ``[layers, experts]``; None for a model without them."""
+        expert layers sowed (``moe_load``), each count stacked over the
+        layers: ``tokens_per_expert [layers, experts]`` and, for a model
+        with identity experts, ``zero_choices [layers]``; None for a
+        model without experts."""
         import jax.numpy as jnp
 
         module = self._module
@@ -1294,34 +1298,52 @@ PagePool`."""
             return module.apply(*args, **kwargs), None
         out, sown = module.apply(*args, mutable=["moe_load"], **kwargs)
         # (the leading dense layers of such a model sow nothing)
-        load = jnp.stack([
-            sown["moe_load"][f"block{i}"]["tokens_per_expert"]
+        layers = [
+            sown["moe_load"][f"block{i}"]
             for i in range(int(module.num_layers))
             if f"block{i}" in sown["moe_load"]
-        ])
+        ]
+        load = {
+            name: jnp.stack([layer[name] for layer in layers])
+            for name in layers[0]
+        }
         return out, load
 
-    def _note_moe_load(self, counts, program: str, rows: int) -> None:
+    def _note_moe_load(self, load, program: str, rows: int) -> None:
         """The load a traced dispatch of a model with experts read back
-        beside its tokens (``counts [layers][experts]`` as the device
-        summed them, already on the host): one ``moe_tokens_per_expert``
-        event. For a model that holds a share of its experts the counts
-        are the held experts', and one ``moe_held_choices`` event says
-        what share of the dispatch's ``rows`` x top-k x layers routed
-        choices they were. Recorded after the dispatch span has closed,
-        so that building the lists is not in it."""
+        beside its tokens (:meth:`_apply`'s, as the device summed it,
+        already on the host): one ``moe_tokens_per_expert`` event of
+        ``tokens_per_expert [layers][experts]``. For a model that holds a
+        share of its experts the counts are the held experts', and one
+        ``moe_held_choices`` event says what share of the dispatch's
+        ``rows`` x top-k x layers routed choices they were. Where the load
+        has ``zero_choices`` (identity experts), one ``moe_zero_choices``
+        event says how many of the routed choices they were. Recorded
+        after the dispatch span has closed, so that building the lists is
+        not in it."""
+        module = self._module
+        counts = np.asarray(load["tokens_per_expert"])
+        routed = rows * counts.shape[0] * int(module.experts_per_token)
+        if "zero_choices" in load:
+            _trace.event(
+                "moe_zero_choices",
+                attrs={
+                    "program": program,
+                    "choices_zero": int(np.sum(load["zero_choices"])),
+                    "choices_routed": routed,
+                },
+            )
         _trace.event(
             "moe_tokens_per_expert",
             attrs={"program": program, "counts": counts.tolist()},
         )
-        if getattr(self._module, "held_experts", ()):
+        if getattr(module, "held_experts", ()):
             _trace.event(
                 "moe_held_choices",
                 attrs={
                     "program": program,
                     "choices_held": int(counts.sum()),
-                    "choices_routed": rows * counts.shape[0]
-                    * int(self._module.experts_per_token),
+                    "choices_routed": routed,
                     "tokens_per_expert_max": int(counts.max()),
                     "tokens_per_expert_mean": float(counts.mean()),
                 },
