@@ -158,6 +158,66 @@ def test_native_augment_mid_epoch_resume():
         np.testing.assert_array_equal(a["target"], b["target"])
 
 
+@needs_native
+@pytest.mark.parametrize("recipe", ["cifar_pad_crop", "rrc_non_square"])
+def test_augment_into_out_is_the_returned_array(recipe):
+    """``out=`` gives bit-identical numbers to the returned-array form,
+    written into the buffer given, whatever that buffer held before."""
+    conf, shape = RECIPES[recipe]
+    store = image_source(shape).arrays["image"]
+    idx = np.array([5, 0, 17, 5, 23], np.int64)
+    kw = dict(
+        out_height=conf["height"], out_width=conf["width"], seed=3, epoch=1,
+        random_resized_crop=conf.get("random_resized_crop", False),
+        crop_scale_range=(0.08, 1.0),
+        log_aspect_range=(float(np.log(3 / 4)), float(np.log(4 / 3))),
+        pad_pixels=conf.get("pad_pixels", 0),
+    )
+    fresh = native.gather_augment_normalize(store, idx, **kw)
+    out = np.full_like(fresh, np.nan)
+    got = native.gather_augment_normalize(store, idx, out=out, **kw)
+    assert got is out
+    np.testing.assert_array_equal(out, fresh)
+
+
+@pytest.mark.parametrize("library", [True, False], ids=["native", "numpy"])
+def test_normalize_into_out_is_the_returned_array(library, monkeypatch):
+    """The same for the plain gather, in the kernel and in its numpy
+    fallback."""
+    if library and not native.available():
+        pytest.skip("no toolchain (numpy-fallback CI leg)")
+    if not library:
+        monkeypatch.setattr(native, "_load", lambda: None)
+    store = image_source((6, 5, 3)).arrays["image"]
+    idx = np.array([3, 0, 9, 3], np.int64)
+    fresh = native.gather_normalize(store, idx, 2.0 / 255.0, -1.0)
+    out = np.full((4, 6, 5, 3), np.nan, np.float32)
+    got = native.gather_normalize(store, idx, 2.0 / 255.0, -1.0, out=out)
+    assert got is out
+    np.testing.assert_array_equal(out, fresh)
+    np.testing.assert_array_equal(
+        fresh, store[idx].astype(np.float32) * np.float32(2.0 / 255.0)
+        + np.float32(-1.0),
+    )
+
+
+@pytest.mark.parametrize(
+    "bad",
+    [
+        np.zeros((4, 6, 5, 3), np.float64),  # dtype
+        np.zeros((4, 6, 5, 2), np.float32),  # shape
+        np.zeros((4, 5, 6, 3), np.float32).transpose(0, 2, 1, 3),  # layout
+    ],
+    ids=["dtype", "shape", "layout"],
+)
+def test_out_is_checked(bad):
+    store = image_source((6, 5, 3)).arrays["image"]
+    with pytest.raises(ValueError, match="out must be"):
+        native.gather_normalize(
+            store, np.array([0, 1, 2, 3]), 1.0, 0.0, out=bad
+        )
+
+
 def test_python_fallback_when_library_absent(monkeypatch):
     """With the .so unavailable the pipeline must keep producing batches
     through the per-example Python path — and because the two paths are

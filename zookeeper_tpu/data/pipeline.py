@@ -9,6 +9,8 @@ The JAX-native replacement for the reference example's
 - :func:`prefetch_to_device` — a double-buffered background thread that
   moves batches into (possibly sharded) device memory with
   ``jax.device_put``, overlapping host work with TPU steps;
+- :class:`HostBatchRing` — recycled host buffers the native batch assembly
+  writes into, each taken again only once nothing can read it;
 - :class:`DataLoader` — the component tying a ``Dataset`` + ``Preprocessing``
   + batch settings together.
 
@@ -22,6 +24,7 @@ from __future__ import annotations
 
 import queue
 import threading
+from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Iterator, Optional
 
@@ -50,6 +53,138 @@ def _column_arrays(source: DataSource) -> Optional[Dict[str, np.ndarray]]:
     return None
 
 
+#: Alignment of a ring buffer: XLA's for its operands, so that a backend
+#: able to read a NumPy operand where it lies (the CPU) always does, and
+#: the ring always sees it (:func:`_lies_in`).
+_ALIGN = 64
+
+
+def _aligned_empty(shape, dtype) -> np.ndarray:
+    dtype = np.dtype(dtype)
+    nbytes = int(np.prod(shape)) * dtype.itemsize
+    raw = np.empty(nbytes + _ALIGN, np.uint8)
+    start = -raw.ctypes.data % _ALIGN
+    return raw[start:start + nbytes].view(dtype).reshape(shape)
+
+
+def _lies_in(staged, buf: np.ndarray) -> bool:
+    """Whether any shard of the staged array is ``buf``'s own memory."""
+    lo = buf.ctypes.data
+    hi = lo + buf.nbytes
+    return any(
+        lo <= shard.data.unsafe_buffer_pointer() < hi
+        for shard in staged.addressable_shards
+    )
+
+
+class HostBatchRing:
+    """Recycled host buffers for the native batch assembly.
+
+    A fresh batch buffer (512 x 224 x 224 x 3 float32 is 308 MB) is a new
+    mapping every step: the assembling threads fault in every page and the
+    kernel zeroes it. The ring hands the assembly a buffer it has written
+    before instead, and never one that anything can still read:
+
+    - the producer hands each buffer back with the array staged from it
+      (:meth:`release`); :meth:`take` reuses it only once that array is
+      ready, i.e. its host-to-device transfer has read the buffer;
+    - where the staged array lies in the buffer itself (a backend that
+      reads a NumPy operand where it lies, as the CPU's does), the buffer
+      is the consumer's from then on: the ring forgets it and allocates a
+      fresh one in its place.
+
+    It holds ``slots`` buffers of one ``(shape, dtype)``, reusing the
+    oldest; a batch of another shape (an eval split's short last batch)
+    starts it over. Safe to share between the producers of consecutive
+    passes.
+    """
+
+    def __init__(self, slots: int):
+        if slots < 1:
+            raise ValueError(f"slots={slots} must be >= 1.")
+        self.slots = slots
+        self._lock = threading.Lock()
+        self._key = None
+        #: ``[buffer, staged array or None once it was ready]``, oldest first.
+        self._handed_back: deque = deque()
+        #: id -> (buffer, recycled) of buffers taken and not yet handed back.
+        self._taken: Dict[int, Any] = {}
+
+    def _reap(self) -> None:
+        """Drop the references to staged arrays that are ready (so the
+        ring keeps no device memory alive) and forget the buffers they
+        lie in. Caller holds the lock."""
+        kept: deque = deque()
+        for entry in self._handed_back:
+            buf, staged = entry
+            if staged is not None and staged.is_ready():
+                if _lies_in(staged, buf):
+                    continue
+                entry[1] = None
+            kept.append(entry)
+        self._handed_back = kept
+
+    def settle(self, step: Optional[int] = None) -> None:
+        """Wait until the buffer the next :meth:`take` reuses has been
+        read by its transfer: outside the assembly, in a
+        ``loader_recycle_wait`` span when there is anything to wait for."""
+        with self._lock:
+            self._reap()
+            full = len(self._handed_back) >= self.slots
+            staged = self._handed_back[0][1] if full else None
+        if staged is not None:
+            with _trace.span("loader_recycle_wait", step=step):
+                staged.block_until_ready()
+
+    def take(self, shape, dtype) -> np.ndarray:
+        """A buffer of ``shape`` and ``dtype`` that nothing else reads:
+        the oldest handed back once the ring is full, else a fresh one."""
+        key = (tuple(shape), np.dtype(dtype))
+        buf = staged = None
+        with self._lock:
+            if key != self._key:
+                self._key, self._handed_back = key, deque()
+            self._reap()
+            if len(self._handed_back) >= self.slots:
+                buf, staged = self._handed_back.popleft()
+        if staged is not None:  # settle() did not run first
+            staged.block_until_ready()
+            if _lies_in(staged, buf):
+                buf = None
+        recycled = buf is not None
+        if buf is None:
+            buf = _aligned_empty(*key)
+        with self._lock:
+            self._taken[id(buf)] = (buf, recycled)
+        return buf
+
+    def recycled(self, batch: Any) -> int:
+        """1 if ``batch`` was assembled into a buffer the ring reused."""
+        import jax
+
+        with self._lock:
+            return int(any(
+                self._taken.get(id(x), (None, False))[1]
+                for x in jax.tree.leaves(batch)
+            ))
+
+    def release(self, batch: Any, staged: Any) -> None:
+        """Hand back the ring's buffers among ``batch``'s leaves, each with
+        the array ``staged`` holds in its place."""
+        import jax
+
+        with self._lock:
+            for host, dev in zip(
+                jax.tree.leaves(batch), jax.tree.leaves(staged)
+            ):
+                entry = self._taken.pop(id(host), None)
+                if entry is None or (host.shape, host.dtype) != self._key:
+                    continue
+                self._handed_back.append([host, dev])
+                if len(self._handed_back) > self.slots:
+                    self._handed_back.popleft()
+
+
 def batch_iterator(
     source: DataSource,
     preprocessing: Optional[Preprocessing],
@@ -64,6 +199,7 @@ def batch_iterator(
     host_count: int = 1,
     num_workers: int = 0,
     start_batch: int = 0,
+    buffers: Optional[HostBatchRing] = None,
 ) -> Iterator[Batch]:
     """Yield batches of stacked numpy arrays from ``source``.
 
@@ -76,6 +212,11 @@ def batch_iterator(
     onward is identical to an uninterrupted epoch's. This is the exact
     mid-epoch-resume hook (a step-granular checkpoint restores at
     ``step % steps_per_epoch == k``).
+
+    ``buffers``: the native assembly writes each batch's input into a
+    buffer taken from this ring (the per-example Python path ignores it).
+    Only a consumer that hands every batch back to the ring
+    (:func:`prefetch_to_device`) may pass one.
     """
     if host_count < 1 or not 0 <= host_index < host_count:
         # A mis-wired host identity (a stale process_id env, a bad
@@ -199,10 +340,15 @@ def batch_iterator(
         from zookeeper_tpu import native
 
         spec, img, lbl = native_spec
+
+        def out(shape):
+            return None if buffers is None else buffers.take(shape, np.float32)
+
         if spec.get("mode", "normalize") == "normalize":
             def assemble(idx):
                 return native.gather_normalize(
-                    img, idx, spec["scale"], spec["shift"]
+                    img, idx, spec["scale"], spec["shift"],
+                    out=out((len(idx), *img.shape[1:])),
                 )
         else:
             eh, ew, _ = spec["expected_shape"]
@@ -222,6 +368,7 @@ def batch_iterator(
                     random_flip=spec["random_flip"],
                     post_scale=spec["post_scale"],
                     post_shift=spec["post_shift"],
+                    out=out((len(idx), eh, ew, img.shape[3])),
                 )
 
         for b in range(start_batch, num_batches):
@@ -345,6 +492,7 @@ def prefetch_to_device(
     size: int = 2,
     sharding: Optional[Any] = None,
     split: Optional[str] = None,
+    ring: Optional[HostBatchRing] = None,
 ) -> Iterator[Any]:
     """Asynchronously stage host batches into device memory.
 
@@ -354,6 +502,9 @@ def prefetch_to_device(
     ``jax.sharding.NamedSharding`` whose batch axis spans the mesh's data
     axis, this is the host→HBM half of data parallelism — XLA never sees a
     host transfer inside the step.
+
+    ``ring``: the one ``iterator`` takes its buffers from; each staged
+    batch is handed back to it (:meth:`HostBatchRing.release`).
     """
     import jax
 
@@ -407,19 +558,32 @@ def prefetch_to_device(
         # Three leaf spans per batch, sharing ``step`` = the batch's
         # index in this pass: where this thread's time goes is the
         # loader's breakdown (assemble: gather/augment or the worker
-        # pool; stage: host-to-device; put_wait: queue full, so the
-        # device is the limit). No-ops while tracing is off.
+        # pool, ``recycled`` 1 when into a reused ring buffer; stage:
+        # host-to-device; put_wait: queue full, so the device is the
+        # limit), and a fourth, loader_recycle_wait, ahead of assemble
+        # when the ring's next buffer is still being read. No-ops while
+        # tracing is off.
         try:
             it = iter(iterator)
             index = 0
             while True:
-                with _trace.span("loader_assemble", step=index):
+                if ring is not None:
+                    ring.settle(step=index)
+                attrs = {} if _trace.enabled() else None
+                with _trace.span("loader_assemble", step=index, attrs=attrs):
                     try:
-                        batch = next(it)
+                        host = next(it)
                     except StopIteration:
                         break
+                    if attrs is not None:
+                        attrs["recycled"] = (
+                            0 if ring is None else ring.recycled(host)
+                        )
                 with _trace.span("loader_stage", step=index):
-                    batch = stage(batch)
+                    batch = stage(host)
+                if ring is not None:
+                    ring.release(host, batch)
+                del host
                 with _trace.span("loader_put_wait", step=index):
                     put = put_or_stop(batch)
                 if not put:
@@ -476,6 +640,16 @@ class DataLoader:
     prefetch: int = Field(2)
     host_index: int = Field(-1)  # -1 => jax.process_index()
     host_count: int = Field(-1)  # -1 => jax.process_count()
+
+    def _ring(self) -> HostBatchRing:
+        """The loader's host batch buffers, kept across epochs: one per
+        batch in the prefetch queue, one being staged, one being
+        assembled."""
+        ring = getattr(self, "_batch_ring", None)
+        if ring is None:
+            ring = HostBatchRing(self.prefetch + 2)
+            object.__setattr__(self, "_batch_ring", ring)
+        return ring
 
     def _source(self, split: str) -> Optional[DataSource]:
         """The split's DataSource, cached for the loader's lifetime: a
@@ -546,6 +720,10 @@ class DataLoader:
         if source is None:
             raise ValueError(f"Dataset has no '{split}' split.")
         hi, hc = self._hosts()
+        # Recycled buffers only where each batch goes to the device
+        # before the next is assembled: without prefetch the consumer
+        # holds the host batch itself, and a slab is a copy.
+        ring = self._ring() if self.prefetch > 0 and unroll == 1 else None
         it = batch_iterator(
             source,
             self.preprocessing,
@@ -559,6 +737,7 @@ class DataLoader:
             host_count=hc,
             num_workers=self.num_workers,
             start_batch=start_batch,
+            buffers=ring,
         )
         if unroll > 1:
             it = slab_iterator(it, unroll, max_batches=max_batches)
@@ -568,7 +747,8 @@ class DataLoader:
             it = itertools.islice(it, max_batches)
         if self.prefetch > 0:
             return prefetch_to_device(
-                it, size=self.prefetch, sharding=sharding, split=split
+                it, size=self.prefetch, sharding=sharding, split=split,
+                ring=ring,
             )
         return it
 

@@ -162,6 +162,8 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_int64, ctypes.c_int32,
         ]
         lib.zk_version.restype = ctypes.c_int
+        lib.zk_hardware_threads.argtypes = []
+        lib.zk_hardware_threads.restype = ctypes.c_int
         _lib = lib
         return _lib
 
@@ -177,8 +179,37 @@ def status() -> str:
     return _status
 
 
+def threads() -> Optional[int]:
+    """The threads each batch-assembly call starts: the host's
+    ``std::thread::hardware_concurrency()``, whatever a loader's
+    ``num_workers`` says (that feeds the per-example Python path only).
+    None without the library."""
+    lib = _load()
+    return None if lib is None else int(lib.zk_hardware_threads())
+
+
 def _ptr(arr: np.ndarray, ctype):
     return arr.ctypes.data_as(ctypes.POINTER(ctype))
+
+
+def _output(out: Optional[np.ndarray], shape) -> np.ndarray:
+    """``out`` checked to be a writable C-contiguous float32 array of
+    ``shape`` (the kernels write every element of it), or a new one."""
+    if out is None:
+        return np.empty(shape, np.float32)
+    if (
+        out.shape != tuple(shape)
+        or out.dtype != np.float32
+        or not out.flags["C_CONTIGUOUS"]
+        or not out.flags["WRITEABLE"]
+    ):
+        raise ValueError(
+            f"out must be a writable C-contiguous float32 array of shape "
+            f"{tuple(shape)}, got {out.dtype} {out.shape} "
+            f"(C-contiguous: {out.flags['C_CONTIGUOUS']}, writable: "
+            f"{out.flags['WRITEABLE']})."
+        )
+    return out
 
 
 def pack_bits(x: np.ndarray) -> np.ndarray:
@@ -206,11 +237,17 @@ def pack_bits(x: np.ndarray) -> np.ndarray:
 
 
 def gather_normalize(
-    store: np.ndarray, indices: np.ndarray, scale: float, shift: float
+    store: np.ndarray,
+    indices: np.ndarray,
+    scale: float,
+    shift: float,
+    *,
+    out: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Fused batch assembly: ``(scale * store[indices] + shift)`` as float32.
 
-    ``store``: [N, ...] uint8; returns [len(indices), ...] float32.
+    ``store``: [N, ...] uint8; returns [len(indices), ...] float32, written
+    into ``out`` when one is given (same numbers either way).
     """
     store = np.ascontiguousarray(store)
     if store.dtype != np.uint8:
@@ -219,20 +256,20 @@ def gather_normalize(
     example_shape = store.shape[1:]
     example_size = int(np.prod(example_shape))
     batch = len(indices)
+    out = _output(out, (batch, *example_shape))
     lib = _load()
     if lib is None:  # numpy fallback
-        return (
-            store[indices].astype(np.float32) * np.float32(scale)
-            + np.float32(shift)
-        )
-    out = np.empty((batch, example_size), dtype=np.float32)
+        out[...] = store[indices]
+        out *= np.float32(scale)
+        out += np.float32(shift)
+        return out
     lib.zk_gather_normalize_u8(
         _ptr(store.reshape(store.shape[0], example_size), ctypes.c_uint8),
         _ptr(indices, ctypes.c_int64),
         _ptr(out, ctypes.c_float),
         batch, example_size, float(scale), float(shift),
     )
-    return out.reshape(batch, *example_shape)
+    return out
 
 
 def gather_augment_normalize(
@@ -250,12 +287,14 @@ def gather_augment_normalize(
     random_flip: bool = True,
     post_scale: float = 2.0,
     post_shift: float = -1.0,
+    out: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Fused AUGMENTED batch assembly over a ``[N, H, W, C]`` uint8 store:
     per-example RandomResizedCrop (bilinear) or reflect-pad+crop, flip,
     normalize — bit-identical to the Python reference path
     (``ImageClassificationPreprocessing`` with ``augment=True``) through
     the shared ``(seed, index, epoch)`` counter RNG (``data/augrng.py``).
+    Written into ``out`` when one is given (same numbers either way).
 
     Unlike the other entry points there is NO numpy fallback here: the
     per-example Python preprocessing path IS the reference
@@ -293,7 +332,7 @@ def gather_augment_normalize(
     indices = np.ascontiguousarray(indices, dtype=np.int64)
     batch = len(indices)
     channels = store.shape[3]
-    out = np.empty((batch, out_height, out_width, channels), np.float32)
+    out = _output(out, (batch, out_height, out_width, channels))
     lib.zk_gather_augment_normalize_u8(
         _ptr(store, ctypes.c_uint8),
         _ptr(indices, ctypes.c_int64),

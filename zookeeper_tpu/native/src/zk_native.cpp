@@ -373,4 +373,7 @@ void zk_xnor_gemm_ref(const int32_t* a, const int32_t* b, int32_t* out,
 
 int zk_version() { return 2; }
 
+// The threads parallel_for starts when a caller gives no limit.
+int zk_hardware_threads() { return hardware_threads(); }
+
 }  // extern "C"
