@@ -336,3 +336,37 @@ def test_an_iteration_ends_with_its_wall_and_cpu_time(engine, tracer, synchronou
     ends = [r["attrs"] for r in records if r["name"] == "sched_iteration_end"]
     assert sum(a["in_flight"] for a in ends) == 3
     assert len([r for r in records if r["name"] == "decode_readback"]) == 1
+
+
+def test_prefix_work_is_one_event_an_iteration_that_moved_it(engine, tracer):
+    """``kv_prefix_work`` (docs/DESIGN.md §20): one event an iteration in
+    which the prefix cache evicted, examined or keyed anything, and
+    together they add up to what its counters moved."""
+    sched = make_scheduler(emptied(engine), synchronous=True)
+    cache = engine.page_pool.prefix
+    before = (cache.evicted_pages, cache.evict_nodes_examined, cache.keyed_tokens)
+    prompt = np.arange(1, 10, dtype=np.int32)
+    try:
+        sched.submit(prompt, max_new_tokens=3).result(timeout=120)
+        warm = np.concatenate([prompt, [11, 12]]).astype(np.int32)
+        sched.submit(warm, max_new_tokens=3).result(timeout=120)
+    finally:
+        sched.close()
+    records = tracer.snapshot()
+    events = [r for r in records if r["name"] == "kv_prefix_work"]
+    assert events
+    steps = [e["step"] for e in events]
+    assert len(set(steps)) == len(steps) and None not in steps
+    total = {"pages_evicted": 0, "nodes_examined": 0, "tokens_keyed": 0}
+    for e in events:
+        assert e["phase"] == "i" and set(e["attrs"]) == set(total)
+        assert any(e["attrs"].values())
+        for key in total:
+            total[key] += e["attrs"][key]
+    assert total == {
+        "pages_evicted": cache.evicted_pages - before[0],
+        "nodes_examined": cache.evict_nodes_examined - before[1],
+        "tokens_keyed": cache.keyed_tokens - before[2],
+    }
+    # each prompt keyed about once: its chunks, and the lookup's miss
+    assert len(prompt) + len(warm) <= total["tokens_keyed"] <= 2 * len(warm) + 8

@@ -72,12 +72,18 @@ is allocated by :func:`allocate_page_pool` and owned/donated by the
 ``DecodeEngine``.
 """
 
+import heapq
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from zookeeper_tpu.serving.decode.prefix_key import walk_insert, walk_match
+from zookeeper_tpu.serving.decode.prefix_key import (
+    detach,
+    token_list,
+    walk_insert,
+    walk_match,
+)
 
 __all__ = [
     "PagePool",
@@ -255,12 +261,14 @@ def _window_layers(
 
 
 class _TrieNode:
-    __slots__ = ("chunk", "page", "children", "parent", "last_used")
+    __slots__ = ("chunk", "page", "children", "heads", "parent", "last_used")
 
     def __init__(self, chunk: Tuple[int, ...], page: int, parent):
         self.chunk = chunk
         self.page = int(page)
         self.children: Dict[Tuple[int, ...], "_TrieNode"] = {}
+        self.heads: Dict[int, Dict[Tuple[int, ...], "_TrieNode"]] = {}
+        #: None once the node has left the trie (evicted or cleared).
         self.parent = parent
         self.last_used = 0
 
@@ -283,6 +291,14 @@ class RadixPrefixCache:
     evictions that actually free pool pages). :meth:`clear` is the
     hot-swap invalidation: cached pages hold K/V of the OLD weights and
     must never serve a warm hit under the new ones.
+
+    What an admission costs is what it changes (docs/DESIGN.md §20):
+    eviction pops candidates from a kept heap of childless nodes by
+    ``last_used`` instead of walking the trie, and :meth:`insert` can
+    resume where the admission's :meth:`lookup` stopped. The owner of
+    the refcounts says when a cached page's count falls to 1
+    (:meth:`unshared`); the heap is validated at pop, so a candidate
+    that was touched, grew a child or is shared again is dropped there.
     """
 
     def __init__(self, page_size: int, ref, unref, evictable) -> None:
@@ -294,6 +310,13 @@ class RadixPrefixCache:
         self._evictable = evictable  # page -> bool (refcount == 1)
         self._root = _TrieNode((), -1, None)
         self._clock = 0
+        self._nodes = 0
+        #: Eviction candidates, ``(last_used, seq, node)``: every
+        #: childless node whose page only the cache holds has an entry
+        #: with its current ``last_used``; stale entries wait for a pop.
+        self._leaves: List[Tuple[int, int, _TrieNode]] = []
+        self._seq = 0
+        self._by_page: Dict[int, _TrieNode] = {}
         #: Token-level accounting behind ``zk_prefix_cache_hit_rate``.
         self.lookup_tokens = 0
         self.hit_tokens = 0
@@ -301,20 +324,44 @@ class RadixPrefixCache:
         self.hits = 0
         self.evicted_pages = 0
         self.invalidations = 0
+        #: What the bookkeeping did: heap entries :meth:`evict_lru`
+        #: popped, and prompt tokens turned into chunk keys.
+        self.evict_nodes_examined = 0
+        self.keyed_tokens = 0
 
     def _touch(self, node: _TrieNode) -> None:
         self._clock += 1
         node.last_used = self._clock
+        if not node.children:
+            self._offer(node)
+
+    def _offer(self, node: _TrieNode) -> None:
+        """Make ``node`` an eviction candidate at its ``last_used``.
+        The heap is compacted to its live entries when stale ones
+        outnumber the nodes (amortized O(1) a push)."""
+        self._seq += 1
+        heapq.heappush(self._leaves, (node.last_used, self._seq, node))
+        if len(self._leaves) > 2 * self._nodes + 64:
+            live = {
+                id(entry[2]): entry
+                for entry in self._leaves
+                if entry[2].parent is not None
+                and not entry[2].children
+                and entry[2].last_used == entry[0]
+            }
+            self._leaves = list(live.values())
+            heapq.heapify(self._leaves)
+
+    def unshared(self, page: int) -> None:
+        """``page``'s refcount fell to 1: if a childless node holds it,
+        the cache alone does now, and the node may be evicted."""
+        node = self._by_page.get(int(page))
+        if node is not None and not node.children:
+            self._offer(node)
 
     @property
     def nodes(self) -> int:
-        count = 0
-        stack = [self._root]
-        while stack:
-            n = stack.pop()
-            stack.extend(n.children.values())
-            count += len(n.children)
-        return count
+        return self._nodes
 
     @property
     def hit_rate(self) -> float:
@@ -334,95 +381,135 @@ class RadixPrefixCache:
         the shared ``prefix_key.walk_match`` — the fleet router's
         per-replica :class:`~zookeeper_tpu.serving.decode.prefix_key.\
 PrefixIndex` predicts THIS method's match length with the same code."""
-        tokens = [int(x) for x in tokens]
+        t, pages, _ = self.lookup_resumable(token_list(tokens))
+        return t, pages
+
+    def lookup_resumable(self, tokens: List[int]):
+        """:meth:`lookup` of a list of ints (``prefix_key.token_list``)
+        that also returns where its walk stopped, ``(node, chunks)``:
+        the last full-chunk node it matched and its depth. Handed to
+        :meth:`insert` of the same tokens as ``resume``, the insert
+        starts there instead of walking the matched chunks again."""
+        ps = self.page_size
         self.lookups += 1
         self.lookup_tokens += len(tokens)
-        t, visited = walk_match(self._root, tokens, self.page_size)
-        pages: List[int] = []
+        t, visited = walk_match(self._root, tokens, ps)
+        full = t // ps
+        # The full chunks it matched, and the one that missed.
+        self.keyed_tokens += min(full + 1, len(tokens) // ps) * ps
         for node in visited:
-            pages.append(node.page)
             self._touch(node)
         if t:
             self.hits += 1
             self.hit_tokens += t
-        return t, pages
+        resume = (visited[full - 1] if full else self._root, full)
+        return t, [node.page for node in visited], resume
 
-    def insert(self, tokens: Sequence[int], pages: Sequence[int]) -> int:
+    def insert(
+        self, tokens: Sequence[int], pages: Sequence[int], *, resume=None
+    ) -> int:
         """Cache ``tokens``' pages (``pages[i]`` covers positions
         ``[i*page_size, (i+1)*page_size)``; the last may be partial).
         Existing nodes keep their ORIGINAL page — by determinism the
         bytes are identical, and swapping would orphan other sharers'
         view of the trie. Returns how many NEW nodes (= new cache page
-        references) were created."""
+        references) were created.
+
+        ``resume``: what :meth:`lookup_resumable` of the same tokens
+        returned. While its node is still in the trie, every chunk
+        above it still is (only childless nodes leave), so the walk
+        starts there and builds the same trie as a walk from the root;
+        once the node has left (an eviction or :meth:`clear` since),
+        the walk starts from the root."""
         ps = self.page_size
-        tokens = [int(x) for x in tokens]
-        created = 0
+        tokens = token_list(tokens)
+        node, start = self._root, 0
+        if resume is not None and (
+            resume[0] is self._root or resume[0].parent is not None
+        ):
+            node, start = resume
+        tail = len(pages) > len(tokens) // ps
         visited = walk_insert(
-            self._root,
+            node,
             tokens,
             ps,
             lambda chunk, i, parent: _TrieNode(chunk, pages[i], parent),
             # A partial tail is cached only when a page actually covers
             # those positions.
-            tail=len(pages) > len(tokens) // ps,
+            tail=tail,
+            start=start,
         )
+        rest = len(tokens) % ps if tail else 0
+        self.keyed_tokens += (len(tokens) // ps - start) * ps + rest
+        created = 0
         for node, was_created in visited:
             if was_created:
                 self._ref(node.page)
+                self._by_page[node.page] = node
                 created += 1
             self._touch(node)
+        self._nodes += created
         return created
 
     def evict_lru(self, want_pages: int) -> int:
         """Free pool pages by dropping LRU childless nodes whose page
         only the cache still references. Returns pages actually freed
         (may be < ``want_pages`` when everything left is shared with an
-        active slot or is an interior node). One DFS collects the whole
-        evictable-leaf layer and frees it in LRU order; the outer loop
-        rescans only when evictions exposed NEW leaves (parents of
-        fully-evicted subtrees) — so the cost is one walk per trie
-        LAYER consumed, not one per page (this runs under the
-        scheduler lock)."""
+        active slot or is an interior node).
+
+        Order: least ``last_used`` first among the candidates; a parent
+        that an eviction leaves childless joins them at its own
+        ``last_used``, so it may go before older leaves' siblings. Each
+        pop examines one heap entry (``evict_nodes_examined``) and
+        stale ones are dropped: about one or two a page freed, O(log n)
+        each, and no walk over the pages live slots hold (this runs
+        under the scheduler lock)."""
         freed = 0
-        while freed < want_pages:
-            leaves = []
-            stack = [self._root]
-            while stack:
-                n = stack.pop()
-                for child in n.children.values():
-                    if child.children:
-                        stack.append(child)
-                    elif self._evictable(child.page):
-                        leaves.append(child)
-            if not leaves:
-                return freed
-            leaves.sort(key=lambda n: n.last_used)
-            for victim in leaves:
-                if freed >= want_pages:
-                    return freed
-                del victim.parent.children[victim.chunk]
-                self._unref(victim.page)
-                self.evicted_pages += 1
-                freed += 1
+        while freed < want_pages and self._leaves:
+            stamp, _, node = heapq.heappop(self._leaves)
+            self.evict_nodes_examined += 1
+            parent = node.parent
+            if (
+                parent is None
+                or node.children
+                or node.last_used != stamp
+                or not self._evictable(node.page)
+            ):
+                continue
+            detach(parent, node)
+            node.parent = None
+            if self._by_page.get(node.page) is node:
+                del self._by_page[node.page]
+            self._nodes -= 1
+            self._unref(node.page)
+            self.evicted_pages += 1
+            freed += 1
+            if parent is not self._root and not parent.children:
+                self._offer(parent)
         return freed
 
     def clear(self) -> int:
         """Drop every cached node + reference (the hot-swap
         invalidation). Returns nodes dropped."""
-        dropped = 0
+        dropped = []
         stack = list(self._root.children.values())
         while stack:
             n = stack.pop()
             stack.extend(n.children.values())
-            self._unref(n.page)
-            dropped += 1
+            dropped.append(n)
         self._root = _TrieNode((), -1, None)
+        self._leaves = []
+        self._by_page = {}
+        self._nodes = 0
+        for n in dropped:
+            n.parent = None
+            self._unref(n.page)
         # Counted unconditionally: "how many times was the cache
         # invalidated" is the hot-swap-discipline number the chaos
         # tests pin (exactly once per applied swap), not "how many
         # invalidations found nodes to drop".
         self.invalidations += 1
-        return dropped
+        return len(dropped)
 
 
 class _WindowGroup:
@@ -587,14 +674,19 @@ class PagePool:
             else None
         )
         self.prefix: Optional[RadixPrefixCache] = (
-            RadixPrefixCache(
-                self.page_size,
-                ref=self._ref,
-                unref=self._unref,
-                evictable=lambda p: int(self.refcount[p]) == 1,
-            )
-            if prefix_cache
-            else None
+            self._new_prefix_cache() if prefix_cache else None
+        )
+        #: A slot's admission walk, for its insert: ``slot -> (prompt,
+        #: tokens, resume)`` from ``assign_prompt`` to ``insert_prefix``
+        #: or ``release_slot``.
+        self._walked: Dict[int, Tuple[Any, List[int], Any]] = {}
+
+    def _new_prefix_cache(self) -> RadixPrefixCache:
+        return RadixPrefixCache(
+            self.page_size,
+            ref=self._ref,
+            unref=self._unref,
+            evictable=lambda p: int(self.refcount[p]) == 1,
         )
 
     # -- refcounting -----------------------------------------------------
@@ -604,10 +696,13 @@ class PagePool:
 
     def _unref(self, page: int) -> None:
         self.refcount[page] -= 1
-        if self.refcount[page] < 0:
+        count = self.refcount[page]
+        if count < 0:
             raise AssertionError(f"page {page} refcount went negative.")
-        if self.refcount[page] == 0:
+        if count == 0:
             self._free.append(int(page))
+        elif count == 1 and self.prefix is not None:
+            self.prefix.unshared(page)
 
     @property
     def free_pages(self) -> int:
@@ -687,12 +782,12 @@ class PagePool:
                 f"slot {slot} still holds pages at admission; release "
                 "first."
             )
-        prompt = [int(x) for x in np.asarray(prompt).tolist()]
-        length = len(prompt)
+        tokens = np.asarray(prompt).tolist()
+        length = len(tokens)
         shared_tokens = 0
         shared_pages: List[int] = []
         if self.prefix is not None:
-            t, pages = self.prefix.lookup(prompt)
+            t, pages, resume = self.prefix.lookup_resumable(tokens)
             # Never match the WHOLE prompt: the final token is always
             # recomputed so the warm dispatch produces the first
             # emission's logits (and the accounting stays honest).
@@ -724,6 +819,8 @@ class PagePool:
             self.cow_pages += 1
         self.table[slot, :len(row)] = row
         self.counts[slot] = len(row)
+        if self.prefix is not None:
+            self._walked[slot] = (prompt, tokens, resume)
         return {"shared_tokens": shared_tokens, "cow": cow}
 
     def adopt_slot(self, slot: int, n_pages: int) -> Optional[List[int]]:
@@ -809,22 +906,29 @@ class PagePool:
         the prefix cache also references stay resident for warm hits;
         everything else returns to the free list."""
         n = int(self.counts[slot])
-        for i in range(n):
-            self._unref(int(self.table[slot, i]))
+        for page in self.table[slot, :n].tolist():
+            self._unref(page)
         self.table[slot, :n] = -1
         self.counts[slot] = 0
+        self._walked.pop(slot, None)
         if self.window_group is not None:
             self.window_group.release_slot(slot)
 
     def insert_prefix(self, slot: int, prompt) -> int:
         """Cache the slot's prompt pages for future warm hits (called
-        after the prefill/extend dispatch landed their contents)."""
+        after the prefill/extend dispatch landed their contents). Given
+        the prompt its admission was given, the insert reuses that
+        admission's tokens and resumes where its lookup stopped."""
         if self.prefix is None:
             return 0
-        prompt = np.asarray(prompt)
-        n = self.pages_for(int(prompt.shape[0]))
+        walked = self._walked.pop(slot, None)
+        if walked is not None and walked[0] is prompt:
+            _, tokens, resume = walked
+        else:
+            tokens, resume = np.asarray(prompt).tolist(), None
+        n = self.pages_for(len(tokens))
         return self.prefix.insert(
-            prompt.tolist(), [int(p) for p in self.table[slot, :n]]
+            tokens, self.table[slot, :n].tolist(), resume=resume
         )
 
     def invalidate_prefix(self) -> int:
@@ -845,21 +949,19 @@ class PagePool:
         self.counts.fill(0)
         self.refcount.fill(0)
         self._free = list(range(self.num_pages - 1, -1, -1))
+        self._walked.clear()
         if self.window_group is not None:
             self.window_group.reset()
         if self.prefix is not None:
             old = self.prefix
-            fresh = RadixPrefixCache(
-                self.page_size,
-                ref=self._ref,
-                unref=self._unref,
-                evictable=lambda p: int(self.refcount[p]) == 1,
-            )
+            fresh = self._new_prefix_cache()
             fresh.lookup_tokens = old.lookup_tokens
             fresh.hit_tokens = old.hit_tokens
             fresh.lookups = old.lookups
             fresh.hits = old.hits
             fresh.evicted_pages = old.evicted_pages
+            fresh.evict_nodes_examined = old.evict_nodes_examined
+            fresh.keyed_tokens = old.keyed_tokens
             fresh.invalidations = old.invalidations + 1
             self.prefix = fresh
 
@@ -913,6 +1015,8 @@ class PagePool:
                 prefix_hits=self.prefix.hits,
                 prefix_hit_rate=round(self.prefix.hit_rate, 4),
                 prefix_evicted_pages=self.prefix.evicted_pages,
+                prefix_evict_nodes_examined=self.prefix.evict_nodes_examined,
+                prefix_keyed_tokens=self.prefix.keyed_tokens,
                 prefix_invalidations=self.prefix.invalidations,
             )
         return out

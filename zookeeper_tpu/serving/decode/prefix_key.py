@@ -13,25 +13,63 @@ consume:
   the chunking + match/insert walks, shared verbatim by the cache's
   ``lookup``/``insert`` and the router's :class:`PrefixIndex`, so the
   two CANNOT drift (the parity test in ``tests/serving/test_fleet.py``
-  pins predicted == actual on top).
+  pins predicted == actual on top). Both take the prompt as a list of
+  Python ints (:func:`token_list`, once a prompt) and key only the
+  chunks they descend through.
 - :class:`PrefixIndex` — the pageless mirror of the trie: the router
   keeps one per replica, ``observe()``-ing every prompt it routes
   there and ``match()``-ing candidate prompts to predict how many
   tokens that replica's REAL cache would serve warm.
 
-Any node object with ``.chunk`` (a token tuple) and ``.children``
-(a ``{chunk_tuple: node}`` dict) can ride the walks — the cache's
+Any node object with ``.chunk`` (a token tuple), ``.children`` (a
+``{chunk_tuple: node}`` dict) and ``.heads`` (the same children by
+their chunk's first token, ``{token: {chunk_tuple: node}}``, kept by
+:func:`attach` / :func:`detach`) can ride the walks — the cache's
 page-holding nodes and the index's bare nodes both qualify.
 """
 
 from typing import Any, Callable, List, Sequence, Tuple
 
+import numpy as np
+
 __all__ = [
     "PrefixIndex",
+    "attach",
     "common_prefix",
+    "detach",
+    "token_list",
     "walk_insert",
     "walk_match",
 ]
+
+
+_NO_HEADS: dict = {}
+
+
+def token_list(tokens: Sequence[int]) -> List[int]:
+    """``tokens`` as the list of Python ints the walks key on: a list
+    is taken as it is, anything else (an int32 / int64 array, a tuple)
+    goes through ``np.asarray(...).tolist()``, which yields Python
+    ints without a conversion an element."""
+    if isinstance(tokens, list):
+        return tokens
+    return np.asarray(tokens).tolist()
+
+
+def attach(parent: Any, child: Any) -> None:
+    """Hang ``child`` under ``parent`` by its chunk, in both maps."""
+    parent.children[child.chunk] = child
+    parent.heads.setdefault(child.chunk[0], {})[child.chunk] = child
+
+
+def detach(parent: Any, child: Any) -> None:
+    """Take ``child`` from under ``parent`` (the inverse of
+    :func:`attach`)."""
+    del parent.children[child.chunk]
+    same = parent.heads[child.chunk[0]]
+    del same[child.chunk]
+    if not same:
+        del parent.heads[child.chunk[0]]
 
 
 def common_prefix(a: Sequence[int], b: Sequence[int]) -> int:
@@ -46,10 +84,14 @@ def common_prefix(a: Sequence[int], b: Sequence[int]) -> int:
 def walk_match(root: Any, tokens: Sequence[int], page_size: int):
     """The ONE match walk (cache lookup == router prediction): exact
     full-``page_size``-chunk descents from ``root``, then the longest
-    common prefix against any child's chunk for the partial tail.
-    Returns ``(t, visited)`` — the first ``t`` tokens are covered by
-    the ``visited`` nodes in walk order (the last may cover ``t``
-    only partially — the cache's CoW case)."""
+    common prefix against any child's chunk for the partial tail (only
+    the children whose chunk starts with the next token can share any:
+    ``node.heads``; the first of the longest wins, in the order the
+    children were attached). Returns ``(t, visited)`` — the first ``t``
+    tokens are covered by the ``visited`` nodes in walk order (the last
+    may cover ``t`` only partially — the cache's CoW case; it is
+    partial exactly when ``t % page_size``). ``tokens``: a list of
+    ints (:func:`token_list`)."""
     ps = int(page_size)
     node = root
     visited: List[Any] = []
@@ -62,10 +104,10 @@ def walk_match(root: Any, tokens: Sequence[int], page_size: int):
         visited.append(child)
         t += ps
         node = child
-    rest = tokens[t:]
-    if rest:
+    if t < n:
+        rest = tokens[t:t + ps]  # no chunk is longer
         best, bestq = None, 0
-        for child in node.children.values():
+        for child in node.heads.get(rest[0], _NO_HEADS).values():
             q = common_prefix(child.chunk, rest)
             if q > bestq:
                 best, bestq = child, q
@@ -82,24 +124,28 @@ def walk_insert(
     make_node: Callable[[Tuple[int, ...], int, Any], Any],
     *,
     tail: bool = True,
+    start: int = 0,
 ):
     """The ONE insert walk: descend/create one node per FULL chunk
     (``make_node(chunk, chunk_index, parent)`` builds missing ones),
     plus the partial tail chunk when ``tail`` is set (the cache skips
-    it when it has no page covering those positions). Returns
-    ``[(node, created), ...]`` in walk order."""
+    it when it has no page covering those positions). ``start`` > 0
+    resumes where a match walk of the same ``tokens`` stopped:
+    ``root`` is then the node of chunk ``start - 1`` and chunks
+    ``start`` on are walked (and keyed) alone. Returns ``[(node,
+    created), ...]`` in walk order. ``tokens``: a list of ints
+    (:func:`token_list`)."""
     ps = int(page_size)
-    tokens = [int(x) for x in tokens]
     node = root
     out: List[Tuple[Any, bool]] = []
     n_full = len(tokens) // ps
-    for i in range(n_full):
+    for i in range(start, n_full):
         chunk = tuple(tokens[i * ps:(i + 1) * ps])
         child = node.children.get(chunk)
         created = child is None
         if created:
             child = make_node(chunk, i, node)
-            node.children[chunk] = child
+            attach(node, child)
         out.append((child, created))
         node = child
     rest = tuple(tokens[n_full * ps:])
@@ -108,17 +154,18 @@ def walk_insert(
         created = child is None
         if created:
             child = make_node(rest, n_full, node)
-            node.children[rest] = child
+            attach(node, child)
         out.append((child, created))
     return out
 
 
 class _IndexNode:
-    __slots__ = ("chunk", "children")
+    __slots__ = ("chunk", "children", "heads")
 
     def __init__(self, chunk: Tuple[int, ...]) -> None:
         self.chunk = chunk
         self.children = {}
+        self.heads = {}
 
 
 class PrefixIndex:
@@ -157,7 +204,7 @@ class PrefixIndex:
             1
             for _, was_created in walk_insert(
                 self._root,
-                tokens,
+                token_list(tokens),
                 self.page_size,
                 lambda chunk, i, parent: _IndexNode(chunk),
             )
@@ -172,8 +219,7 @@ class PrefixIndex:
     def match(self, tokens: Sequence[int]) -> int:
         """Predicted warm match length for ``tokens`` — the ``t`` the
         replica's real ``RadixPrefixCache.lookup`` would return."""
-        tokens = [int(x) for x in tokens]
-        t, _ = walk_match(self._root, tokens, self.page_size)
+        t, _ = walk_match(self._root, token_list(tokens), self.page_size)
         return t
 
     def predict(self, tokens: Sequence[int]) -> int:

@@ -73,7 +73,7 @@ import logging
 import threading
 import time
 from collections import deque
-from typing import Any, List, NamedTuple, Optional
+from typing import Any, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -434,6 +434,9 @@ class DecodeScheduler:
         # tokens decoded for a slot whose stream had gone.
         object.__setattr__(self, "_unread", None)
         object.__setattr__(self, "_pipeline", {"in_flight": 0, "dropped": 0})
+        # The prefix cache's work counters as the last iteration left
+        # them (``kv_prefix_work`` reports what moved since).
+        object.__setattr__(self, "_prefix_work_seen", self._prefix_work())
         return self
 
     def _require_bound(self) -> None:
@@ -1716,6 +1719,35 @@ class DecodeScheduler:
                 attrs={"full": now[0] - seen[0], "window": now[1] - seen[1]},
             )
 
+    def _note_prefix_work(self) -> None:
+        """While tracing, one ``kv_prefix_work`` event an iteration in
+        which the prefix cache's bookkeeping moved (docs/DESIGN.md §20):
+        pages it evicted, eviction candidates it examined and prompt
+        tokens it keyed since the last iteration. A no-op without a
+        prefix cache."""
+        now = self._prefix_work()
+        seen = self._prefix_work_seen
+        object.__setattr__(self, "_prefix_work_seen", now)
+        if now != seen and _trace.enabled():
+            _trace.event(
+                "kv_prefix_work",
+                attrs={
+                    "pages_evicted": now[0] - seen[0],
+                    "nodes_examined": now[1] - seen[1],
+                    "tokens_keyed": now[2] - seen[2],
+                },
+            )
+
+    def _prefix_work(self) -> Tuple[int, int, int]:
+        cache = self._engine.page_pool.prefix
+        if cache is None:
+            return (0, 0, 0)
+        return (
+            cache.evicted_pages,
+            cache.evict_nodes_examined,
+            cache.keyed_tokens,
+        )
+
     def _update_occupancy(self) -> None:
         if self._metrics is None:
             return
@@ -1794,6 +1826,7 @@ class DecodeScheduler:
             # remainder (docs/DESIGN.md §25). No-op when chunking off.
             chunks = self._prefill_chunks(spent)
             self._release_behind_window()
+            self._note_prefix_work()
             with self._lock:
                 self._maybe_apply_swap()  # slot array may have drained
             with _trace.span("sched_bookkeeping"):
